@@ -92,6 +92,14 @@ def _build_task(entrypoint, name, workdir, infra, gpus, cpus, memory,
         overrides['use_spot'] = use_spot
     if overrides:
         task.set_resources({r.copy(**overrides) for r in task.resources})
+    # A Local-cloud job shares this machine's filesystem but runs from
+    # a synced copy whose path carries the cluster name, so the
+    # in-checkout default compile cache (utils/compile_cache.py) would
+    # move with it and never hit: hand the job the launcher's.
+    cache_dir = os.environ.get('JAX_COMPILATION_CACHE_DIR')
+    if cache_dir and 'JAX_COMPILATION_CACHE_DIR' not in task.envs and \
+            all(str(r.cloud) == 'Local' for r in task.resources):
+        task.update_envs({'JAX_COMPILATION_CACHE_DIR': cache_dir})
     return task
 
 

@@ -4,8 +4,10 @@ Training input pipeline for the recipe models: binary token shards
 (nanoGPT-style .bin of uint16/uint32) → [batch, seq+1] uint32 arrays,
 deterministic per (seed, step, rank) so data-parallel hosts draw
 disjoint streams. The native core (native/token_loader.cpp) mmaps
-shards and prefetches on background threads; a pure-numpy fallback
-keeps everything working where the .so is not built.
+shards and prefetches on background threads. The .so is a build
+product, not a tracked file: it is built with `make` on first use
+(g++ and make are part of the installation); a pure-numpy fallback
+keeps everything working where no toolchain exists.
 """
 from __future__ import annotations
 
@@ -32,7 +34,9 @@ def _build_native(force: bool = False) -> bool:
     if not os.path.exists(os.path.join(_NATIVE_DIR, 'token_loader.cpp')):
         return False
     try:
-        cmd = ['make', '-C', _NATIVE_DIR]
+        # Only this target: the Makefile's default also builds the
+        # FUSE shims, which need nothing to do with the loader.
+        cmd = ['make', '-C', _NATIVE_DIR, 'libtoken_loader.so']
         if force:
             cmd.insert(1, '-B')
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)

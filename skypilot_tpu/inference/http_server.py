@@ -43,6 +43,7 @@ from skypilot_tpu.observability import REGISTRY
 from skypilot_tpu.observability import catalog as obs_catalog
 from skypilot_tpu.observability import tracing
 from skypilot_tpu.ops import pallas_paged as _pallas_paged
+from skypilot_tpu.parallel import mesh as _mesh_lib
 from skypilot_tpu.robustness import faults
 from skypilot_tpu.robustness import train_guard
 from skypilot_tpu.robustness.errors import (AdapterLoadError,
@@ -178,6 +179,9 @@ def make_server(rt: InferenceRuntime,
             if self.path == '/debug/flight':
                 self._debug_flight()
                 return
+            if self.path == '/debug/pool_collectives':
+                self._debug_pool_collectives()
+                return
             if self.path == '/v1/models':
                 # OpenAI client bootstrap: most SDKs list models
                 # before first use. Adapters are models: the `model`
@@ -243,6 +247,22 @@ def make_server(rt: InferenceRuntime,
                             for eng in rt.live_engines()],
             })
 
+        def _debug_pool_collectives(self):
+            """The sharded-pool guard on the decode dispatch as
+            compiled HERE (docs/guides.md "Sharded serving"): `lines`
+            lists HLO collectives that move a pool-shaped operand —
+            empty means a tensor-sharded pool is never gathered.
+            Compiles (or reads the compile cache), so it is a
+            bring-up check, not a scrape target."""
+            try:
+                lines = (rt.engine.decode_pool_collectives()
+                         if rt.engine is not None else None)
+            except Exception as e:  # pylint: disable=broad-except
+                self._plain_error(e)
+                return
+            self._json({'mesh_devices': rt.mesh_devices,
+                        'stages': rt.stages, 'lines': lines})
+
         def _prometheus_metrics(self):
             """Prometheus text exposition of the process registry.
             Snapshot gauges (queue depth, slot occupancy, page pool)
@@ -291,7 +311,10 @@ def make_server(rt: InferenceRuntime,
                         # when it can run (interpret mode always can).
                         'attention_kernel_unavailable_reason':
                             _pallas_paged.unavailable_reason(),
-                    }}
+                    },
+                    # Per-device bytes in use / peak, from the process
+                    # that holds the chips (nulls off-accelerator).
+                    'device_memory': _mesh_lib.device_memory()}
             if rt.role or rt.handoffs_total or rt.kv_imports_total:
                 body['handoff'] = rt.handoff_stats()
             mig = rt.migration_stats()
@@ -349,6 +372,12 @@ def make_server(rt: InferenceRuntime,
                 'requests_shed': engine.requests_shed,
                 'deadline_exceeded': engine.deadline_exceeded,
                 'engine_restarts': engine.engine_restarts,
+                # Errors the scheduler contained without a restart
+                # (failed requests on a server that stayed up).
+                'soft_errors': engine.soft_errors_total,
+                # Which cache layout runs, and why ('paged: ...' /
+                # 'dense: ...').
+                'kv_cache': engine.kv_cache_choice,
                 'queued_tokens': engine.queued_tokens(),
                 'max_queue_requests': engine.max_queue_requests,
                 'max_queue_tokens': engine.max_queue_tokens,
@@ -1123,11 +1152,9 @@ def make_server(rt: InferenceRuntime,
         def _openai_completions(self):
             try:
                 body = self._read_body()
-                prompts = body.get('prompt', '')
-                if isinstance(prompts, str):
-                    prompts = [prompts]
                 req = oai.CompletionRequest(
-                    prompts=prompts,
+                    prompts=oai.normalize_prompts(
+                        body.get('prompt', '')),
                     max_new=int(body.get('max_tokens', 16)),
                     temperature=float(body.get('temperature', 1.0)),
                     top_p=float(body.get('top_p', 1.0)),

@@ -24,7 +24,63 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 from skypilot_tpu.inference.runtime import (InferenceRuntime,
+                                            NoTokenizerError,
                                             iter_interleaved)
+
+
+class TokenIdText:
+    """Stands in for the tokenizer on /v1/completions when the model
+    has none (a registry model with seeded random weights): text IS
+    whitespace-separated decimal token ids, both ways. With it the
+    OpenAI surface — streaming, `logprobs`, `echo` scoring — can be
+    driven against any model the server can load, not only --hf
+    checkpoints that ship tokenizer files."""
+
+    def __call__(self, text: str) -> Dict[str, List[int]]:
+        try:
+            return {'input_ids': [int(t) for t in text.split()]}
+        except ValueError:
+            raise ValueError(
+                'this model has no tokenizer: send `prompt` as an '
+                'array of token ids (or as decimal ids separated by '
+                'spaces); text prompts need a --hf checkpoint with '
+                'tokenizer files') from None
+
+    def decode(self, ids, skip_special_tokens: bool = False) -> str:
+        del skip_special_tokens
+        return ''.join(f' {int(i)}' for i in ids)
+
+
+def completion_tokenizer(rt: InferenceRuntime):
+    try:
+        return rt.get_tokenizer()
+    except NoTokenizerError:
+        return TokenIdText()
+
+
+def encode_prompt(rt: InferenceRuntime, tok, prompt) -> List[int]:
+    """Token ids for one `prompt` entry: a string goes through the
+    tokenizer; an array of token ids (the OpenAI contract allows
+    both) is taken as is, after a range check."""
+    if isinstance(prompt, str):
+        return tok(prompt)['input_ids']
+    ids = [int(t) for t in prompt]
+    bad = [t for t in ids if not 0 <= t < rt.vocab_size]
+    if bad:
+        raise ValueError(f'token ids {bad[:4]} outside the vocabulary '
+                         f'[0, {rt.vocab_size})')
+    return ids
+
+
+def normalize_prompts(prompt) -> list:
+    """The four shapes OpenAI accepts for `prompt` -> a list of
+    prompts, each a string or a list of token ids."""
+    if isinstance(prompt, str):
+        return [prompt]
+    if isinstance(prompt, list) and prompt and \
+            all(isinstance(t, int) for t in prompt):
+        return [prompt]
+    return list(prompt)
 
 
 class IncrementalDecoder:
@@ -127,7 +183,7 @@ def trim_stops(text: str, stops: List[str]) -> Tuple[str, bool]:
 class CompletionRequest:
     """Validated, normalized body shared by both /v1 endpoints."""
 
-    def __init__(self, prompts: List[str], max_new: int,
+    def __init__(self, prompts: list, max_new: int,
                  temperature: float, top_p: float,
                  stop_strings, n: int, stream: bool,
                  logprobs: Optional[int] = None,
@@ -215,9 +271,9 @@ def run_completion(rt: InferenceRuntime, req: CompletionRequest
     """Non-streaming completions: returns the OpenAI response dict.
     Each prompt yields `n` choices (indices p*n..p*n+n-1, the OpenAI
     layout for multi-prompt + n)."""
-    tok = rt.get_tokenizer()
+    tok = completion_tokenizer(rt)
     t0 = time.monotonic()
-    encoded = [tok(p)['input_ids'] for p in req.prompts]
+    encoded = [encode_prompt(rt, tok, p) for p in req.prompts]
     limit = rt.limit_for(req.temperature)
     for ids in encoded:
         if len(ids) >= limit:
@@ -326,8 +382,8 @@ def stream_completion(rt: InferenceRuntime, req: CompletionRequest,
     CONCURRENTLY (engine slots); their chunks interleave by arrival,
     each tagged with its choice index. Ends with per-choice
     finish_reason chunks and `data: [DONE]`."""
-    tok = rt.get_tokenizer()
-    ids = tok(req.prompts[0])['input_ids']
+    tok = completion_tokenizer(rt)
+    ids = encode_prompt(rt, tok, req.prompts[0])
     limit = rt.limit_for(req.temperature, streaming=True)
     if len(ids) >= limit:
         raise ValueError(f'prompt tokenizes to {len(ids)} >= '

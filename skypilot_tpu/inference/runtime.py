@@ -19,6 +19,11 @@ from concurrent.futures import Future
 from typing import Dict, List, Optional, Tuple
 
 
+class NoTokenizerError(ValueError):
+    """The served model has no tokenizer (a registry model; --hf
+    checkpoints bring theirs)."""
+
+
 class ServingMetrics:
     """Request metrics, thread-safe, dual-exported:
 
@@ -561,7 +566,7 @@ class InferenceRuntime:
         with self._tok_lock:
             if 'tok' not in self._tok_holder:
                 if self.tokenizer_dir is None:
-                    raise ValueError(
+                    raise NoTokenizerError(
                         'no tokenizer available: text endpoints need '
                         'a --hf checkpoint with tokenizer files; use '
                         '/generate with token ids instead')
@@ -739,22 +744,64 @@ class InferenceRuntime:
             self._stream_engine.stop()
 
 
+def _init_params(model):
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+    return nn.meta.unbox(model.init(
+        jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))['params'])
+
+
+def _init_params_on_host(model):
+    """Seeded random weights as a HOST tree (f32, the CPU device)."""
+    import jax
+    with jax.default_device(jax.devices('cpu')[0]):
+        return _init_params(model)
+
+
+def _init_params_placed(model, mesh, dtype):
+    """Seeded random weights created where they are served: under one
+    jit whose outputs carry the serving shardings (the model's logical
+    axes over `mesh`; a single device without one) and the serving
+    dtype, so neither the f32 tree nor an unsharded copy of any leaf
+    is ever resident. The threefry RNG is partitionable: each chip
+    draws only its own shard."""
+    import jax
+    shardings = None
+    if mesh is not None:
+        from skypilot_tpu.parallel.serving import serving_param_shardings
+        shardings = serving_param_shardings(model, mesh)
+    return jax.jit(
+        lambda: jax.tree.map(lambda x: x.astype(dtype),
+                             _init_params(model)),
+        out_shardings=shardings)()
+
+
 def build_runtime(args) -> InferenceRuntime:
     """Construct the runtime from serve_lm CLI args: load the model
     (registry or HF checkpoint), place params (TP-sharded over the
     mesh or single-device, bf16 by default), restore a checkpoint if
     given, and build the continuous engine when enabled."""
-    import flax.linen as nn
     import jax
     if args.cpu:
         jax.config.update('jax_platforms', 'cpu')
     import jax.numpy as jnp
 
     from skypilot_tpu.recipes.train_lm import _build_model
+    from skypilot_tpu.utils import compile_cache
+    print(f'compile cache: {compile_cache.configure()}', flush=True)
 
+    # The dtype weights are SERVED at, for both sources (--hf and the
+    # registry's seeded random weights): bf16 unless --param-dtype
+    # f32. Compute runs in bf16 either way; f32 storage only doubles
+    # every decode step's weight traffic and the HBM the weights take.
+    import ml_dtypes
+    import numpy as _np
+    param_dtype = getattr(args, 'param_dtype', 'bf16') or 'bf16'
+    serve_cast = (ml_dtypes.bfloat16 if param_dtype == 'bf16'
+                  else _np.float32)
     tokenizer_dir = None
     hf_params = None
-    serve_cast = None
     if args.hf:
         from skypilot_tpu.models import hf_import
         model, hf_params = hf_import.load_hf_checkpoint(
@@ -762,10 +809,6 @@ def build_runtime(args) -> InferenceRuntime:
         # Raw f32 numpy here; the cast (bf16 via ml_dtypes) happens
         # PER LEAF at placement time below — host transient is one
         # leaf, device footprint is the bf16 shards.
-        import ml_dtypes
-        import numpy as _np
-        serve_cast = (ml_dtypes.bfloat16 if args.param_dtype == 'bf16'
-                      else _np.float32)
         vocab_size = model.config.vocab_size
         print(f'loaded HF checkpoint from {args.hf} '
               f'({type(model).__name__}, vocab={vocab_size})',
@@ -854,12 +897,39 @@ def build_runtime(args) -> InferenceRuntime:
                   f'below max_seq_len={model.config.max_seq_len})',
                   flush=True)
 
+    # The serving mesh first: registry weights are created ON it.
+    mesh = None
+    num_stages = int(getattr(args, 'stages', 1) or 1)
+    if num_stages > 1:
+        if weight_dtype == 'int8':
+            raise SystemExit(
+                '--stages does not compose with --weight-dtype int8 '
+                '(the quantized wrapper has no per-stage split)')
+        from skypilot_tpu.parallel import mesh as mesh_lib
+        mesh = mesh_lib.make_mesh(
+            mesh_lib.MeshConfig(stage=num_stages, tensor=args.tensor),
+            devices=jax.devices()[:num_stages * args.tensor])
+    elif args.tensor > 1:
+        from skypilot_tpu.parallel import mesh as mesh_lib
+        mesh = mesh_lib.make_mesh(
+            mesh_lib.MeshConfig(tensor=args.tensor),
+            devices=jax.devices()[:args.tensor])
+    if weight_dtype not in ('bf16', 'int8'):
+        raise SystemExit(f'unsupported --weight-dtype {weight_dtype}')
+
+    placed = False
     if hf_params is not None:
         params = hf_params
+    elif weight_dtype == 'int8' or num_stages > 1:
+        # Host-side consumers below (per-channel quantization, the
+        # per-stage split) take a host tree, like --hf hands them.
+        params = _init_params_on_host(model)
     else:
-        params = nn.meta.unbox(model.init(
-            jax.random.PRNGKey(0),
-            jnp.ones((1, 8), jnp.int32))['params'])
+        # Already sharded, already in the serving dtype: the whole
+        # tree never exists on one chip (llama3-8b is 32 GB in f32
+        # against 16 GB of HBM).
+        params = _init_params_placed(model, mesh, serve_cast)
+        placed = True
     # int8 projection weights: quantize HOST-SIDE from the f32/bf16
     # tree, then wrap the model so every jitted serving fn
     # dequantizes on read (inference/quant.py).
@@ -879,23 +949,11 @@ def build_runtime(args) -> InferenceRuntime:
         model = quant_lib.QuantizedModel(model)
         print('weights: int8 per-output-channel projections '
               '(dequant-on-read)', flush=True)
-    elif weight_dtype != 'bf16':
-        raise SystemExit(f'unsupported --weight-dtype {weight_dtype}')
-    # ONE placement block for both param sources: TP-shard over the
-    # mesh (per-leaf cast, shard-only transfers), stage×tensor split,
-    # or single-device.
-    mesh = None
-    num_stages = int(getattr(args, 'stages', 1) or 1)
+    # ONE placement block for every host tree: TP-shard over the mesh
+    # (per-leaf cast, shard-only transfers), stage×tensor split, or
+    # single-device.
     if num_stages > 1:
-        if weight_dtype == 'int8':
-            raise SystemExit(
-                '--stages does not compose with --weight-dtype int8 '
-                '(the quantized wrapper has no per-stage split)')
-        from skypilot_tpu.parallel import mesh as mesh_lib
         from skypilot_tpu.parallel.serving import build_staged_serving
-        mesh = mesh_lib.make_mesh(
-            mesh_lib.MeshConfig(stage=num_stages, tensor=args.tensor),
-            devices=jax.devices()[:num_stages * args.tensor])
         # Place per stage HERE (per-leaf cast, shard-only transfers
         # onto each stage's tensor submesh) and hand the engine the
         # re-merged tree: stage key sets are disjoint top-level
@@ -910,36 +968,28 @@ def build_runtime(args) -> InferenceRuntime:
               f'{args.tensor}-way tensor over '
               f'{num_stages * args.tensor} devices', flush=True)
     elif args.tensor > 1:
-        from skypilot_tpu.parallel import mesh as mesh_lib
-        mesh = mesh_lib.make_mesh(
-            mesh_lib.MeshConfig(tensor=args.tensor),
-            devices=jax.devices()[:args.tensor])
         if weight_dtype == 'int8':
             params = quant_lib.shard_quantized_for_serving(
                 model, params, mesh, dtype=serve_cast)
-        else:
+        elif not placed:
             from skypilot_tpu.parallel.serving import \
                 shard_params_for_serving
             params = shard_params_for_serving(model, params, mesh,
                                               dtype=serve_cast)
-        print(f'tensor-parallel serving over {args.tensor} devices',
-              flush=True)
+        print(f'tensor-parallel serving over {args.tensor} devices: '
+              f'{mesh_lib.mesh_summary(mesh)}', flush=True)
     elif weight_dtype == 'int8':
         # Quantized leaves keep their int8/f32 dtypes; serve_cast
         # applies to the surviving dense leaves (embeddings, norms,
         # head) exactly as the bf16 path does.
-        import numpy as _np
-
         def _place(x):
             x = _np.asarray(x)
-            if serve_cast is not None and x.dtype == _np.float32 \
-                    and x.ndim > 1:
+            if x.dtype == _np.float32 and x.ndim > 1:
                 x = x.astype(serve_cast)
             return jnp.asarray(x)
 
         params = jax.tree.map(_place, params)
-    elif serve_cast is not None:
-        import numpy as _np
+    elif not placed:
         params = jax.tree.map(
             lambda x: jnp.asarray(_np.asarray(x).astype(serve_cast)),
             params)
@@ -1053,13 +1103,17 @@ def build_runtime(args) -> InferenceRuntime:
         max_queue_requests=max_queue_requests,
         max_queue_tokens=max_queue_tokens,
         adapters=adapters,
-        kv_dtype=kv_dtype, weight_dtype=weight_dtype,
+        # What the weights ARE, not what a flag defaulted to: int8
+        # projections, else the dtype they were placed in.
+        kv_dtype=kv_dtype,
+        weight_dtype=('int8' if weight_dtype == 'int8'
+                      else param_dtype),
         role=role, decode_peers=decode_peers, mesh=mesh)
     from skypilot_tpu.observability import catalog as _obs_catalog
     _obs_catalog.gauge('skypilot_serving_weight_bytes').set(
         rt.weight_bytes)
     _obs_catalog.gauge('skypilot_serving_storage_info').labels(
-        kv_dtype=kv_dtype, weight_dtype=weight_dtype).set(1)
+        kv_dtype=kv_dtype, weight_dtype=rt.weight_dtype).set(1)
     # Distributed tracing: head-sample at the configured rate; the
     # process tag makes this node's spans a distinct pid row in the
     # merged Chrome trace.

@@ -36,6 +36,7 @@ HTTP server in recipes/serve_lm.py (--continuous-batching).
 from __future__ import annotations
 
 import collections
+import contextlib
 import functools
 import itertools
 import queue
@@ -285,7 +286,7 @@ class ContinuousBatchingEngine:
         'kv_restore_lookups': 'scheduler',
         'kv_restore_hits': 'scheduler',
         'deadline_exceeded': 'scheduler', 'engine_restarts': 'scheduler',
-        '_soft_errors': 'scheduler',
+        '_soft_errors': 'scheduler', 'soft_errors_total': 'scheduler',
         # live-migration counters (PR 20): evacuated sessions and the
         # subset that shipped a packed KV chain with them
         'sessions_evacuated': 'scheduler',
@@ -342,11 +343,11 @@ class ContinuousBatchingEngine:
         # dispatch (the serving analog of the trainer's multi-step) —
         # outputs are BIT-IDENTICAL to step-by-step because the rng
         # split chain is the same, and post-limit/post-eos junk writes
-        # follow the speculative write-before-read contract. Pays on
-        # dispatch-overhead-bound hosts (TPU-over-relay: ~100ms per
-        # dispatch vs ~ms of decode compute); costs up to N-1 wasted
-        # steps per finishing request and batches admission at chunk
-        # boundaries. Mutually exclusive with speculation (verify
+        # follow the speculative write-before-read contract. Pays
+        # where per-dispatch host overhead dominates the decode step
+        # (on the chip: not measured, ROADMAP S3a); costs up to N-1
+        # wasted steps per finishing request and batches admission at
+        # chunk boundaries. Mutually exclusive with speculation (verify
         # chunks already amortize dispatches).
         assert decode_chunk >= 1
         assert not (decode_chunk > 1 and speculative_k), (
@@ -445,6 +446,26 @@ class ContinuousBatchingEngine:
                 f'usable {(max(cfg_pool - 1, 0)) * cfg_page} tokens; '
                 f'page 0 is reserved).')
         self.paged = paged
+        # Which cache layout this engine runs, and why — said out
+        # loud (and in /stats `kv_cache`): a default pool too small
+        # for one sequence used to select the dense cache silently.
+        usable = max(cfg_pool - 1, 0) * cfg_page
+        if paged:
+            self.kv_cache_choice = (
+                f'paged: pool of {cfg_pool} pages x {cfg_page} tokens '
+                f'holds max_total_len={max_total_len} '
+                f'(+{self._write_lookahead} write headroom)')
+        elif cfg_page > 0 and cfg_pool > 0:
+            self.kv_cache_choice = (
+                f'dense: the page pool ({cfg_pool} pages x {cfg_page} '
+                f'tokens, {usable} usable) cannot hold one '
+                f'max_total_len={max_total_len} sequence '
+                f'(+{self._write_lookahead} write headroom); size it '
+                f'with serve_lm --kv-pool-bytes to get the paged pool')
+        else:
+            self.kv_cache_choice = (
+                f'dense: {type(model.config).__name__} declares no '
+                f'kv_page_size/kv_total_pages')
         # KV storage format (models/llama.py LlamaConfig.kv_dtype):
         # int8 pages + parallel scale arrays. Quantization lives
         # entirely inside the model's cache variables and the
@@ -545,6 +566,8 @@ class ContinuousBatchingEngine:
         # by engine instance; counters tick at the event sites below,
         # gauges refresh in update_metric_gauges() at scrape time.
         self.engine_id = str(next(self._instance_ids))
+        print(f'engine {self.engine_id}: KV cache = '
+              f'{self.kv_cache_choice}', flush=True)
         self.metrics = _obs.EngineMetrics(self.engine_id)
         self.metrics.num_slots.set(num_slots)
         self._weight_bytes: Optional[int] = None  # lazy (roofline)
@@ -629,6 +652,11 @@ class ContinuousBatchingEngine:
         self.deadline_exceeded = 0
         self.engine_restarts = 0
         self._soft_errors = 0       # consecutive cache-intact errors
+        # Every error the scheduler contained, lifetime (/stats
+        # `soft_errors`): a kernel the compiler refuses inside a
+        # dispatch becomes failed requests on a server that stays up
+        # and exits 0, so a health check reads this, not the rc.
+        self.soft_errors_total = 0
         # Crash-only: a dead scheduler thread flips this instead of
         # hanging clients (submit fails fast; /readyz reports 503).
         self._dead = threading.Event()
@@ -707,7 +735,6 @@ class ContinuousBatchingEngine:
         """Zeroed KV cache for the slot pool. Also the recovery path:
         prefill/decode DONATE the cache buffer, so after a failed
         device execution the old buffer is gone and must be rebuilt."""
-        import flax.linen as nn
         if self.stages > 1:
             return self._fresh_staged_cache()
         kwargs = {}
@@ -715,24 +742,39 @@ class ContinuousBatchingEngine:
             self._reset_paging()
             kwargs['page_indices'] = jnp.zeros(
                 (self.num_slots, self.pages_per_seq), jnp.int32)
-        cache = self.model.init(
-            jax.random.PRNGKey(0),
-            jnp.zeros((self.num_slots, 1), jnp.int32),
-            positions=jnp.zeros((self.num_slots, 1), jnp.int32),
-            decode=True, **kwargs)['cache']
-        # init *ran* a step; zero it (same contract as generate.py).
-        cache = jax.tree.map(jnp.zeros_like, nn.meta.unbox(cache))
-        if self.mesh is not None:
+        shapes = self._cache_shapes(
+            self.model, jnp.zeros((self.num_slots, 1), jnp.int32),
+            kwargs)
+        if self.mesh is not None and self._cache_shardings is None:
             # Explicit placement: the pool starts on its declared
             # shardings and every dispatch's out_shardings keeps the
             # donated buffer there — the layout survives resets too.
             from skypilot_tpu.parallel import serving as _tp_serving
-            if self._cache_shardings is None:
-                self._cache_shardings = \
-                    _tp_serving.serving_cache_shardings(cache,
-                                                        self.mesh)
-            cache = jax.device_put(cache, self._cache_shardings)
-        return cache
+            self._cache_shardings = \
+                _tp_serving.serving_cache_shardings(shapes, self.mesh)
+        return self._zeros(shapes, self._cache_shardings)
+
+    def _cache_shapes(self, model, x, kwargs):
+        """The model's cache collection as shapes only. `model.init`
+        would also run the forward pass and materialize every
+        PARAMETER (f32, on the default device) just to be thrown
+        away — 32 GB for an 8B model, on one 16 GB chip."""
+        import flax.linen as nn
+        return nn.meta.unbox(jax.eval_shape(
+            lambda: model.init(
+                jax.random.PRNGKey(0), x,
+                positions=jnp.zeros((self.num_slots, 1), jnp.int32),
+                decode=True, **kwargs)['cache']))
+
+    @staticmethod
+    def _zeros(shapes, shardings):
+        """Zeroed arrays for `shapes`, created ON their shardings (a
+        sharded pool is never whole on one chip, not even at birth)."""
+        make = lambda: jax.tree.map(  # noqa: E731
+            lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+        if shardings is None:
+            return make()
+        return jax.jit(make, out_shardings=shardings)()
 
     def _pin_cache_out(self, *tail, stage=None):
         """jit kwargs pinning a dispatch's donated-cache OUTPUT to
@@ -764,7 +806,6 @@ class ContinuousBatchingEngine:
         pool split that lets an S-stage T-way mesh hold ~S·T x the
         pages at fixed per-chip HBM. Within a stage the placement is
         exactly the PR 15 tensor-parallel layout on the submesh."""
-        import flax.linen as nn
         from skypilot_tpu.parallel import serving as _tp_serving
         self._reset_paging()
         cfg = self.model.config
@@ -778,17 +819,12 @@ class ContinuousBatchingEngine:
             x = (jnp.zeros((self.num_slots, 1), jnp.int32) if s == 0
                  else jnp.zeros((self.num_slots, 1, cfg.embed_dim),
                                 cfg.dtype))
-            cache = sm.init(
-                jax.random.PRNGKey(0), x,
-                positions=jnp.zeros((self.num_slots, 1), jnp.int32),
-                decode=True, **page_kw)['cache']
-            cache = jax.tree.map(jnp.zeros_like, nn.meta.unbox(cache))
+            shapes = self._cache_shapes(sm, x, page_kw)
             if first_shardings:
                 self._cache_shardings.append(
                     _tp_serving.serving_cache_shardings(
-                        cache, self._stage_submeshes[s]))
-            caches.append(jax.device_put(cache,
-                                         self._cache_shardings[s]))
+                        shapes, self._stage_submeshes[s]))
+            caches.append(self._zeros(shapes, self._cache_shardings[s]))
         return caches
 
     def _stage_decode_fn(self, s: int):
@@ -869,15 +905,16 @@ class ContinuousBatchingEngine:
             for s in range(self.stages):
                 x = jax.device_put(x, self._stage_replicated[s])
                 fn = self._stage_decode_fn(s)
-                if s < self.stages - 1:
-                    new_cache, x = fn(params[s], cache[s], x,
-                                      positions, page_indices,
-                                      **lora_kw)
-                else:
-                    new_cache, out = fn(params[s], cache[s], x,
-                                        positions, temps, top_ks,
-                                        top_ps, rng, page_indices,
-                                        **lora_kw)
+                with self._stage_submeshes[s]:
+                    if s < self.stages - 1:
+                        new_cache, x = fn(params[s], cache[s], x,
+                                          positions, page_indices,
+                                          **lora_kw)
+                    else:
+                        new_cache, out = fn(params[s], cache[s], x,
+                                            positions, temps, top_ks,
+                                            top_ps, rng, page_indices,
+                                            **lora_kw)
                 caches.append(new_cache)
             return caches, out
 
@@ -962,13 +999,15 @@ class ContinuousBatchingEngine:
             for s in range(self.stages):
                 x = jax.device_put(x, self._stage_replicated[s])
                 fn = self._stage_prefill_fn(s, bucket_len, fresh)
-                if s < self.stages - 1:
-                    new_cache, x = fn(params[s], cache[s], x,
-                                      positions, page_row, **lora_kw)
-                else:
-                    new_cache, last = fn(params[s], cache[s], x,
-                                         positions, plen, page_row,
-                                         **lora_kw)
+                with self._stage_submeshes[s]:
+                    if s < self.stages - 1:
+                        new_cache, x = fn(params[s], cache[s], x,
+                                          positions, page_row,
+                                          **lora_kw)
+                    else:
+                        new_cache, last = fn(params[s], cache[s], x,
+                                             positions, plen, page_row,
+                                             **lora_kw)
                 caches.append(new_cache)
             return caches, last
 
@@ -1505,6 +1544,40 @@ class ContinuousBatchingEngine:
         from skypilot_tpu.ops import pallas_paged
         return pallas_paged.resolve_impl(
             'auto', quantized=self.kv_dtype == 'int8')
+
+    def decode_pool_collectives(self) -> Optional[List[str]]:
+        """The zero-resharding guard (parallel/serving
+        .pool_collective_lines) run on THIS engine's decode dispatch
+        as compiled for the backend it serves on: HLO lines where an
+        all-gather / all-to-all touches a pool-shaped operand
+        ([] = the sharded pool stays put). None for staged engines
+        (their per-stage dispatches are guarded in test_pp_serving).
+        Lowers on the scheduler thread — same mesh context, same
+        route selection as the live dispatch; with the persistent
+        compile cache on, the compile is a cache read."""
+        if self.stages > 1:
+            return None
+        if self.mesh is None:
+            return []
+        from skypilot_tpu.parallel import serving as _tp_serving
+
+        def op():
+            n = self.num_slots
+            args = [self.params, self.cache,
+                    jnp.zeros((n, self.spec_k + 1) if self.spec_k
+                              else (n,), jnp.int32),
+                    jnp.zeros((n,), jnp.int32),
+                    jnp.zeros((n,), jnp.float32),
+                    jnp.zeros((n,), jnp.int32),
+                    jnp.ones((n,), jnp.float32),
+                    jax.random.PRNGKey(0)]
+            if self.paged:
+                args.append(jnp.asarray(self.page_table))
+            compiled = self._decode.lower(*args).compile()
+            return _tp_serving.pool_collective_lines(
+                compiled, self.cache, self.mesh)
+
+        return self.run_on_scheduler(op, timeout=1800.0)
 
     def attention_bytes_per_token(self) -> Dict[str, Any]:
         """Analytic HBM bytes one decode step moves per generated
@@ -2084,12 +2157,19 @@ class ContinuousBatchingEngine:
         EngineDeadError immediately instead of hanging on a silently
         absent scheduler (and /readyz reports 503)."""
         try:
-            while not self._stop.is_set():
-                try:
-                    self._iterate()
-                    self._soft_errors = 0
-                except Exception as e:  # pylint: disable=broad-except
-                    self._recover_from_error(e)
+            # Every dispatch traces on this thread, inside the mesh
+            # context: that is where the paged-attention kernels look
+            # for the `tensor` axis to shard_map over
+            # (ops/pallas_paged.shard_over_kv_heads). Staged engines
+            # enter each stage's own submesh around its dispatch.
+            with (self.mesh if self.mesh is not None and self.stages == 1
+                  else contextlib.nullcontext()):
+                while not self._stop.is_set():
+                    try:
+                        self._iterate()
+                        self._soft_errors = 0
+                    except Exception as e:  # pylint: disable=broad-except
+                        self._recover_from_error(e)
         finally:
             if not self._stop.is_set():
                 self._dead.set()
@@ -2183,6 +2263,7 @@ class ContinuousBatchingEngine:
         import traceback
         traceback.print_exc()
         self._soft_errors += 1
+        self.soft_errors_total += 1
         victims = [s for s in range(self.num_slots)
                    if self.active[s] or self.prefilling[s]]
         self.flight.record('soft_error', error=type(e).__name__,
@@ -2641,6 +2722,7 @@ class ContinuousBatchingEngine:
                 print(f'engine {self.engine_id}: prefill chunk for '
                       f'slot {slot} failed ({type(e).__name__}: {e}); '
                       f'failing only that request', flush=True)
+                self.soft_errors_total += 1
                 self._fail_slot(slot, e)
                 continue
             self.metrics.prefill_chunk_seconds.observe(

@@ -9,10 +9,11 @@ is judged by. Records are flushed line-by-line so a preempted run's
 file is still valid JSONL up to the last completed window.
 
 MFU model: achieved = 6 * n_params * tokens/s (the standard dense-
-transformer train-FLOPs estimate, fwd+bwd); peak comes from
-SKYPILOT_DEVICE_PEAK_FLOPS (per device, bf16) or a small device-kind
-table. Unknown hardware (CPU smoke runs) reports mfu = null rather
-than a made-up number.
+transformer train-FLOPs estimate, fwd+bwd) over the device's peak
+from ONE table keyed by the `device_kind` the chip itself reports. A
+TPU whose kind is not in the table is an error — a guessed peak
+would put a made-up number under a device metric's name. Only a
+non-TPU backend (the CPU tests) reports mfu = null.
 """
 from __future__ import annotations
 
@@ -21,33 +22,40 @@ import os
 import time
 from typing import Any, Dict, List, Optional
 
-# Peak bf16 FLOPs per chip (marketing numbers; the MFU denominator).
-# device_kind substrings, checked in order.
-_PEAK_FLOPS_BY_KIND = (
-    ('v5p', 459e12),
-    ('v5e', 197e12),  # v5 litepod
-    ('v6e', 918e12),
-    ('v4', 275e12),
-    ('v3', 123e12),
-    ('v2', 45e12),
-)
+#: Peak bf16 FLOP/s per chip, keyed by `jax.devices()[0].device_kind`
+#: exactly as the runtime prints it: a v5e chip says 'TPU v5 lite'
+#: under libtpu 0.0.34 (my chip run, PR 21); JAX's own tables
+#: (jax/_src/pallas/mosaic/tpu_info.py) accept both spellings per
+#: generation, so both are listed. Source of the numbers: Google Cloud
+#: TPU documentation, per-generation system-architecture pages.
+#: bench.py reads the same table.
+PEAK_BF16_FLOPS_BY_DEVICE_KIND: Dict[str, float] = {
+    'TPU v2': 45e12,
+    'TPU v3': 123e12,
+    'TPU v4': 275e12,
+    'TPU v5 lite': 197e12, 'TPU v5e': 197e12,
+    'TPU v5': 459e12, 'TPU v5p': 459e12,
+    'TPU v6 lite': 918e12, 'TPU v6e': 918e12,
+}
 
 
 def peak_flops_per_device() -> Optional[float]:
-    """Per-device peak FLOPs: env override first, then the device-kind
-    table; None when neither matches (e.g. CPU)."""
-    env = os.environ.get('SKYPILOT_DEVICE_PEAK_FLOPS')
-    if env:
-        return float(env)
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # pylint: disable=broad-except
+    """Peak bf16 FLOP/s of one device of the default backend: the
+    table entry for its `device_kind`; None off TPU (there is no MFU
+    to speak of); KeyError for a TPU the table does not know."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != 'tpu':
         return None
-    for sub, flops in _PEAK_FLOPS_BY_KIND:
-        if sub in kind:
-            return flops
-    return None
+    try:
+        return PEAK_BF16_FLOPS_BY_DEVICE_KIND[dev.device_kind]
+    except KeyError:
+        raise KeyError(
+            f'no peak FLOP/s known for device_kind '
+            f'{dev.device_kind!r}: add it, with its source, to '
+            f'PEAK_BF16_FLOPS_BY_DEVICE_KIND (known: '
+            f'{sorted(PEAK_BF16_FLOPS_BY_DEVICE_KIND)}) — an MFU '
+            f'against an assumed peak is not a measurement') from None
 
 
 class StepMetrics:

@@ -2,7 +2,7 @@
 
 MXU-friendly attention for the recipe models. On TPU with long enough
 sequences, uses the pallas flash-attention kernel (blockwise softmax,
-O(S) memory, no S×S materialization in HBM); otherwise falls back to
+O(S) memory, no S×S materialization in HBM); otherwise
 `jax.nn.dot_product_attention` (XLA fuses the mask+softmax chain).
 
 Layout convention: q/k/v are [batch, seq, heads, head_dim] (BSHD).
@@ -11,32 +11,16 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-# Measured on v5e (GPT-2 124M, B=8 S=1024 H=12 D=64): the pallas flash
-# kernel's fwd+bwd LOSES to XLA's fused attention by ~45ms/step (148 vs
-# 103 ms — 24% vs 35% MFU); its O(S) memory only pays off once the S×S
-# scores stop fitting in VMEM-friendly fusions. Dispatch to pallas only
-# from 2k context up; override via SKYPILOT_TPU_FLASH_MIN_SEQ.
-try:
-    _FLASH_MIN_SEQ = int(
-        os.environ.get('SKYPILOT_TPU_FLASH_MIN_SEQ') or 2048)
-except ValueError:
-    _FLASH_MIN_SEQ = 2048
-
-
-@functools.lru_cache(maxsize=1)
-def _pallas_flash_available() -> bool:
-    if jax.default_backend() != 'tpu':
-        return False
-    try:
-        from jax.experimental.pallas.ops.tpu import flash_attention  # noqa: F401
-        return True
-    except ImportError:
-        return False
+# Sequence length from which 'auto' takes the Pallas flash kernel on
+# TPU: its O(S) memory pays once the S x S scores stop fitting
+# VMEM-friendly XLA fusions. Where the crossover sits on a v5e is NOT
+# MEASURED (ROADMAP S2; benchmarks/flash_crossover.py is the tool);
+# override via SKYPILOT_TPU_FLASH_MIN_SEQ.
+_FLASH_MIN_SEQ = int(os.environ.get('SKYPILOT_TPU_FLASH_MIN_SEQ') or 2048)
 
 
 def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -76,12 +60,10 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                  heads_axis=heads_axis)
     seq_len = q.shape[1]
     use_flash = (impl == 'flash' or
-                 (impl == 'auto' and _pallas_flash_available() and
-                  seq_len >= _FLASH_MIN_SEQ))
+                 (impl == 'auto' and jax.default_backend() == 'tpu' and
+                  seq_len >= _FLASH_MIN_SEQ and _flash_shardable(q)))
     if use_flash:
-        out = _flash(q, k, v, causal=causal)
-        if out is not None:
-            return out
+        return _flash(q, k, v, causal=causal)
     # GQA: expand kv heads to q heads for the XLA path.
     num_q_heads, num_kv_heads = q.shape[2], k.shape[2]
     if num_kv_heads != num_q_heads:
@@ -124,39 +106,59 @@ def _pallas_flash_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def _active_mesh():
-    """The `with mesh:` context's mesh, or None.
-
-    jax.interpreters.pxla.thread_resources is deprecated (0.8.2) with
-    no public replacement for reading the context mesh yet; go through
-    the underlying module directly.
-    """
-    try:
-        from jax._src import mesh as mesh_mod
-        mesh = mesh_mod.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):  # jax internals moved
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
+    """The `with mesh:` context's mesh, or None. The trainer and the
+    serving engine enter the legacy context manager, which JAX 0.9
+    keeps in `jax._src.mesh.thread_resources` (the public
+    `jax.sharding.get_abstract_mesh` only sees `jax.set_mesh`)."""
+    from jax._src import mesh as mesh_mod
+    mesh = mesh_mod.thread_resources.env.physical_mesh
     return None if mesh.empty else mesh
 
 
+def _batch_shards(mesh) -> tuple:
+    """(axes, product) of the mesh axes the batch dim is sharded on."""
+    axes = [a for a in ('data', 'fsdp') if mesh.shape.get(a, 1) > 1]
+    shards = 1
+    for a in axes:
+        shards *= mesh.shape[a]
+    return axes, shards
+
+
+def _flash_shardable(q: jax.Array) -> bool:
+    """Whether the flash kernel can be shard_mapped over the active
+    mesh: the batch must divide the data x fsdp shards. When it does
+    not, 'auto' takes the GSPMD-native XLA attention — said once, at
+    trace time, because the O(S) memory guarantee goes with it."""
+    mesh = _active_mesh()
+    if mesh is None or mesh.size == 1:
+        return True
+    _, shards = _batch_shards(mesh)
+    if q.shape[0] % shards == 0:
+        return True
+    import warnings
+    warnings.warn(
+        f'flash attention not used at seq={q.shape[1]}: batch '
+        f'{q.shape[0]} does not divide the mesh\'s {shards} data x '
+        f'fsdp shards, so the Pallas call cannot be shard_mapped; XLA '
+        f'attention (S x S scores materialized) runs instead.',
+        stacklevel=3)
+    return False
+
+
 def _flash(q: jax.Array, k: jax.Array, v: jax.Array, *,
-           causal: bool,
-           kernel=_pallas_flash_kernel) -> Optional[jax.Array]:
-    """Sharding-safe flash attention; returns None when the operands
-    cannot be cleanly shard_mapped (caller falls back to XLA)."""
+           causal: bool, kernel=_pallas_flash_kernel) -> jax.Array:
+    """Sharding-safe flash attention. Raises when the batch cannot be
+    cleanly shard_mapped ('auto' checks `_flash_shardable` first;
+    impl='flash' asked for this kernel by name)."""
     num_q_heads, num_kv_heads = q.shape[2], k.shape[2]
     mesh = _active_mesh()
-    # Feasibility checks BEFORE the GQA expansion so the bail-out path
-    # doesn't materialize a repeat the XLA fallback then redoes.
-    batch_shards = 1
     batch_axes = []
     if mesh is not None and mesh.size > 1:
-        for a in ('data', 'fsdp'):
-            if mesh.shape.get(a, 1) > 1:
-                batch_axes.append(a)
-                batch_shards *= mesh.shape[a]
+        batch_axes, batch_shards = _batch_shards(mesh)
         if q.shape[0] % batch_shards != 0:
-            return None  # caller falls back to the GSPMD-native XLA path
+            raise ValueError(
+                f'flash attention: batch {q.shape[0]} does not divide '
+                f'the mesh\'s {batch_shards} data x fsdp shards')
     if num_kv_heads != num_q_heads:
         rep = num_q_heads // num_kv_heads
         k = jnp.repeat(k, rep, axis=2)
@@ -169,11 +171,10 @@ def _flash(q: jax.Array, k: jax.Array, v: jax.Array, *,
     # is per (batch, head) so shards are independent.
     heads_axis = ('tensor' if mesh.shape.get('tensor', 1) > 1 and
                   num_q_heads % mesh.shape['tensor'] == 0 else None)
-    from skypilot_tpu.utils.jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
     spec = P(tuple(batch_axes) if batch_axes else None, None, heads_axis,
              None)
-    return shard_map(
+    return jax.shard_map(
         functools.partial(kernel, causal=causal), mesh=mesh,
         in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False)(q, k, v)

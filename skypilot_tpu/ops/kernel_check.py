@@ -1,0 +1,288 @@
+"""Compile every Pallas call the TPU path can reach, ON THE CHIP, and
+compare it with its XLA reference.
+
+    python -m skypilot_tpu.ops.kernel_check [--out PATH] [--seed N]
+
+The CPU tests run these kernels with `interpret=True`, which says
+nothing about what Mosaic accepts: block shapes, tile alignment,
+scalar-prefetch reads and VMEM limits are only checked by the real
+compiler. This runs each call at Llama-3-8B serving shapes (32 query /
+8 KV heads of 128, page 16, 128 pages per sequence, batch 16) through
+the same wrappers the models call, compiled, and reports per kernel
+either `ok` with the largest error against the reference, `mismatch`,
+or `refused` with the compiler's own message. It exits non-zero when
+any case is not `ok`, and when the backend is not a TPU: a CPU run of
+this file would check nothing.
+
+Tolerances are set from the dtype: operands are bf16 (8 mantissa
+bits, eps 2^-8 ~ 4e-3) and both sides accumulate in f32, so outputs
+of magnitude <= 1 must agree to 2e-2 absolute + 2e-2 relative; a
+kernel that dropped the softmax scale, a mask or a page would be off
+by O(1). References run under `default_matmul_precision('highest')`:
+a TPU's default f32 matmul is itself bf16-pass.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+import time
+import traceback
+from typing import Any, Callable, Dict, List
+
+ATOL = RTOL = 2e-2
+
+# Llama-3-8B attention geometry and the serving page geometry
+# (chip_smoke.py serves the same: --max-total-len 2048, page 16).
+Q_HEADS, KV_HEADS, HEAD_DIM = 32, 8, 128
+PAGE, PAGES_PER_SEQ, BATCH = 16, 128, 16
+D_MODEL, LORA_RANK, LORA_SLOTS = 4096, 16, 8
+
+
+def _pool(key, quantized: bool):
+    """(k_pages, v_pages, k_scales, v_scales, page_indices): a pool in
+    which every sequence owns distinct pages, as the allocator hands
+    them out (page 0 is the engine's trash page)."""
+    import jax
+    import jax.numpy as jnp
+    from skypilot_tpu.ops import paged_attention as paged_ops
+    total_pages = BATCH * PAGES_PER_SEQ + 1
+    kk, kv, kp = jax.random.split(key, 3)
+    shape = (total_pages, PAGE, KV_HEADS, HEAD_DIM)
+    k = jax.random.normal(kk, shape, jnp.bfloat16)
+    v = jax.random.normal(kv, shape, jnp.bfloat16)
+    perm = jax.random.permutation(kp, total_pages - 1) + 1
+    page_indices = perm.reshape(BATCH, PAGES_PER_SEQ).astype(jnp.int32)
+    to_pool = lambda x: jnp.transpose(x, (2, 0, 1, 3))  # noqa: E731
+    if not quantized:
+        return to_pool(k), to_pool(v), None, None, page_indices
+    qk, sk = paged_ops.quantize_kv_rows(k)
+    qv, sv = paged_ops.quantize_kv_rows(v)
+    return to_pool(qk), to_pool(qv), sk, sv, page_indices
+
+
+def _paged_decode(impl: str, quantized: bool) -> Callable[[Any], Dict]:
+    def case(key):
+        import jax
+        import jax.numpy as jnp
+        from skypilot_tpu.ops import paged_attention as paged_ops
+        kq, kl, kpool = jax.random.split(key, 3)
+        k_pages, v_pages, ks, vs, tbl = _pool(kpool, quantized)
+        q = jax.random.normal(kq, (BATCH, Q_HEADS, HEAD_DIM),
+                              jnp.bfloat16)
+        lengths = jax.random.randint(
+            kl, (BATCH,), 1, PAGE * PAGES_PER_SEQ + 1, jnp.int32)
+
+        def run(route):
+            return jax.jit(lambda *a: paged_ops.paged_decode_attention(
+                a[0], a[1], a[2], a[3], a[4], k_scales=ks, v_scales=vs,
+                impl=route))
+        args = (q, k_pages, v_pages, lengths, tbl)
+        return _compare(run(impl), run('xla'), args)
+    return case
+
+
+def _paged_chunk(quantized: bool, chunk: int, batch: int
+                 ) -> Callable[[Any], Dict]:
+    def case(key):
+        import jax
+        import jax.numpy as jnp
+        from skypilot_tpu.ops import paged_attention as paged_ops
+        kq, ko, kpool = jax.random.split(key, 3)
+        k_pages, v_pages, ks, vs, tbl = _pool(kpool, quantized)
+        tbl = tbl[:batch]
+        q = jax.random.normal(kq, (batch, chunk, Q_HEADS, HEAD_DIM),
+                              jnp.bfloat16)
+        # A chunk at a ragged per-row offset into the history, the
+        # suffix-prefill / verify-chunk shape.
+        offset = jax.random.randint(
+            ko, (batch, 1), 0, PAGE * PAGES_PER_SEQ - chunk, jnp.int32)
+        positions = offset + jnp.arange(chunk, dtype=jnp.int32)[None]
+
+        def run(route):
+            return jax.jit(lambda *a: paged_ops.paged_chunk_attention(
+                a[0], a[1], a[2], a[3], a[4], k_scales=ks, v_scales=vs,
+                impl=route))
+        args = (q, k_pages, v_pages, positions, tbl)
+        return _compare(run('fused'), run('xla'), args)
+    return case
+
+
+def _qkv_lora(chunk: int, batch: int) -> Callable[[Any], Dict]:
+    def case(key):
+        import jax
+        import jax.numpy as jnp
+        from skypilot_tpu.models import lora as lora_lib
+        from skypilot_tpu.ops import pallas_paged
+        keys = jax.random.split(key, 8)
+        x = jax.random.normal(keys[0], (batch, chunk, D_MODEL),
+                              jnp.bfloat16)
+        ids = jax.random.randint(keys[1], (batch,), 0, LORA_SLOTS,
+                                 jnp.int32)
+        factors = []
+        for i, d_out in enumerate((Q_HEADS * HEAD_DIM,
+                                   KV_HEADS * HEAD_DIM,
+                                   KV_HEADS * HEAD_DIM)):
+            factors.append({
+                'a': 0.02 * jax.random.normal(
+                    keys[2 + 2 * i], (LORA_SLOTS, D_MODEL, LORA_RANK),
+                    jnp.bfloat16),
+                'b': 0.02 * jax.random.normal(
+                    keys[3 + 2 * i], (LORA_SLOTS, LORA_RANK, d_out),
+                    jnp.bfloat16)})
+
+        @jax.jit
+        def fused(x, ids, *fs):
+            return pallas_paged.fused_qkv_lora_delta(x, *fs, ids)
+
+        @jax.jit
+        def ref(x, ids, *fs):
+            return tuple(
+                lora_lib.apply_delta(
+                    jnp.zeros(x.shape[:2] + (f['b'].shape[-1],),
+                              jnp.float32), x, f, ids, 1.0)
+                for f in fs)
+        return _compare(fused, ref, (x, ids, *factors))
+    return case
+
+
+def _flash(batch: int, heads: int, kv_heads: int, head_dim: int,
+           seq: int = 2048) -> Callable[[Any], Dict]:
+    def case(key):
+        import jax
+        import jax.numpy as jnp
+        from skypilot_tpu.ops import attention as attention_ops
+        kq, kk, kv, kc = jax.random.split(key, 4)
+        q = jax.random.normal(kq, (batch, seq, heads, head_dim),
+                              jnp.bfloat16)
+        k = jax.random.normal(kk, (batch, seq, kv_heads, head_dim),
+                              jnp.bfloat16)
+        v = jax.random.normal(kv, (batch, seq, kv_heads, head_dim),
+                              jnp.bfloat16)
+        cot = jax.random.normal(kc, q.shape, jnp.bfloat16)
+
+        def run(impl):
+            def loss(q, k, v):
+                out = attention_ops.dot_product_attention(
+                    q, k, v, causal=True, impl=impl)
+                return jnp.sum(out.astype(jnp.float32) *
+                               cot.astype(jnp.float32)), out
+            # Forward output and all three gradients: the backward
+            # kernels are separate Pallas calls.
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                              has_aux=True))
+        return _compare(run('flash'), run('xla'), (q, k, v),
+                        # dq/dk/dv sum ~S terms of O(1): scale the
+                        # tolerance by the reference's own magnitude.
+                        relative_to_max=True)
+    return case
+
+
+def _compare(kernel_fn, ref_fn, args, relative_to_max: bool = False
+             ) -> Dict[str, Any]:
+    """Compile + run both; the kernel's compile is where Mosaic
+    refuses, so it is timed and lowered on its own."""
+    import jax
+    import numpy as np
+    t0 = time.perf_counter()
+    compiled = kernel_fn.lower(*args).compile()
+    compile_s = time.perf_counter() - t0
+    got = jax.block_until_ready(compiled(*args))
+    with jax.default_matmul_precision('highest'):
+        want = jax.block_until_ready(ref_fn(*args))
+    worst = 0.0
+    ok = True
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        g = np.asarray(g, np.float32)
+        w = np.asarray(w, np.float32)
+        if g.shape != w.shape or not np.all(np.isfinite(g)):
+            return {'verdict': 'mismatch', 'detail':
+                    f'shape {g.shape} vs {w.shape} or non-finite values'}
+        scale = float(np.max(np.abs(w))) if relative_to_max else 1.0
+        err = np.abs(g - w)
+        bound = ATOL * max(scale, 1.0) + RTOL * np.abs(w)
+        worst = max(worst, float(np.max(err)) / max(scale, 1.0))
+        ok = ok and bool(np.all(err <= bound))
+    return {'verdict': 'ok' if ok else 'mismatch',
+            'max_abs_err': round(worst, 6),
+            'compile_s': round(compile_s, 2)}
+
+
+def cases() -> List[tuple]:
+    """(name, route users reach it by, case fn)."""
+    return [
+        ('upstream_paged_attention/bf16/S=1',
+         "resolve_impl('auto') bf16 pool: decode",
+         _paged_decode('kernel', quantized=False)),
+        ('fused_paged_attention/int8/S=1',
+         "resolve_impl('auto') int8 pool: decode",
+         _paged_decode('fused', quantized=True)),
+        ('fused_paged_attention/int8/S=256',
+         "resolve_impl('auto') int8 pool: suffix prefill chunk",
+         _paged_chunk(quantized=True, chunk=256, batch=2)),
+        ('fused_paged_attention/bf16/S=1',
+         "impl='fused' by name on a bf16 pool: decode",
+         _paged_decode('fused', quantized=False)),
+        ('fused_paged_attention/bf16/S=256',
+         "impl='fused' by name on a bf16 pool: chunk",
+         _paged_chunk(quantized=False, chunk=256, batch=2)),
+        ('fused_qkv_lora_delta/S=1',
+         'int8 pool + --adapter-dir: decode',
+         _qkv_lora(chunk=1, batch=BATCH)),
+        ('fused_qkv_lora_delta/S=256',
+         'int8 pool + --adapter-dir: prefill chunk',
+         _qkv_lora(chunk=256, batch=1)),
+        ('upstream_flash_attention/fwd+bwd/D=64/S=2048',
+         'train_lm --seq >= 2048, GPT-2 heads',
+         _flash(batch=2, heads=12, kv_heads=12, head_dim=64)),
+        ('upstream_flash_attention/fwd+bwd/D=128/S=2048',
+         'train_lm --seq >= 2048, Llama-3 heads (GQA 32/8)',
+         _flash(batch=1, heads=Q_HEADS, kv_heads=KV_HEADS,
+                head_dim=HEAD_DIM)),
+    ]
+
+
+def main() -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split('\n')[0])
+    parser.add_argument('--seed', type=int, default=0)
+    parser.add_argument('--out', default=None, metavar='PATH',
+                        help='also write the verdicts as JSON')
+    args = parser.parse_args()
+
+    import jax
+    dev = jax.devices()[0]
+    device = {'platform': dev.platform, 'kind': dev.device_kind,
+              'count': len(jax.devices())}
+    print(f'kernel_check: device {json.dumps(device)}', flush=True)
+    if dev.platform != 'tpu':
+        print(f'kernel_check: FAILED — found platform {dev.platform!r}; '
+              f'Mosaic compiles for a TPU only and interpret mode '
+              f'proves nothing about it', flush=True)
+        return 1
+
+    results = []
+    key = jax.random.PRNGKey(args.seed)
+    for i, (name, reached_by, case) in enumerate(cases()):
+        try:
+            res = case(jax.random.fold_in(key, i))
+        except Exception as e:  # pylint: disable=broad-except
+            # The boundary this tool exists for: a Mosaic refusal is a
+            # result to report, in the compiler's words, not a crash.
+            traceback.print_exc()
+            res = {'verdict': 'refused',
+                   'error': f'{type(e).__name__}: {e}'[:4000]}
+        res.update(name=name, reached_by=reached_by)
+        results.append(res)
+        print(f'kernel_check: {json.dumps(res)}', flush=True)
+    if args.out:
+        with open(args.out, 'w', encoding='utf-8') as f:
+            json.dump({'device': device, 'atol': ATOL, 'rtol': RTOL,
+                       'results': results}, f, indent=1)
+    bad = [r['name'] for r in results if r['verdict'] != 'ok']
+    print(f'kernel_check: {len(results) - len(bad)}/{len(results)} ok'
+          + (f'; NOT ok: {bad}' if bad else ''), flush=True)
+    return 1 if bad else 0
+
+
+if __name__ == '__main__':
+    sys.exit(main())

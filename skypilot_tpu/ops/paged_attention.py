@@ -7,11 +7,13 @@ is sized for the *aggregate* live tokens, so many short sequences fit
 where the dense layout would exhaust HBM — more decode slots, higher
 serving throughput.
 
-On TPU the attention reads dispatch to the pallas paged-attention
-kernel (jax.experimental.pallas.ops.tpu.paged_attention — blockwise
-page gathers in VMEM); elsewhere a pure-XLA reference (gather + masked
-attention) keeps the path testable and correct. The reference also
-defines the semantics the kernel is tested against on TPU.
+On TPU the decode reads dispatch to a Pallas kernel (bf16 pools: the
+upstream jax.experimental.pallas.ops.tpu.paged_attention; int8 pools:
+ops/pallas_paged.py); on the CPU test backend a pure-XLA reference
+(gather + masked attention) runs instead. The reference also defines
+the semantics the kernels are checked against on the chip
+(ops/kernel_check.py). The route is chosen once, at trace time
+(`pallas_paged.resolve_impl`); nothing switches routes at run time.
 
 Layouts (matching the pallas kernel):
   q            [B, num_q_heads, head_dim]      one decode token per row
@@ -56,15 +58,6 @@ import jax
 import jax.numpy as jnp
 
 
-def _pallas_paged_available() -> bool:
-    """Upstream bf16 pallas kernel usable here. Probe result (and the
-    failure REASON, for /stats and skip messages) is cached at module
-    level in ops/pallas_paged.py — see `pallas_paged.available()` /
-    `unavailable_reason()` for the in-repo fused kernel's probe."""
-    from skypilot_tpu.ops import pallas_paged
-    return pallas_paged.upstream_available()
-
-
 def quantize_kv_rows(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Symmetric absmax int8 quantization of per-token KV rows.
 
@@ -105,6 +98,8 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     'fused_interpret' the in-repo kernel that dequantizes int8 pages
     in-register (ops/pallas_paged.py), 'xla' the gather reference —
     which dequantizes in HBM, the traffic the fused path deletes.
+    Under a tensor mesh context both kernels run per chip on that
+    chip's kv-head slice of the pool (`shard_over_kv_heads`).
     """
     assert q.ndim == 3 and k_pages.ndim == 4, (q.shape, k_pages.shape)
     from skypilot_tpu.ops import pallas_paged
@@ -118,6 +113,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     if impl == 'kernel':
         from jax.experimental.pallas.ops.tpu.paged_attention import (
             paged_attention)
+        from jax.sharding import PartitionSpec as P
         pages_per_seq = page_indices.shape[1]
         # Block size must divide the per-sequence page walk.
         block = min(8, pages_per_seq)
@@ -127,9 +123,17 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         # (its qk is a raw einsum) — pre-scale q to match the
         # reference semantics (MaxText does the same).
         scale = 1.0 / (q.shape[-1] ** 0.5)
-        return paged_attention(q * scale, k_pages, v_pages, lengths,
-                               page_indices,
-                               pages_per_compute_block=block)
+
+        def call(q_, k_, v_, lengths_, tbl):
+            return paged_attention(q_ * scale, k_, v_, lengths_, tbl,
+                                   pages_per_compute_block=block)
+
+        heads = P(None, 'tensor', None)
+        pool = P('tensor', None, None, None)
+        return pallas_paged.shard_over_kv_heads(
+            call, k_pages.shape[0],
+            in_specs=(heads, pool, pool, P(None), P(None, None)),
+            out_specs=heads)(q, k_pages, v_pages, lengths, page_indices)
     return _reference_paged_attention(q, k_pages, v_pages, lengths,
                                       page_indices,
                                       k_scales=k_scales,

@@ -36,13 +36,12 @@ gaps:
 DISPATCH. `resolve_impl(impl, quantized=...)` maps a requested impl to
 the concrete route; 'auto' consults, in order: an explicit
 `set_default_impl()` / `impl_scope()` override, the
-SKYPILOT_TPU_PAGED_IMPL environment variable, then backend defaults
-(TPU quantized -> 'fused'; TPU bf16 -> upstream 'kernel'; anything
-else -> 'xla'). Unavailable routes degrade silently to 'xla' — the
-reference path is always correct, just slower. `unavailable_reason()`
-records WHY the compiled kernel path is off (mirroring
-data/token_loader.native_unavailable_reason) so /stats and test skip
-messages can say so.
+SKYPILOT_TPU_PAGED_IMPL environment variable, then the backend (TPU
+quantized -> 'fused'; TPU bf16 -> upstream 'kernel'; the CPU test
+backend -> 'xla'). A route selected by name that cannot run here is a
+ValueError, never a silent switch to another route.
+`unavailable_reason()` says WHY the compiled kernel path is off so
+/stats and test skip messages can say so.
 
 INTERPRET-MODE CONTRACT. Every pallas_call here takes
 `interpret=<kwarg>` (enforced repo-wide by `stpu check` rule SKY006),
@@ -86,60 +85,21 @@ ENV_VAR = 'SKYPILOT_TPU_PAGED_IMPL'
 IMPLS: Tuple[str, ...] = ('auto', 'xla', 'kernel', 'fused',
                           'fused_interpret')
 
-# -- availability probes (module-level cache + recorded reason) -------------
-_probed = False
-_import_error: Optional[str] = None
-
-
-def _probe() -> None:
-    global _probed, _import_error
-    if _probed:
-        return
-    _probed = True
-    try:
-        from jax.experimental import pallas  # noqa: F401
-        from jax.experimental.pallas import tpu  # noqa: F401
-    except ImportError as e:  # no pallas in this jax build
-        _import_error = f'pallas import failed: {e}'
-
-
-def pallas_importable() -> bool:
-    """True when the pallas + pallas-TPU modules import here (the
-    floor for `fused_interpret`, which needs no TPU)."""
-    _probe()
-    return _import_error is None
-
-
+# -- availability -----------------------------------------------------------
 def available() -> bool:
-    """True when the COMPILED fused kernel path can run here (pallas
-    imports and the default backend is TPU)."""
-    return pallas_importable() and jax.default_backend() == 'tpu'
+    """True when the COMPILED kernel routes ('kernel', 'fused') can
+    run here: Mosaic compiles for a TPU backend only."""
+    return jax.default_backend() == 'tpu'
 
 
 def unavailable_reason() -> Optional[str]:
     """None when `available()`; otherwise why the compiled kernel path
     is off — surfaced in /stats' storage section and test skips."""
-    _probe()
-    if _import_error is not None:
-        return _import_error
     backend = jax.default_backend()
     if backend != 'tpu':
         return (f"backend is {backend!r}: the fused kernel compiles on "
                 f"TPU only (impl='fused_interpret' still runs here)")
     return None
-
-
-@functools.lru_cache(maxsize=1)
-def upstream_available() -> bool:
-    """Upstream bf16 pallas paged-attention kernel (`impl='kernel'`)."""
-    if jax.default_backend() != 'tpu':
-        return False
-    try:
-        from jax.experimental.pallas.ops.tpu.paged_attention import (  # noqa: F401
-            paged_attention)
-        return True
-    except ImportError:
-        return False
 
 
 # -- impl selection ---------------------------------------------------------
@@ -190,25 +150,26 @@ def resolve_impl(impl: str = 'auto', *, quantized: bool = False) -> str:
     """Concrete route for a requested impl: one of 'xla' | 'kernel' |
     'fused' | 'fused_interpret'.
 
-    'auto' prefers the fused kernel for quantized pools on TPU and the
-    upstream kernel for bf16 (matching the pre-fused fast path);
-    unavailable routes degrade to 'xla', and 'kernel' degrades for
-    quantized pools (the upstream kernel is bf16-only)."""
+    'auto' is decided by what the code can observe: off TPU (the CPU
+    test backend) the XLA gather reference; on TPU the fused kernel
+    for int8 pools and the upstream kernel for bf16 ones. A route
+    asked for BY NAME that cannot run here raises — it never degrades
+    to another route, so what /stats reports is what was compiled."""
     _validate(impl)
     if impl == 'auto':
         impl = default_impl()
     if impl == 'auto':
         if not available():
             return 'xla'
-        if quantized:
-            return 'fused'
-        return 'kernel' if upstream_available() else 'fused'
-    if impl == 'kernel' and (quantized or not upstream_available()):
-        return 'xla'
-    if impl == 'fused' and not available():
-        return 'xla'
-    if impl == 'fused_interpret' and not pallas_importable():
-        return 'xla'
+        return 'fused' if quantized else 'kernel'
+    if impl == 'kernel' and quantized:
+        raise ValueError(
+            "paged-attention impl 'kernel' (the upstream Pallas kernel) "
+            "reads bf16 pools only; an int8 pool needs 'fused'")
+    if impl in ('kernel', 'fused') and not available():
+        raise ValueError(
+            f'paged-attention impl {impl!r} was selected but cannot '
+            f'run: {unavailable_reason()}')
     return impl
 
 
@@ -221,17 +182,29 @@ def lora_fusion_impl(quantized: bool = False) -> Optional[str]:
 
 
 # -- fused paged attention --------------------------------------------------
+#: Query rows per kv head are padded to a multiple of this before the
+#: kernel: 16 is the bf16 sublane tile (8 for f32), so the q/out
+#: blocks and the (rows, 1) softmax scratch are whole tiles whatever
+#: the GQA group (Llama-3: 4) or chunk length.
+_ROW_ALIGN = 16
+
+
 def _attention_kernel(quantized, sm_scale, page_size, pages_per_seq,
-                      perturb, tbl_ref, pos_ref, q_ref, k_ref, v_ref,
+                      perturb, tbl_ref, q_ref, pos_ref, k_ref, v_ref,
                       *rest):
     """Grid (batch, kv_heads, pages_per_seq): one physical page of one
-    kv head per step, online-softmax state in VMEM scratch."""
+    kv head per step, online-softmax state in VMEM scratch.
+
+    Refs (blocks; squeezed dims dropped): q/o [rows, D] — the kv
+    head's grouped queries, row = s * group + g; pos i32[rows, 1] —
+    each row's causal bound; k/v [page, D]; int8 pools add k/v scale
+    rows [1, page]."""
     import jax.experimental.pallas as pl
+    del tbl_ref  # consumed by the index maps
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
-    b = pl.program_id(0)
     p = pl.program_id(2)
 
     @pl.when(p == 0)
@@ -240,15 +213,19 @@ def _attention_kernel(quantized, sm_scale, page_size, pages_per_seq,
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    q = q_ref[0].astype(jnp.float32)            # [S, G, D]
-    k = k_ref[0, 0].astype(jnp.float32)         # [page, D]
-    v = v_ref[0, 0].astype(jnp.float32)
+    q = q_ref[...].astype(jnp.float32)          # [rows, D]
+    k = k_ref[...].astype(jnp.float32)          # [page, D]
+    v = v_ref[...].astype(jnp.float32)
+    # q @ k^T as a 2-D NT contraction (Mosaic has no 3-D einsum).
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale  # [rows, page]
     if quantized:
-        # In-register dequant: int8 page values * the page's f32
-        # per-slot scale rows. No dequantized page ever exists in HBM.
-        k = k * ks_ref[0][:, None]
-        v = v * vs_ref[0][:, None]
-    s = jnp.einsum('sgd,td->sgt', q, k) * sm_scale
+        # In-register dequant, folded into the scores: the page's f32
+        # per-slot scale row [1, page] broadcasts over the query rows
+        # (q . (ks_t * k_t) == ks_t * (q . k_t)). No dequantized page
+        # ever exists, in HBM or in VMEM.
+        s = s * ks_ref[...]
     if perturb:
         # Non-vacuity hook: a deliberately wrong kernel for tests to
         # prove the parity pins actually bite. Scores are SCALED (a
@@ -256,27 +233,28 @@ def _attention_kernel(quantized, sm_scale, page_size, pages_per_seq,
         # under softmax's shift invariance.
         s = s * (1.0 + perturb)
     t_idx = (p * page_size +
-             jax.lax.broadcasted_iota(jnp.int32, s.shape, 2))
-    pos = pos_ref[b]                            # [S] causal bounds
-    s = jnp.where(t_idx <= pos[:, None, None], s, -jnp.inf)
+             jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+    s = jnp.where(t_idx <= pos_ref[...], s, -jnp.inf)
 
-    m_prev = m_ref[...]                         # [S, G]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    m_prev = m_ref[...]                         # [rows, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     # All-masked rows keep m == -inf; shifting by 0 there keeps every
     # exp() argument finite-or--inf (exp(-inf) == 0, never a nan).
-    m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+    m_safe = jnp.where(m_new > -jnp.inf, m_new, 0.0)
     alpha = jnp.exp(m_prev - m_safe)
-    w = jnp.exp(s - m_safe[..., None])
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(w, axis=-1)
-    acc_ref[...] = (acc_ref[...] * alpha[..., None] +
-                    jnp.einsum('sgt,td->sgd', w, v))
+    w = jnp.exp(s - m_safe)                     # [rows, page]
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(w, axis=-1, keepdims=True)
+    if quantized:
+        w = w * vs_ref[...]                     # same fold, v side
+    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+        w, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(p == pages_per_seq - 1)
     def _finish():
         l = l_ref[...]
         l = jnp.where(l > 0, l, 1.0)            # fully-masked rows -> 0
-        o_ref[0] = (acc_ref[...] / l[..., None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def _fused_call(q, k_pages, v_pages, positions, page_indices,
@@ -292,42 +270,73 @@ def _fused_call(q, k_pages, v_pages, positions, page_indices,
     kernel = functools.partial(_attention_kernel, quantized, sm_scale,
                                page_size, pages_per_seq, perturb)
 
-    # Index maps see the scalar-prefetch refs (page table, positions):
-    # the page walk gathers SCATTERED physical pages into VMEM blocks.
-    def q_map(b, h, p, tbl, pos):
-        return (b, 0, h, 0)
+    # One kv head's grouped queries as contiguous rows: [B, S, Hq, D]
+    # -> [B, Hkv, S*G (padded), D], so the block's last two dims are
+    # the array's own (Mosaic refuses a (group, D) block cut out of a
+    # 32-head axis). Padded rows carry position -1: fully masked, they
+    # produce zeros that the unpad drops.
+    rows = chunk * group
+    rows_pad = -(-rows // _ROW_ALIGN) * _ROW_ALIGN
+    q_rows = jnp.transpose(
+        q.reshape(batch, chunk, num_kv_heads, group, head_dim),
+        (0, 2, 1, 3, 4)).reshape(batch, num_kv_heads, rows, head_dim)
+    pos_rows = jnp.repeat(positions.astype(jnp.int32), group, axis=1)
+    if rows_pad != rows:
+        q_rows = jnp.pad(
+            q_rows, ((0, 0), (0, 0), (0, rows_pad - rows), (0, 0)))
+        pos_rows = jnp.pad(pos_rows, ((0, 0), (0, rows_pad - rows)),
+                           constant_values=-1)
+    pos_rows = pos_rows[:, :, None]             # [B, rows, 1]
 
-    def kv_map(b, h, p, tbl, pos):
+    # Index maps see the scalar-prefetch page table: the page walk
+    # gathers SCATTERED physical pages into VMEM blocks.
+    def q_map(b, h, p, tbl):
+        return (b, h, 0, 0)
+
+    def pos_map(b, h, p, tbl):
+        return (b, 0, 0)
+
+    def kv_map(b, h, p, tbl):
         return (h, tbl[b, p], 0, 0)
 
-    def scale_map(b, h, p, tbl, pos):
-        return (tbl[b, p], 0)
+    def scale_map(b, h, p, tbl):
+        return (tbl[b, p], 0, 0)
 
+    q_spec = pl.BlockSpec((None, None, rows_pad, head_dim), q_map)
     in_specs = [
-        pl.BlockSpec((1, chunk, group, head_dim), q_map),
-        pl.BlockSpec((1, 1, page_size, head_dim), kv_map),
-        pl.BlockSpec((1, 1, page_size, head_dim), kv_map),
+        q_spec,
+        pl.BlockSpec((None, rows_pad, 1), pos_map),
+        pl.BlockSpec((None, None, page_size, head_dim), kv_map),
+        pl.BlockSpec((None, None, page_size, head_dim), kv_map),
     ]
-    operands = [q, k_pages, v_pages]
+    operands = [q_rows, pos_rows, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page_size), scale_map),
-                     pl.BlockSpec((1, page_size), scale_map)]
-        operands += [k_scales, v_scales]
+        # Scale rows as [total_pages, 1, page_size] (a free reshape):
+        # the (1, page) block is then the array's own last two dims.
+        in_specs += [pl.BlockSpec((None, 1, page_size), scale_map),
+                     pl.BlockSpec((None, 1, page_size), scale_map)]
+        operands += [k_scales[:, None, :], v_scales[:, None, :]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=1,
         grid=(batch, num_kv_heads, pages_per_seq),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, chunk, group, head_dim), q_map),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((chunk, group), jnp.float32),
-            pltpu.VMEM((chunk, group), jnp.float32),
-            pltpu.VMEM((chunk, group, head_dim), jnp.float32),
+            pltpu.VMEM((rows_pad, 1), jnp.float32),
+            pltpu.VMEM((rows_pad, 1), jnp.float32),
+            pltpu.VMEM((rows_pad, head_dim), jnp.float32),
         ])
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_rows.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'parallel', 'arbitrary')),
         interpret=interpret,
-    )(page_indices, positions.astype(jnp.int32), *operands)
+        name='fused_paged_attention',
+    )(page_indices, *operands)
+    out = out[:, :, :rows].reshape(batch, num_kv_heads, chunk, group,
+                                   head_dim)
+    return jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(q.shape)
 
 
 def fused_paged_attention(q: jax.Array, k_pages: jax.Array,
@@ -357,14 +366,7 @@ def fused_paged_attention(q: jax.Array, k_pages: jax.Array,
     assert q.shape[2] % num_kv_heads == 0, (q.shape, k_pages.shape)
     call = functools.partial(_fused_call, interpret=interpret,
                              perturb=perturb)
-    from skypilot_tpu.ops.attention import _active_mesh
-    mesh = _active_mesh()
-    tensor = mesh.shape.get('tensor', 1) if mesh is not None else 1
-    if tensor <= 1 or num_kv_heads % tensor != 0:
-        return call(q, k_pages, v_pages, positions, page_indices,
-                    k_scales, v_scales)
     from jax.sharding import PartitionSpec as P
-    from skypilot_tpu.utils.jax_compat import shard_map
     qspec = P(None, None, 'tensor', None)       # grouped q heads
     pool = P('tensor', None, None, None)        # kv-heads axis
     rep = P(None, None)
@@ -378,8 +380,27 @@ def fused_paged_attention(q: jax.Array, k_pages: jax.Array,
         in_specs = (qspec, pool, pool, rep, rep, rep, rep)
         args = (q, k_pages, v_pages, positions, page_indices,
                 k_scales, v_scales)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=qspec, check_vma=False)(*args)
+    return shard_over_kv_heads(fn, num_kv_heads, in_specs=in_specs,
+                               out_specs=qspec)(*args)
+
+
+def shard_over_kv_heads(fn, num_kv_heads: int, *, in_specs, out_specs):
+    """`fn` shard_mapped over the active mesh's `tensor` axis when the
+    kv-heads axis divides it, else `fn` unchanged (no mesh context, a
+    single device, or the GQA-remainder replicated pool).
+
+    GSPMD treats a Pallas call as opaque: left alone under a sharded
+    jit it gathers the head-sharded pool onto every chip each layer,
+    each step. Both paged-attention kernels (this module's and the
+    upstream bf16 one) are per-kv-head independent, so each chip runs
+    the kernel on its own head slice of the pool."""
+    from skypilot_tpu.ops.attention import _active_mesh
+    mesh = _active_mesh()
+    tensor = mesh.shape.get('tensor', 1) if mesh is not None else 1
+    if tensor <= 1 or num_kv_heads % tensor != 0:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # -- fused QKV LoRA ---------------------------------------------------------

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from skypilot_tpu.utils import jax_compat
-from skypilot_tpu.utils.jax_compat import shard_map
 
 
 def _online_block_update(o, m, l, s, v):
@@ -50,7 +48,7 @@ def _ring_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
                             vary_axes: Tuple[str, ...] = ()) -> jax.Array:
     """Runs on each shard: q,k,v are the LOCAL [B,Sl,H,D] blocks."""
     vary_axes = tuple(vary_axes) or (axis_name,)
-    num_shards = jax_compat.axis_size(axis_name)
+    num_shards = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     batch, s_local, num_heads, head_dim = q.shape
     scale = 1.0 / (head_dim ** 0.5)
@@ -58,10 +56,9 @@ def _ring_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
 
     # Mark accumulators device-varying over every axis the inputs vary
     # on, so the fori_loop carry type stays stable once they mix with
-    # per-shard data (jax>=0.9 spells pvary as pcast(to='varying');
-    # pre-vma jax has no such type system and the shim is identity).
+    # per-shard data.
     def _vary(x):
-        return jax_compat.pvary(x, vary_axes)
+        return lax.pcast(x, vary_axes, to='varying')
 
     o = _vary(jnp.zeros((batch, s_local, num_heads, head_dim), jnp.float32))
     m = _vary(jnp.full((batch, s_local, num_heads), -jnp.inf, jnp.float32))
@@ -111,7 +108,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     vary_axes = tuple(batch_axes) + (seq_axis,)
     if heads_axis is not None:
         vary_axes += (heads_axis,)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_attention_sharded, axis_name=seq_axis,
                           causal=causal, vary_axes=vary_axes),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)

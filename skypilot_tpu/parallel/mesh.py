@@ -169,8 +169,18 @@ def make_mesh(config: MeshConfig,
         try:
             device_array = mesh_utils.create_device_mesh(
                 config.shape, devices=devices)
-        except (ValueError, AssertionError):
-            # Fallback (e.g. CPU device counts with no physical topology).
+        except (ValueError, AssertionError) as e:
+            # CPU device counts have no physical topology: enumeration
+            # order is all there is. On a TPU the same refusal (a
+            # subset of a slice, an odd shape) costs ICI locality, so
+            # it is said, not swallowed.
+            if devices[0].platform == 'tpu':
+                print(f'mesh: create_device_mesh refused shape '
+                      f'{config.shape} over devices '
+                      f'{[d.id for d in devices]} ({e}); laying the '
+                      f'mesh out in enumeration order — neighbours on '
+                      f'a mesh axis may not be ICI neighbours',
+                      flush=True)
             device_array = np.asarray(devices).reshape(config.shape)
     return Mesh(device_array, config.axis_names)
 
@@ -196,4 +206,26 @@ def rules_with_overrides(
 def mesh_summary(mesh: Mesh) -> str:
     parts = [f'{name}={size}' for name, size in
              zip(mesh.axis_names, mesh.devices.shape) if size > 1]
-    return f'Mesh({", ".join(parts) or "single-device"})'
+    if not parts:
+        return 'Mesh(single-device)'
+    # Device ids in mesh order: on a 2x2 host this is where a layout
+    # that ignores the torus would show.
+    ids = [d.id for d in mesh.devices.flat]
+    shown = ids if len(ids) <= 16 else ids[:16] + ['...']
+    return f'Mesh({", ".join(parts)}; device ids {shown})'
+
+
+def device_memory() -> List[Dict[str, Optional[int]]]:
+    """Bytes in use, and the peak, on each local device as the
+    runtime reports them (`memory_stats()`; nulls where the backend
+    has none, e.g. CPU). Only the process that holds the chips can
+    ask: the trainer prints this at exit and the server reports it in
+    /stats, which is how a model initialised whole on chip 0 shows."""
+    out = []
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        out.append({'id': d.id,
+                    'bytes_in_use': stats.get('bytes_in_use'),
+                    'peak_bytes_in_use': stats.get('peak_bytes_in_use'),
+                    'bytes_limit': stats.get('bytes_limit')})
+    return out

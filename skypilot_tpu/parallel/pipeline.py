@@ -520,8 +520,7 @@ class PipelinedLM:
                                                      'stage')
             return jax.lax.pmean(total / M, 'data')
 
-        from skypilot_tpu.utils.jax_compat import shard_map
-        fn = shard_map(
+        fn = jax.shard_map(
             pipeline, mesh=self.mesh,
             in_specs=(jax.tree.map(lambda _: P('stage'), stacked),
                       self._rest_specs(rest),
@@ -826,8 +825,7 @@ class PipelinedLM:
                 rest_local, rest_psum)
             return loss, (g_stacked, g_rest)
 
-        from skypilot_tpu.utils.jax_compat import shard_map
-        fn = shard_map(
+        fn = jax.shard_map(
             pipeline, mesh=self.mesh,
             in_specs=(stacked_specs, rest_specs,
                       P(None, 'data', None), P()),

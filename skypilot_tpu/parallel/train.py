@@ -71,15 +71,16 @@ def default_optimizer(learning_rate: float = 3e-4,
     )
 
 
-# XLA's async-collective / latency-hiding knobs (TPU compiler): with
+# The TPU compiler's async-collective / latency-hiding knobs: with
 # these on, the per-leaf grad "buckets" the overlap path emits become
 # independently schedulable async reduce-scatters that the latency-
 # hiding scheduler hoists into the backward, instead of one fused
-# blocking all-reduce after it. They must be in XLA_FLAGS before
-# backend init (train_lm --overlap sets them; bench/profile runs show
-# the collective gaps closing). Harmless to list; only applied on TPU
-# — the CPU build rejects unknown --xla_tpu_* flags.
-OVERLAP_XLA_FLAGS: Tuple[str, ...] = (
+# blocking all-reduce after it. The TPU compiler lives inside libtpu,
+# so they go in LIBTPU_INIT_ARGS, before backend init (train_lm
+# --overlap sets them): in XLA_FLAGS the host-side parser aborts the
+# process on them ("Unknown flag in XLA_FLAGS", my chip run, PR 21).
+# Nothing but libtpu reads that variable, so a CPU run ignores it.
+OVERLAP_LIBTPU_FLAGS: Tuple[str, ...] = (
     '--xla_tpu_enable_async_collective_fusion=true',
     '--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true',
     '--xla_tpu_enable_async_collective_fusion_multiple_steps=true',
@@ -87,16 +88,6 @@ OVERLAP_XLA_FLAGS: Tuple[str, ...] = (
     '--xla_enable_async_all_gather=true',
     '--xla_enable_async_collective_permute=true',
 )
-
-
-def overlap_xla_flags(platform: Optional[str] = None) -> Tuple[str, ...]:
-    """The XLA_FLAGS `--overlap` adds for `platform` ('tpu'/'cpu'/
-    None=probe-free default 'tpu'). CPU gets none: the CPU XLA build
-    aborts on unknown --xla_tpu_* flags, and its collectives are
-    thread-copies with nothing to hide."""
-    if platform == 'cpu':
-        return ()
-    return OVERLAP_XLA_FLAGS
 
 
 def _supports_fused(model: nn.Module, loss_fn: Callable) -> bool:
@@ -204,7 +195,7 @@ class ShardedTrainer:
         # grad LEAF to the ZeRO-1 data-sharded layout right where the
         # backward produces it, so XLA emits one independent
         # reduce-scatter per stacked-layer leaf (schedulable into the
-        # backward under OVERLAP_XLA_FLAGS) instead of one fused
+        # backward under OVERLAP_LIBTPU_FLAGS) instead of one fused
         # all-reduce after the full backward.
         self.overlap = overlap
         self.guard = guard
@@ -455,8 +446,7 @@ class ShardedTrainer:
 
         `lax.scan` keeps the whole inner loop on-device: one dispatch,
         one executable, N steps — amortizing host->device dispatch
-        latency (dominant under remote-relay/RPC device access, and a
-        free win on directly-attached chips too). Takes tokens stacked
+        latency. Takes tokens stacked
         [inner_steps, B, S]; returns (state, losses[inner_steps]).
         """
         sharding = self.state_sharding(example_tokens)

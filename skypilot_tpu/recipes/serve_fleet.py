@@ -126,8 +126,12 @@ def main() -> None:
     parser.add_argument('--tensor', type=int, default=1,
                         help='forwarded to every replica: tensor-'
                              'parallel serving over N devices '
-                             '(serve_lm --tensor). Each replica '
-                             'claims its own N chips')
+                             '(serve_lm --tensor). On a TPU host only '
+                             'ONE real replica can run — a chip '
+                             'belongs to one process and replicas '
+                             'have no device assignment yet; a '
+                             'second is refused (replica_manager.'
+                             'serve_lm_factory)')
     parser.add_argument('--stages', type=int, default=1,
                         help='forwarded to every replica: pipeline-'
                              'parallel serving over S stages '

@@ -286,9 +286,7 @@ def main() -> None:
                              'skypilot_serving_slo_* gauges go live '
                              '(docs/guides.md "Tracing & SLOs")')
     parser.add_argument('--cpu', action='store_true',
-                        help='pin the CPU backend (smoke/dev runs; the '
-                             'JAX_PLATFORMS env var is overridden by '
-                             'some TPU plugins, jax.config is not)')
+                        help='pin the CPU backend (smoke/dev runs)')
     args = parser.parse_args()
     if args.slo:
         # Fail fast at startup, not at first scrape.

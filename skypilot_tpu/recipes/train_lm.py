@@ -15,6 +15,7 @@ Usage (see examples/*.yaml):
 from __future__ import annotations
 
 import argparse
+import json
 import os
 import sys
 import time
@@ -47,6 +48,16 @@ def _build_model(name: str, seq: int, remat: bool):
     if name == 'llama3-8b':
         from skypilot_tpu.models.llama import Llama, LlamaConfig
         cfg = LlamaConfig.llama3_8b(max_seq_len=max(seq, 2048), remat=remat)
+        return Llama(cfg), cfg.vocab_size, None
+    if name == 'llama3-8b-l8':
+        # Llama-3-8B at every published width, cut to 8 of its 32
+        # layers (the model-configs guide's depth cut: data, not an
+        # architecture) so that one 16 GB chip holds the bf16 weights
+        # (~2.8B parameters, ~5.6 GB) and a real page pool.
+        from skypilot_tpu.models.llama import Llama, LlamaConfig
+        cfg = LlamaConfig.llama3_8b(num_layers=8,
+                                    max_seq_len=max(seq, 2048),
+                                    remat=remat)
         return Llama(cfg), cfg.vocab_size, None
     if name == 'llama-tiny':
         from skypilot_tpu.models.llama import Llama, LlamaConfig
@@ -237,9 +248,10 @@ def main() -> None:
                              'otherwise)')
     parser.add_argument('--overlap', action='store_true',
                         help='overlap collectives with compute: adds '
-                             "XLA's async-collective latency-hiding "
-                             'flags to XLA_FLAGS (TPU; no-op on '
-                             '--cpu) and, with --zero1, buckets the '
+                             "the TPU compiler's async-collective "
+                             'latency-hiding flags to LIBTPU_INIT_ARGS '
+                             '(read by libtpu only) and, with --zero1, '
+                             'buckets the '
                              'grad reduce-scatter per parameter leaf '
                              'so it issues as backward produces each '
                              'leaf instead of one fused update after '
@@ -289,23 +301,22 @@ def main() -> None:
                         help='step window to trace (after compile; '
                              'default 4:8)')
     parser.add_argument('--cpu', action='store_true',
-                        help='pin the CPU backend (smoke/dev runs; the '
-                             'JAX_PLATFORMS env var is overridden by '
-                             'some TPU plugins, jax.config is not)')
+                        help='pin the CPU backend (smoke/dev runs)')
     args = parser.parse_args()
 
+    t_start = time.perf_counter()
     if args.overlap:
-        # XLA reads XLA_FLAGS at backend init — extend it before any
-        # device access. CPU adds none: that build aborts on unknown
-        # --xla_tpu_* flags (and its collectives hide nothing).
-        from skypilot_tpu.parallel.train import overlap_xla_flags
-        flags = overlap_xla_flags('cpu' if args.cpu else None)
-        existing = os.environ.get('XLA_FLAGS', '')
-        add = [f for f in flags if f.split('=')[0] not in existing]
+        # libtpu reads LIBTPU_INIT_ARGS at backend init — extend it
+        # before any device access. (Not XLA_FLAGS: the host-side
+        # parser aborts on --xla_tpu_* flags it does not know.)
+        from skypilot_tpu.parallel.train import OVERLAP_LIBTPU_FLAGS
+        existing = os.environ.get('LIBTPU_INIT_ARGS', '')
+        add = [f for f in OVERLAP_LIBTPU_FLAGS
+               if f.split('=')[0] not in existing]
         if add:
-            os.environ['XLA_FLAGS'] = (existing + ' ' +
-                                       ' '.join(add)).strip()
-            print(f'overlap: XLA_FLAGS += {" ".join(add)}',
+            os.environ['LIBTPU_INIT_ARGS'] = (
+                existing + ' ' + ' '.join(add)).strip()
+            print(f'overlap: LIBTPU_INIT_ARGS += {" ".join(add)}',
                   flush=True)
 
     if args.cpu:
@@ -315,6 +326,9 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
+
+    from skypilot_tpu.utils import compile_cache
+    cache_dir = compile_cache.configure()
 
     from skypilot_tpu.utils import timeline
     if args.trace_file:
@@ -333,7 +347,7 @@ def main() -> None:
         raise SystemExit('--overlap buckets the grad reduce-scatter '
                          'onto the ZeRO-1 moment layout; add --zero1 '
                          '(under --pipeline-stages it only sets the '
-                         'XLA latency-hiding flags)')
+                         'compiler latency-hiding flags)')
     if args.virtual_stages and args.pipeline_schedule != 'interleaved':
         raise SystemExit('--virtual-stages only applies with '
                          '--pipeline-schedule interleaved')
@@ -683,6 +697,13 @@ def main() -> None:
             bad_flag = None
         else:
             loss, gnorm, bad_flag = aux, None, None
+        if first and proc_id == 0:
+            # Set-up and steady state are reported apart: the first
+            # step carries the compile (or the compile-cache read).
+            jax.block_until_ready(loss)
+            print(f'setup: init {t0 - t_start:.1f}s, first step (compile '
+                  f'+ run) {time.perf_counter() - t0:.1f}s, compile '
+                  f'cache {cache_dir}', flush=True)
         if tracing and step + 1 >= prof_stop:
             # Block so the trace holds COMPLETE device timelines for
             # the window, not just dispatches.
@@ -833,6 +854,8 @@ def main() -> None:
     if emitter is not None:
         emitter.close()
     if proc_id == 0:
+        print(f'device memory: {json.dumps(mesh_lib.device_memory())}',
+              flush=True)
         print('training done', flush=True)
     if args.trace_file:
         timeline.save()

@@ -202,7 +202,18 @@ def serve_lm_factory(base_cmd: List[str],
     """Factory spawning `serve_lm` subprocesses: `base_cmd` is the
     full command line WITHOUT `--port` (appended per replica).
     `python -m skypilot_tpu.recipes.serve_lm --model ... --cpu` is
-    the usual shape (recipes/serve_fleet.py builds it)."""
+    the usual shape (recipes/serve_fleet.py builds it).
+
+    ONE PROCESS PER CHIP. A TPU chip belongs to the process that
+    opened it; a second `serve_lm` on the same host fails at backend
+    init ("The TPU is already in use by process with pid ..."), and
+    nothing here gives each replica its own chips yet (ROADMAP R6:
+    per-replica device assignment, or N one-chip replicas inside one
+    process). So on a host with TPU chips, a second live real replica
+    is refused here, by name, instead of dying with `quiet` eating
+    the reason. CPU replicas (`--cpu`, `JAX_PLATFORMS=cpu`) and stub
+    replicas are not affected."""
+    live: List['subprocess.Popen'] = []
 
     def spawn(replica_id: int, port: int,
               instance_uuid: str = '',
@@ -211,6 +222,21 @@ def serve_lm_factory(base_cmd: List[str],
         del replica_id
         out = subprocess.DEVNULL if quiet else None
         child_env = dict(env if env is not None else os.environ)
+        live[:] = [p for p in live if p.poll() is None]
+        on_cpu = ('--cpu' in base_cmd or
+                  child_env.get('JAX_PLATFORMS', '').strip() == 'cpu')
+        if live and not on_cpu:
+            from skypilot_tpu.utils import tpu_utils
+            chips = tpu_utils.local_tpu_chips()
+            if chips:
+                raise RuntimeError(
+                    f'refusing to start a second serve_lm replica on '
+                    f'this TPU host ({chips} chip(s); replica pid '
+                    f'{live[0].pid} holds them): a chip belongs to one '
+                    f'process and replicas have no per-replica device '
+                    f'assignment yet (ROADMAP R6). Run one replica per '
+                    f'host — `--tensor N` makes it span the host\'s '
+                    f'chips — or pass --cpu for a CPU fleet.')
         if instance_uuid:
             child_env[INSTANCE_UUID_ENV] = instance_uuid
         cmd = base_cmd + ['--port', str(port)]
@@ -218,9 +244,11 @@ def serve_lm_factory(base_cmd: List[str],
             cmd += ['--role', role]
         if zone:
             cmd += ['--zone', zone]
-        return subprocess.Popen(
+        proc = subprocess.Popen(
             cmd, env=child_env,
             stdout=out, stderr=subprocess.STDOUT if quiet else None)
+        live.append(proc)
+        return proc
 
     return spawn
 

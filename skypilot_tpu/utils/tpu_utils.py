@@ -196,3 +196,14 @@ def standard_slice_sizes(version: str) -> List[int]:
     if suffix_is_chips:
         return chips
     return [c * cores_per_chip for c in chips]
+
+
+def local_tpu_chips() -> int:
+    """TPU chips attached to THIS host, counted from the device nodes
+    the driver exposes (`/dev/accel*` on v2-v4 VMs, `/dev/vfio/<n>` on
+    v5e and later) — without importing JAX: a process that asks JAX
+    takes the chip, and the orchestration layer (replica manager,
+    chip_smoke.py's parent) must stay off it. 0 on a CPU-only host."""
+    import glob
+    return (len(glob.glob('/dev/accel[0-9]*')) +
+            len(glob.glob('/dev/vfio/[0-9]*')))

@@ -178,7 +178,7 @@ def test_aws_resource_count_exceeded_is_transient():
 
 def test_minimum_pattern_breadth():
     """The library must keep >=20 distinct classified shapes per major
-    cloud (VERDICT r3 item 3)."""
+    cloud."""
     assert len(fp.GCP_PATTERNS) >= 20
     assert len(fp.AWS_PATTERNS) >= 20
     assert len(fp.AZURE_PATTERNS) >= 20

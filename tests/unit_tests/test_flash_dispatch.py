@@ -8,6 +8,7 @@ data/fsdp, heads over tensor) and match the XLA reference exactly.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from skypilot_tpu.ops import attention as attn
 from skypilot_tpu.parallel import mesh as mesh_lib
@@ -70,14 +71,20 @@ def test_flash_gqa_expansion_under_mesh():
                                atol=1e-5)
 
 
-def test_flash_falls_back_when_batch_indivisible():
-    """Batch 3 can't split over 8 shards: _flash must signal fallback
-    (None) instead of crashing in shard_map."""
+def test_flash_says_so_when_batch_indivisible():
+    """Batch 3 can't split over 8 shards: 'auto' is told so ONCE at
+    trace time (a warning naming the XLA route it takes instead), and
+    asking for the kernel by name raises — nothing quietly returns
+    None for the caller to paper over."""
     mesh = mesh_lib.make_mesh(mesh_lib.MeshConfig(data=2, fsdp=4))
     q, k, v = _rand_qkv(batch=3)
     with mesh:
-        assert attn._flash(q, k, v, causal=True,
-                           kernel=_plain_kernel) is None
+        with pytest.warns(UserWarning, match='flash attention not used'):
+            assert attn._flash_shardable(q) is False
+        with pytest.raises(ValueError, match='does not divide'):
+            attn._flash(q, k, v, causal=True, kernel=_plain_kernel)
+        q8, _, _ = _rand_qkv(batch=8)
+        assert attn._flash_shardable(q8) is True
 
 
 def test_flash_no_mesh_runs_kernel_directly():

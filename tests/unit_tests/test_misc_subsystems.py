@@ -710,7 +710,7 @@ def test_hosts_legacy_unscoped_block_is_migrated(isolated_state,
 def test_launch_daemon_pdeathsig_reaps_on_parent_kill(tmp_path):
     """With SKYPILOT_DAEMON_PDEATHSIG (test runs set it), a daemon dies
     when its launcher dies — a killed pytest run cannot strand
-    agents/controllers (VERDICT r3 test-hygiene item)."""
+    agents/controllers."""
     import signal
     import subprocess
     import sys

@@ -291,19 +291,15 @@ def test_zero1_checkpoint_roundtrip(tmp_path):
 
 def test_overlap_requires_zero1_and_flag_list():
     """--overlap contract: the trainer rejects overlap without the
-    ZeRO-1 layout it buckets onto, and the XLA flag helper is
-    platform-aware (the CPU build aborts on unknown --xla_tpu_*
-    flags, so CPU gets none)."""
-    from skypilot_tpu.parallel.train import (OVERLAP_XLA_FLAGS,
-                                             overlap_xla_flags)
+    ZeRO-1 layout it buckets onto, and the compiler flags are a list
+    for LIBTPU_INIT_ARGS (the TPU compiler is inside libtpu; the
+    host-side XLA_FLAGS parser aborts on --xla_tpu_* flags)."""
+    from skypilot_tpu.parallel.train import OVERLAP_LIBTPU_FLAGS
     model = Llama(LlamaConfig.tiny(dtype=jnp.float32))
     mesh = mesh_lib.make_mesh(mesh_lib.MeshConfig(data=4, fsdp=2))
     with pytest.raises(ValueError, match='zero1'):
         ShardedTrainer(model, mesh, overlap=True)
-    assert overlap_xla_flags('cpu') == ()
-    assert overlap_xla_flags('tpu') == OVERLAP_XLA_FLAGS
-    assert overlap_xla_flags() == OVERLAP_XLA_FLAGS
-    assert all(f.startswith('--xla') for f in OVERLAP_XLA_FLAGS)
+    assert all(f.startswith('--xla') for f in OVERLAP_LIBTPU_FLAGS)
 
 
 def test_overlap_grad_buckets_follow_zero1_layout():

@@ -11,8 +11,8 @@ Four contracts:
     (the PR 15 collective-guard discipline — a pin that cannot fail
     proves nothing);
   - DISPATCH: resolve_impl's auto rules, the $SKYPILOT_TPU_PAGED_IMPL
-    override, impl_scope, clean degradation to 'xla', and the
-    module-level probe + unavailable_reason;
+    override, impl_scope, a missing selected route raising instead of
+    degrading to 'xla', and unavailable_reason;
   - BIT IDENTITY end to end: an int8 + active-LoRA engine on the
     fused interpret path emits byte-identical greedy tokens to the
     XLA engine, and the mesh-sharded (tensor-2 host devices) kernel
@@ -170,18 +170,45 @@ def test_fused_qkv_lora_matches_apply_delta():
 
 # -- dispatch resolution ----------------------------------------------------
 def test_resolve_impl_cpu_rules():
-    # CPU: no compiled kernel, upstream kernel TPU-only -> everything
-    # degrades to 'xla' except the interpret route.
+    # CPU: 'auto' observes the backend and takes the XLA reference;
+    # the interpret route runs anywhere.
     assert pp.resolve_impl('auto', quantized=True) == 'xla'
     assert pp.resolve_impl('auto', quantized=False) == 'xla'
-    assert pp.resolve_impl('kernel', quantized=False) == 'xla'
-    assert pp.resolve_impl('kernel', quantized=True) == 'xla'
-    assert pp.resolve_impl('fused', quantized=True) == 'xla'
+    assert pp.resolve_impl('xla', quantized=True) == 'xla'
     assert pp.resolve_impl('fused_interpret') == 'fused_interpret'
     with pytest.raises(ValueError):
         pp.resolve_impl('bogus')
     with pytest.raises(ValueError):
         pp.set_default_impl('bogus')
+
+
+def test_resolve_impl_raises_when_selected_route_is_missing(monkeypatch):
+    """A route selected BY NAME that cannot run is an error, never a
+    quiet 'xla': on the chip that switch is what would let a kernel
+    Mosaic refused go unnoticed behind a server that still answers."""
+    for impl in ('kernel', 'fused'):
+        with pytest.raises(ValueError, match='cannot run'):
+            pp.resolve_impl(impl)
+        monkeypatch.setenv(pp.ENV_VAR, impl)
+        with pytest.raises(ValueError, match='cannot run'):
+            pp.resolve_impl('auto')
+        monkeypatch.delenv(pp.ENV_VAR)
+    # The upstream kernel reads bf16 pools only — also an error, on
+    # any backend, instead of the old degrade.
+    with pytest.raises(ValueError, match='bf16 pools only'):
+        pp.resolve_impl('kernel', quantized=True)
+
+
+def test_resolve_impl_tpu_rules(monkeypatch):
+    """What 'auto' picks where the compiled routes exist (the backend
+    is the one thing faked: CPU tests cannot have a TPU)."""
+    monkeypatch.setattr(pp.jax, 'default_backend', lambda: 'tpu')
+    assert pp.unavailable_reason() is None
+    assert pp.resolve_impl('auto', quantized=False) == 'kernel'
+    assert pp.resolve_impl('auto', quantized=True) == 'fused'
+    assert pp.resolve_impl('fused', quantized=False) == 'fused'
+    assert pp.lora_fusion_impl(quantized=True) == 'fused'
+    assert pp.lora_fusion_impl(quantized=False) is None
 
 
 def test_env_and_scope_overrides(monkeypatch):
@@ -198,16 +225,13 @@ def test_env_and_scope_overrides(monkeypatch):
     assert pp.lora_fusion_impl() is None
 
 
-def test_probe_reports_why_kernel_is_off():
-    """Module-level cached probe + recorded reason (the /stats
-    storage field and skip-message source)."""
-    assert pp.pallas_importable()
+def test_reports_why_kernel_is_off():
+    """The recorded reason (the /stats storage field and skip-message
+    source)."""
     assert not pp.available()            # CPU test environment
     reason = pp.unavailable_reason()
     assert reason is not None and 'fused_interpret' in reason
-    assert pp.unavailable_reason() is reason or \
-        pp.unavailable_reason() == reason       # stable across calls
-    assert pa._pallas_paged_available() is False
+    assert pp.unavailable_reason() == reason    # stable across calls
 
 
 def test_bytes_per_token_model_fused_beats_xla_at_int8():
@@ -257,6 +281,45 @@ def test_mesh_sharded_kernel_bit_identical():
         out3 = pp.fused_paged_attention(q3, k3, v3, pos, tbl3,
                                         interpret=True)
     np.testing.assert_array_equal(np.asarray(out3), np.asarray(ref3))
+
+
+def test_upstream_kernel_is_shard_mapped_under_a_tensor_mesh(monkeypatch):
+    """The route the one-chip server takes on TPU (bf16 pool ->
+    upstream kernel) under --tensor N: the call must be shard_mapped
+    over kv heads like the in-repo kernel is, or GSPMD — to which a
+    Pallas call is opaque — gathers the head-sharded pool onto every
+    chip, each layer, each step. Runs the real upstream kernel in the
+    TPU interpreter (the one way a CPU can execute its DMAs)."""
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from skypilot_tpu.parallel import mesh as mesh_lib
+    if len(jax.devices()) < 2:
+        pytest.skip('needs >= 2 host devices')
+    monkeypatch.setattr(pp, 'available', lambda: True)
+    mesh = mesh_lib.make_mesh(mesh_lib.MeshConfig(tensor=2),
+                              devices=jax.devices()[:2])
+    batch, hq, hkv, hd, page, total = 2, 8, 2, 128, 16, 9
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    k = jax.random.normal(keys[0], (hkv, total, page, hd), jnp.bfloat16)
+    v = jax.random.normal(keys[1], (hkv, total, page, hd), jnp.bfloat16)
+    q = jax.random.normal(keys[2], (batch, hq, hd), jnp.bfloat16)
+    tbl = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
+    lengths = jnp.asarray([37, 64], jnp.int32)
+    ref = pa.paged_decode_attention(q, k, v, lengths, tbl, impl='xla')
+    pool = NamedSharding(mesh, P('tensor'))
+    heads = NamedSharding(mesh, P(None, 'tensor', None))
+    args = (jax.device_put(q, heads), jax.device_put(k, pool),
+            jax.device_put(v, pool), lengths, tbl)
+    fn = jax.jit(lambda *a: pa.paged_decode_attention(*a, impl='kernel'))
+    with pltpu.force_tpu_interpret_mode(), mesh:
+        out = fn(*args)
+        hlo = fn.lower(*args).compile().as_text()
+    assert out.sharding.spec == P(None, 'tensor', None)
+    assert 'all-gather' not in hlo and 'all-to-all' not in hlo
+    # bf16 operands, f32 accumulation on both sides.
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=2e-2, rtol=2e-2)
 
 
 # -- end-to-end engine bit identity (int8 KV + active LoRA) -----------------

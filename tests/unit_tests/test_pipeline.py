@@ -148,24 +148,7 @@ def test_train_lm_pipeline_cli(tmp_path):
     assert 'resumed from checkpoint step 2' in out.stdout
 
 
-# Probe-based gate (re-triaged in the schedule-object PR): the probe
-# compiles the failing ingredient itself — axis_index over a manual
-# mesh axis with another axis left auto — so these tests re-enable
-# automatically the moment the pinned jax/XLA partitions the
-# PartitionId HLO, and until then the skip names the exact missing
-# feature verbatim (on jax 0.4.37: "UNIMPLEMENTED: PartitionId
-# instruction is not supported for SPMD partitioning").
-_pm_reason = __import__(
-    'skypilot_tpu.utils.jax_compat',
-    fromlist=['x']).partial_manual_unsupported_reason()
-_needs_partial_manual = pytest.mark.skipif(
-    _pm_reason is not None,
-    reason=f'partial-manual shard_map (tensor-within-stages) '
-           f'unsupported by the pinned jax/XLA: {_pm_reason}')
-
-
 @pytest.mark.slow
-@_needs_partial_manual
 def test_train_lm_pipeline_with_tensor_cli(tmp_path):
     """dp x pp x tp from the CLI: v2 shards tensor WITHIN stages."""
     import os
@@ -224,7 +207,6 @@ def test_pipeline_llama_matches_sequential():
 
 
 @pytest.mark.slow
-@_needs_partial_manual
 def test_pipeline_tp_within_stages():
     """dp x pp x tp: tensor parallelism composes INSIDE pipeline
     stages (v2) — block leaves shard over `tensor` on their logical

@@ -62,7 +62,7 @@ def test_daemons_run_on_interval_and_survive_failures(monkeypatch):
 @pytest.mark.slow
 @pytest.mark.e2e
 def test_preempted_cluster_flips_out_of_up(isolated_state):
-    """VERDICT r3 item 6's done-criterion: a Local cluster whose agents
+    """A Local cluster whose agents
     die flips out of UP after one daemon tick with NOBODY calling
     status(refresh=True) from the outside."""
     import skypilot_tpu as sky
