@@ -1,13 +1,16 @@
 """SKY007: tracing spans must be closed.
 
 A span opened with `tracing.span(...)` or `tracing.start_span(...)`
-records its Chrome-trace event only on `end()` — a leaked span is a
+records its Chrome-trace event only on `end()`, and a loop phase
+opened with `tracing.phase(...)` reaches its accumulator and closes
+its profiler annotation only on exit — a leaked span is a
 silent hole in the merged trace (the request "disappears" mid-flight)
 and, at volume, an unbounded pile of never-recorded Span objects. The
 rule enforces the tracing module's own contract at every open site in
 non-test code:
 
-  - `with tracing.span(...):` — closed by `__exit__`; always clean.
+  - `with tracing.span(...):` / `with tracing.phase(...):` — closed
+    by `__exit__`; always clean.
   - `sp = tracing.start_span(...)` + `sp.end()` inside a `finally`
     in the same function — clean (the manual-lifetime idiom).
   - `sp.end()` NOT under a `finally` — finding: any exception between
@@ -28,7 +31,7 @@ from typing import Dict, List, Set, Tuple
 
 from skypilot_tpu.analysis import core
 
-_OPENERS = ('span', 'start_span')
+_OPENERS = ('span', 'start_span', 'phase')
 
 
 def _is_test_path(path: str) -> bool:
@@ -40,8 +43,8 @@ def _is_test_path(path: str) -> bool:
 class SpanDisciplineChecker(core.Checker):
     rule = 'SKY007'
     name = 'span-discipline'
-    description = ('Spans from tracing.span/start_span must be closed '
-                   'via `with` or `.end()` in a finally.')
+    description = ('Spans from tracing.span/start_span/phase must be '
+                   'closed via `with` or `.end()` in a finally.')
 
     def __init__(self, ctx: core.FileContext) -> None:
         super().__init__(ctx)

@@ -5,6 +5,8 @@ JetStream-shaped native endpoints + OpenAI shims:
   GET  /                       readiness + capacity
   GET  /stats                  engine + serving metrics, JSON
                                (rolling-window percentiles)
+  POST /debug/profile          one jax.profiler session of this
+                               process, {"seconds", "dir"}
   GET  /metrics                Prometheus text exposition of the
                                process registry: engine internals
                                (queue depth, slots, page pool,
@@ -117,8 +119,14 @@ def make_server(rt: InferenceRuntime,
     # /readyz flips to 503 while in-flight requests finish (k8s
     # readiness probes pull the replica out of rotation first).
     _draining = threading.Event()
+    # One profiler session at a time (POST /debug/profile).
+    _profiling = threading.Lock()
 
     class Handler(BaseHTTPRequestHandler):
+        # (seconds from handler entry to the engine submit's return,
+        # stream handles) of a streamed request whose first token has
+        # not reached the socket yet.
+        _ttft_pending = None
 
         def log_message(self, *a):  # quiet
             pass
@@ -137,7 +145,14 @@ def make_server(rt: InferenceRuntime,
         def send_json(self, obj, code=200):
             self._json(obj, code)
 
-        def sse_start(self):
+        def sse_start(self, handles=()):
+            # A streamed generation passes its stream handles: the
+            # engine's submit() has just returned for every row, which
+            # ends the first half of the HTTP layer's share of the
+            # first token (the second ends in sse_send).
+            self._ttft_pending = (
+                (time.monotonic() - self._t_enter, handles)
+                if handles else None)
             self.send_response(200)
             self.send_header('Content-Type', 'text/event-stream')
             self.send_header('Cache-Control', 'no-cache')
@@ -149,6 +164,19 @@ def make_server(rt: InferenceRuntime,
             self.wfile.write(b'data: ' + json.dumps(obj).encode() +
                              b'\n\n')
             self.wfile.flush()
+            if self._ttft_pending is not None:
+                self._observe_ttft_overhead(*self._ttft_pending)
+
+        def _observe_ttft_overhead(self, submit_s, handles):
+            """Once a request, at the first frame flushed after a
+            row's first token was committed: handler entry to the
+            submit's return, plus that commit to this flush."""
+            firsts = [h.first_token_t for h in handles
+                      if h.first_token_t is not None]
+            if firsts:
+                self._ttft_pending = None
+                rt.metrics.prom.http_ttft_overhead_seconds.observe(
+                    submit_s + time.monotonic() - min(firsts))
 
         def sse_done(self):
             self.wfile.write(b'data: [DONE]\n\n')
@@ -246,6 +274,42 @@ def make_server(rt: InferenceRuntime,
                 'engines': [eng.flight.dump()
                             for eng in rt.live_engines()],
             })
+
+        def _debug_profile(self):
+            """`{"seconds": s, "dir": path}`: one jax.profiler
+            session of `s` seconds in THIS process, the one that
+            holds the chip, written under `path`: device events and
+            the host tracer (which records the scheduler's `engine.*`
+            phases on the device events' clock), the Python tracer
+            off, since it slows the loop it would observe. Answers
+            when the session has stopped; 409 while another runs.
+            Load `path` in Perfetto or TensorBoard."""
+            try:
+                req = self._read_body()
+                seconds, out_dir = float(req['seconds']), str(req['dir'])
+                if not 0 < seconds <= 120 or not out_dir:
+                    raise ValueError('seconds in (0, 120] and a dir')
+            except (KeyError, TypeError, ValueError) as e:
+                self._json({'error': f'{type(e).__name__}: {e}'}, 400)
+                return
+            if not _profiling.acquire(blocking=False):
+                self._json({'error': 'a profiler session is running'},
+                           409)
+                return
+            try:
+                import jax
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                options.host_tracer_level = 1
+                jax.profiler.start_trace(out_dir,
+                                         profiler_options=options)
+                try:
+                    time.sleep(seconds)
+                finally:
+                    jax.profiler.stop_trace()
+            finally:
+                _profiling.release()
+            self._json({'dir': out_dir, 'seconds': seconds})
 
         def _debug_pool_collectives(self):
             """The sharded-pool guard on the decode dispatch as
@@ -353,7 +417,26 @@ def make_server(rt: InferenceRuntime,
                 'prefill_chunks_run': engine.prefill_chunks_run,
                 'prefill_backlog_tokens':
                     engine.prefill_backlog_tokens(),
-                'decode_stall_s': round(engine.decode_stall_s, 4),
+                # The scheduler loop's phases (docs/guides.md
+                # "Scheduler phases"): self seconds and counts per
+                # phase; `loop_s` is the loop's wall time, which they
+                # partition; `decode_stall_s` is engine.fetch_wait's.
+                'decode_stall_s': round(engine.decode_stall_s, 6),
+                'phases': engine.phase_stats(),
+                'loop_s': round(engine.loop_s, 6),
+                'time': time.time(),
+                # Every request's intervals on the way to its first
+                # token, as bucket counts (the histograms /metrics
+                # renders): a reader takes a window's percentile from
+                # the growth between two scrapes.
+                'latency': {
+                    'queue_wait': obs_catalog.latency_stats(
+                        engine.metrics.queue_wait_seconds),
+                    'admit_to_first_token': obs_catalog.latency_stats(
+                        engine.metrics.prefill_seconds),
+                    'http_ttft_overhead': obs_catalog.latency_stats(
+                        rt.metrics.prom.http_ttft_overhead_seconds),
+                },
                 # Pipeline-parallel serving (--stages): stage count
                 # and the closed-form (S-1)/(M+S-1) fill/drain bubble
                 # of the last prefill burst (0.0 when unstaged).
@@ -434,6 +517,7 @@ def make_server(rt: InferenceRuntime,
 
         # -- POST ---------------------------------------------------
         def do_POST(self):  # noqa: N802
+            self._t_enter = time.monotonic()
             with _inflight_lock:
                 _inflight['n'] += 1
             try:
@@ -501,6 +585,9 @@ def make_server(rt: InferenceRuntime,
                 return
             if self.path == '/kv/migrate':
                 self._kv_migrate()
+                return
+            if self.path == '/debug/profile':
+                self._debug_profile()
                 return
             handler = self._route_generation(self.path)
             if handler is None:
@@ -1029,7 +1116,7 @@ def make_server(rt: InferenceRuntime,
                 deadline_s=deadline_s, adapter=adapter,
                 trace_ctx=getattr(self, '_trace_ctx', None))
                 for row in tokens]
-            self.sse_start()
+            self.sse_start(handles)
             n_gen = 0
             ttft = None
             migrated = False
@@ -1331,7 +1418,7 @@ def make_server(rt: InferenceRuntime,
                 deadline_s=deadline_s, adapter=adapter,
                 trace_ctx=getattr(self, '_trace_ctx', None))
                        for ids in encoded]
-            self.sse_start()
+            self.sse_start(handles)
             decs = [oai.IncrementalDecoder(tok) for _ in encoded]
             scans = [oai.StopStringScanner(stop_strings)
                      for _ in encoded]

@@ -394,7 +394,7 @@ def stream_completion(rt: InferenceRuntime, req: CompletionRequest,
                                 deadline_s=req.deadline_s,
                                 adapter=req.adapter)
                for _ in range(req.n)]
-    writer.sse_start()
+    writer.sse_start(handles)
     obj = 'chat.completion.chunk' if chat else 'text_completion'
     model_name = req.model or rt.model_name
 

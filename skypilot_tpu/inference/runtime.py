@@ -182,6 +182,9 @@ class StreamHandle:
         self.future: Optional['Future'] = None  # set right after submit
         self.t0 = time.monotonic()
         self.first_token_s: Optional[float] = None
+        # Monotonic instant of the first commit: the HTTP layer times
+        # that token's way to the socket from it.
+        self.first_token_t: Optional[float] = None
         self._metrics = metrics
         self._last_token_t: Optional[float] = None
 
@@ -189,6 +192,7 @@ class StreamHandle:
         now = time.monotonic()
         if self.first_token_s is None:
             self.first_token_s = now - self.t0
+            self.first_token_t = now
         elif self._metrics is not None:
             self._metrics.record_inter_token(now - self._last_token_t)
         self._last_token_t = now

@@ -280,7 +280,7 @@ class ContinuousBatchingEngine:
         # counters (scrape threads read these racily, on purpose)
         'decode_calls': 'scheduler', 'tokens_committed': 'scheduler',
         'preemptions': 'scheduler', 'prefill_chunks_run': 'scheduler',
-        'decode_stall_s': 'scheduler',
+        'phases': 'scheduler',
         'last_prefill_tokens': 'scheduler',
         'kv_restored_pages': 'scheduler',
         'kv_restore_lookups': 'scheduler',
@@ -632,7 +632,10 @@ class ContinuousBatchingEngine:
         self.tokens_committed = 0
         self.preemptions = 0
         self.prefill_chunks_run = 0
-        self.decode_stall_s = 0.0        # host blocked on device_get
+        # The scheduler loop's phases (observability/tracing.phase):
+        # self seconds and counts per name, served by /stats
+        # (`phases`, `loop_s`, `decode_stall_s`) and /metrics.
+        self.phases = tracing.PhaseClock()
         self.last_prefill_tokens = 0     # budget spent, last iteration
         # Live migration (PR 20): sessions evacuated off this engine
         # (drain / preemption notice / rebalance) and the subset whose
@@ -1415,13 +1418,13 @@ class ContinuousBatchingEngine:
         deadline = (time.monotonic() + float(deadline_s)
                     if deadline_s is not None else 0.0)
         fut: Future = Future()
-        # `tref` carries (ctx, enqueue perf_counter) so admission can
-        # emit the queue-wait span; None for unsampled requests (no
-        # clock read). Positional invariants the rest of the
-        # scheduler relies on survive: item[0] is the prompt,
-        # item[-2] the deadline, item[-1] the future.
-        tref = ((trace_ctx, time.perf_counter())
-                if trace_ctx is not None else None)
+        # `tref` carries (ctx, enqueue perf_counter): admission
+        # observes every request's queue wait from it, and emits the
+        # queue-wait span for a sampled one (ctx is None otherwise).
+        # Positional invariants the rest of the scheduler relies on
+        # survive: item[0] is the prompt, item[-2] the deadline,
+        # item[-1] the future.
+        tref = (trace_ctx, time.perf_counter())
         self._queue.put((list(prompt), int(max_new_tokens),
                          float(temp), int(top_k), float(top_p),
                          frozenset(stop_token_ids or ()), adapter,
@@ -1654,6 +1657,28 @@ class ContinuousBatchingEngine:
                                         self.kv_dtype)
         self.metrics.attention_bytes_per_token.set(
             self.attention_bytes_per_token()['total_bytes_per_token'])
+        for name, rec in self.phase_stats().items():
+            self.metrics.set_phase_seconds(name, rec['s'])
+
+    @property
+    def decode_stall_s(self) -> float:
+        """Seconds the scheduler stood blocked fetching a round's
+        tokens: the `engine.fetch_wait` phase."""
+        return self.phases.seconds('engine.fetch_wait')
+
+    @property
+    def loop_s(self) -> float:
+        """Cumulative wall seconds of the scheduler loop's
+        iterations (recovery included); the phases partition it."""
+        return self.phases.inclusive('engine.loop')
+
+    def phase_stats(self) -> Dict[str, Dict[str, Any]]:
+        """{phase: {'n', 's'}} of the scheduler loop, self seconds;
+        `loop_s` less their sum is what no phase covers (racy read:
+        at worst one iteration apart)."""
+        return {name: {'n': int(rec[0]), 's': round(rec[1], 6)}
+                for name, rec in sorted(self.phases.totals.items())
+                if name != 'engine.loop'}
 
     # -- KV page transfer + tiered cache ------------------------------------
     def run_on_scheduler(self, fn, timeout: float = 120.0):  # stpu: hop[scheduler]
@@ -2165,11 +2190,14 @@ class ContinuousBatchingEngine:
             with (self.mesh if self.mesh is not None and self.stages == 1
                   else contextlib.nullcontext()):
                 while not self._stop.is_set():
-                    try:
-                        self._iterate()
-                        self._soft_errors = 0
-                    except Exception as e:  # pylint: disable=broad-except
-                        self._recover_from_error(e)
+                    with tracing.phase('engine.loop', self.phases):
+                        try:
+                            self._iterate()
+                            self._soft_errors = 0
+                        except Exception as e:  # pylint: disable=broad-except
+                            with tracing.phase('engine.recover',
+                                               self.phases):
+                                self._recover_from_error(e)
         finally:
             if not self._stop.is_set():
                 self._dead.set()
@@ -2199,27 +2227,38 @@ class ContinuousBatchingEngine:
         chunked prefill -> one decode round for the active slots. Long
         prompts therefore interleave with decoding instead of stalling
         it; with pipelining the decode round's host commit overlaps
-        the NEXT round's device compute."""
-        progressed = self._run_control_ops()
-        progressed = self._admit() or progressed
-        self._apply_cancellations()
-        self._reap_deadlines()
+        the NEXT round's device compute.
+
+        Every stretch of it is one of the `engine.*` phases
+        (docs/guides.md "Scheduler phases"): exclusive, and together
+        the iteration's wall time."""
+        phases = self.phases
+        with tracing.phase('engine.control', phases):
+            progressed = self._run_control_ops()
+        with tracing.phase('engine.admit', phases):
+            progressed = self._admit() or progressed
+        with tracing.phase('engine.control', phases):
+            self._apply_cancellations()
+            self._reap_deadlines()
         if self._prefill_order:
             self._prefill_work()
             progressed = True
         if self.active.any() or self._inflight is not None or \
                 any(f is not None for f in self._group_inflight):
-            t_step = time.perf_counter()
-            committed0 = self.tokens_committed
-            self._decode_step()
-            dt_step = time.perf_counter() - t_step
-            self.metrics.decode_step_seconds.observe(dt_step)
-            self.flight.record(
-                'round_commit',
-                tokens=self.tokens_committed - committed0,
-                active=int(self.active.sum()))
+            # The round's fetch_wait and commit phases nest in this
+            # one, so its SELF time is the dispatch half and its whole
+            # duration the decode step.
+            with tracing.phase('engine.decode_dispatch',
+                               phases) as step:
+                committed0 = self.tokens_committed
+                self._decode_step()
+                self.flight.record(
+                    'round_commit',
+                    tokens=self.tokens_committed - committed0,
+                    active=int(self.active.sum()))
+            self.metrics.decode_step_seconds.observe(step.dur)
             if tracing.enabled():
-                self._trace_decode_round(dt_step)
+                self._trace_decode_round(step.dur)
             progressed = True
         if not progressed and self._queue.empty() and \
                 not self._ready:
@@ -2227,10 +2266,11 @@ class ContinuousBatchingEngine:
             # item goes straight into _ready — a get+put-back
             # would rotate the queue head to the TAIL,
             # inverting FCFS admission order.
-            try:
-                self._ready.append(self._queue.get(timeout=0.05))
-            except queue.Empty:
-                pass
+            with tracing.phase('engine.idle_wait', phases):
+                try:
+                    self._ready.append(self._queue.get(timeout=0.05))
+                except queue.Empty:
+                    pass
 
     def _cache_lost(self) -> bool:
         """True when the donated KV cache buffer is gone (the device
@@ -2439,7 +2479,7 @@ class ContinuousBatchingEngine:
         while self._ready and not self._occupied().all():
             (prompt, max_new, temp, top_k, top_p, stops, adapter,
              tref, on_token, deadline, fut) = self._ready.popleft()
-            t_adm = time.perf_counter() if tref is not None else 0.0
+            t_adm = time.perf_counter()
             self._queued_tokens_sub(len(prompt))
             if deadline and time.monotonic() > deadline:
                 # Expired while queued: prefilling it would only delay
@@ -2501,7 +2541,7 @@ class ContinuousBatchingEngine:
                         n_res0 = len(shared)
                         t_res = time.perf_counter()
                         self._restore_from_spill(keys, shared)
-                        if tref is not None and len(shared) > n_res0:
+                        if len(shared) > n_res0:
                             tracing.record_span(
                                 'engine.kv_restore', tref[0],
                                 time.perf_counter() - t_res,
@@ -2523,7 +2563,7 @@ class ContinuousBatchingEngine:
                 # max_total_len, so a lone sequence always fits.
                 assert plen + 1 <= (self.total_pages - 1) * self.page_size
                 if self.prefix_cache is not None:
-                    self._evict_for(need, tref)
+                    self._evict_for(need, tref[0])
                 if not self.allocator.can_allocate(need):
                     # Pool exhausted: back to the HEAD and stop
                     # admitting until a sequence releases pages —
@@ -2581,12 +2621,15 @@ class ContinuousBatchingEngine:
             self.prefilling[slot] = True
             self._prefill_order.append(slot)
             self._prefill_t0[slot] = time.perf_counter()
-            self._slot_ctx[slot] = tref[0] if tref is not None else None
-            if tref is not None:
+            self._slot_ctx[slot] = tref[0]
+            # Every request's queue wait (a preempted one observes
+            # each of its waits); the spans only for a sampled one.
+            self.metrics.queue_wait_seconds.observe(t_adm - tref[1])
+            if tref[0] is not None:
                 tracing.record_span('engine.queue_wait', tref[0],
                                     t_adm - tref[1], slot=slot)
                 tracing.record_span('engine.admit', tref[0],
-                                    time.perf_counter() - t_adm,
+                                    self._prefill_t0[slot] - t_adm,
                                     slot=slot, prompt_len=plen,
                                     cached_tokens=n_cached)
             self.flight.record('admit', slot=slot, prompt_len=plen,
@@ -2596,13 +2639,13 @@ class ContinuousBatchingEngine:
             admitted = True
         return admitted
 
-    def _evict_for(self, need: int, tref) -> None:
+    def _evict_for(self, need: int, ctx) -> None:
         """Prefix-cache eviction for an admission, with an
         'engine.kv_spill' span when the admitting request is traced
         and the eviction actually ran (untraced requests call
         straight through: no clock reads)."""
         cache = self.prefix_cache
-        if tref is None:
+        if ctx is None:
             cache.evict_into(self.allocator, need)
             return
         ev0, sp0 = cache.evictions, cache.spilled_pages
@@ -2610,7 +2653,7 @@ class ContinuousBatchingEngine:
         cache.evict_into(self.allocator, need)
         if cache.evictions > ev0:
             tracing.record_span(
-                'engine.kv_spill', tref[0],
+                'engine.kv_spill', ctx,
                 time.perf_counter() - t0,
                 evicted=cache.evictions - ev0,
                 spilled=cache.spilled_pages - sp0)
@@ -2707,37 +2750,40 @@ class ContinuousBatchingEngine:
                 n = min(n, self.prefill_chunk)
             if budget is not None and spent + n > budget:
                 break   # budget spent: decode steps run first
-            self.flight.record('chunk_dispatch', slot=slot,
-                               offset=offset, n=n)
-            t0 = time.perf_counter()
-            try:
-                last = self._run_prefill_chunk(slot, offset, n)
-            except Exception as e:  # pylint: disable=broad-except
-                if self._cache_lost():
-                    raise  # every slot's history died with the cache
-                # Crash-only isolation: the fault fired before the
-                # device touched the cache (e.g. an injected
-                # engine.prefill_chunk fault) — only THIS slot's
-                # request fails; the rest keep decoding untouched.
-                print(f'engine {self.engine_id}: prefill chunk for '
-                      f'slot {slot} failed ({type(e).__name__}: {e}); '
-                      f'failing only that request', flush=True)
-                self.soft_errors_total += 1
-                self._fail_slot(slot, e)
-                continue
-            self.metrics.prefill_chunk_seconds.observe(
-                time.perf_counter() - t0)
+            # One phase a chunk: the dispatch (which returns at
+            # enqueue), the slot's bookkeeping and, after a prompt's
+            # last chunk, the enqueue of its first-token sampling.
+            with tracing.phase('engine.prefill_dispatch',
+                               self.phases) as chunk:
+                self.flight.record('chunk_dispatch', slot=slot,
+                                   offset=offset, n=n)
+                try:
+                    last = self._run_prefill_chunk(slot, offset, n)
+                except Exception as e:  # pylint: disable=broad-except
+                    if self._cache_lost():
+                        raise  # every slot's history died with the cache
+                    # Crash-only isolation: the fault fired before the
+                    # device touched the cache (e.g. an injected
+                    # engine.prefill_chunk fault) — only THIS slot's
+                    # request fails; the rest keep decoding untouched.
+                    print(f'engine {self.engine_id}: prefill chunk '
+                          f'for slot {slot} failed '
+                          f'({type(e).__name__}: {e}); failing only '
+                          f'that request', flush=True)
+                    self.soft_errors_total += 1
+                    self._fail_slot(slot, e)
+                    continue
+                spent += n
+                offset += n
+                self.prefill_frontier[slot] = offset
+                self.pos[slot] = offset
+                if offset >= plen:
+                    self._prefill_order.popleft()
+                    done.append((slot, self._sample_first(slot, last)))
+            self.metrics.prefill_chunk_seconds.observe(chunk.dur)
             tracing.record_span('engine.prefill_chunk',
-                                self._slot_ctx[slot],
-                                time.perf_counter() - t0,
-                                slot=slot, offset=offset, n=n)
-            spent += n
-            offset += n
-            self.prefill_frontier[slot] = offset
-            self.pos[slot] = offset
-            if offset >= plen:
-                self._prefill_order.popleft()
-                done.append((slot, self._sample_first(slot, last)))
+                                self._slot_ctx[slot], chunk.dur,
+                                slot=slot, offset=offset - n, n=n)
         self.last_prefill_tokens = spent
         if budget:
             self.metrics.prefill_budget_utilization.set(
@@ -2755,15 +2801,18 @@ class ContinuousBatchingEngine:
         if not done:
             return
         # ONE host/device sync for every prompt that completed this
-        # round (not one per admission).
-        firsts = jax.device_get([first for _, first in done])
-        for (slot, _), first in zip(done, firsts):
-            self.cur_token[slot] = int(first)
-            self.pos[slot] = int(self.prompt_len[slot])
-            self.prefilling[slot] = False
-            self.active[slot] = True
-            self.metrics.prefill_seconds.observe(
-                time.perf_counter() - self._prefill_t0[slot])
+        # round (not one per admission). The scheduler stands still
+        # for it: no round is dispatched meanwhile.
+        with tracing.phase('engine.first_token_sync', self.phases):
+            firsts = jax.device_get([first for _, first in done])
+            now = time.perf_counter()
+            for (slot, _), first in zip(done, firsts):
+                self.cur_token[slot] = int(first)
+                self.pos[slot] = int(self.prompt_len[slot])
+                self.prefilling[slot] = False
+                self.active[slot] = True
+                self.metrics.prefill_seconds.observe(
+                    now - self._prefill_t0[slot])
 
     def prefill_backlog_tokens(self) -> int:
         """Prompt-suffix tokens admitted but not yet prefilled (the
@@ -2837,8 +2886,7 @@ class ContinuousBatchingEngine:
             if fut is not None:
                 # The trace ctx rides the re-queued request: its
                 # re-admission emits a second queue-wait span.
-                tref = ((ctx, time.perf_counter())
-                        if ctx is not None else None)
+                tref = (ctx, time.perf_counter())
                 preempted.append((list(self.outputs[slot]),
                                   max(remaining, 1),
                                   float(self.temps[slot]),
@@ -3008,10 +3056,11 @@ class ContinuousBatchingEngine:
         sampled = self._fetch_tokens(sampled)
         self.decode_calls += 1
         self.metrics.decode_steps.inc()
-        for slot in range(self.num_slots):
-            if not self.active[slot]:
-                continue
-            self._commit_token(slot, int(sampled[slot]))
+        with tracing.phase('engine.commit', self.phases):
+            for slot in range(self.num_slots):
+                if not self.active[slot]:
+                    continue
+                self._commit_token(slot, int(sampled[slot]))
 
     def _trace_decode_round(self, dur: float) -> None:
         """One 'engine.decode_round' span per traced slot riding this
@@ -3034,10 +3083,9 @@ class ContinuousBatchingEngine:
         host spends blocked here is exactly the serial host/device
         bubble pipelining exists to hide."""
         faults.point('engine.device_get')
-        t0 = time.perf_counter()
-        out = np.asarray(jax.device_get(dev))
-        stall = time.perf_counter() - t0
-        self.decode_stall_s += stall
+        with tracing.phase('engine.fetch_wait', self.phases) as wait:
+            out = np.asarray(jax.device_get(dev))
+        stall = wait.dur
         self.metrics.decode_stall_seconds.inc(stall)
         if tracing.enabled():
             # The stall is shared by the whole round: attribute ONE
@@ -3098,13 +3146,14 @@ class ContinuousBatchingEngine:
         finished, was preempted, or was replaced since dispatch are
         discarded (their round-N+1 token belongs to nobody)."""
         sampled = self._fetch_tokens(inflight['sampled'])
-        for slot in range(self.num_slots):
-            if not inflight['mask'][slot]:
-                continue
-            if not self.active[slot] or \
-                    self.futures[slot] is not inflight['futs'][slot]:
-                continue
-            self._commit_token(slot, int(sampled[slot]))
+        with tracing.phase('engine.commit', self.phases):
+            for slot in range(self.num_slots):
+                if not inflight['mask'][slot]:
+                    continue
+                if not self.active[slot] or \
+                        self.futures[slot] is not inflight['futs'][slot]:
+                    continue
+                self._commit_token(slot, int(sampled[slot]))
 
     def _pipelined_decode_step(self) -> None:
         """One pipelined iteration: dispatch round N+1 FIRST (device
@@ -3178,14 +3227,15 @@ class ContinuousBatchingEngine:
         slot g*W + i); discard rules match _commit_round."""
         sampled = self._fetch_tokens(inflight['sampled'])
         base = self._group_slice(g).start
-        for i in range(len(sampled)):
-            slot = base + i
-            if not inflight['mask'][i]:
-                continue
-            if not self.active[slot] or \
-                    self.futures[slot] is not inflight['futs'][i]:
-                continue
-            self._commit_token(slot, int(sampled[i]))
+        with tracing.phase('engine.commit', self.phases):
+            for i in range(len(sampled)):
+                slot = base + i
+                if not inflight['mask'][i]:
+                    continue
+                if not self.active[slot] or \
+                        self.futures[slot] is not inflight['futs'][i]:
+                    continue
+                self._commit_token(slot, int(sampled[i]))
 
     def _staged_pipelined_decode_step(self) -> None:
         """One iteration of the S-deep decode ring: slots partition
@@ -3229,12 +3279,13 @@ class ContinuousBatchingEngine:
         toks = self._fetch_tokens(toks)               # [n, slots]
         self.decode_calls += 1
         self.metrics.decode_steps.inc()
-        for slot in range(self.num_slots):
-            if not was_active[slot]:
-                continue
-            for i in range(n):
-                if self._commit_token(slot, int(toks[i, slot])):
-                    break  # finished: discard the chunk's tail
+        with tracing.phase('engine.commit', self.phases):
+            for slot in range(self.num_slots):
+                if not was_active[slot]:
+                    continue
+                for i in range(n):
+                    if self._commit_token(slot, int(toks[i, slot])):
+                        break  # finished: discard the chunk's tail
 
     def _spec_decode_step(self) -> None:
         """One speculative round: draft K tokens per slot (host-side
@@ -3262,19 +3313,21 @@ class ContinuousBatchingEngine:
         y = self._fetch_tokens(y)                      # [slots, K+1]
         self.decode_calls += 1
         self.metrics.decode_steps.inc()
-        for slot in range(self.num_slots):
-            if not self.active[slot]:
-                continue
-            accept = 0
-            while (accept < k and
-                   int(drafts[slot, accept]) == int(y[slot, accept])):
-                accept += 1
-            # Commit: the pending current token, then every accepted
-            # draft; each commit's successor is the model's own token
-            # for that position (y), so the final pending token is the
-            # first correction. (The accepted-prefix invariant makes
-            # cur_token equal the next commit at every step, so the
-            # shared _commit_token applies unchanged.)
-            for nxt in y[slot, :accept + 1]:
-                if self._commit_token(slot, int(nxt)):
-                    break
+        with tracing.phase('engine.commit', self.phases):
+            for slot in range(self.num_slots):
+                if not self.active[slot]:
+                    continue
+                accept = 0
+                while (accept < k and
+                       int(drafts[slot, accept]) == int(y[slot, accept])):
+                    accept += 1
+                # Commit: the pending current token, then every
+                # accepted draft; each commit's successor is the
+                # model's own token for that position (y), so the
+                # final pending token is the first correction. (The
+                # accepted-prefix invariant makes cur_token equal the
+                # next commit at every step, so the shared
+                # _commit_token applies unchanged.)
+                for nxt in y[slot, :accept + 1]:
+                    if self._commit_token(slot, int(nxt)):
+                        break

@@ -45,6 +45,7 @@ def filter_logits(logits: jax.Array, top_k: jax.Array,
     return jnp.where(keep_k & keep_p, logits, -jnp.inf)
 
 
+@jax.named_scope('sample')
 def sample_tokens(rng: jax.Array, logits: jax.Array, temps: jax.Array,
                   top_k: jax.Array, top_p: jax.Array) -> jax.Array:
     """Per-row sampling: greedy where temps == 0, else categorical

@@ -233,6 +233,7 @@ def embed_tokens(params, tokens: jax.Array, cfg: GPTConfig) -> jax.Array:
     return wte[tokens] + wpe[:tokens.shape[1]]
 
 
+@jax.named_scope('lm_head')
 def final_norm_logits(params, x: jax.Array, cfg: GPTConfig) -> jax.Array:
     """Functional form of GPT's ln_f + tied LM head (the pipeline
     trainer's last-stage op)."""
@@ -318,8 +319,10 @@ class GPT(nn.Module):
         # operands keep the matmul on the MXU's native bf16 path
         # (~4-8x the f32 rate); cfg.logits_dtype picks the output
         # precision (bf16 default — see GPTConfig).
-        logits = jnp.einsum('bse,ve->bsv', x.astype(cfg.dtype),
-                            wte.astype(cfg.dtype),
-                            preferred_element_type=(cfg.logits_dtype or
-                                                    cfg.dtype))
+        with jax.named_scope('lm_head'):
+            logits = jnp.einsum(
+                'bse,ve->bsv', x.astype(cfg.dtype),
+                wte.astype(cfg.dtype),
+                preferred_element_type=(cfg.logits_dtype or
+                                        cfg.dtype))
         return nn.with_logical_constraint(logits, ('batch', 'seq', 'vocab'))

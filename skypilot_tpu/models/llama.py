@@ -411,6 +411,7 @@ def embed_tokens(params, tokens: jax.Array, cfg: LlamaConfig) -> jax.Array:
     return params['tok_embed'].astype(cfg.dtype)[tokens]
 
 
+@jax.named_scope('lm_head')
 def final_norm_logits(params, x: jax.Array, cfg: LlamaConfig) -> jax.Array:
     """Functional final RMSNorm + untied LM head (the pipeline
     trainer's last-stage op; numerics mirror Llama.__call__)."""
@@ -485,10 +486,12 @@ class Llama(nn.Module):
                 x, ('batch', 'seq', 'act_embed'))
         # bf16 operands, accumulation dtype from cfg.logits_dtype
         # (None = f32: MXU-native rate, f32-safe softmax numerics).
-        logits = jnp.einsum('bse,ev->bsv', x.astype(cfg.dtype),
-                            head.astype(cfg.dtype),
-                            preferred_element_type=(cfg.logits_dtype or
-                                                    jnp.float32))
+        with jax.named_scope('lm_head'):
+            logits = jnp.einsum(
+                'bse,ev->bsv', x.astype(cfg.dtype),
+                head.astype(cfg.dtype),
+                preferred_element_type=(cfg.logits_dtype or
+                                        jnp.float32))
         return nn.with_logical_constraint(logits, ('batch', 'seq', 'vocab'))
 
 
@@ -563,8 +566,10 @@ class LlamaStage(nn.Module):
             nn.with_logical_partitioning(
                 nn.initializers.normal(stddev=0.02), ('embed', 'vocab')),
             (cfg.embed_dim, cfg.vocab_size), jnp.float32)
-        logits = jnp.einsum('bse,ev->bsv', x.astype(cfg.dtype),
-                            head.astype(cfg.dtype),
-                            preferred_element_type=(cfg.logits_dtype or
-                                                    jnp.float32))
+        with jax.named_scope('lm_head'):
+            logits = jnp.einsum(
+                'bse,ev->bsv', x.astype(cfg.dtype),
+                head.astype(cfg.dtype),
+                preferred_element_type=(cfg.logits_dtype or
+                                        jnp.float32))
         return nn.with_logical_constraint(logits, ('batch', 'seq', 'vocab'))

@@ -25,6 +25,12 @@ REQUEST_BUCKETS = (0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
                    10.0, 30.0, 60.0, 120.0)
 TOKEN_GAP_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                      0.25, 0.5, 1.0, 2.5)
+# Per-request intervals that /stats `latency` serves as bucket counts:
+# 2**(k/8) ms from 1 ms to 262 s, so a percentile read from the counts
+# is within 4.5% (half a bucket) of the sample's.
+FINE_BUCKET_RATIO = 2.0 ** 0.125
+FINE_BUCKETS = tuple(1e-3 * 2.0 ** (k / 8) for k in range(145))
+_FINE_EDGES_MS = [f'{1e3 * b:.4f}' for b in FINE_BUCKETS] + ['inf']
 
 # name -> (kind, help, labelnames[, options])
 #   kind: counter | gauge | histogram | gauge_as_counter
@@ -56,10 +62,21 @@ SPECS: Dict[str, Tuple] = {
                      'host commit)', ('engine',),
         {'buckets': STEP_BUCKETS}),
     'skypilot_serving_prefill_seconds': (
-        'histogram', 'Wall time from a request\'s first prefill '
-                     'chunk dispatch to its first token (whole-prompt '
-                     'prefill when chunking is off)', ('engine',),
-        {'buckets': STEP_BUCKETS}),
+        'histogram', 'Wall time from a request\'s admission into a '
+                     'slot to its first token, every request '
+                     '(/stats latency.admit_to_first_token)',
+        ('engine',), {'buckets': FINE_BUCKETS}),
+    'skypilot_serving_queue_wait_seconds': (
+        'histogram', 'Wall time from submit() to admission into a '
+                     'slot, every request; a preempted request '
+                     'observes each of its waits (/stats '
+                     'latency.queue_wait)', ('engine',),
+        {'buckets': FINE_BUCKETS}),
+    'skypilot_serving_scheduler_phase_seconds_total': (
+        'gauge_as_counter', 'Cumulative self time of each phase of '
+                            'the engine\'s scheduler loop (/stats '
+                            'phases; set at scrape from the loop\'s '
+                            'own accumulator)', ('engine', 'phase')),
     'skypilot_serving_prefill_chunk_seconds': (
         'histogram', 'Wall time of one chunked-prefill dispatch '
                      '(async dispatch cost, not device compute — the '
@@ -222,6 +239,13 @@ SPECS: Dict[str, Tuple] = {
         'histogram', 'Time to first token: first committed token for '
                      'engine-backed requests (streaming and not)',
         (), {'buckets': REQUEST_BUCKETS}),
+    'skypilot_serving_http_ttft_overhead_seconds': (
+        'histogram', 'What the HTTP layer adds to a streamed '
+                     'request\'s first token: handler entry to the '
+                     'engine submit returning, plus the first token\'s '
+                     'commit to its bytes flushed to the socket '
+                     '(/stats latency.http_ttft_overhead)', (),
+        {'buckets': FINE_BUCKETS}),
     'skypilot_serving_inter_token_seconds': (
         'histogram', 'Gap between consecutive streamed tokens of one '
                      'request row', (),
@@ -455,6 +479,8 @@ class EngineMetrics:
             'skypilot_serving_decode_step_seconds').labels(**lab)
         self.prefill_seconds = histogram(
             'skypilot_serving_prefill_seconds').labels(**lab)
+        self.queue_wait_seconds = histogram(
+            'skypilot_serving_queue_wait_seconds').labels(**lab)
         self.prefill_chunk_seconds = histogram(
             'skypilot_serving_prefill_chunk_seconds').labels(**lab)
         self.prefill_backlog = gauge(
@@ -495,6 +521,10 @@ class EngineMetrics:
         self.attention_bytes_per_token = gauge(
             'skypilot_serving_attention_bytes_per_token').labels(**lab)
 
+    def set_phase_seconds(self, phase: str, seconds: float) -> None:
+        gauge('skypilot_serving_scheduler_phase_seconds_total').labels(
+            engine=self._engine_label, phase=phase).set(seconds)
+
     def set_attention_info(self, impl: str, kv_dtype: str) -> None:
         """Info-style gauge (always 1): the resolved paged-attention
         impl and KV storage dtype ride the labels, so a dashboard can
@@ -515,6 +545,8 @@ class RequestMetrics:
         self.completion_tokens = counter(
             'skypilot_serving_completion_tokens_total')
         self.ttft_seconds = histogram('skypilot_serving_ttft_seconds')
+        self.http_ttft_overhead_seconds = histogram(
+            'skypilot_serving_http_ttft_overhead_seconds')
         self.inter_token_seconds = histogram(
             'skypilot_serving_inter_token_seconds')
         self.e2e_latency_seconds = histogram(
@@ -523,6 +555,20 @@ class RequestMetrics:
             'skypilot_serving_requests_shed_total')
         self.deadline_exceeded = counter(
             'skypilot_serving_deadline_exceeded_total')
+
+
+def latency_stats(hist) -> Dict[str, object]:
+    """One FINE_BUCKETS histogram (a family without labels, or a
+    labeled child) as /stats `latency` serves it: observations, their
+    sum, and the non-empty buckets keyed by upper edge in ms. A bucket
+    spans (edge / ratio, edge]; what is under 1 ms counts in the first
+    and what is over the last edge under 'inf'."""
+    child = hist._default() if isinstance(hist, m.Histogram) else hist
+    counts, total, n = child.snapshot()
+    return {'n': n, 'sum_s': round(total, 6),
+            'ratio': FINE_BUCKET_RATIO,
+            'buckets': {e: c for e, c in zip(_FINE_EDGES_MS, counts)
+                        if c}}
 
 
 class FirstTokenLatch:

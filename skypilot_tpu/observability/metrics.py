@@ -25,6 +25,7 @@ off it) rather than instantiating these classes directly.
 """
 from __future__ import annotations
 
+import bisect
 import math
 import re
 import threading
@@ -115,13 +116,16 @@ class _HistogramChild:
         with family._lock:
             self._sum += value
             self._count += 1
-            # Linear scan: bucket lists are ~a dozen entries and the
-            # observe sites are host-side (ms-scale device steps).
-            for i, bound in enumerate(family.buckets):
-                if value <= bound:
-                    self._counts[i] += 1
-                    return
-            self._counts[-1] += 1
+            # First bucket whose bound is >= value; past the last
+            # bound it is the +Inf slot.
+            self._counts[bisect.bisect_left(family.buckets,
+                                            value)] += 1
+
+    def snapshot(self) -> Tuple[List[int], float, int]:
+        """(per-bucket counts with +Inf last, sum, count), read
+        together."""
+        with self._family._lock:
+            return list(self._counts), self._sum, self._count
 
     @property
     def count(self) -> int:

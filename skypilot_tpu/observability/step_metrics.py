@@ -2,7 +2,9 @@
 
 `train_lm.py --metrics-file out.jsonl` constructs a StepMetrics and
 calls `log()` at every `--log-every` boundary. Each record carries
-the TPU-pod vital signs (step time, tokens/s, loss, grad norm) plus
+the TPU-pod vital signs (step time, tokens/s, loss, grad norm), where
+the host spent the step (`data_s`, `dispatch_s`, `sync_s`, `ckpt_s`,
+`other_s`: the loop's phases, summing to `step_time_s`) plus
 an achieved-MFU estimate against the device's peak FLOPs — the
 "are we running as fast as the hardware allows" number every perf PR
 is judged by. Records are flushed line-by-line so a preempted run's
@@ -86,6 +88,7 @@ class StepMetrics:
             loss: float, grad_norm: Optional[float] = None,
             bubble_frac: Optional[float] = None,
             collective_wait_s: Optional[float] = None,
+            phase_s: Optional[Dict[str, float]] = None,
             extra: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
         """Write one record covering a window that ended at `step`:
         `step_time_s` is the mean per-step wall time over the window,
@@ -93,7 +96,12 @@ class StepMetrics:
         the pipeline schedule's idle fraction (null for non-pipeline
         runs); `collective_wait_s` the host-observed drain wait at
         the window boundary — the un-overlapped remainder of the
-        device critical path the --overlap knob exists to shrink."""
+        device critical path the --overlap knob exists to shrink.
+        `phase_s` is the trainer loop's phases over the window
+        (`data_s`, `dispatch_s`, `sync_s`, `ckpt_s`: seconds per
+        step, like `step_time_s`); the record adds `other_s`, the
+        rest of the loop body, so that the five sum to
+        `step_time_s`."""
         tokens_per_sec = (tokens / step_time_s if step_time_s > 0
                           else 0.0)
         record: Dict[str, Any] = {
@@ -111,6 +119,11 @@ class StepMetrics:
                 None if collective_wait_s is None
                 else round(float(collective_wait_s), 6)),
         }
+        if phase_s is not None:
+            record.update({k: round(float(v), 6)
+                           for k, v in phase_s.items()})
+            record['other_s'] = round(
+                float(step_time_s) - sum(phase_s.values()), 6)
         if extra:
             record.update(extra)
         self._f.write(json.dumps(record) + '\n')

@@ -38,6 +38,14 @@ still produce per-role `pid` rows.
 Span discipline: every span must be closed — use `with span(...)`
 or put `.end()` in a `finally`. `stpu check` rule SKY007 enforces
 this for non-test code.
+
+Loop phases (`phase` / `PhaseClock`) are the second primitive: a
+loop thread's OWN work (the engine's scheduler loop, the trainer's
+step loop), not a request's. Unsampled and always on: a phase adds
+its self time to a per-name accumulator its caller owns (served as
+counters through `/stats` and the `--metrics-file` records) and is a
+`jax.profiler.TraceAnnotation`, so that under any profiler session it
+lies in the host plane of the same trace as the device operations.
 """
 from __future__ import annotations
 
@@ -46,6 +54,8 @@ import random
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from skypilot_tpu.utils import timeline
 
 #: The propagation header (lowercase: http.server title-cases on the
 #: wire but compares case-insensitively).
@@ -254,6 +264,87 @@ def record_span(name: str, ctx: Optional[Ctx], dur_s: float,
     sp._wall = start if start is not None else time.time() - dur_s
     sp._t0 = time.perf_counter() - dur_s
     sp.end()
+
+
+class PhaseClock:
+    """Per-name accumulator of one loop thread's phases:
+    `totals[name] = [n, self seconds, inclusive seconds]`. Single
+    writer (the loop thread); other threads read racily, like the
+    engine's other counters. Phases nest, and a phase's seconds are
+    SELF time (its children's durations are taken off), so the names
+    under one root partition the root's inclusive time."""
+
+    __slots__ = ('totals', '_open')
+
+    def __init__(self) -> None:
+        self.totals: Dict[str, List[float]] = {}
+        self._open: List['_Phase'] = []
+
+    def n(self, name: str) -> int:
+        return int(self.totals.get(name, _ZERO)[0])
+
+    def seconds(self, name: str) -> float:
+        """Accumulated self time of `name`."""
+        return self.totals.get(name, _ZERO)[1]
+
+    def inclusive(self, name: str) -> float:
+        """Accumulated whole durations of `name`, children included."""
+        return self.totals.get(name, _ZERO)[2]
+
+
+_ZERO = (0, 0.0, 0.0)
+_annotation = None      # jax.profiler.TraceAnnotation, imported late
+
+
+class _Phase:
+    """One open phase; `dur` holds its whole duration after exit."""
+
+    __slots__ = ('name', 'dur', '_clock', '_ann', '_t0', '_child')
+
+    def __init__(self, name: str, clock: PhaseClock) -> None:
+        self.name = name
+        self.dur = 0.0
+        self._clock = clock
+        self._child = 0.0
+
+    def __enter__(self) -> '_Phase':
+        global _annotation
+        if _annotation is None:
+            # Late: the LB and the CLI import this module and stay
+            # off JAX; only a loop that opens a phase needs it.
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
+        self._clock._open.append(self)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        dur = self.dur = time.perf_counter() - self._t0
+        clock = self._clock
+        clock._open.pop()
+        if clock._open:
+            clock._open[-1]._child += dur
+        rec = clock.totals.get(self.name)
+        if rec is None:
+            rec = clock.totals[self.name] = [0, 0.0, 0.0]
+        rec[0] += 1
+        rec[1] += dur - self._child
+        rec[2] += dur
+        self._ann.__exit__(*exc)
+        if timeline.enabled():
+            timeline.record(self.name, self._t0, dur)
+
+
+def phase(name: str, clock: PhaseClock) -> _Phase:
+    """Open a phase of a loop thread's own work, as a context manager
+    (SKY007 flags a bare call). On exit its self time and 1 go to
+    `clock`; for its duration it is a `TraceAnnotation`, which costs
+    nothing to speak of while no profiler session runs; with
+    `utils/timeline` enabled it is a Chrome event there too. No
+    sampling, no store."""
+    return _Phase(name, clock)
 
 
 def get_trace(trace_id: str) -> Optional[Dict[str, Any]]:

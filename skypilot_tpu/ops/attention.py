@@ -23,6 +23,7 @@ import jax.numpy as jnp
 _FLASH_MIN_SEQ = int(os.environ.get('SKYPILOT_TPU_FLASH_MIN_SEQ') or 2048)
 
 
+@jax.named_scope('attention')
 def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                           *, causal: bool = True,
                           impl: str = 'auto') -> jax.Array:
@@ -94,6 +95,7 @@ def _unequal_dims_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.astype(q.dtype)
 
 
+@jax.named_scope('flash_attention')
 def _pallas_flash_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
                          causal: bool) -> jax.Array:
     """Single-shard pallas flash attention ([B,S,H,D] in/out)."""
@@ -180,6 +182,7 @@ def _flash(q: jax.Array, k: jax.Array, v: jax.Array, *,
         check_vma=False)(q, k, v)
 
 
+@jax.named_scope('attention')
 def chunked_cache_attention(q: jax.Array, k_new: jax.Array,
                             v_new: jax.Array, cached_k: jax.Array,
                             cached_v: jax.Array, positions: jax.Array,
@@ -234,6 +237,7 @@ def chunked_cache_attention(q: jax.Array, k_new: jax.Array,
     return out.astype(q.dtype), cached_k, cached_v
 
 
+@jax.named_scope('attention')
 def cached_decode_attention(q: jax.Array, k_new: jax.Array,
                             v_new: jax.Array, cached_k: jax.Array,
                             cached_v: jax.Array, pos: jax.Array):

@@ -170,6 +170,7 @@ def _blockwise_bwd(block, vocab, res, g):
 _blockwise_xent.defvjp(_blockwise_fwd, _blockwise_bwd)
 
 
+@jax.named_scope('xent')
 def fused_next_token_loss(hidden: jax.Array, weight: jax.Array,
                           tokens: jax.Array, *,
                           vocab_in_rows: Optional[bool] = None,

@@ -80,6 +80,7 @@ def dequantize_kv(q: jax.Array, scale: jax.Array) -> jax.Array:
     return q.astype(jnp.float32) * scale[..., None, None]
 
 
+@jax.named_scope('paged_attention')
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, lengths: jax.Array,
                            page_indices: jax.Array,
@@ -201,6 +202,7 @@ def _reference_paged_attention(q: jax.Array, k_pages: jax.Array,
     return out.astype(q.dtype)
 
 
+@jax.named_scope('kv_write')
 def write_kv(k_pages: jax.Array, v_pages: jax.Array, k_new: jax.Array,
              v_new: jax.Array, positions: jax.Array,
              page_indices: jax.Array
@@ -228,6 +230,7 @@ def write_kv(k_pages: jax.Array, v_pages: jax.Array, k_new: jax.Array,
     return write_one(k_pages, k_new), write_one(v_pages, v_new)
 
 
+@jax.named_scope('kv_write')
 def write_kv_chunk(k_pages: jax.Array, v_pages: jax.Array,
                    k_new: jax.Array, v_new: jax.Array,
                    positions: jax.Array, page_indices: jax.Array
@@ -254,6 +257,7 @@ def write_kv_chunk(k_pages: jax.Array, v_pages: jax.Array,
     return write_one(k_pages, k_new), write_one(v_pages, v_new)
 
 
+@jax.named_scope('kv_write')
 def write_kv_quant(k_pages: jax.Array, v_pages: jax.Array,
                    k_scales: jax.Array, v_scales: jax.Array,
                    k_new: jax.Array, v_new: jax.Array,
@@ -281,6 +285,7 @@ def write_kv_quant(k_pages: jax.Array, v_pages: jax.Array,
             v_scales.at[physical, slot].set(sv))
 
 
+@jax.named_scope('kv_write')
 def write_kv_chunk_quant(k_pages: jax.Array, v_pages: jax.Array,
                          k_scales: jax.Array, v_scales: jax.Array,
                          k_new: jax.Array, v_new: jax.Array,
@@ -381,6 +386,7 @@ def init_pages(num_kv_heads: int, total_pages: int, page_size: int,
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
+@jax.named_scope('chunk_attention')
 def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
                           v_pages: jax.Array, positions: jax.Array,
                           page_indices: jax.Array,

@@ -457,7 +457,7 @@ def fused_qkv_lora_delta(x: jax.Array, wq_factors: Dict,
         in_specs=in_specs, out_specs=out_specs)
     return pl.pallas_call(
         _qkv_lora_kernel, grid_spec=grid_spec, out_shape=out_shapes,
-        interpret=interpret,
+        interpret=interpret, name='fused_qkv_lora',
     )(adapter_ids.astype(jnp.int32), *operands)
 
 

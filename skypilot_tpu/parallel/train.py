@@ -368,8 +368,9 @@ class ShardedTrainer:
                 grads, self._grad_sharding)
         gnorm = (optax.global_norm(grads) if self.collect_grad_norm
                  else None)
-        updates, opt_state = self.tx.update(grads, state.opt_state,
-                                            state.params)
+        with jax.named_scope('optimizer'):
+            updates, opt_state = self.tx.update(
+                grads, state.opt_state, state.params)
         if self.zero1 and self._state_sharding is not None:
             # Pin the moment update to the ZeRO-1 layout *inside* the
             # step (the jit out_shardings only constrain the final
@@ -379,7 +380,8 @@ class ShardedTrainer:
             # replicated Adam state between inner steps).
             opt_state = jax.lax.with_sharding_constraint(
                 opt_state, self._state_sharding.opt_state)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope('optimizer'):
+            params = optax.apply_updates(state.params, updates)
         if ctl is None:
             aux = loss if gnorm is None else (loss, gnorm)
             return state.replace(step=state.step + 1, params=params,

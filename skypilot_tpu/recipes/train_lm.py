@@ -109,6 +109,33 @@ def _build_model(name: str, seq: int, remat: bool):
     raise ValueError(f'unknown model {name!r}')
 
 
+#: The loop phases a --metrics-file record carries, by its field.
+_RECORD_PHASES = {'data_s': 'train.data', 'dispatch_s': 'train.dispatch',
+                  'sync_s': 'train.sync', 'ckpt_s': 'train.checkpoint'}
+
+
+def _phase_seconds(clock) -> dict:
+    return {field: clock.seconds(name)
+            for field, name in _RECORD_PHASES.items()}
+
+
+def _write_step_dump(path: str, step: int, dump: dict, loss,
+                     gnorm) -> None:
+    """--dump-step: one .npz with `step`, `tokens`, `loss`,
+    `grad_norm` and the parameters before the update as float32
+    under `param:<tree path>` keys."""
+    import jax
+    import numpy as np
+    flat, _ = jax.tree_util.tree_flatten_with_path(dump['params'])
+    arrays = {f'param:{jax.tree_util.keystr(path)}':
+              np.asarray(leaf, np.float32) for path, leaf in flat}
+    np.savez(path, step=np.int64(step), tokens=dump['tokens'],
+             loss=np.float32(loss), grad_norm=np.float32(gnorm),
+             **arrays)
+    print(f'dump: step {step} -> {path} ({len(arrays)} parameter '
+          f'arrays)', flush=True)
+
+
 def main() -> None:
     parser = argparse.ArgumentParser()
     parser.add_argument('--model', default='gpt2-124m')
@@ -289,6 +316,16 @@ def main() -> None:
                              'data, step, checkpoint — same format as '
                              'SKYPILOT_TIMELINE_FILE_PATH, enabled '
                              'from the CLI')
+    parser.add_argument('--dump-step', nargs=2, default=None,
+                        metavar=('N', 'FILE'),
+                        help='write step N as one .npz to FILE: its '
+                             'input tokens, the float32 parameters '
+                             'before its update, and the loss and '
+                             'gradient norm the step then reports '
+                             '(N counts from 0: the state\'s step '
+                             'before the update) — what a plain '
+                             'reference needs to check the step; no '
+                             'cost without the flag')
     parser.add_argument('--profile', default=None, metavar='DIR',
                         help='capture a jax.profiler trace '
                              '(TensorBoard/Perfetto-readable) of a few '
@@ -330,6 +367,7 @@ def main() -> None:
     from skypilot_tpu.utils import compile_cache
     cache_dir = compile_cache.configure()
 
+    from skypilot_tpu.observability import tracing
     from skypilot_tpu.utils import timeline
     if args.trace_file:
         timeline.enable(args.trace_file)
@@ -426,6 +464,11 @@ def main() -> None:
             rank=args.lora,
             alpha=args.lora_alpha or float(args.lora),
             targets=lora_lib.targets_from_name(args.lora_targets))
+    # Both trainers return (loss, grad_norm) when a record or a
+    # --dump-step wants the norm — and with --guard, (loss,
+    # grad_norm, bad).
+    has_gnorm = (args.metrics_file is not None or
+                 args.dump_step is not None)
     tx = default_optimizer(learning_rate=args.lr, warmup_steps=10,
                            total_steps=max(args.steps, 20))
     if args.pipeline_stages > 1:
@@ -466,7 +509,7 @@ def main() -> None:
             hf_params = pp.split_params(hf_params)
         step_fn = pp.make_train_step(
             tx, guard=args.guard,
-            collect_grad_norm=args.metrics_file is not None)
+            collect_grad_norm=has_gnorm)
         pipeline_bubble_frac = pp.schedule.bubble_fraction
         from skypilot_tpu.observability import catalog
         catalog.gauge('skypilot_train_pipeline_bubble_fraction').set(
@@ -484,7 +527,7 @@ def main() -> None:
             # --metrics-file wants grad_norm in every record; --guard
             # needs it unconditionally (the trainer forces it on and
             # computes the norm once for both consumers).
-            collect_grad_norm=args.metrics_file is not None,
+            collect_grad_norm=has_gnorm,
             guard=args.guard,
             lora=lora_spec,
             **kwargs)
@@ -574,12 +617,10 @@ def main() -> None:
     if args.profile and proc_id == 0:
         prof_start, prof_stop = (int(x) for x in
                                  args.profile_steps.split(':'))
-    tracing = False
+    profiling = False
 
     # Step telemetry (--metrics-file): one JSONL record per logged
-    # window. Both trainers return (loss, grad_norm) when metrics are
-    # on — and with --guard, (loss, grad_norm, bad).
-    has_gnorm = args.metrics_file is not None
+    # window.
     emitter = None
     if args.metrics_file and proc_id == 0:
         from skypilot_tpu.observability.step_metrics import StepMetrics
@@ -640,7 +681,16 @@ def main() -> None:
         ckpt_interval_s = float(args.ckpt_interval)
     cadence_fixed = ckpt_interval_s is None
 
+    # The loop's phases (observability/tracing.phase): on the
+    # profiler's clock under --profile, Chrome events under
+    # --trace-file, and per-step seconds in every --metrics-file
+    # record.
+    clock = tracing.PhaseClock()
+    dump_step, dump_file = ((int(args.dump_step[0]), args.dump_step[1])
+                            if args.dump_step else (-1, None))
+    dump = None
     t0 = time.perf_counter()
+    window_phases = _phase_seconds(clock)
     window_tokens = 0
     window_steps = 0
     step = start_step
@@ -659,7 +709,7 @@ def main() -> None:
                       f'rc={train_guard.EXIT_PREEMPTED_GRACEFUL}',
                       flush=True)
             if mgr is not None:
-                with timeline.Event('train/checkpoint', 'preempt'):
+                with tracing.phase('train.checkpoint', clock):
                     mgr.save(step, state, force=True)
                     mgr.wait_until_finished()
                     mgr.close()
@@ -669,159 +719,183 @@ def main() -> None:
                 timeline.save()
             sup.stop()
             sys.exit(train_guard.EXIT_PREEMPTED_GRACEFUL)
-        # >= not ==: a checkpoint resume may land past prof_start.
-        if not tracing and prof_start >= 0 and \
-                prof_start <= step < prof_stop:
-            jax.profiler.start_trace(args.profile)
-            tracing = True
-        first = step == start_step
-        if sup is not None:
-            sup.beat('data', first_step=first)
-        with timeline.Event('train/data'):
-            tokens = next_tokens()
-        if sup is not None:
-            sup.beat('step', first_step=first)
-        with timeline.Event('train/step', f'step {step}'):
+        with tracing.phase('train.loop', clock):
+            # >= not ==: a checkpoint resume may land past prof_start.
+            if not profiling and prof_start >= 0 and \
+                    prof_start <= step < prof_stop:
+                jax.profiler.start_trace(args.profile)
+                profiling = True
+            first = step == start_step
             if sup is not None:
-                max_gnorm, loss_scale = sup.step_ctl(step)
-                state, aux = step_fn(state, tokens, max_gnorm,
-                                     loss_scale)
+                sup.beat('data', first_step=first)
+            with tracing.phase('train.data', clock):
+                tokens = next_tokens()
+            if sup is not None:
+                sup.beat('step', first_step=first)
+            if step == dump_step:
+                dump = {'tokens': np.asarray(jax.device_get(tokens)),
+                        'params': jax.device_get(state.params)}
+            # The step function returns at enqueue: what the device
+            # then takes shows in train.sync, not here.
+            with tracing.phase('train.dispatch', clock):
+                if sup is not None:
+                    max_gnorm, loss_scale = sup.step_ctl(step)
+                    state, aux = step_fn(state, tokens, max_gnorm,
+                                         loss_scale)
+                else:
+                    faults.point('train.step', step=str(step),
+                                 **resume_ctx)
+                    state, aux = step_fn(state, tokens)
+            if sup is not None:
+                loss, gnorm, bad_flag = aux
+            elif has_gnorm:
+                loss, gnorm = aux
+                bad_flag = None
             else:
-                faults.point('train.step', step=str(step),
-                             **resume_ctx)
-                state, aux = step_fn(state, tokens)
-        if sup is not None:
-            loss, gnorm, bad_flag = aux
-        elif has_gnorm:
-            loss, gnorm = aux
-            bad_flag = None
-        else:
-            loss, gnorm, bad_flag = aux, None, None
-        if first and proc_id == 0:
-            # Set-up and steady state are reported apart: the first
-            # step carries the compile (or the compile-cache read).
-            jax.block_until_ready(loss)
-            print(f'setup: init {t0 - t_start:.1f}s, first step (compile '
-                  f'+ run) {time.perf_counter() - t0:.1f}s, compile '
-                  f'cache {cache_dir}', flush=True)
-        if tracing and step + 1 >= prof_stop:
-            # Block so the trace holds COMPLETE device timelines for
-            # the window, not just dispatches.
-            jax.block_until_ready(loss)
-            jax.profiler.stop_trace()
-            tracing = False
-            print(f'profile: steps {prof_start}..{prof_stop} traced '
-                  f'to {args.profile}', flush=True)
-        window_tokens += batch * args.seq
-        window_steps += 1
-        if sup is not None:
-            # Lagged observation: fetch the PREVIOUS step's verdict
-            # while this one computes (one-step pipelining keeps the
-            # device busy; a rollback discards at most the one step
-            # dispatched since).
-            if pending is not None:
-                p_step, p_loss, p_gnorm, p_bad = pending
-                pending = None
-                verdict = sup.observe(p_step, float(p_loss),
-                                      float(p_gnorm), bool(p_bad))
-                if verdict == 'rollback':
-                    from skypilot_tpu.robustness.errors import (
-                        CheckpointNotFoundError)
-                    restored = False
-                    if mgr is not None:
-                        try:
-                            state = mgr.restore(state)
-                            restored = True
-                        except CheckpointNotFoundError:
-                            pass
-                    if restored:
-                        sup.guard.reset_after_rollback()
-                        step = int(state.step)
-                        t0 = time.perf_counter()
-                        window_tokens = 0
-                        window_steps = 0
+                loss, gnorm, bad_flag = aux, None, None
+            if dump is not None:
+                _write_step_dump(dump_file, step, dump, loss, gnorm)
+                dump = None
+            if first and proc_id == 0:
+                # Set-up and steady state are reported apart: the first
+                # step carries the compile (or the compile-cache read).
+                with tracing.phase('train.sync', clock):
+                    jax.block_until_ready(loss)
+                print(f'setup: init {t0 - t_start:.1f}s, first step (compile '
+                      f'+ run) {time.perf_counter() - t0:.1f}s, compile '
+                      f'cache {cache_dir}', flush=True)
+            if profiling and step + 1 >= prof_stop:
+                # Block so the trace holds COMPLETE device timelines for
+                # the window, not just dispatches.
+                with tracing.phase('train.sync', clock):
+                    jax.block_until_ready(loss)
+                jax.profiler.stop_trace()
+                profiling = False
+                print(f'profile: steps {prof_start}..{prof_stop} traced '
+                      f'to {args.profile}', flush=True)
+            window_tokens += batch * args.seq
+            window_steps += 1
+            if sup is not None:
+                # Lagged observation: fetch the PREVIOUS step's verdict
+                # while this one computes (one-step pipelining keeps the
+                # device busy; a rollback discards at most the one step
+                # dispatched since).
+                if pending is not None:
+                    p_step, p_loss, p_gnorm, p_bad = pending
+                    pending = None
+                    with tracing.phase('train.sync', clock):
+                        p_obs = (float(p_loss), float(p_gnorm),
+                                 bool(p_bad))
+                    verdict = sup.observe(p_step, *p_obs)
+                    if verdict == 'rollback':
+                        from skypilot_tpu.robustness.errors import (
+                            CheckpointNotFoundError)
+                        restored = False
+                        if mgr is not None:
+                            try:
+                                state = mgr.restore(state)
+                                restored = True
+                            except CheckpointNotFoundError:
+                                pass
+                        if restored:
+                            sup.guard.reset_after_rollback()
+                            step = int(state.step)
+                            t0 = time.perf_counter()
+                            window_phases = _phase_seconds(clock)
+                            window_tokens = 0
+                            window_steps = 0
+                            if proc_id == 0:
+                                print(f'train-guard: rolled back to '
+                                      f'last checkpoint (step {step})',
+                                      flush=True)
+                            continue
+                        # Nothing to roll back to. The params are still
+                        # clean (every bad step was skipped on device):
+                        # reset the escalation counter and keep skipping.
+                        sup.guard.consecutive_bad = 0
                         if proc_id == 0:
-                            print(f'train-guard: rolled back to '
-                                  f'last checkpoint (step {step})',
-                                  flush=True)
-                        continue
-                    # Nothing to roll back to. The params are still
-                    # clean (every bad step was skipped on device):
-                    # reset the escalation counter and keep skipping.
-                    sup.guard.consecutive_bad = 0
-                    if proc_id == 0:
-                        print('train-guard: rollback requested but '
-                              'no checkpoint available; continuing '
-                              'with per-step skips', flush=True)
-            pending = (step, loss, gnorm, bad_flag)
-        if mgr is not None and (ckpt_interval_s is None or
-                                (step + 1) % ckpt_every_steps == 0):
-            with timeline.Event('train/checkpoint', f'step {step + 1}'):
-                mgr.save(step + 1, state)
-        if tracing and step + 1 >= args.steps:
-            # Window ran past the final step: still flush the trace.
-            jax.block_until_ready(loss)
-            jax.profiler.stop_trace()
-            tracing = False
-            print(f'profile: traced through final step {step + 1} '
-                  f'to {args.profile}', flush=True)
-        boundary = (step + 1) % args.log_every == 0
-        if boundary and not cadence_fixed:
-            # Every process fixes the cadence (checkpoint saves are
-            # collective); proc 0's value is broadcast so clock skew
-            # cannot desynchronize the save schedule.
-            if sup is not None:
-                sup.beat('commit')
-            jax.block_until_ready(loss)
-            mean_step = ((time.perf_counter() - t0) /
-                         max(window_steps, 1))
-            cadence = max(1, round(ckpt_interval_s /
-                                   max(mean_step, 1e-9)))
-            if jax.process_count() > 1:
-                from jax.experimental import multihost_utils
-                cadence = int(multihost_utils.broadcast_one_to_all(
-                    np.int32(cadence)))
-            ckpt_every_steps = cadence
-            cadence_fixed = True
-            if proc_id == 0:
-                print(f'ckpt cadence: interval '
-                      f'{ckpt_interval_s:.0f}s / measured step '
-                      f'{mean_step:.3f}s -> checkpoint every '
-                      f'{ckpt_every_steps} steps', flush=True)
-        if boundary and proc_id == 0:
-            if sup is not None:
-                sup.beat('commit')
-            # Host-observed drain wait for the in-flight step: the
-            # device's critical path (compute + any un-overlapped
-            # collectives) still outstanding at the window boundary.
-            # On TPU the --profile trace shows WHICH collectives the
-            # gap is; this counter tracks whether --overlap shrinks
-            # it run-over-run.
-            wait0 = time.perf_counter()
-            jax.block_until_ready(loss)
-            collective_wait_s = time.perf_counter() - wait0
-            from skypilot_tpu.observability import catalog
-            catalog.counter(
-                'skypilot_train_collective_wait_seconds_total').inc(
-                    collective_wait_s)
-            dt = time.perf_counter() - t0
-            print(f'step {step + 1}/{args.steps} '
-                  f'loss={float(loss):.4f} '
-                  f'tokens/s={window_tokens / dt:,.0f}', flush=True)
-            if emitter is not None:
-                emitter.log(
-                    step + 1,
-                    step_time_s=dt / max(window_steps, 1),
-                    tokens=batch * args.seq,
-                    loss=float(loss),
-                    grad_norm=(float(gnorm) if gnorm is not None
-                               else None),
-                    bubble_frac=pipeline_bubble_frac,
-                    collective_wait_s=collective_wait_s)
-            t0 = time.perf_counter()
-            window_tokens = 0
-            window_steps = 0
-        step += 1
+                            print('train-guard: rollback requested but '
+                                  'no checkpoint available; continuing '
+                                  'with per-step skips', flush=True)
+                pending = (step, loss, gnorm, bad_flag)
+            if mgr is not None and (ckpt_interval_s is None or
+                                    (step + 1) % ckpt_every_steps == 0):
+                with tracing.phase('train.checkpoint', clock):
+                    mgr.save(step + 1, state)
+            if profiling and step + 1 >= args.steps:
+                # Window ran past the final step: still flush the trace.
+                with tracing.phase('train.sync', clock):
+                    jax.block_until_ready(loss)
+                jax.profiler.stop_trace()
+                profiling = False
+                print(f'profile: traced through final step {step + 1} '
+                      f'to {args.profile}', flush=True)
+            boundary = (step + 1) % args.log_every == 0
+            if boundary and not cadence_fixed:
+                # Every process fixes the cadence (checkpoint saves are
+                # collective); proc 0's value is broadcast so clock skew
+                # cannot desynchronize the save schedule.
+                if sup is not None:
+                    sup.beat('commit')
+                with tracing.phase('train.sync', clock):
+                    jax.block_until_ready(loss)
+                mean_step = ((time.perf_counter() - t0) /
+                             max(window_steps, 1))
+                cadence = max(1, round(ckpt_interval_s /
+                                       max(mean_step, 1e-9)))
+                if jax.process_count() > 1:
+                    from jax.experimental import multihost_utils
+                    cadence = int(multihost_utils.broadcast_one_to_all(
+                        np.int32(cadence)))
+                ckpt_every_steps = cadence
+                cadence_fixed = True
+                if proc_id == 0:
+                    print(f'ckpt cadence: interval '
+                          f'{ckpt_interval_s:.0f}s / measured step '
+                          f'{mean_step:.3f}s -> checkpoint every '
+                          f'{ckpt_every_steps} steps', flush=True)
+            if boundary and proc_id == 0:
+                if sup is not None:
+                    sup.beat('commit')
+                # Host-observed drain wait for the in-flight step: the
+                # device's critical path (compute + any un-overlapped
+                # collectives) still outstanding at the window boundary.
+                # On TPU the --profile trace shows WHICH collectives the
+                # gap is; this counter tracks whether --overlap shrinks
+                # it run-over-run.
+                with tracing.phase('train.sync', clock) as drain:
+                    jax.block_until_ready(loss)
+                collective_wait_s = drain.dur
+                from skypilot_tpu.observability import catalog
+                catalog.counter(
+                    'skypilot_train_collective_wait_seconds_total').inc(
+                        collective_wait_s)
+                dt = time.perf_counter() - t0
+                now_phases = _phase_seconds(clock)
+                with tracing.phase('train.log', clock):
+                    print(f'step {step + 1}/{args.steps} '
+                          f'loss={float(loss):.4f} '
+                          f'tokens/s={window_tokens / dt:,.0f}',
+                          flush=True)
+                    if emitter is not None:
+                        n = max(window_steps, 1)
+                        emitter.log(
+                            step + 1,
+                            step_time_s=dt / n,
+                            tokens=batch * args.seq,
+                            loss=float(loss),
+                            grad_norm=(float(gnorm) if gnorm is not None
+                                       else None),
+                            bubble_frac=pipeline_bubble_frac,
+                            collective_wait_s=collective_wait_s,
+                            phase_s={k: (now_phases[k] -
+                                         window_phases[k]) / n
+                                     for k in now_phases})
+                t0 = time.perf_counter()
+                window_phases = _phase_seconds(clock)
+                window_tokens = 0
+                window_steps = 0
+            step += 1
     if sup is not None:
         if pending is not None:
             p_step, p_loss, p_gnorm, p_bad = pending
@@ -831,7 +905,7 @@ def main() -> None:
         if proc_id == 0:
             print(f'train-guard summary: {sup.summary()}', flush=True)
     if mgr is not None:
-        with timeline.Event('train/checkpoint', 'final'):
+        with tracing.phase('train.checkpoint', clock):
             mgr.save(args.steps, state, force=True)
             mgr.wait_until_finished()
             mgr.close()

@@ -55,34 +55,43 @@ class Event:
         return self
 
     def __exit__(self, *args) -> None:
-        global _saved
         if _enabled_path is None:
             return
-        end = time.perf_counter()
-        with _lock:
-            if _saved:
-                # The buffer was flushed by an explicit save(); keep
-                # collecting into a fresh trace (a later save()
-                # rewrites the file) but say so once — callers that
-                # meant to stop tracing should have cleared the env /
-                # not re-entered Event.
-                _saved = False
-                from skypilot_tpu.utils import ux_utils
-                ux_utils.log(
-                    f'timeline: events recorded after save(); '
-                    f'starting a fresh trace buffer for '
-                    f'{_enabled_path} (the next save() overwrites '
-                    f'it).')
-            _events.append({
-                'name': self._name,
-                'cat': 'skypilot_tpu',
-                'ph': 'X',
-                'ts': self._start * 1e6,
-                'dur': (end - self._start) * 1e6,
-                'pid': os.getpid(),
-                'tid': threading.get_ident() % 100000,
-                'args': {'message': self._message} if self._message else {},
-            })
+        record(self._name, self._start,
+               time.perf_counter() - self._start, self._message)
+
+
+def record(name: str, start: float, dur: float,
+           message: Optional[str] = None) -> None:
+    """Append one complete ('X') event the caller already timed
+    (`start` on the perf_counter clock); no-op while disabled."""
+    global _saved
+    if _enabled_path is None:
+        return
+    with _lock:
+        if _saved:
+            # The buffer was flushed by an explicit save(); keep
+            # collecting into a fresh trace (a later save()
+            # rewrites the file) but say so once — callers that
+            # meant to stop tracing should have cleared the env /
+            # not re-entered Event.
+            _saved = False
+            from skypilot_tpu.utils import ux_utils
+            ux_utils.log(
+                f'timeline: events recorded after save(); '
+                f'starting a fresh trace buffer for '
+                f'{_enabled_path} (the next save() overwrites '
+                f'it).')
+        _events.append({
+            'name': name,
+            'cat': 'skypilot_tpu',
+            'ph': 'X',
+            'ts': start * 1e6,
+            'dur': dur * 1e6,
+            'pid': os.getpid(),
+            'tid': threading.get_ident() % 100000,
+            'args': {'message': message} if message else {},
+        })
 
 
 def event(fn_or_name: Union[Callable, str]) -> Callable:

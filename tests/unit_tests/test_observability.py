@@ -315,7 +315,8 @@ def test_train_lm_metrics_file_end_to_end(tmp_path):
         assert rec['grad_norm'] is not None and rec['grad_norm'] > 0
     with open(trace, 'r', encoding='utf-8') as f:
         spans = {e['name'] for e in json.load(f)['traceEvents']}
-    assert {'train/init', 'train/data', 'train/step'} <= spans
+    assert {'train/init', 'train.loop', 'train.data', 'train.dispatch',
+            'train.sync', 'train.log'} <= spans
 
 
 def test_step_metrics_jsonl_roundtrip(tmp_path):
