@@ -670,12 +670,19 @@ def serve_checks(ctx: Ctx, client: Client, ready_s: float) -> None:
         f'{hit_s:.2f}s')
     stats = client.json('/stats')
     check_stats(ctx, stats)
+    guard = client.json('/debug/pool_collectives', timeout=1800)
+    say(f'serve: pool_collective_lines and pool_copy_lines on the '
+        f'compiled decode and prefill-chunk programs: '
+        f'{json.dumps(guard)}')
+    copies = guard['copies']
+    if not copies or any(copies.values()):
+        raise SmokeFailure(
+            f'serve: a compiled program copies a whole pool-shaped '
+            f'array around its KV write (expected none in '
+            f'{sorted(copies or [])}): {guard}')
     if ctx.args.chips > 1:
         check_memory('serve', stats.get('device_memory'),
                      ctx.args.chips)
-        guard = client.json('/debug/pool_collectives', timeout=1800)
-        say(f'serve: pool_collective_lines on the compiled decode '
-            f'function: {json.dumps(guard)}')
         if guard['lines'] != [] or guard['mesh_devices'] != \
                 ctx.args.chips:
             raise SmokeFailure(

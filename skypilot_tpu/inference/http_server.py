@@ -312,20 +312,26 @@ def make_server(rt: InferenceRuntime,
             self._json({'dir': out_dir, 'seconds': seconds})
 
         def _debug_pool_collectives(self):
-            """The sharded-pool guard on the decode dispatch as
-            compiled HERE (docs/guides.md "Sharded serving"): `lines`
-            lists HLO collectives that move a pool-shaped operand —
-            empty means a tensor-sharded pool is never gathered.
-            Compiles (or reads the compile cache), so it is a
-            bring-up check, not a scrape target."""
+            """The page-pool guards on the programs as compiled HERE
+            (docs/guides.md "Sharded serving"): `lines` lists HLO
+            collectives of the decode dispatch that move a pool-shaped
+            operand — empty means a tensor-sharded pool is never
+            gathered; `copies` lists, per program (decode, one prefill
+            chunk), HLO copies that produce a pool-shaped array —
+            empty means the KV write is in place. Compiles (or reads
+            the compile cache), so it is a bring-up check, not a
+            scrape target."""
             try:
-                lines = (rt.engine.decode_pool_collectives()
-                         if rt.engine is not None else None)
+                lines = copies = None
+                if rt.engine is not None:
+                    lines = rt.engine.decode_pool_collectives()
+                    copies = rt.engine.pool_copy_lines()
             except Exception as e:  # pylint: disable=broad-except
                 self._plain_error(e)
                 return
             self._json({'mesh_devices': rt.mesh_devices,
-                        'stages': rt.stages, 'lines': lines})
+                        'stages': rt.stages, 'lines': lines,
+                        'copies': copies})
 
         def _prometheus_metrics(self):
             """Prometheus text exposition of the process registry.

@@ -482,6 +482,12 @@ class ContinuousBatchingEngine:
                 'dense per-slot cache has no scale storage (size the '
                 'kv page pool to hold max_total_len, or serve bf16)')
         if self.paged:
+            # A prompt's first chunk starts at 0. A later one starts a
+            # whole number of pages (the prefix hit) and of chunks in:
+            # on a page boundary iff chunks are whole pages. That is
+            # the promise the suffix dispatches pass to write_kv_chunk
+            # (page_aligned), which then writes pages, not tokens.
+            self._suffix_page_aligned = prefill_chunk % cfg_page == 0
             self.page_size = cfg_page
             self.total_pages = cfg_pool
             self.pages_per_seq = -(
@@ -936,6 +942,7 @@ class ContinuousBatchingEngine:
         if key in self._stage_fns:
             return self._stage_fns[key]
         sm = self._stage_models[s]
+        aligned = fresh or self._suffix_page_aligned
         if s == self.stages - 1:
 
             @functools.partial(jax.jit, donate_argnums=(1,),
@@ -948,7 +955,7 @@ class ContinuousBatchingEngine:
                     {'params': params, 'cache': cache}, x,
                     positions=positions, decode=True,
                     mutable=['cache'], page_indices=page_row,
-                    prefill=fresh, **extra)
+                    prefill=fresh, page_aligned=aligned, **extra)
                 # The continuation samples from the LAST REAL chunk
                 # position, not the padded tail.
                 last = jax.lax.dynamic_index_in_dim(
@@ -967,7 +974,7 @@ class ContinuousBatchingEngine:
                     {'params': params, 'cache': cache}, x,
                     positions=positions, decode=True,
                     mutable=['cache'], page_indices=page_row,
-                    prefill=fresh, **extra)
+                    prefill=fresh, page_aligned=aligned, **extra)
                 return mutated['cache'], hidden
 
         self._stage_fns[key] = stage_fn
@@ -1027,6 +1034,9 @@ class ContinuousBatchingEngine:
         # Donate the cache: the caller always replaces self.cache with
         # the result, so XLA updates in place instead of copying the
         # full KV cache every token (no-op on CPU, vital on TPU).
+        # Donation is necessary, not sufficient: the KV write must also
+        # keep the pool's layout (ops/paged_attention._write_pool) — a
+        # scatter gets its own on TPU and two whole-pool copies.
         paged = self.paged
 
         @functools.partial(jax.jit, donate_argnums=(1,),
@@ -1204,7 +1214,8 @@ class ContinuousBatchingEngine:
                     {'params': params, 'cache': cache},
                     prompt[None, :], positions=positions,
                     decode=True, mutable=['cache'],
-                    page_indices=page_row, prefill=True, **extra)
+                    page_indices=page_row, prefill=True,
+                    page_aligned=True, **extra)
                 # The continuation samples from the LAST REAL prompt
                 # position, not the padded tail.
                 last = jax.lax.dynamic_index_in_dim(
@@ -1265,6 +1276,7 @@ class ContinuousBatchingEngine:
             self._prefill_fns[key] = fn
             return fn
         model = self.model
+        aligned = self._suffix_page_aligned
 
         @functools.partial(jax.jit, donate_argnums=(1,),
                            **self._pin_cache_out(None))
@@ -1278,7 +1290,8 @@ class ContinuousBatchingEngine:
                 {'params': params, 'cache': cache},
                 suffix[None, :], positions=positions,
                 decode=True, mutable=['cache'],
-                page_indices=page_row, prefill=False, **extra)
+                page_indices=page_row, prefill=False,
+                page_aligned=aligned, **extra)
             last = jax.lax.dynamic_index_in_dim(
                 logits[0].astype(jnp.float32), suffix_len - 1, axis=0,
                 keepdims=False)
@@ -1548,16 +1561,31 @@ class ContinuousBatchingEngine:
         return pallas_paged.resolve_impl(
             'auto', quantized=self.kv_dtype == 'int8')
 
+    def _compile_decode(self):
+        """This engine's decode dispatch, lowered at its own shapes
+        and compiled for the backend it serves on (scheduler thread:
+        same mesh context and route selection as the live dispatch;
+        with the persistent compile cache on, a cache read)."""
+        n = self.num_slots
+        args = [self.params, self.cache,
+                jnp.zeros((n, self.spec_k + 1) if self.spec_k
+                          else (n,), jnp.int32),
+                jnp.zeros((n,), jnp.int32),
+                jnp.zeros((n,), jnp.float32),
+                jnp.zeros((n,), jnp.int32),
+                jnp.ones((n,), jnp.float32),
+                jax.random.PRNGKey(0)]
+        if self.paged:
+            args.append(jnp.asarray(self.page_table))
+        return self._decode.lower(*args).compile()
+
     def decode_pool_collectives(self) -> Optional[List[str]]:
         """The zero-resharding guard (parallel/serving
         .pool_collective_lines) run on THIS engine's decode dispatch
         as compiled for the backend it serves on: HLO lines where an
         all-gather / all-to-all touches a pool-shaped operand
         ([] = the sharded pool stays put). None for staged engines
-        (their per-stage dispatches are guarded in test_pp_serving).
-        Lowers on the scheduler thread — same mesh context, same
-        route selection as the live dispatch; with the persistent
-        compile cache on, the compile is a cache read."""
+        (their per-stage dispatches are guarded in test_pp_serving)."""
         if self.stages > 1:
             return None
         if self.mesh is None:
@@ -1565,20 +1593,37 @@ class ContinuousBatchingEngine:
         from skypilot_tpu.parallel import serving as _tp_serving
 
         def op():
-            n = self.num_slots
-            args = [self.params, self.cache,
-                    jnp.zeros((n, self.spec_k + 1) if self.spec_k
-                              else (n,), jnp.int32),
-                    jnp.zeros((n,), jnp.int32),
-                    jnp.zeros((n,), jnp.float32),
-                    jnp.zeros((n,), jnp.int32),
-                    jnp.ones((n,), jnp.float32),
-                    jax.random.PRNGKey(0)]
-            if self.paged:
-                args.append(jnp.asarray(self.page_table))
-            compiled = self._decode.lower(*args).compile()
             return _tp_serving.pool_collective_lines(
-                compiled, self.cache, self.mesh)
+                self._compile_decode(), self.cache, self.mesh)
+
+        return self.run_on_scheduler(op, timeout=1800.0)
+
+    def pool_copy_lines(self) -> Optional[Dict[str, List[str]]]:
+        """The in-place-write guard (parallel/serving.pool_copy_lines)
+        on THIS engine's compiled decode dispatch and one prefill
+        chunk (a whole `prefill_chunk` at a page-aligned offset, the
+        suffix program): HLO lines where a `copy` produces a
+        pool-shaped array, per program ([] = the donated pool is
+        written where it lies). None for staged and dense engines.
+        A bring-up check (chip_smoke.py), never on a request's path."""
+        if self.stages > 1 or not self.paged:
+            return None
+        from skypilot_tpu.parallel import serving as _tp_serving
+
+        def op():
+            # The largest chunk a suffix at offset one page can be.
+            chunk = min(self.prefill_chunk or self.page_size,
+                        (self.pages_per_seq - 1) * self.page_size)
+            suffix = self._prefill_suffix_fn(chunk).lower(
+                self.params, self.cache,
+                jnp.zeros((chunk,), jnp.int32), jnp.int32(chunk),
+                jnp.int32(self.page_size),
+                jnp.asarray(self.page_table[:1])).compile()
+            return {
+                'decode': _tp_serving.pool_copy_lines(
+                    self._compile_decode(), self.cache),
+                f'prefill_suffix_{chunk}': _tp_serving.pool_copy_lines(
+                    suffix, self.cache)}
 
         return self.run_on_scheduler(op, timeout=1800.0)
 

@@ -87,7 +87,8 @@ class CausalSelfAttention(nn.Module):
                  positions: Optional[jax.Array] = None,
                  decode: bool = False,
                  page_indices: Optional[jax.Array] = None,
-                 prefill: bool = False) -> jax.Array:
+                 prefill: bool = False,
+                 page_aligned: bool = False) -> jax.Array:
         cfg = self.config
         batch, seq, _ = x.shape
         qkv = _dense(3 * cfg.embed_dim, ('embed', 'mlp'), cfg.dtype,
@@ -113,7 +114,7 @@ class CausalSelfAttention(nn.Module):
                 k_pages, v_pages = _page_vars()
                 k_pages.value, v_pages.value = paged_ops.write_kv_chunk(
                     k_pages.value, v_pages.value, k, v, positions,
-                    page_indices)
+                    page_indices, page_aligned=page_aligned)
                 if prefill:
                     out = attention_ops.dot_product_attention(
                         q, k, v, causal=True)
@@ -208,7 +209,8 @@ class Block(nn.Module):
                  positions: Optional[jax.Array] = None,
                  decode: bool = False,
                  page_indices: Optional[jax.Array] = None,
-                 prefill: bool = False) -> jax.Array:
+                 prefill: bool = False,
+                 page_aligned: bool = False) -> jax.Array:
         cfg = self.config
         ln = lambda name: nn.LayerNorm(
             epsilon=cfg.norm_eps, dtype=cfg.dtype, name=name,
@@ -218,7 +220,8 @@ class Block(nn.Module):
                 nn.initializers.zeros_init(), ('norm',)))
         x = x + CausalSelfAttention(cfg, name='attn')(
             ln('ln_1')(x), deterministic, positions=positions,
-            decode=decode, page_indices=page_indices, prefill=prefill)
+            decode=decode, page_indices=page_indices, prefill=prefill,
+            page_aligned=page_aligned)
         x = x + MLP(cfg, name='mlp')(ln('ln_2')(x), deterministic)
         return nn.with_logical_constraint(x, ('batch', 'seq', 'act_embed'))
 
@@ -267,7 +270,8 @@ class GPT(nn.Module):
                  decode: bool = False,
                  page_indices: Optional[jax.Array] = None,
                  prefill: bool = False,
-                 return_hidden: bool = False) -> jax.Array:
+                 return_hidden: bool = False,
+                 page_aligned: bool = False) -> jax.Array:
         cfg = self.config
         batch, seq = tokens.shape
         assert seq <= cfg.block_size, (seq, cfg.block_size)
@@ -305,7 +309,8 @@ class GPT(nn.Module):
                                               positions=positions,
                                               decode=decode,
                                               page_indices=page_indices,
-                                              prefill=prefill)
+                                              prefill=prefill,
+                                              page_aligned=page_aligned)
         x = nn.LayerNorm(
             epsilon=cfg.norm_eps, dtype=cfg.dtype, name='ln_f',
             scale_init=nn.with_logical_partitioning(

@@ -164,7 +164,8 @@ class Attention(nn.Module):
                  prefill: bool = False,
                  lora: Optional[dict] = None,
                  adapter_ids: Optional[jax.Array] = None,
-                 lora_scale: Optional[jax.Array] = None) -> jax.Array:
+                 lora_scale: Optional[jax.Array] = None,
+                 page_aligned: bool = False) -> jax.Array:
         cfg = self.config
         batch, seq, _ = x.shape
         hd = cfg.head_dim
@@ -254,6 +255,9 @@ class Attention(nn.Module):
             # attention (empty-cache contract, flash-eligible);
             # otherwise the chunk attends the full history (speculative
             # verification chunks at arbitrary per-row offsets).
+            # `page_aligned` (static): the caller's promise that the
+            # chunk starts on a page boundary (every prefill chunk),
+            # so the pool takes it page by page (write_kv_chunk).
             if page_indices is not None:
                 from skypilot_tpu.ops import paged_attention as paged_ops
                 k_pages, v_pages, k_sc, v_sc = _page_vars()
@@ -261,12 +265,14 @@ class Attention(nn.Module):
                     (k_pages.value, v_pages.value, k_sc.value,
                      v_sc.value) = paged_ops.write_kv_chunk_quant(
                         k_pages.value, v_pages.value, k_sc.value,
-                        v_sc.value, k, v, positions, page_indices)
+                        v_sc.value, k, v, positions, page_indices,
+                        page_aligned=page_aligned)
                 else:
                     k_pages.value, v_pages.value = \
                         paged_ops.write_kv_chunk(
                             k_pages.value, v_pages.value, k, v,
-                            positions, page_indices)
+                            positions, page_indices,
+                            page_aligned=page_aligned)
                 if prefill:
                     # Chunk-local attention reads the chunk's own
                     # bf16 K/V (exact); later chunks/decodes read the
@@ -394,11 +400,13 @@ class Block(nn.Module):
                  prefill: bool = False,
                  lora: Optional[dict] = None,
                  adapter_ids: Optional[jax.Array] = None,
-                 lora_scale: Optional[jax.Array] = None) -> jax.Array:
+                 lora_scale: Optional[jax.Array] = None,
+                 page_aligned: bool = False) -> jax.Array:
         cfg = self.config
         x = x + Attention(cfg, name='attn')(
             RMSNorm(cfg.norm_eps, cfg.dtype, name='attn_norm')(x), positions,
-            decode, page_indices, prefill, lora, adapter_ids, lora_scale)
+            decode, page_indices, prefill, lora, adapter_ids, lora_scale,
+            page_aligned)
         x = x + FeedForward(cfg, name='mlp')(
             RMSNorm(cfg.norm_eps, cfg.dtype, name='mlp_norm')(x),
             lora, adapter_ids, lora_scale)
@@ -445,7 +453,8 @@ class Llama(nn.Module):
                  prefill: bool = False,
                  return_hidden: bool = False,
                  lora: Optional[dict] = None,
-                 adapter_ids: Optional[jax.Array] = None) -> jax.Array:
+                 adapter_ids: Optional[jax.Array] = None,
+                 page_aligned: bool = False) -> jax.Array:
         cfg = self.config
         batch, seq = tokens.shape
         # `lora` = {'scale': f32, 'layers': {'layer_i': {target:
@@ -467,12 +476,13 @@ class Llama(nn.Module):
         block = Block
         if cfg.remat:
             block = nn.remat(Block, prevent_cse=False,
-                             static_argnums=(3, 5))
+                             static_argnums=(3, 5, 9))
         for i in range(cfg.num_layers):
             x = block(cfg, name=f'layer_{i}')(x, positions, decode,
                                               page_indices, prefill,
                                               lora_layers.get(f'layer_{i}'),
-                                              adapter_ids, lora_scale)
+                                              adapter_ids, lora_scale,
+                                              page_aligned)
         x = RMSNorm(cfg.norm_eps, cfg.dtype, name='final_norm')(x)
         head = self.param(
             'lm_head',
@@ -522,7 +532,8 @@ class LlamaStage(nn.Module):
                  page_indices: Optional[jax.Array] = None,
                  prefill: bool = False,
                  lora: Optional[dict] = None,
-                 adapter_ids: Optional[jax.Array] = None) -> jax.Array:
+                 adapter_ids: Optional[jax.Array] = None,
+                 page_aligned: bool = False) -> jax.Array:
         cfg = self.config
         # The WHOLE lora stack threads through every stage; each stage
         # gathers only its own layers' factors below (the rest are
@@ -552,12 +563,13 @@ class LlamaStage(nn.Module):
         block = Block
         if cfg.remat:
             block = nn.remat(Block, prevent_cse=False,
-                             static_argnums=(3, 5))
+                             static_argnums=(3, 5, 9))
         for i in range(self.lo, self.hi):
             x = block(cfg, name=f'layer_{i}')(x, positions, decode,
                                               page_indices, prefill,
                                               lora_layers.get(f'layer_{i}'),
-                                              adapter_ids, lora_scale)
+                                              adapter_ids, lora_scale,
+                                              page_aligned)
         if not self.last:
             return x
         x = RMSNorm(cfg.norm_eps, cfg.dtype, name='final_norm')(x)

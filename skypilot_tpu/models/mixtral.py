@@ -167,14 +167,15 @@ class Block(nn.Module):
     def __call__(self, x: jax.Array, positions: jax.Array,
                  decode: bool = False,
                  page_indices: Optional[jax.Array] = None,
-                 prefill: bool = False
+                 prefill: bool = False,
+                 page_aligned: bool = False
                  ) -> Tuple[jax.Array, jax.Array]:
         cfg = self.config
         lcfg = cfg.as_llama()
         x = x + llama_lib.Attention(lcfg, name='attn')(
             llama_lib.RMSNorm(cfg.norm_eps, cfg.dtype, name='attn_norm')(x),
             positions, decode=decode, page_indices=page_indices,
-            prefill=prefill)
+            prefill=prefill, page_aligned=page_aligned)
         moe_out, aux = MoEFeedForward(cfg, name='moe')(
             llama_lib.RMSNorm(cfg.norm_eps, cfg.dtype, name='moe_norm')(x))
         x = x + moe_out
@@ -192,7 +193,8 @@ class Mixtral(nn.Module):
                  decode: bool = False,
                  page_indices: Optional[jax.Array] = None,
                  prefill: bool = False,
-                 return_hidden: bool = False):
+                 return_hidden: bool = False,
+                 page_aligned: bool = False):
         """Training: (logits, aux_loss). decode=True (serving): logits
         only — the KV-cache path of the shared llama attention, so the
         generate/continuous-batching engines drive Mixtral unchanged.
@@ -219,7 +221,8 @@ class Mixtral(nn.Module):
             x, aux = block(cfg, name=f'layer_{i}')(x, positions,
                                                    decode=decode,
                                                    page_indices=page_indices,
-                                                   prefill=prefill)
+                                                   prefill=prefill,
+                                                   page_aligned=page_aligned)
             total_aux = total_aux + aux
         x = llama_lib.RMSNorm(cfg.norm_eps, cfg.dtype, name='final_norm')(x)
         head = self.param(

@@ -52,10 +52,12 @@ token's already-quantized values.
 """
 from __future__ import annotations
 
+import functools
 from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 def quantize_kv_rows(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -202,7 +204,90 @@ def _reference_paged_attention(q: jax.Array, k_pages: jax.Array,
     return out.astype(q.dtype)
 
 
-@jax.named_scope('kv_write')
+def _placed_like_pool(x: jax.Array) -> jax.Array:
+    """Under a tensor mesh, pin `x` ([Hkv, ...]: the pool, or the
+    rows to write into it) to the pool's own placement: kv heads over
+    `tensor` when they divide it, else replicated (parallel/serving's
+    GQA remainder rule). `_write_pool` pins both ends of its update
+    chain: the projections leave the new rows sharded over `tensor`
+    however the heads divide, and left alone GSPMD carries THAT
+    through every update and all-gathers a replicated pool back
+    whole, each layer, each round. Pinned, it gathers the few rows."""
+    from skypilot_tpu.ops.attention import _active_mesh
+    mesh = _active_mesh()
+    tensor = mesh.shape.get('tensor', 1) if mesh is not None else 1
+    if tensor <= 1:
+        return x
+    spec = P('tensor') if x.shape[0] % tensor == 0 else P()
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+@functools.partial(jax.jit, static_argnames=('page_aligned',))
+def _write_pool(pages: jax.Array, new: jax.Array, positions: jax.Array,
+                page_indices: jax.Array, *, page_aligned: bool
+                ) -> jax.Array:
+    """Put new[b, s] ([B, S, Hkv, D]) at positions[b, s]'s page slot
+    of `pages` ([Hkv, P, page, D]) IN PLACE: one unrolled
+    `lax.dynamic_update_slice` per written page or token.
+
+    Not `pages.at[:, physical, slot, :].set(...)`: XLA:TPU gives a
+    scatter a page-major layout ({3,0,2,1}) while the program's
+    parameters, results and the paged-attention custom call hold the
+    pool in the default one, so every scatter is wrapped in TWO
+    whole-pool layout copies (168 MB each at a 5 GiB pool of 16
+    layers), donated or not. A dynamic-update-slice keeps the pool's
+    own layout and aliases through: the donated pool is updated where
+    it lies.
+
+    `page_aligned` (static) is the caller's promise that every row's
+    positions are consecutive and start on a page boundary (prefill
+    chunks): whole pages then go in as one [Hkv, 1, page, D] update
+    each, and only the sub-page remainder token by token. Without it
+    (decode rows at mixed depths, speculative-verify chunks) every
+    token is its own [Hkv, 1, 1, D] update. Updates apply in (row,
+    position) order; rows own distinct pages and only junk ever
+    collides (on the trash page), so the order is unobservable.
+
+    Jitted on its own so that a program traces and lowers the
+    unrolled updates once, not once per layer and pool (the K and V
+    pools of every layer share the shapes): XLA inlines the calls.
+    """
+    batch, chunk = positions.shape
+    page_size = pages.shape[2]
+    whole = chunk // page_size if page_aligned else 0
+    sizes = (page_size,) * whole + (1,) * (chunk - whole * page_size)
+    new = _placed_like_pool(jnp.moveaxis(new, 2, 0))   # [Hkv, B, S, D]
+    physical = jnp.take_along_axis(page_indices, positions // page_size,
+                                   axis=1)
+    slot = positions % page_size
+    for b in range(batch):
+        s = 0
+        for tokens in sizes:
+            # A whole page starts at its slot 0. Table pages and slots
+            # are never negative, so no wrap-around ops are traced.
+            at_slot = slot[b, s] if tokens == 1 else 0
+            pages = jax.lax.dynamic_update_slice(
+                pages, new[:, b:b + 1, s:s + tokens],
+                (0, physical[b, s], at_slot, 0),
+                allow_negative_indices=False)
+            s += tokens
+    return _placed_like_pool(pages)
+
+
+def _write_scales(scales: jax.Array, new: jax.Array,
+                  positions: jax.Array, page_indices: jax.Array
+                  ) -> jax.Array:
+    """Per-token scales ([B, S] f32) into the [P, page] scale pages.
+    A scatter: the array is small (4 bytes a cached token), so
+    whatever layout it is given costs nothing pool-sized."""
+    page_size = scales.shape[1]
+    physical = jnp.take_along_axis(page_indices, positions // page_size,
+                                   axis=1)
+    return scales.at[physical.reshape(-1),
+                     (positions % page_size).reshape(-1)].set(
+                         new.reshape(-1))
+
+
 def write_kv(k_pages: jax.Array, v_pages: jax.Array, k_new: jax.Array,
              v_new: jax.Array, positions: jax.Array,
              page_indices: jax.Array
@@ -212,52 +297,36 @@ def write_kv(k_pages: jax.Array, v_pages: jax.Array, k_new: jax.Array,
     k_new/v_new: [B, num_kv_heads, head_dim]; positions: i32[B] (the
     index the token lands at, i.e. lengths - 1 after admission);
     returns updated (k_pages, v_pages). Rows write distinct physical
-    pages (the allocator guarantees no sharing), so a scatter over
-    (page, slot) pairs is race-free.
+    pages (the allocator guarantees no sharing). In place, in the
+    pool's own layout (`_write_pool`): B small updates, no pool copy.
     """
-    page_size = k_pages.shape[2]
-    logical_page = positions // page_size
-    slot = positions % page_size
-    batch = positions.shape[0]
-    physical = page_indices[jnp.arange(batch), logical_page]  # [B]
-
-    # [Hkv, P, page, D] scatter at (:, physical[b], slot[b], :) = new[b]
-    def write_one(pages, new):
-        # pages: [Hkv, P, page, D]; new: [B, Hkv, D]
-        return pages.at[:, physical, slot, :].set(
-            jnp.swapaxes(new, 0, 1))
-
-    return write_one(k_pages, k_new), write_one(v_pages, v_new)
+    return write_kv_chunk(k_pages, v_pages, k_new[:, None],
+                          v_new[:, None], positions[:, None],
+                          page_indices)
 
 
 @jax.named_scope('kv_write')
 def write_kv_chunk(k_pages: jax.Array, v_pages: jax.Array,
                    k_new: jax.Array, v_new: jax.Array,
-                   positions: jax.Array, page_indices: jax.Array
+                   positions: jax.Array, page_indices: jax.Array,
+                   *, page_aligned: bool = False
                    ) -> Tuple[jax.Array, jax.Array]:
-    """Chunked-prefill write: S tokens per row in one scatter.
+    """Chunk write: S tokens per row, in place (`_write_pool`).
 
     k_new/v_new: [B, S, num_kv_heads, head_dim]; positions: i32[B, S].
     Within a row positions are distinct; padded-tail positions map to
     unallocated table entries, i.e. the trash page (duplicate writes
-    there are benign).
+    there are benign). `page_aligned` (static): every row's positions
+    are consecutive from a page boundary, so whole pages are written
+    as pages (prefill chunks); speculative-verify chunks start
+    anywhere and leave it False.
     """
-    batch, chunk = positions.shape
-    page_size = k_pages.shape[2]
-    logical = positions // page_size                       # [B, S]
-    slot = (positions % page_size).reshape(-1)             # [B*S]
-    physical = jnp.take_along_axis(page_indices, logical,
-                                   axis=1).reshape(-1)     # [B*S]
-
-    def write_one(pages, new):
-        flat = new.reshape(batch * chunk, *new.shape[2:])  # [BS, Hkv, D]
-        return pages.at[:, physical, slot, :].set(
-            jnp.swapaxes(flat, 0, 1))
-
-    return write_one(k_pages, k_new), write_one(v_pages, v_new)
+    return (_write_pool(k_pages, k_new, positions, page_indices,
+                        page_aligned=page_aligned),
+            _write_pool(v_pages, v_new, positions, page_indices,
+                        page_aligned=page_aligned))
 
 
-@jax.named_scope('kv_write')
 def write_kv_quant(k_pages: jax.Array, v_pages: jax.Array,
                    k_scales: jax.Array, v_scales: jax.Array,
                    k_new: jax.Array, v_new: jax.Array,
@@ -265,24 +334,12 @@ def write_kv_quant(k_pages: jax.Array, v_pages: jax.Array,
                    ) -> Tuple[jax.Array, jax.Array, jax.Array,
                               jax.Array]:
     """`write_kv` for an int8 pool: quantize the token's K/V rows and
-    scatter values + per-slot scales in one pass. Same race-freedom
-    argument (rows own distinct physical pages; trash-page collisions
-    write junk over junk)."""
-    page_size = k_pages.shape[2]
-    logical_page = positions // page_size
-    slot = positions % page_size
-    batch = positions.shape[0]
-    physical = page_indices[jnp.arange(batch), logical_page]  # [B]
-    qk, sk = quantize_kv_rows(k_new)
-    qv, sv = quantize_kv_rows(v_new)
-
-    def write_one(pages, new):
-        return pages.at[:, physical, slot, :].set(
-            jnp.swapaxes(new, 0, 1))
-
-    return (write_one(k_pages, qk), write_one(v_pages, qv),
-            k_scales.at[physical, slot].set(sk),
-            v_scales.at[physical, slot].set(sv))
+    write values (in place, `_write_pool`) + per-slot scales. Same
+    race-freedom argument (rows own distinct physical pages;
+    trash-page collisions write junk over junk)."""
+    return write_kv_chunk_quant(
+        k_pages, v_pages, k_scales, v_scales, k_new[:, None],
+        v_new[:, None], positions[:, None], page_indices)
 
 
 @jax.named_scope('kv_write')
@@ -290,30 +347,22 @@ def write_kv_chunk_quant(k_pages: jax.Array, v_pages: jax.Array,
                          k_scales: jax.Array, v_scales: jax.Array,
                          k_new: jax.Array, v_new: jax.Array,
                          positions: jax.Array,
-                         page_indices: jax.Array
+                         page_indices: jax.Array,
+                         *, page_aligned: bool = False
                          ) -> Tuple[jax.Array, jax.Array, jax.Array,
                                     jax.Array]:
     """`write_kv_chunk` for an int8 pool: S tokens per row quantized
-    (one scale per (row, position) token) and scattered with their
+    (one scale per (row, position) token) and written with their
     scales. Padded-tail positions land in the trash page exactly as
     the bf16 write does."""
-    batch, chunk = positions.shape
-    page_size = k_pages.shape[2]
-    logical = positions // page_size                       # [B, S]
-    slot = (positions % page_size).reshape(-1)             # [B*S]
-    physical = jnp.take_along_axis(page_indices, logical,
-                                   axis=1).reshape(-1)     # [B*S]
     qk, sk = quantize_kv_rows(k_new)                       # sk: [B, S]
     qv, sv = quantize_kv_rows(v_new)
-
-    def write_one(pages, new):
-        flat = new.reshape(batch * chunk, *new.shape[2:])
-        return pages.at[:, physical, slot, :].set(
-            jnp.swapaxes(flat, 0, 1))
-
-    return (write_one(k_pages, qk), write_one(v_pages, qv),
-            k_scales.at[physical, slot].set(sk.reshape(-1)),
-            v_scales.at[physical, slot].set(sv.reshape(-1)))
+    return (_write_pool(k_pages, qk, positions, page_indices,
+                        page_aligned=page_aligned),
+            _write_pool(v_pages, qv, positions, page_indices,
+                        page_aligned=page_aligned),
+            _write_scales(k_scales, sk, positions, page_indices),
+            _write_scales(v_scales, sv, positions, page_indices))
 
 
 def gather_page_rows(arr: jax.Array, idx: jax.Array) -> jax.Array:

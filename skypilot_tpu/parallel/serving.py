@@ -21,7 +21,9 @@ pins the paged pool's kv-heads axis over `tensor` (GQA remainder
 rule: shard only when the head count divides evenly, else replicate)
 and the engine declares those shardings on every jitted dispatch's
 donated cache output — zero per-step resharding of the pool, which
-`pool_collective_lines` lets tests assert from the compiled HLO.
+`pool_collective_lines` lets tests assert from the compiled HLO;
+`pool_copy_lines` is its single-chip sibling (no whole-pool copy
+around the KV write).
 """
 from __future__ import annotations
 
@@ -271,7 +273,10 @@ def pool_collective_lines(compiled: Any, cache: Any,
         else str(compiled)
     hits = []
     for line in text.splitlines():
-        if 'all-gather' not in line and 'all-to-all' not in line:
+        # The collective APPLIED on this line, not one named among
+        # its operands (`fusion(%all-gather.5, ...)` writing a few
+        # gathered rows into the pool is the in-place KV write).
+        if not re.search(r' all-(gather|to-all)(-start)?\(', line):
             continue
         for m in re.finditer(r'\[([0-9,]+)\]', line):
             n = 1
@@ -280,4 +285,35 @@ def pool_collective_lines(compiled: Any, cache: Any,
             if n in sizes:
                 hits.append(line.strip())
                 break
+    return hits
+
+
+def pool_copy_lines(compiled: Any, cache: Any) -> List[str]:
+    """HLO lines of a compiled serving module where a `copy` produces
+    a POOL-SHAPED array — the in-place-write guard for the page pool.
+
+    A donated pool that is written in its own layout never shows one.
+    The scatter form of the KV write did, twice per pool array per
+    program (a layout round trip around every scatter, 168 MB each at
+    a 5 GiB pool of 16 layers), which donation could not remove.
+    `cache` supplies the k_pages / v_pages shapes (arrays or
+    ShapeDtypeStructs); a shard of one along its leading kv-heads
+    axis counts as pool-shaped too. Returns the offending lines
+    (empty = guard green)."""
+    shapes = set()
+    flat, _ = jax.tree_util.tree_flatten_with_path(cache)
+    for path, leaf in flat:
+        if _leaf_name(path) in _PAGED_VALUE_LEAVES and len(leaf.shape) == 4:
+            shapes.add(tuple(leaf.shape))
+    text = compiled.as_text() if hasattr(compiled, 'as_text') \
+        else str(compiled)
+    hits = []
+    for line in text.splitlines():
+        m = re.search(r'= \w+\[([0-9,]+)\]\S* copy\(', line)
+        if m is None:
+            continue
+        dims = tuple(int(d) for d in m.group(1).split(','))
+        if any(len(dims) == 4 and dims[1:] == shape[1:] and
+               shape[0] % dims[0] == 0 for shape in shapes):
+            hits.append(line.strip())
     return hits

@@ -66,6 +66,8 @@ def main() -> None:
                              '(one compiled shape instead of a log2 '
                              'bucket ladder), so one long prompt '
                              'cannot stall every active decode slot. '
+                             'A multiple of the KV page size (16) '
+                             'lets chunks go into the pool by pages. '
                              '0 = whole-prompt prefill (the legacy '
                              'synchronous path)')
     parser.add_argument('--prefill-budget', type=int, default=0,

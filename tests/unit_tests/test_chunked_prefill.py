@@ -254,6 +254,29 @@ def test_pipeline_rejects_multi_token_decode_modes(llama_tiny):
     eng.stop()
 
 
+def test_chunk_that_splits_pages_writes_token_wise(llama_tiny):
+    """Only chunks of whole pages may promise the KV write a
+    page-aligned start (page_aligned in the suffix dispatch): a
+    prefill_chunk that splits pages (12 tokens, pages of 8) leaves the
+    promise out, and its outputs equal the whole-prompt prefill's."""
+    model, params = llama_tiny
+
+    def run(chunk):
+        eng = ContinuousBatchingEngine(model, params, num_slots=2,
+                                       max_total_len=64,
+                                       prefill_chunk=chunk)
+        aligned = eng._suffix_page_aligned  # pylint: disable=protected-access
+        try:
+            futs = [eng.submit(p, max_new_tokens=4)
+                    for p in PROMPTS + [LONG_PROMPT]]
+            return aligned, [f.result(timeout=300) for f in futs]
+        finally:
+            eng.stop()
+
+    assert run(12) == (False, run(0)[1])
+    assert run(16)[0] is True
+
+
 def test_cancel_mid_prefill_resolves_with_prompt(llama_tiny):
     """A request cancelled while still PREFILLING resolves with its
     prompt, frees the slot, and never poisons the prefix cache with

@@ -5,9 +5,12 @@ semantics. These tests prove the paged layout (scattered pages, page
 tables, per-row lengths) computes EXACTLY what dense causal decode
 attention computes, including GQA and non-contiguous page assignment.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from skypilot_tpu.ops import paged_attention as pa
 
@@ -123,6 +126,102 @@ def test_rows_at_different_depths():
     # Row 1's token landed in physical page 5 slot 1:
     np.testing.assert_allclose(np.asarray(k_pages[:, 5, 1]),
                                np.asarray(k_new[1]), atol=0)
+
+
+def _scatter_reference(pool, new, positions, table):
+    """The old scatter's semantics in plain NumPy: token (b, s) lands
+    at pool[:, table[b, pos // page], pos % page, :], applied in
+    (row, position) order (last write wins where junk collides on
+    the trash page)."""
+    pool = np.array(pool)
+    page = pool.shape[2]
+    for b in range(positions.shape[0]):
+        for s in range(positions.shape[1]):
+            pos = int(positions[b, s])
+            pool[:, table[b, pos // page], pos % page, :] = new[b, s]
+    return pool
+
+
+#: name -> (positions [B, S], page table [B, PAGES_PER_SEQ],
+#: page_aligned). Page 0 is the trash page (unallocated entries).
+_WRITE_CASES = {
+    # One token a row: first slot, last slot of a page, first slot of
+    # the next page, deep in the last page.
+    'decode_rows_mixed_depths': (
+        np.asarray([[0], [PAGE - 1], [PAGE], [4 * PAGE - 3]]),
+        np.asarray([[3, 9, 1, 7], [12, 4, 30, 2], [5, 6, 8, 10],
+                    [20, 21, 22, 23]]), False),
+    # A prefill chunk of three pages starting at page 1 of the row:
+    # one real page, then a padded tail through two unallocated
+    # table entries, i.e. twice into the trash page.
+    'aligned_chunk_padded_tail': (
+        PAGE + np.arange(3 * PAGE)[None, :],
+        np.asarray([[11, 17, 0, 0]]), True),
+    # Aligned start, but shorter than a page: token by token.
+    'aligned_chunk_shorter_than_page': (
+        2 * PAGE + np.arange(PAGE // 2)[None, :],
+        np.asarray([[11, 17, 13, 0]]), True),
+    # Aligned start, a page and a half: one page, then four tokens.
+    'aligned_chunk_page_and_remainder': (
+        np.arange(PAGE + PAGE // 2)[None, :],
+        np.asarray([[25, 26, 0, 0]]), True),
+    # Speculative-verify shape [B, k+1]: rows start anywhere and
+    # cross page boundaries mid-chunk.
+    'unaligned_verify_chunk': (
+        np.asarray([[5], [PAGE - 2], [2 * PAGE - 1]])
+        + np.arange(5)[None, :],
+        np.asarray([[3, 9, 1, 7], [12, 4, 30, 2], [5, 6, 8, 10]]),
+        False),
+}
+
+
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32],
+                         ids=['bf16', 'f32'])
+@pytest.mark.parametrize('case', sorted(_WRITE_CASES))
+def test_write_is_bit_identical_to_scatter_reference(case, dtype):
+    """The in-place write (dynamic-update-slices, by page where the
+    caller promises alignment) leaves the pool holding exactly the
+    bytes the scatter form put there, everywhere in the pool."""
+    positions, table, aligned = _WRITE_CASES[case]
+    batch, chunk = positions.shape
+    k_pool = _rand((HKV, TOTAL_PAGES, PAGE, D), 10).astype(dtype)
+    v_pool = _rand((HKV, TOTAL_PAGES, PAGE, D), 11).astype(dtype)
+    k_new = _rand((batch, chunk, HKV, D), 12).astype(dtype)
+    v_new = _rand((batch, chunk, HKV, D), 13).astype(dtype)
+    pos, tbl = jnp.asarray(positions, jnp.int32), jnp.asarray(
+        table, jnp.int32)
+    if chunk == 1:
+        write = jax.jit(pa.write_kv)
+        k_out, v_out = write(k_pool, v_pool, k_new[:, 0], v_new[:, 0],
+                             pos[:, 0], tbl)
+    else:
+        write = jax.jit(functools.partial(pa.write_kv_chunk,
+                                          page_aligned=aligned))
+        k_out, v_out = write(k_pool, v_pool, k_new, v_new, pos, tbl)
+    for out, pool, new in ((k_out, k_pool, k_new),
+                           (v_out, v_pool, v_new)):
+        assert out.dtype == dtype
+        want = _scatter_reference(np.asarray(pool), np.asarray(new),
+                                  positions, table)
+        np.testing.assert_array_equal(np.asarray(out), want)
+
+
+def test_unaligned_chunk_must_not_claim_alignment():
+    """What `page_aligned` promises: the same unaligned chunk written
+    page-wise lands on the wrong slots, so the flag is the caller's
+    statement and never a default."""
+    positions = 3 + np.arange(PAGE)[None, :]
+    table = np.asarray([[4, 5, 6, 7]])
+    pool = np.zeros((HKV, TOTAL_PAGES, PAGE, D), np.float32)
+    new = np.asarray(_rand((1, PAGE, HKV, D), 14))
+    want = _scatter_reference(pool, new, positions, table)
+    args = (jnp.asarray(pool), jnp.asarray(pool), jnp.asarray(new),
+            jnp.asarray(new), jnp.asarray(positions, jnp.int32),
+            jnp.asarray(table, jnp.int32))
+    honest, _ = pa.write_kv_chunk(*args)
+    np.testing.assert_array_equal(np.asarray(honest), want)
+    wrong, _ = pa.write_kv_chunk(*args, page_aligned=True)
+    assert not np.array_equal(np.asarray(wrong), want)
 
 
 def test_allocator_lifecycle():

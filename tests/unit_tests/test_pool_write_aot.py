@@ -1,0 +1,140 @@
+"""The KV write compiled for the chip, without the chip: no whole-pool
+copy around it.
+
+XLA:TPU gave the scatter form of the write a page-major layout of its
+own, so every scatter was wrapped in two copies of the whole pool
+array (PERF.md, PR 26). These tests compile the write as it is today
+for a described v5e at the benchmark cell's shapes
+(`mistral-7b-l16`: pool bf16[8, 5120, 16, 128], 32 slots, 128 pages a
+row) and hold `parallel/serving.pool_copy_lines` to zero. Nothing runs:
+a compile says nothing about results or times.
+
+All ahead-of-time compiles of the repo live in THIS file: the TPU
+library belongs to one process, so only the worker that is given this
+file loads it (from the fixture, never at import).
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from skypilot_tpu.ops import paged_attention as pa
+from skypilot_tpu.parallel.serving import pool_copy_lines
+
+POOL = (8, 5120, 16, 128)      # [Hkv, pages, page, D]
+SLOTS, PAGES_PER_ROW, HQ = 32, 128, 32
+CHUNK = 256
+
+
+@pytest.fixture(scope='module')
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:  # pylint: disable=broad-except
+        pytest.skip(f'no v5e:2x2 topology can be described here: {e}')
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope='module')
+def compile_for_chip(one_chip):
+    """compile(fn, donate, (shape, dtype)...) -> compiled, with the
+    persistent compile cache off around it (an entry written for a
+    described chip cannot be read back without one)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def compile_(fn, donate, *avals):
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in avals]
+        return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    try:
+        yield compile_
+    finally:
+        jax.config.update('jax_enable_compilation_cache', was)
+        compilation_cache.reset_cache()
+
+
+def _cache(dtype):
+    pool = jax.ShapeDtypeStruct(POOL, dtype)
+    return {'layer_0': {'attn': {'k_pages': pool, 'v_pages': pool}}}
+
+
+def _assert_in_place(compiled, dtype):
+    text = compiled.as_text()
+    assert pool_copy_lines(compiled, _cache(dtype)) == []
+    # The write is there, in the form that aliases: not optimised
+    # away, and no scatter but the two of an int8 pool's small
+    # [pages, page] scale arrays.
+    assert ' dynamic-update-slice(' in text
+    assert text.count(' scatter(') == (2 if dtype == jnp.int8 else 0)
+
+
+def test_decode_write_and_kernel_copy_no_pool(compile_for_chip):
+    """One layer of a decode round: the token-wise write, then the
+    upstream Pallas kernel the chip's route reads the pool with."""
+    from jax.experimental.pallas.ops.tpu.paged_attention import (
+        paged_attention)
+
+    def layer(k_pages, v_pages, q, k_new, v_new, positions, table):
+        k_pages, v_pages = pa.write_kv(k_pages, v_pages, k_new, v_new,
+                                       positions, table)
+        out = paged_attention(q * (POOL[3] ** -0.5), k_pages, v_pages,
+                              positions + 1, table,
+                              pages_per_compute_block=8)
+        return k_pages, v_pages, out
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    compiled = compile_for_chip(
+        layer, (0, 1), (POOL, bf16), (POOL, bf16),
+        ((SLOTS, HQ, POOL[3]), bf16), ((SLOTS, POOL[0], POOL[3]), bf16),
+        ((SLOTS, POOL[0], POOL[3]), bf16), ((SLOTS,), i32),
+        ((SLOTS, PAGES_PER_ROW), i32))
+    assert 'tpu_custom_call' in compiled.as_text()
+    _assert_in_place(compiled, bf16)
+
+
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.int8],
+                         ids=['bf16', 'int8'])
+def test_aligned_chunk_write_copies_no_pool(compile_for_chip, dtype):
+    """One layer's write of a page-aligned 256-token prefill chunk
+    (16 whole pages an array), bf16 and int8 pools."""
+
+    def write(k_pages, v_pages, k_scales, v_scales, k_new, v_new,
+              offset, table):
+        positions = (offset + jnp.arange(CHUNK, dtype=jnp.int32))[None]
+        if dtype == jnp.int8:
+            return pa.write_kv_chunk_quant(
+                k_pages, v_pages, k_scales, v_scales, k_new, v_new,
+                positions, table, page_aligned=True)
+        return pa.write_kv_chunk(k_pages, v_pages, k_new, v_new,
+                                 positions, table, page_aligned=True)
+
+    new = ((1, CHUNK, POOL[0], POOL[3]), jnp.bfloat16)
+    scales = (POOL[1:3], jnp.float32)
+    compiled = compile_for_chip(
+        write, (0, 1, 2, 3), (POOL, dtype), (POOL, dtype), scales,
+        scales, new, new, ((), jnp.int32),
+        ((1, PAGES_PER_ROW), jnp.int32))
+    _assert_in_place(compiled, dtype)
+    assert compiled.as_text().count(' dynamic-update-slice(') == \
+        2 * CHUNK // POOL[2]
+
+
+def test_guard_sees_a_pool_copy(compile_for_chip):
+    """The guard is not blind: the scatter form of the same write (what
+    `write_kv` was) still compiles to pool-shaped copies."""
+
+    def scatter(pages, new, physical, slot):
+        return pages.at[:, physical, slot, :].set(
+            jnp.swapaxes(new, 0, 1))
+
+    compiled = compile_for_chip(
+        scatter, (0,), (POOL, jnp.bfloat16),
+        ((SLOTS, POOL[0], POOL[3]), jnp.bfloat16),
+        ((SLOTS,), jnp.int32), ((SLOTS,), jnp.int32))
+    assert len(pool_copy_lines(compiled, _cache(jnp.bfloat16))) == 2

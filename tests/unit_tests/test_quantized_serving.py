@@ -172,6 +172,63 @@ def test_chunk_write_equals_tokenwise_write():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+#: name -> (positions [B, S], page table, page_aligned); page 4 tokens,
+#: page 0 the trash page.
+_INT8_WRITE_CASES = {
+    'decode_rows': (np.asarray([[0], [3], [4], [10]]),
+                    np.asarray([[1, 2, 3], [4, 5, 6], [7, 8, 9],
+                                [10, 11, 12]]), False),
+    'aligned_chunk_padded_tail': (4 + np.arange(12)[None, :],
+                                  np.asarray([[5, 6, 0, 0]]), True),
+    'unaligned_verify_chunk': (np.asarray([[2], [7]])
+                               + np.arange(3)[None, :],
+                               np.asarray([[1, 2, 3], [4, 5, 6]]),
+                               False),
+}
+
+
+@pytest.mark.parametrize('case', sorted(_INT8_WRITE_CASES))
+def test_int8_write_is_bit_identical_to_scatter_reference(case):
+    """The in-place int8 write leaves pages AND scale pages holding
+    exactly what the scatter form put there: the quantized rows of
+    `quantize_kv_rows` at (page, slot) through the table, in (row,
+    position) order, nothing else touched."""
+    positions, table, aligned = _INT8_WRITE_CASES[case]
+    batch, chunk = positions.shape
+    heads, pages, page, hd = 2, 13, 4, 8
+    rng = np.random.default_rng(7)
+    pools = [jnp.asarray(rng.integers(-127, 128, (heads, pages, page,
+                                                  hd)), jnp.int8)
+             for _ in range(2)]
+    scales = [jnp.asarray(rng.random((pages, page)), jnp.float32)
+              for _ in range(2)]
+    news = [jnp.asarray(rng.normal(size=(batch, chunk, heads, hd)),
+                        jnp.float32) for _ in range(2)]
+    pos = jnp.asarray(positions, jnp.int32)
+    tbl = jnp.asarray(table, jnp.int32)
+    if chunk == 1:
+        got = jax.jit(paged_ops.write_kv_quant)(
+            *pools, *scales, news[0][:, 0], news[1][:, 0], pos[:, 0],
+            tbl)
+    else:
+        got = jax.jit(paged_ops.write_kv_chunk_quant,
+                      static_argnames='page_aligned')(
+            *pools, *scales, *news, pos, tbl, page_aligned=aligned)
+    for i in range(2):
+        q, sc = paged_ops.quantize_kv_rows(news[i])
+        want_pool, want_scale = np.array(pools[i]), np.array(scales[i])
+        for b in range(batch):
+            for s in range(chunk):
+                phys = table[b, positions[b, s] // page]
+                slot = positions[b, s] % page
+                want_pool[:, phys, slot, :] = np.asarray(q[b, s])
+                want_scale[phys, slot] = np.asarray(sc[b, s])
+        assert got[i].dtype == jnp.int8
+        np.testing.assert_array_equal(np.asarray(got[i]), want_pool)
+        np.testing.assert_array_equal(np.asarray(got[2 + i]),
+                                      want_scale)
+
+
 # -- weight quantization ----------------------------------------------------
 def test_weight_quantize_targets_and_bounds(base):
     """Only the projection kernels quantize (embeddings/norms/head

@@ -132,6 +132,82 @@ def test_decode_step_has_no_pool_resharding(setup):
         eng.stop()
 
 
+@pytest.mark.parametrize('kv_heads,tensor,ways',
+                         [(4, 2, 2), (4, 4, 4), (2, 4, 1)])
+def test_engine_guards_on_its_own_programs(kv_heads, tensor, ways):
+    """The engine's own bring-up checks (what `GET
+    /debug/pool_collectives` serves and chip_smoke.py asserts on the
+    chip): the decode dispatch it compiled moves no pool-shaped
+    operand between devices with the in-place KV write (every
+    update spans the sharded kv-heads axis whole; where the heads do
+    not divide the mesh and the pool replicates, only the few new
+    rows are gathered, never the pool), and the copy guard reports on
+    the decode dispatch and one page-aligned prefill chunk."""
+    cfg = dataclasses.replace(
+        LlamaConfig.tiny(dtype=jnp.float32, kv_page_size=8,
+                         kv_total_pages=40), num_kv_heads=kv_heads)
+    model = Llama(cfg)
+    params = nn.meta.unbox(model.init(
+        jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))['params'])
+    mesh = mesh_lib.make_mesh(mesh_lib.MeshConfig(tensor=tensor),
+                              devices=jax.devices()[:tensor])
+    tp = shard_params_for_serving(model, params, mesh)
+    eng = ContinuousBatchingEngine(model, tp, num_slots=2,
+                                   max_total_len=48, prefill_chunk=16,
+                                   mesh=mesh)
+    try:
+        assert eng.kv_shard_ways == ways
+        assert eng.decode_pool_collectives() == []
+        copies = eng.pool_copy_lines()
+        assert sorted(copies) == ['decode', 'prefill_suffix_16']
+        assert all(isinstance(v, list) for v in copies.values())
+        # The engine still serves after lowering on its live cache.
+        row = eng.submit([3, 1, 4, 1, 5], max_new_tokens=3).result(
+            timeout=300)
+        assert len(row) == 8
+    finally:
+        eng.stop()
+
+
+def test_collective_guard_reads_the_applied_op_only():
+    """A fusion that writes a few all-gathered rows into the pool
+    names `%all-gather.N` among its operands and is no resharding; an
+    all-gather that PRODUCES a pool-shaped array is."""
+    mesh = mesh_lib.make_mesh(mesh_lib.MeshConfig(tensor=2),
+                              devices=jax.devices()[:2])
+    cache = {'attn': {'k_pages': jnp.zeros((2, 40, 8, 32),
+                                           jnp.float32)}}
+    write = ('%dus_fusion = f32[2,40,8,32]{3,2,1,0} fusion(%param.11, '
+             '%all-gather.5, %gather_fusion), kind=kLoop')
+    small = ('%all-gather.5 = f32[2,1,2,32]{3,1,0,2} all-gather('
+             '%copy.14), dimensions={2}')
+    regather = ('%all-gather.7 = f32[2,40,8,32]{3,2,1,0} all-gather('
+                '%copy.15), dimensions={0}')
+    hlo = '\n'.join(['  ' + write, '  ' + small, '  ' + regather])
+    assert pool_collective_lines(hlo, cache, mesh) == [regather]
+
+
+def test_copy_guard_matches_pool_and_head_shards():
+    """`pool_copy_lines` flags a copy that produces the pool's shape
+    or a kv-head shard of it, in any layout, and nothing else."""
+    from skypilot_tpu.parallel.serving import pool_copy_lines
+    cache = {'layer_0': {'attn': {
+        'k_pages': jax.ShapeDtypeStruct((8, 5120, 16, 128),
+                                        jnp.bfloat16),
+        'k_scales': jax.ShapeDtypeStruct((5120, 16), jnp.float32)}}}
+    hlo = """
+  %copy.1 = bf16[8,5120,16,128]{3,0,2,1:T(8,128)(2,1)} copy(%p), x
+  ROOT %copy.2 = bf16[2,5120,16,128]{3,2,1,0} copy(%fusion)
+  %copy.3 = f32[5120,16]{0,1} copy(%scales)
+  %copy.4 = bf16[32,8,128]{2,1,0} copy(%new)
+  %dus = bf16[8,5120,16,128]{3,2,1,0} dynamic-update-slice(%p, %u)
+  %copy.5 = bf16[3,5120,16,128]{3,2,1,0} copy(%odd)
+"""
+    hits = pool_copy_lines(hlo, cache)
+    assert [h.split(' = ')[0] for h in hits] == ['%copy.1',
+                                                 'ROOT %copy.2']
+
+
 def test_pool_guard_detects_forced_reshard(setup):
     """The guard is not vacuous: forcing the pool off its sharding
     (replicate = all-gather; axis move = all-to-all, whose per-shard
