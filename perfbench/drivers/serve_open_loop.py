@@ -11,7 +11,8 @@ generator (a child that never imports JAX) for the warm-up and for the
 window, reads `/stats` at the window's two ends, starts and stops the
 profiler for `--trace 1`, scores some finished rows against the
 configuration's plain float32 reference, prints the result line and ends
-the process.
+the process. The traced span is the window's last seconds; the closing
+`/stats` is read at the close and the profiler stopped after it.
 """
 from __future__ import annotations
 
@@ -37,17 +38,42 @@ from perfbench import shim
 from perfbench import trace_reduce
 
 #: How far below the position's best log-probability (under the plain
-#: float32 reference) the engine's greedy token may score, in nats. The
-#: engine computes in bf16 (paged kernel, chunked prefill, a batch of
-#: slots); its logits differ from the reference's by rounding noise that
-#: grows with depth and leaves a near-tie's argmax free to flip. Largest
-#: shortfall chip_smoke.py measured on the chip against the program's
-#: own bf16 forward pass: 0.057 nats over 8 layers, 0.170 over 32
-#: (PERF.md, PR 21). With seeded random weights the best logit stands about 5 nats
-#: above a typical token, which is where a kernel that drops a page, a
-#: mask or the softmax scale lands; 0.5 keeps 3x from the noise and 10x
-#: from that.
-LOGPROB_MARGIN = 0.5
+#: float32 reference) the engine's greedy token may score, in nats,
+#: where the configuration file states no `score_margin_nats` of its
+#: own, and why. A configuration states its own with
+#: `score_margin_why` (routed experts, say: a near-tie in the router
+#: flips an expert between bf16 and float32); one without its why is
+#: refused.
+DEFAULT_MARGIN_NATS = 0.5
+DEFAULT_MARGIN_WHY = (
+    'Llama blocks of 8-32 layers computed in bf16 (paged kernel, chunked '
+    'prefill, a batch of slots) differ from the float32 reference by '
+    'rounding noise that grows with depth and leaves a near-tie\'s argmax '
+    'free to flip: the largest shortfall read on the chip is 0.057 nats '
+    'over 8 layers, 0.17 over 32 and 0.225 over 16 '
+    '(PERF.md, PR 21 and 24); with seeded random weights the best logit '
+    'stands about 5 nats above a typical token, which is where a kernel '
+    'that drops a page, a mask or the softmax scale lands')
+
+
+def score_margin(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """The scoring margin the configuration states, or the default."""
+    if 'score_margin_nats' not in cfg:
+        return {'nats': DEFAULT_MARGIN_NATS, 'why': DEFAULT_MARGIN_WHY,
+                'from': 'the driver\'s default'}
+    why = str(cfg.get('score_margin_why') or '').strip()
+    nats = cfg['score_margin_nats']
+    if not why:
+        raise manifest_lib.ManifestError(
+            'the configuration states score_margin_nats without '
+            'score_margin_why: a tolerance comes with its reason')
+    if isinstance(nats, bool) or not isinstance(nats, (int, float)) \
+            or not 0 < nats < 10:
+        raise manifest_lib.ManifestError(
+            f'score_margin_nats {nats!r} is not a number of nats')
+    return {'nats': float(nats), 'why': why,
+            'from': 'the configuration file'}
+
 
 COMPILE_EVENT = '/jax/core/compile/jaxpr_to_mlir_module_duration'
 #: Limits, in seconds: the server's start to /readyz, one warm-up wave
@@ -184,48 +210,86 @@ def warm_up(ctx: harness.Ctx, base: str, mix: Dict[str, Any], vocab: int,
                 f'{len(compiles.times) - n0} compile events')
 
 
-def score_rows(ctx: harness.Ctx, cfg: Dict[str, Any],
-               mix: Dict[str, Any], params: Any,
-               requests: List[Dict[str, Any]],
-               records: List[Dict[str, Any]]) -> Dict[str, Any]:
-    """Some finished rows, drawn from the seed, against the
-    configuration's plain reference (`perfbench/references/`, float32,
-    no cache, no kernels) on the weights the server holds: at every
-    generated position the engine's token scores within LOGPROB_MARGIN
-    of the reference's best. Outside the window, on the device.
-
-    Attention is causal, so a row cut to `score_max_tokens` scores its
-    kept positions exactly; rows are padded to that length so that the
-    reference compiles one shape."""
+def pick_rows(ctx: harness.Ctx, mix: Dict[str, Any],
+              requests: List[Dict[str, Any]],
+              records: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
+    """The finished rows that are scored: the longest that fits
+    `score_max_tokens` and `score_rows` - 1 others drawn from the seed,
+    each as its tokens (prompt, then what the engine served, cut to the
+    cap) and the prompt's length."""
     cap = int(mix.get('score_max_tokens', 512))
     want = int(mix.get('score_rows', 3))
-    reference = manifest_lib.reference(cfg['reference'])
     prompts = {r['id']: r['prompt'] for r in requests}
     fits = [r for r in records if not accounting.failed(r)
             and r['prompt_tokens'] + 8 <= cap]
+    if not fits:
+        return []
     rng = random.Random(f'perfbench-score-{ctx.args.seed}')
-    chosen = rng.sample(fits, min(want, len(fits)))
+    longest = max(fits, key=lambda r: (
+        min(cap, r['prompt_tokens'] + len(r['tokens'])), -r['id']))
+    others = [r for r in fits if r is not longest]
+    chosen = [longest] + rng.sample(others, min(want - 1, len(others)))
+    return [{'id': r['id'], 'prompt_tokens': r['prompt_tokens'],
+             'tokens': (prompts[r['id']] + r['tokens'])[:cap],
+             'cap': cap} for r in chosen]
+
+
+def _padded(row: Dict[str, Any]) -> List[int]:
+    """The row's tokens padded to the cap, so that the reference
+    compiles one shape (attention is causal: the kept positions score
+    exactly)."""
+    return row['tokens'] + [1] * (row['cap'] - len(row['tokens']))
+
+
+def score_rows(cfg: Dict[str, Any], params: Any,
+               rows: List[Dict[str, Any]], margin: float
+               ) -> Dict[str, Any]:
+    """The rows against the configuration's plain reference
+    (`perfbench/references/`, float32, no cache, no kernels) on the
+    weights the server holds: at every generated position the engine's
+    token scores within `margin` nats of the reference's best. Outside
+    the window, on the device.
+
+    A row cut to `score_max_tokens` scores its kept positions
+    exactly."""
+    reference = manifest_lib.reference(cfg['reference'])
     worst, n_pos = 0.0, 0
-    for rec in chosen:
-        n_prompt = rec['prompt_tokens']
-        row = (prompts[rec['id']] + rec['tokens'])[:cap]
-        padded = row + [1] * (cap - len(row))
-        lp = np.asarray(reference.log_probs(params, cfg, padded))
-        for i in range(n_prompt, len(row)):
-            chosen_lp, best = lp[i - 1, row[i]], lp[i - 1].max()
+    for row in rows:
+        tokens = row['tokens']
+        lp = np.asarray(reference.log_probs(params, cfg, _padded(row)))
+        for i in range(row['prompt_tokens'], len(tokens)):
+            chosen_lp, best = lp[i - 1, tokens[i]], lp[i - 1].max()
             if not (math.isfinite(chosen_lp) and math.isfinite(best)):
-                return {'ok': False, 'why': f'non-finite at {i}'}
+                worst = float('inf')
             worst = max(worst, float(best - chosen_lp))
             n_pos += 1
-    return {'ok': bool(chosen) and worst <= LOGPROB_MARGIN,
-            'rows': len(chosen), 'positions': n_pos,
-            'worst_shortfall_nats': worst, 'margin': LOGPROB_MARGIN}
+    return {'ok': bool(rows) and worst <= margin, 'rows': len(rows),
+            'positions': n_pos, 'worst_shortfall_nats': worst,
+            'margin': margin}
+
+
+def control_reading(cfg: Dict[str, Any], params: Any,
+                    rows: List[Dict[str, Any]]) -> Dict[str, Any]:
+    """`--control`: the reference put in the program's place, computed
+    in the nearest precision below the served one (the reference's
+    `control_shortfall`), on the rows and positions just scored. What
+    it reads is what the margin must refuse (PERF.md section 2)."""
+    reference = manifest_lib.reference(cfg['reference'])
+    worst = 0.0
+    for row in rows:
+        worst = max(worst, reference.control_shortfall(
+            params, cfg, _padded(row), row['prompt_tokens'],
+            len(row['tokens'])))
+    return {'rows': len(rows), 'control_shortfall_nats': worst}
 
 
 def control(ctx: harness.Ctx, base: str, cfg: Dict[str, Any],
             mix: Dict[str, Any], compiles: CompileLog,
             runtimes: List[Any]) -> None:
     seconds = float(ctx.args.seconds)
+    margin = score_margin(cfg)
+    ctx.say(f'scoring margin {margin["nats"]:g} nats, from '
+            f'{margin["from"]}: {margin["why"]}')
     ready_s = wait_ready(ctx, base, READY_LIMIT_S)
     info = get_json(base, '/')
     stats0 = get_json(base, '/stats')
@@ -248,23 +312,38 @@ def control(ctx: harness.Ctx, base: str, cfg: Dict[str, Any],
     stats_open = get_json(base, '/stats')
     setup_s = open_at - ctx.t_start
     trace_dir = os.path.join(ctx.work, 'profile')
+    trace_t0 = trace_t1 = None
     if ctx.trace:
         import jax
+        # The traced span is the window's LAST seconds, so that the
+        # closing /stats is read at the close and the profiler stopped
+        # after it: the stop takes 25-30 s at this server's operation
+        # rate (my chip run, PR 26), and a /stats read behind it spread
+        # every per-count metric over the drain.
         span = min(float(mix.get('trace_span_s', 3.0)), seconds * 0.5)
-        time.sleep(max(0.0, open_at + (seconds - span) / 2 - time.time()))
-        # Device events only: the Python tracer (on by default) hooks
-        # every call of the scheduler loop and slows the host it is
-        # meant to observe; nothing here reads host events yet.
+        time.sleep(max(0.0, open_at + seconds - span - time.time()))
+        # Device events and the program's own phase events; the Python
+        # tracer (on by default) hooks every call of the scheduler loop
+        # and slows the host it is meant to observe.
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         options.host_tracer_level = 1
+        t_call = time.time()
         jax.profiler.start_trace(trace_dir, profiler_options=options)
-        time.sleep(span)
-        jax.profiler.stop_trace()
+        trace_t0 = time.time()
+        ctx.say(f'profiler started {trace_t0 - open_at:.3f}s into the '
+                f'window (the call took {trace_t0 - t_call:.3f}s)')
     time.sleep(max(0.0, open_at + seconds - time.time()))
     read_close = time.time()
     stats_close = get_json(base, '/stats')
     close_at = time.time()
+    ctx.say(f'closing /stats read {1000 * (close_at - open_at - seconds):.0f}'
+            f'ms after the close')
+    if ctx.trace:
+        trace_t1 = time.time()
+        jax.profiler.stop_trace()
+        ctx.say(f'profiler stopped after the closing /stats; the stop took '
+                f'{time.time() - trace_t1:.1f}s')
     n_compiles = compiles.between(open_at, open_at + seconds)
     records = reap(ctx, 'window', proc, drain_s + 60)
     stats_end = get_json(base, '/stats')
@@ -278,9 +357,8 @@ def control(ctx: harness.Ctx, base: str, cfg: Dict[str, Any],
             + ' '.join(f'{1000 * accounting.ttft_s(r, seconds):.0f}'
                        for r in records))
     ctx.say(f'backlog (requests due and unfinished) at the middle {mid}, '
-            f'at the close {close}; /stats read '
-            f'{1000 * (close_at - open_at - seconds):.0f}ms after the '
-            f'close; compile events in the window {n_compiles}')
+            f'at the close {close}; compile events in the window '
+            f'{n_compiles}')
     engine = {k: (stats_close.get(k), stats_close.get(k, 0)
                   - stats_open.get(k, 0))
               for k in ('decode_calls', 'tokens_committed',
@@ -293,25 +371,38 @@ def control(ctx: harness.Ctx, base: str, cfg: Dict[str, Any],
 
     finished_wrong = [r for r in records if r.get('end') == 'done'
                       and len(r['tokens']) != r['max_new_tokens']]
-    scoring = score_rows(ctx, cfg, mix, runtimes[0].params, requests,
-                         records)
-    checks = {
-        'exact_lengths': not finished_wrong,
-        'soft_errors_0': stats_end.get('soft_errors') == 0,
-        'engine_restarts_0': stats_end.get('engine_restarts') == 0,
-        'paged_cache': str(stats_end.get('kv_cache', '')).startswith(
-            'paged'),
-        'plain_forward': scoring['ok'],
+    rows = pick_rows(ctx, mix, requests, records)
+    scoring = score_rows(cfg, runtimes[0].params, rows, margin['nats'])
+    if ctx.args.control:
+        ctx.say(f'control (not part of a run of the benchmark): '
+                f'{json.dumps(control_reading(cfg, runtimes[0].params, rows))}'
+                f' against the program\'s {scoring["worst_shortfall_nats"]}')
+    paged = str(stats_end.get('kv_cache', '')).startswith('paged')
+    # Each number compared, beside its limit (`at_most`).
+    compared = {
+        'shortfall_nats': {'value': scoring['worst_shortfall_nats'],
+                           'at_most': margin['nats']},
+        'rows_scored': {'value': scoring['rows'], 'at_least': 1},
+        'wrong_lengths': {'value': len(finished_wrong), 'at_most': 0},
+        'soft_errors': {'value': stats_end.get('soft_errors'),
+                        'at_most': 0},
+        'engine_restarts': {'value': stats_end.get('engine_restarts'),
+                            'at_most': 0},
+        'paged_cache': {'value': int(paged), 'at_least': 1},
     }
-    ctx.say(f'correctness {json.dumps(checks)}; scoring '
-            f'{json.dumps(scoring)}')
+    ctx.say(f'scoring {json.dumps(scoring)}')
 
-    trace_summary = None
+    trace_summary = trace_path = None
     if ctx.trace:
         kw = mix.get('trace_planes') or {}
-        trace_summary, seen = trace_reduce.reduce_trace_dir(trace_dir, **kw)
+        t_reduce = time.time()
+        trace_summary, seen, trace_path = trace_reduce.reduce_trace_dir(
+            trace_dir, **kw)
         for line in seen:
             ctx.say(f'trace plane {line[:300]}')
+        ctx.say(f'trace {trace_path} reduced in '
+                f'{time.time() - t_reduce:.1f}s')
+        harness.say_trace(ctx, trace_summary)
     end_to_end = {'ttft_p95_ms': summary['ttft_p95_ms'],
                   'itl_p95_ms': summary['itl_p95_ms'],
                   'serve_tokens_per_s': summary['serve_tokens_per_s'],
@@ -319,15 +410,21 @@ def control(ctx: harness.Ctx, base: str, cfg: Dict[str, Any],
     sources = {
         'stats_open': stats_open, 'stats_close': stats_close,
         'records': records, 'end_to_end': end_to_end,
-        'trace': trace_summary, 'config': cfg, 'mix': mix,
-        'device': ctx.device,
-        # The counters grow between the two /stats reads; a traced run
-        # reads the second one late when the profiler is slow to stop.
+        'trace': trace_summary, 'trace_path': trace_path,
+        # The traced span on the records' clock (seconds from the
+        # window's opening): from start_trace's return to the call of
+        # stop_trace, so the device's lines cover all of it.
+        'trace_t0': None if trace_t0 is None else trace_t0 - open_at,
+        'trace_t1': None if trace_t1 is None else trace_t1 - open_at,
+        'config': cfg, 'mix': mix, 'device': ctx.device, 'say': ctx.say,
+        # The counters grow between the two /stats reads, both taken
+        # at the window's ends (the profiler is stopped after the
+        # second).
         'harness': {'window_s': seconds, 'ready_s': ready_s,
                     'stats_span_s': read_close - read_open,
                     'compiles_in_window': n_compiles,
                     'lateness_p95_ms': summary['lateness_p95_ms']}}
-    harness.finish(ctx, correct=all(checks.values()),
+    harness.finish(ctx, compared=compared,
                    attempted=summary['attempted'],
                    failed=summary['failed'], end_to_end=end_to_end,
                    sources=sources, trace_summary=trace_summary)

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 import os
 import shutil
 import sys
@@ -96,6 +97,15 @@ def claim_device(ctx: Ctx) -> Dict[str, Any]:
     import jax
     if ctx.rehearse:
         jax.config.update('jax_platforms', 'cpu')
+    elif ctx.trace:
+        # A program read from the persistent cache carries the op names
+        # of whichever checkout compiled it first (the key leaves the
+        # metadata out), and the reducer reads those names. A traced
+        # run keys its programs on their metadata as well: its first
+        # run in a checkout compiles them anew, under its own names;
+        # untraced runs keep the cache they had.
+        jax.config.update('jax_compilation_cache_include_metadata_in_key',
+                          True)
     try:
         devices = jax.devices()
     except RuntimeError as e:
@@ -159,12 +169,47 @@ def read_layer_metrics(ctx: Ctx, sources: Dict[str, Any]
     return out
 
 
-def finish(ctx: Ctx, *, correct: bool, attempted: int, failed: int,
+def holds(entry: Dict[str, Any]) -> bool:
+    """Whether a compared number keeps to its limit(s): `at_most`,
+    `at_least`. A missing or non-finite number does not."""
+    value = entry.get('value')
+    if value is None or isinstance(value, bool) or not math.isfinite(value):
+        return False
+    return (('at_most' not in entry or value <= entry['at_most'])
+            and ('at_least' not in entry or value >= entry['at_least']))
+
+
+def say_trace(ctx: Ctx, summary: Optional[Dict[str, Any]],
+              rows: int = 24) -> None:
+    """Earlier lines: where the device's time went by program and by
+    operation path, and the phases the host plane held."""
+    if not summary:
+        return
+    ctx.say('device self seconds (calls) by program: ' + ', '.join(
+        f'{name} {sec:.4f} ({calls:.0f})' for name, (sec, calls)
+        in list(summary['by_program'].items())[:rows]))
+    for program, path, sec, count in summary['by_path'][:rows]:
+        ctx.say(f'by path: {sec:.4f}s {count:.0f} ops {program}: {path}')
+    ctx.say(f'idle gaps {summary["idle_gap_count"]}; phase events on the '
+            f'host plane {json.dumps(summary["phases_seen"])}; the first '
+            f'begins {summary["first_phase_s"]}s after the span\'s first '
+            f'operation; listed gaps no phase covers begin at '
+            f'{summary["unattributed_at_s"]}s')
+
+
+def finish(ctx: Ctx, *, compared: Dict[str, Dict[str, Any]],
+           attempted: int, failed: int,
            end_to_end: Dict[str, float], sources: Dict[str, Any],
            trace_summary: Optional[Dict[str, Any]]) -> None:
     """Print the result line (the LAST line of stdout) and end the
     process. `--trace 0`: the cell's end-to-end metrics; `--trace 1`:
-    its per-layer metrics, with busy_s/window_s and the breakdown."""
+    its per-layer metrics, with busy_s/window_s and the breakdown.
+    `correct` is whether every compared number keeps to its limit;
+    the numbers stand beside their limits on the last lines of stderr
+    and under the result's last key, `compared`."""
+    compared = {name: dict(entry, ok=holds(entry))
+                for name, entry in compared.items()}
+    correct = bool(compared) and all(e['ok'] for e in compared.values())
     device = dict(ctx.device, memory_peak_bytes=memory_peak_bytes(ctx))
     if ctx.trace:
         metrics = read_layer_metrics(ctx, sources)
@@ -182,23 +227,33 @@ def finish(ctx: Ctx, *, correct: bool, attempted: int, failed: int,
         metrics = {n: {'value': float(end_to_end[n]), 'unit': units[n]}
                    for n in units}
     result: Dict[str, Any] = {
-        'correct': bool(correct), 'attempted': int(attempted),
+        'correct': correct, 'attempted': int(attempted),
         'failed': int(failed), 'metrics': metrics, 'device': device}
     if ctx.trace:
         result['breakdown'] = {
-            'device_ops': trace_summary['device_ops'],
-            'idle_gaps': trace_summary['idle_gaps']}
+            'device_ops': trace_summary['device_ops'][:10],
+            'idle_gaps': trace_summary['idle_gaps'][:10]}
     if ctx.args.rate is not None:
         result['sweep'] = True        # a sweep run is not a measurement
     if ctx.rehearse:
         # No number from a CPU run stands under a metric's name.
         result['metrics'] = {f'rehearsal.{k}': v
                              for k, v in metrics.items()}
-    line = (REHEARSAL_MARK if ctx.rehearse else '') + json.dumps(result)
+    result['compared'] = compared     # the last key of the line
+    mark = REHEARSAL_MARK if ctx.rehearse else ''
+    line = mark + json.dumps(result)
     if not ctx.args.work_dir:
         shutil.rmtree(ctx.work, ignore_errors=True)
-    sys.stderr.flush()
     sys.stdout.flush()
+    for name, entry in compared.items():
+        limits = ', '.join(f'{k.replace("_", " ")} {entry[k]:g}'
+                           for k in ('at_least', 'at_most') if k in entry)
+        print(f'{mark}perfbench: compared {name} = {entry["value"]} '
+              f'({limits}): {"ok" if entry["ok"] else "NOT MET"}',
+              file=sys.stderr)
+    print(f'{mark}perfbench: correct = {str(correct).lower()}',
+          file=sys.stderr)
+    sys.stderr.flush()
     # Past any Tee: the result line is the LAST line, whole and unmarked
     # by anything but the rehearsal's own mark.
     out = getattr(sys.stdout, '_stream', sys.stdout)

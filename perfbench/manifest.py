@@ -105,6 +105,18 @@ def reference(name: str):
     return _module('references', name)
 
 
+def sizes(family: str):
+    """`perfbench/sizes/<family>.py`: `params(cfg)`,
+    `train_flops_per_token(cfg, seq)`, `serve_flops_per_token(cfg)`."""
+    return _module('sizes', family)
+
+
+def roofline(name: str):
+    """`perfbench/rooflines/<name>.py`; it has `cost(sources)` ->
+    `{'flops', 'bytes'}` over the traced span, or None."""
+    return _module('rooflines', name)
+
+
 def reader(name: str):
     """`perfbench/readers/<name>.py`; it has `read(sources, **args)`."""
     return _module('readers', name)

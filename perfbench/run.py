@@ -18,6 +18,9 @@ result: one JSON object (see perfbench/README.md).
     --rate R        serving cells: offer R requests/s instead of the
                     mix's rate (the knee sweep); the result is marked
                     `"sweep": true`
+    --control       serving cells: after the scoring, also read the
+                    control (the reference in the next lower precision)
+                    on the same rows; an earlier line, no metric
     --work-dir DIR  keep the run's files (records, metrics, trace) in
                     DIR instead of a temporary directory
 """
@@ -43,6 +46,7 @@ def main() -> int:
     parser.add_argument('--trace', type=int, choices=(0, 1), default=0)
     parser.add_argument('--rehearse', action='store_true')
     parser.add_argument('--rate', type=float, default=None)
+    parser.add_argument('--control', action='store_true')
     parser.add_argument('--work-dir', default=None)
     args = parser.parse_args()
 

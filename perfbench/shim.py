@@ -19,8 +19,6 @@ from typing import Any, Dict, Optional
 
 from perfbench import manifest as manifest_lib
 
-CONFIGS = os.path.join(manifest_lib.HERE, 'configs')
-
 #: HuggingFace config.json key -> models/llama.py LlamaConfig field.
 LLAMA_FIELDS = {
     'vocab_size': 'vocab_size',
@@ -38,7 +36,7 @@ def file_config(name: str) -> Optional[Dict[str, Any]]:
     """The configuration file for `name`, if it is one the shim builds."""
     if not manifest_lib.NAME_RE.match(name):
         return None
-    path = os.path.join(CONFIGS, f'{name}.json')
+    path = os.path.join(manifest_lib.HERE, 'configs', f'{name}.json')
     if not os.path.isfile(path):
         return None
     with open(path, 'r', encoding='utf-8') as f:

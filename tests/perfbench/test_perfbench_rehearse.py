@@ -40,19 +40,24 @@ def test_unknown_workload_fails_and_prints_no_result():
 
 
 CELLS = {'train_job': 'gpt2-124m.pretrain',
-         'serve_open_loop': 'mistral-7b-l16.chat'}
+         'serve_open_loop': 'mistral-7b-l16.chat-r2'}
 
 
 # One rehearsal of each driver runs in tier-1 and covers both kinds of
-# result line; the other two are `slow` (and `e2e`: live processes), to
-# keep tier-1 short.
+# result line, and the traced serving run as well (the order of the
+# closing /stats and the profiler's stop is asserted in it); the
+# untraced trainer run is `slow` (and `e2e`: live processes).
 _SLOW = [pytest.mark.slow, pytest.mark.e2e]
+#: Readers that need the chip's peaks or a TPU plane's programs: a CPU
+#: rehearsal leaves their metrics out.
+_CHIP_ONLY = {'train.mfu_pct', 'serve.mfu_pct',
+              'kernel.paged_decode_roofline', 'engine.prefill_share_pct'}
 
 
 @pytest.mark.parametrize('driver, trace', [
     ('train_job', 1), ('serve_open_loop', 0),
     pytest.param('train_job', 0, marks=_SLOW),
-    pytest.param('serve_open_loop', 1, marks=_SLOW)])
+    ('serve_open_loop', 1)])
 def test_rehearsal_of_each_driver(driver, trace):
     cell = CELLS[driver]
     out = _run(['--workload', cell, '--seed', str(2 ** 31 + 12345),
@@ -75,10 +80,76 @@ def test_rehearsal_of_each_driver(driver, trace):
     got = {name[len('rehearsal.'):] for name in got}
     # A reader that finds nothing leaves its metric out (the CPU is in
     # no table of peaks); nothing undeclared is ever reported.
-    assert got <= declared and got >= declared - {'train.mfu_pct'}
+    assert got <= declared and got >= declared - _CHIP_ONLY
+    # The numbers compared stand beside their limits: under the result's
+    # LAST key, and on the last lines of stderr.
+    assert list(result)[-1] == 'compared' and result['compared']
+    assert all(e['ok'] and ('at_most' in e or 'at_least' in e)
+               for e in result['compared'].values())
+    tail = out.stderr.strip().splitlines()[-len(result['compared']) - 1:]
+    assert tail[-1] == '[rehearsal] perfbench: correct = true'
+    assert all(f'compared {name} = ' in line
+               for name, line in zip(result['compared'], tail))
+    if trace and driver == 'serve_open_loop':
+        # The closing /stats is read at the close, the profiler stopped
+        # after it (a stop of 25-30 s on the chip otherwise spreads
+        # every per-count metric over the drain).
+        said = [i for i, line in enumerate(lines)
+                if 'closing /stats read' in line
+                or 'profiler stopped after the closing /stats' in line
+                or 'profiler started' in line]
+        assert len(said) == 3 and said == sorted(said)
+        assert 'profiler started' in lines[said[0]]
+        assert 'closing /stats read' in lines[said[1]]
+        ms = float(lines[said[1]].split('read ')[1].split('ms')[0])
+        assert ms < 1000
+        assert any('phase events on the host plane' in line
+                   and 'engine.decode_dispatch' in line for line in lines)
     if trace:
         assert result['device']['busy_s'] > 0
         assert result['device']['window_s'] >= result['device']['busy_s']
         assert len(result['breakdown']['device_ops']) <= 10
     else:
         assert 'breakdown' not in result
+
+
+FAULT = """
+import runpy, sys
+sys.path.insert(0, {root!r})
+from skypilot_tpu.models import batching
+original = batching.ContinuousBatchingEngine._commit_token
+calls = [0]
+def altered(self, slot, next_tok):
+    calls[0] += 1
+    if calls[0] % 5 == 0:       # every fifth token, where it is produced
+        next_tok = (int(next_tok) + 1) % 512
+    return original(self, slot, next_tok)
+batching.ContinuousBatchingEngine._commit_token = altered
+sys.argv = ['run.py'] + sys.argv[1:]
+runpy.run_path({run!r}, run_name='__main__')
+"""
+
+
+def test_a_token_altered_where_it_is_produced_is_not_correct():
+    """The rest of a run with the timed path broken underneath: the
+    engine installs another token than the one it sampled, every fifth
+    time; the run ends, and `correct` is false by the one number that
+    is there to catch it."""
+    code = FAULT.format(root=ROOT, run=RUN[1])
+    env = dict(os.environ, JAX_PLATFORMS='cpu',
+               XLA_FLAGS='--xla_cpu_multi_thread_eigen=false',
+               OMP_NUM_THREADS='1')
+    out = subprocess.run(
+        [sys.executable, '-c', code, '--workload',
+         'mistral-7b-l16.chat-saturated-r2', '--seed', '5', '--seconds',
+         '2', '--trace', '0', '--rehearse'], cwd=ROOT, env=env,
+        timeout=300, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+        text=True)
+    assert out.returncode == 0, out.stderr[-3000:]
+    result = json.loads(
+        out.stdout.splitlines()[-1][len('[rehearsal] '):])
+    assert result['correct'] is False
+    bad = {k for k, e in result['compared'].items() if not e['ok']}
+    assert bad == {'shortfall_nats'}
+    assert 'compared shortfall_nats = ' in out.stderr
+    assert out.stderr.strip().endswith('correct = false')

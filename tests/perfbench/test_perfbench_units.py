@@ -79,7 +79,7 @@ def test_recorded_tpu_trace():
     """A trace of three calls of one small jitted function, 2 ms apart,
     recorded on a TPU v5e (PR 24) and kept beside this test."""
     path = os.path.join(HERE, 'data', 'tiny_v5e.xplane.pb')
-    per_device, seen = trace_reduce.read_xplane(path)
+    per_device, seen, extra = trace_reduce.read_xplane(path)
     assert any(s.startswith('/device:TPU:0') for s in seen)
     out = trace_reduce.reduce_events(per_device)
     assert out is not None and out['devices'] == 1
@@ -118,7 +118,7 @@ def test_ten_samples_beyond_rule(n, q, ok):
 
 
 # -- schedule -----------------------------------------------------------------
-CHAT = manifest_lib.mix('chat')
+CHAT = manifest_lib.mix('chat-r2')
 
 
 def test_schedule_is_a_pure_function_of_seed_rate_and_window():
@@ -448,15 +448,37 @@ def test_train_window_and_verdict():
     assert spec.window_of(recs[:1], 10.0) == (None, None)
     assert spec.window_of(recs[:4], 10.0) == (1, None)
     assert spec.window_of(recs, 10.0) == (1, 6)
-    ok = spec.judge(recs, 1, 6, 50304, 5)
-    assert ok['correct'] and ok['missing'] == 0
+    judge = lambda: {name: harness.holds(entry) for name, entry
+                     in spec.judge(recs, 1, 6, 50304, 5).items()}
+    assert judge() == {'losses_not_finite': True, 'last_loss': True,
+                       'steps_missing': True}
+    # Every number compared stands beside its limit.
+    assert spec.judge(recs, 1, 6, 50304, 5)['last_loss'] == {
+        'value': recs[6]['loss'], 'at_least': math.log(50304) - 0.05,
+        'at_most': math.nextafter(recs[0]['loss'], -math.inf)}
     recs[4]['step'] += 5
-    assert not spec.judge(recs, 1, 6, 50304, 5)['correct']
+    assert not judge()['steps_missing']
     recs[4]['step'] -= 5
     recs[6]['loss'] = math.log(50304) - 0.2
-    assert not spec.judge(recs, 1, 6, 50304, 5)['checks']['above_floor']
+    assert not judge()['last_loss']                # under the floor
+    recs[6]['loss'] = recs[0]['loss']
+    assert not judge()['last_loss']                # did not fall
     recs[6]['loss'] = float('nan')
-    assert not spec.judge(recs, 1, 6, 50304, 5)['checks']['finite']
+    assert not judge()['last_loss'] and not judge()['losses_not_finite']
+
+
+@pytest.mark.parametrize('entry, ok', [
+    ({'value': 0.2, 'at_most': 0.5}, True),
+    ({'value': 0.5, 'at_most': 0.5}, True),
+    ({'value': 0.6, 'at_most': 0.5}, False),
+    ({'value': 3, 'at_least': 1}, True),
+    ({'value': 0, 'at_least': 1}, False),
+    ({'value': 2, 'at_least': 1, 'at_most': 1.5}, False),
+    ({'value': None, 'at_most': 0}, False),
+    ({'value': float('nan'), 'at_most': 0}, False),
+    ({'value': float('inf'), 'at_least': 0}, False)])
+def test_a_compared_number_holds_its_limits(entry, ok):
+    assert harness.holds(entry) is ok
 
 
 # -- the configuration shim ---------------------------------------------------
