@@ -1772,8 +1772,8 @@ def main() -> None:
                              '(the committed BENCH_quant record). '
                              'Requires --kv-pool-bytes')
     parser.add_argument('--paged-impl', default=None,
-                        choices=['auto', 'xla', 'kernel', 'fused',
-                                 'fused_interpret'],
+                        choices=['auto', 'xla', 'decode', 'kernel',
+                                 'fused', 'fused_interpret'],
                         help='pin the server\'s paged-attention '
                              'implementation (exported as '
                              'SKYPILOT_TPU_PAGED_IMPL; see '

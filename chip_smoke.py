@@ -135,7 +135,7 @@ def preset(chips: int, rehearse: bool) -> Dict[str, Any]:
     cfg = dict(
         train_model='gpt2-124m', seq=1024, vocab=50304, steps=30,
         resume_steps=40, ckpt_every=10, log_every=5,
-        attention_impl='kernel', default_pages=128, max_new=16)
+        attention_impl='decode', default_pages=128, max_new=16)
     if chips == 1:
         cfg.update(
             serve_model='llama3-8b-l8',
@@ -710,9 +710,9 @@ def check_stats(ctx: Ctx, stats: Dict[str, Any]) -> None:
             problems.append(f'{name}={got!r} (expected {want!r})')
 
     expect('engine', stats.get('engine'), 'continuous')
-    # The route the code chose for a bf16 pool on this backend: the
-    # upstream Pallas kernel on TPU — never the XLA reference, the
-    # dense cache or interpret mode on the chip.
+    # The route the code chose for a bf16 pool of 128-wide heads on
+    # this backend: the in-repo decode read on TPU — never the XLA
+    # reference, the dense cache or interpret mode on the chip.
     expect('attention_impl', stats.get('attention_impl'),
            c['attention_impl'])
     expect('engine_restarts', stats.get('engine_restarts'), 0)

@@ -588,6 +588,13 @@ class ContinuousBatchingEngine:
         # _fresh_cache is the single paging-reset point (also the
         # error-recovery path).
         self.cache = self._fresh_cache()
+        # A K pool's static shape, for `attention_impl()`: the decode
+        # read's route hangs on it, and scrape threads may not read
+        # the scheduler's `self.cache`. None without a paged pool.
+        self._pool_aval = next(
+            (jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
+             for leaf in jax.tree.leaves(self.cache) if leaf.ndim == 4),
+            None) if self.paged else None
 
         # Host-side slot bookkeeping (device work stays fixed-shape).
         # A slot is OCCUPIED when `prefilling` (admitted, prompt
@@ -1559,7 +1566,8 @@ class ContinuousBatchingEngine:
             return 'dense'
         from skypilot_tpu.ops import pallas_paged
         return pallas_paged.resolve_impl(
-            'auto', quantized=self.kv_dtype == 'int8')
+            'auto', quantized=self.kv_dtype == 'int8',
+            decode_pool=self._pool_aval)
 
     def _compile_decode(self):
         """This engine's decode dispatch, lowered at its own shapes

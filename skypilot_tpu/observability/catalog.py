@@ -116,9 +116,9 @@ SPECS: Dict[str, Tuple] = {
         ('kv_dtype', 'weight_dtype')),
     'skypilot_serving_attention_impl_info': (
         'gauge', 'Resolved paged-attention implementation in effect '
-                 '(always 1; read the labels — impl is xla | kernel | '
-                 'fused | fused_interpret, or dense when the engine '
-                 'runs the dense KV cache; ops/pallas_paged.py '
+                 '(always 1; read the labels — impl is xla | decode | '
+                 'kernel | fused | fused_interpret, or dense when the '
+                 'engine runs the dense KV cache; ops/pallas_paged.py '
                  'dispatch rules)',
         ('engine', 'impl', 'kv_dtype')),
     'skypilot_serving_attention_bytes_per_token': (

@@ -9,10 +9,16 @@ scalar-prefetch reads and VMEM limits are only checked by the real
 compiler. This runs each call at Llama-3-8B serving shapes (32 query /
 8 KV heads of 128, page 16, 128 pages per sequence, batch 16) through
 the same wrappers the models call, compiled, and reports per kernel
-either `ok` with the largest error against the reference, `mismatch`,
-or `refused` with the compiler's own message. It exits non-zero when
-any case is not `ok`, and when the backend is not a TPU: a CPU run of
-this file would check nothing.
+either `ok` with the largest error against the reference and the
+compiled call's run time (`run_us`: a call of twenty enqueued back to
+back, the median of five such batches), `mismatch`, or `refused` with
+the compiler's own message.
+The `.../32rows` cases run the two unquantized decode routes on the
+SAME inputs (32 rows, every fourth of length 0), so their `run_us`
+are the in-repo decode read (also under a tensor mesh of the host's
+chips) against the upstream call. It
+exits non-zero when any case is not `ok`, and when the backend is not
+a TPU: a CPU run of this file would check nothing.
 
 Tolerances are set from the dtype: operands are bf16 (8 mantissa
 bits, eps 2^-8 ~ 4e-3) and both sides accumulate in f32, so outputs
@@ -28,9 +34,11 @@ import json
 import sys
 import time
 import traceback
+import zlib
 from typing import Any, Callable, Dict, List
 
 ATOL = RTOL = 2e-2
+RUNS = 20          # calls a timed batch
 
 # Llama-3-8B attention geometry and the serving page geometry
 # (chip_smoke.py serves the same: --max-total-len 2048, page 16).
@@ -39,20 +47,20 @@ PAGE, PAGES_PER_SEQ, BATCH = 16, 128, 16
 D_MODEL, LORA_RANK, LORA_SLOTS = 4096, 16, 8
 
 
-def _pool(key, quantized: bool):
+def _pool(key, quantized: bool, batch: int = BATCH):
     """(k_pages, v_pages, k_scales, v_scales, page_indices): a pool in
     which every sequence owns distinct pages, as the allocator hands
     them out (page 0 is the engine's trash page)."""
     import jax
     import jax.numpy as jnp
     from skypilot_tpu.ops import paged_attention as paged_ops
-    total_pages = BATCH * PAGES_PER_SEQ + 1
+    total_pages = batch * PAGES_PER_SEQ + 1
     kk, kv, kp = jax.random.split(key, 3)
     shape = (total_pages, PAGE, KV_HEADS, HEAD_DIM)
     k = jax.random.normal(kk, shape, jnp.bfloat16)
     v = jax.random.normal(kv, shape, jnp.bfloat16)
     perm = jax.random.permutation(kp, total_pages - 1) + 1
-    page_indices = perm.reshape(BATCH, PAGES_PER_SEQ).astype(jnp.int32)
+    page_indices = perm.reshape(batch, PAGES_PER_SEQ).astype(jnp.int32)
     to_pool = lambda x: jnp.transpose(x, (2, 0, 1, 3))  # noqa: E731
     if not quantized:
         return to_pool(k), to_pool(v), None, None, page_indices
@@ -61,24 +69,57 @@ def _pool(key, quantized: bool):
     return to_pool(qk), to_pool(qv), sk, sv, page_indices
 
 
-def _paged_decode(impl: str, quantized: bool) -> Callable[[Any], Dict]:
+def _paged_decode(impl: str, quantized: bool, batch: int = BATCH,
+                  dead_every: int = 0, over_tensor_mesh: bool = False
+                  ) -> Callable[[Any], Dict]:
+    """`dead_every` n: every n-th row has length 0 (a lane that is
+    not decoding); such rows are compared as zeros, since the
+    reference's softmax over no token is not a number.
+    `over_tensor_mesh`: pool and heads sharded over all the host's
+    chips (a one-chip host runs the same case unsharded)."""
     def case(key):
         import jax
         import jax.numpy as jnp
         from skypilot_tpu.ops import paged_attention as paged_ops
         kq, kl, kpool = jax.random.split(key, 3)
-        k_pages, v_pages, ks, vs, tbl = _pool(kpool, quantized)
-        q = jax.random.normal(kq, (BATCH, Q_HEADS, HEAD_DIM),
+        k_pages, v_pages, ks, vs, tbl = _pool(kpool, quantized, batch)
+        q = jax.random.normal(kq, (batch, Q_HEADS, HEAD_DIM),
                               jnp.bfloat16)
         lengths = jax.random.randint(
-            kl, (BATCH,), 1, PAGE * PAGES_PER_SEQ + 1, jnp.int32)
+            kl, (batch,), 1, PAGE * PAGES_PER_SEQ + 1, jnp.int32)
+        if dead_every:
+            lengths = jnp.where(
+                jnp.arange(batch) % dead_every == 0, 0, lengths)
 
         def run(route):
-            return jax.jit(lambda *a: paged_ops.paged_decode_attention(
-                a[0], a[1], a[2], a[3], a[4], k_scales=ks, v_scales=vs,
-                impl=route))
+            def fn(q, k_pages, v_pages, lengths, tbl):
+                out = paged_ops.paged_decode_attention(
+                    q, k_pages, v_pages, lengths, tbl, k_scales=ks,
+                    v_scales=vs, impl=route)
+                return jnp.where((lengths > 0)[:, None, None], out, 0)
+            return jax.jit(fn)
         args = (q, k_pages, v_pages, lengths, tbl)
-        return _compare(run(impl), run('xla'), args)
+        if not over_tensor_mesh:
+            return _compare(run(impl), run('xla'), args)
+        # `--tensor N` as the server runs it: kv heads (and their
+        # query groups) over every chip of the host, the call under
+        # the mesh context so that it is shard-mapped.
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from skypilot_tpu.parallel import mesh as mesh_lib
+        mesh = mesh_lib.make_mesh(
+            mesh_lib.MeshConfig(tensor=jax.device_count()))
+        pool = NamedSharding(mesh, P('tensor'))
+        args = (jax.device_put(q, NamedSharding(
+                    mesh, P(None, 'tensor', None))),
+                jax.device_put(k_pages, pool),
+                jax.device_put(v_pages, pool), lengths, tbl)
+        with mesh:
+            res = _compare(run(impl), run('xla'), args)
+            hlo = run(impl).lower(*args).compile().as_text()
+        if 'all-gather' in hlo or 'all-to-all' in hlo:
+            res.update(verdict='mismatch', detail='the compiled call '
+                       'moves the head-sharded pool between chips')
+        return res
     return case
 
 
@@ -188,6 +229,17 @@ def _compare(kernel_fn, ref_fn, args, relative_to_max: bool = False
     compiled = kernel_fn.lower(*args).compile()
     compile_s = time.perf_counter() - t0
     got = jax.block_until_ready(compiled(*args))
+    # Twenty calls enqueued back to back and waited for once, so that
+    # the device's time shows and not the wait's round trip (some
+    # 0.5 ms a call here); the median of five such batches.
+    batches = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for _ in range(RUNS):
+            out = compiled(*args)
+        jax.block_until_ready(out)
+        batches.append((time.perf_counter() - t0) / RUNS)
+    run_us = sorted(batches)[len(batches) // 2] * 1e6
     with jax.default_matmul_precision('highest'):
         want = jax.block_until_ready(ref_fn(*args))
     worst = 0.0
@@ -205,14 +257,33 @@ def _compare(kernel_fn, ref_fn, args, relative_to_max: bool = False
         ok = ok and bool(np.all(err <= bound))
     return {'verdict': 'ok' if ok else 'mismatch',
             'max_abs_err': round(worst, 6),
-            'compile_s': round(compile_s, 2)}
+            'compile_s': round(compile_s, 2),
+            'run_us': round(run_us, 1)}
 
 
 def cases() -> List[tuple]:
-    """(name, route users reach it by, case fn)."""
+    """(name, route users reach it by, case fn[, inputs]): cases that
+    name the same `inputs` are given the same key, so the same
+    arrays."""
     return [
+        ('paged_decode_attention/bf16/S=1',
+         "resolve_impl('auto') bf16 pool of 128-wide heads: decode",
+         _paged_decode('decode', quantized=False)),
+        ('paged_decode_attention/bf16/S=1/32rows',
+         "the same at the benchmark cells' 32 slots, 8 of them dead",
+         _paged_decode('decode', quantized=False, batch=32,
+                       dead_every=4), '32rows'),
+        ('paged_decode_attention/bf16/S=1/32rows/tensor_mesh',
+         'the same under --tensor N: each chip on its kv-head slice',
+         _paged_decode('decode', quantized=False, batch=32,
+                       dead_every=4, over_tensor_mesh=True), '32rows'),
+        ('upstream_paged_attention/bf16/S=1/32rows',
+         "impl='kernel' by name on the same inputs: the comparison",
+         _paged_decode('kernel', quantized=False, batch=32,
+                       dead_every=4), '32rows'),
         ('upstream_paged_attention/bf16/S=1',
-         "resolve_impl('auto') bf16 pool: decode",
+         "resolve_impl('auto') bf16 pool whose pages are not whole "
+         "tiles a head (8-token pages): decode; here at 16",
          _paged_decode('kernel', quantized=False)),
         ('fused_paged_attention/int8/S=1',
          "resolve_impl('auto') int8 pool: decode",
@@ -247,6 +318,9 @@ def main() -> int:
     parser.add_argument('--seed', type=int, default=0)
     parser.add_argument('--out', default=None, metavar='PATH',
                         help='also write the verdicts as JSON')
+    parser.add_argument('--only', default='', metavar='TEXT',
+                        help='run the cases whose name contains TEXT '
+                             '(a four-chip host: tensor_mesh)')
     args = parser.parse_args()
 
     import jax
@@ -262,7 +336,11 @@ def main() -> int:
 
     results = []
     key = jax.random.PRNGKey(args.seed)
-    for i, (name, reached_by, case) in enumerate(cases()):
+    for i, (name, reached_by, case, *inputs) in enumerate(cases()):
+        if args.only not in name:
+            continue
+        if inputs:
+            i = zlib.crc32(inputs[0].encode()) % (1 << 31)
         try:
             res = case(jax.random.fold_in(key, i))
         except Exception as e:  # pylint: disable=broad-except

@@ -7,13 +7,19 @@ is sized for the *aggregate* live tokens, so many short sequences fit
 where the dense layout would exhaust HBM — more decode slots, higher
 serving throughput.
 
-On TPU the decode reads dispatch to a Pallas kernel (bf16 pools: the
-upstream jax.experimental.pallas.ops.tpu.paged_attention; int8 pools:
-ops/pallas_paged.py); on the CPU test backend a pure-XLA reference
-(gather + masked attention) runs instead. The reference also defines
-the semantics the kernels are checked against on the chip
-(ops/kernel_check.py). The route is chosen once, at trace time
-(`pallas_paged.resolve_impl`); nothing switches routes at run time.
+On TPU the decode reads dispatch to a Pallas kernel: an unquantized
+pool whose pages are whole tiles a head (128-wide heads, 16-token
+bf16 pages) to ops/pallas_paged.paged_decode_kernel, which fetches a
+page for every KV head of the chip in one copy, multiplies all of
+the row's head groups in one step and walks live rows only;
+unquantized pools of other shapes (64-wide heads) to the upstream
+jax.experimental.pallas.ops.tpu.paged_attention; int8 pools to
+ops/pallas_paged.fused_paged_attention. On the CPU test backend a
+pure-XLA reference (gather + masked attention) runs instead. The
+reference also defines the semantics the kernels are checked against
+on the chip (ops/kernel_check.py). The route is chosen once, at trace
+time and from static shapes (`pallas_paged.resolve_impl`); nothing
+switches routes at run time, and /stats names the one compiled.
 
 Layouts (matching the pallas kernel):
   q            [B, num_q_heads, head_dim]      one decode token per row
@@ -96,17 +102,25 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     (f32[total_pages, page_size]) mark int8 pages.
 
     `impl` resolves through `pallas_paged.resolve_impl` (overridable
-    process-wide via $SKYPILOT_TPU_PAGED_IMPL / `impl_scope`):
-    'kernel' is the upstream bf16 pallas kernel, 'fused' /
-    'fused_interpret' the in-repo kernel that dequantizes int8 pages
-    in-register (ops/pallas_paged.py), 'xla' the gather reference —
-    which dequantizes in HBM, the traffic the fused path deletes.
-    Under a tensor mesh context both kernels run per chip on that
-    chip's kv-head slice of the pool (`shard_over_kv_heads`).
+    process-wide via $SKYPILOT_TPU_PAGED_IMPL / `impl_scope`), at
+    trace time and from static shapes: 'decode' is the in-repo kernel
+    for unquantized pools (ops/pallas_paged.paged_decode_kernel: what
+    'auto' takes on a TPU where a page of one head is whole tiles),
+    'kernel' the upstream pallas kernel (the other unquantized
+    shapes, 64-wide heads among them), 'fused' / 'fused_interpret'
+    the in-repo kernel that dequantizes int8 pages in-register, 'xla'
+    the gather reference — which dequantizes in HBM, the traffic the
+    fused path deletes. Under a tensor mesh context every kernel runs
+    per chip on that chip's kv-head slice of the pool
+    (`shard_over_kv_heads`).
     """
     assert q.ndim == 3 and k_pages.ndim == 4, (q.shape, k_pages.shape)
     from skypilot_tpu.ops import pallas_paged
-    impl = pallas_paged.resolve_impl(impl, quantized=k_scales is not None)
+    impl = pallas_paged.resolve_impl(impl, quantized=k_scales is not None,
+                                     decode_pool=k_pages)
+    if impl == 'decode':
+        return pallas_paged.paged_decode_kernel(
+            q, k_pages, v_pages, lengths, page_indices)
     if impl in ('fused', 'fused_interpret'):
         out = pallas_paged.fused_paged_attention(
             q[:, None], k_pages, v_pages, (lengths - 1)[:, None],
@@ -116,7 +130,6 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     if impl == 'kernel':
         from jax.experimental.pallas.ops.tpu.paged_attention import (
             paged_attention)
-        from jax.sharding import PartitionSpec as P
         pages_per_seq = page_indices.shape[1]
         # Block size must divide the per-sequence page walk.
         block = min(8, pages_per_seq)

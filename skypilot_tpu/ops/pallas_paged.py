@@ -1,16 +1,36 @@
-"""In-repo fused Pallas paged-attention + LoRA kernels (the int8 fast
-path) and the impl-dispatch plumbing that selects between them.
+"""In-repo Pallas paged-attention + LoRA kernels and the impl-dispatch
+plumbing that selects between them.
 
 WHY. Decode is memory-bound: per-chip tokens/s is HBM bytes/token or
 nothing. The upstream pallas paged-attention kernel
-(jax.experimental.pallas.ops.tpu.paged_attention) is bf16-only, so the
-int8 KV pool — the config that doubled pool capacity — used to fall
-back to the XLA gather route, which DEQUANTIZES IN HBM: it
-materializes f32 copies of every gathered page (then GQA-expands
-them) each step. Per-slot LoRA likewise paid one batched
-gather+matmul chain per projection. The two kernels here close both
-gaps:
+(jax.experimental.pallas.ops.tpu.paged_attention) misses that twice.
+It reads unquantized pools only, so the int8 KV pool — the config that
+doubled pool capacity — used to fall back to the XLA gather route,
+which DEQUANTIZES IN HBM: it materializes f32 copies of every
+gathered page (then GQA-expands them) each step; per-slot LoRA
+likewise paid one batched gather+matmul chain per projection. And on a
+bf16 pool it is bound by the count of its copies, not by their bytes:
+a 4 KB copy and a wait a page AND head, one loop over the row's
+context a head, every block cast to f32 (5-11% of the HBM roofline in
+the benchmark's serving cells; PERF.md, PR 27). The kernels here close
+these gaps:
 
+  paged_decode_kernel     the S=1 decode read of an unquantized pool
+                          (route 'decode'). One invocation walks the
+                          (row, block) pairs with block * tokens <
+                          length, so a row of length 0 costs no copy
+                          and no product; a block's pages arrive as
+                          ONE strided copy a page covering every KV
+                          head the chip holds (`pool.at[:, page]`),
+                          started only for the row's live pages and
+                          double-buffered across blocks and rows; a
+                          step multiplies all of the row's head groups
+                          against the block, operands as stored,
+                          scores / softmax state / accumulator in f32.
+                          The pool stays [Hkv, pages, page, D] in HBM:
+                          no layout change, no pool-shaped copy. The
+                          block is sized from the static shapes
+                          against `_DECODE_VMEM_BUDGET`.
   fused_paged_attention   reads int8 k/v pages plus their parallel
                           f32 scale rows straight from the pool and
                           dequantizes IN-REGISTER inside the kernel
@@ -33,25 +53,29 @@ gaps:
                           body instead of three separate gather+matmul
                           dispatches per layer.
 
-DISPATCH. `resolve_impl(impl, quantized=...)` maps a requested impl to
-the concrete route; 'auto' consults, in order: an explicit
-`set_default_impl()` / `impl_scope()` override, the
-SKYPILOT_TPU_PAGED_IMPL environment variable, then the backend (TPU
-quantized -> 'fused'; TPU bf16 -> upstream 'kernel'; the CPU test
-backend -> 'xla'). A route selected by name that cannot run here is a
-ValueError, never a silent switch to another route.
-`unavailable_reason()` says WHY the compiled kernel path is off so
-/stats and test skip messages can say so.
+DISPATCH. `resolve_impl(impl, quantized=..., decode_pool=...)` maps a
+requested impl to the concrete route; 'auto' consults, in order: an
+explicit `set_default_impl()` / `impl_scope()` override, the
+SKYPILOT_TPU_PAGED_IMPL environment variable, then the backend and
+the static shapes (TPU quantized -> 'fused'; TPU unquantized, the
+one-token decode read of a pool `decode_kernel_refusal` takes ->
+'decode'; TPU unquantized otherwise -> upstream 'kernel', which for an
+S>1 chunk means the XLA gather; the CPU test backend -> 'xla'). A
+route selected by name that cannot run here is a ValueError, never a
+silent switch to another route. `unavailable_reason()` says WHY the
+compiled kernel path is off so /stats and test skip messages can say
+so.
 
 INTERPRET-MODE CONTRACT. Every pallas_call here takes
 `interpret=<kwarg>` (enforced repo-wide by `stpu check` rule SKY006),
-so the kernels run on CPU under `impl='fused_interpret'` —
-bit-tolerance pinned against the XLA reference in
+so the kernels run on CPU (`impl='fused_interpret'`;
+`paged_decode_kernel(..., interpret=True)`, whose DMAs take the TPU
+interpreter) — bit-tolerance pinned against the XLA reference in
 tests/unit_tests/test_pallas_paged.py, with a deliberately perturbed
-kernel (the `perturb` hook below) proving the pins are non-vacuous.
+kernel (the `perturb` hooks below) proving the pins are non-vacuous.
 
-SHARDING. Under an active `with mesh:` context the attention wrapper
-shard_maps over the PR 15 pool layout: kv-heads (and the grouped q
+SHARDING. Under an active `with mesh:` context the attention wrappers
+shard_map over the PR 15 pool layout: kv-heads (and the grouped q
 heads) ride `tensor` when divisible, everything else replicates; the
 GQA-remainder rule (kv-heads not divisible by tensor -> replicated
 pool) falls out as the unsharded call. Without a mesh context (the
@@ -71,7 +95,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import os
-from typing import Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -79,16 +103,21 @@ import jax.numpy as jnp
 ENV_VAR = 'SKYPILOT_TPU_PAGED_IMPL'
 
 #: Accepted impl names: 'auto' resolves per backend/config; 'xla' is
-#: the gather reference; 'kernel' the upstream bf16 pallas kernel;
-#: 'fused' this module's compiled kernels; 'fused_interpret' the same
-#: kernels in pallas interpret mode (runs anywhere, CPU included).
-IMPLS: Tuple[str, ...] = ('auto', 'xla', 'kernel', 'fused',
+#: the gather reference; 'decode' this module's decode read of an
+#: unquantized pool (`paged_decode_kernel`); 'kernel' the upstream
+#: bf16 pallas kernel; 'fused' this module's int8-capable kernel;
+#: 'fused_interpret' the same kernel in pallas interpret mode (runs
+#: anywhere, CPU included).
+IMPLS: Tuple[str, ...] = ('auto', 'xla', 'decode', 'kernel', 'fused',
                           'fused_interpret')
+
+#: The routes Mosaic compiles: a TPU backend only.
+_COMPILED = ('decode', 'kernel', 'fused')
 
 # -- availability -----------------------------------------------------------
 def available() -> bool:
-    """True when the COMPILED kernel routes ('kernel', 'fused') can
-    run here: Mosaic compiles for a TPU backend only."""
+    """True when the COMPILED kernel routes ('decode', 'kernel',
+    'fused') can run here: Mosaic compiles for a TPU backend only."""
     return jax.default_backend() == 'tpu'
 
 
@@ -146,27 +175,37 @@ def impl_scope(impl: str):
         set_default_impl(prev)
 
 
-def resolve_impl(impl: str = 'auto', *, quantized: bool = False) -> str:
-    """Concrete route for a requested impl: one of 'xla' | 'kernel' |
-    'fused' | 'fused_interpret'.
+def resolve_impl(impl: str = 'auto', *, quantized: bool = False,
+                 decode_pool: Any = None) -> str:
+    """Concrete route for a requested impl: one of 'xla' | 'decode' |
+    'kernel' | 'fused' | 'fused_interpret'.
 
     'auto' is decided by what the code can observe: off TPU (the CPU
     test backend) the XLA gather reference; on TPU the fused kernel
-    for int8 pools and the upstream kernel for bf16 ones. A route
-    asked for BY NAME that cannot run here raises — it never degrades
-    to another route, so what /stats reports is what was compiled."""
+    for int8 pools and, for unquantized ones, this module's decode
+    kernel where `decode_pool` (the K pool, an array or its
+    ShapeDtypeStruct: only the one-token decode read passes it) has a
+    static shape the kernel takes (`decode_kernel_refusal`), else the
+    upstream kernel. A route asked for BY NAME that cannot run here
+    raises — it never degrades to another route, so what /stats
+    reports is what was compiled."""
     _validate(impl)
     if impl == 'auto':
         impl = default_impl()
     if impl == 'auto':
         if not available():
             return 'xla'
-        return 'fused' if quantized else 'kernel'
-    if impl == 'kernel' and quantized:
+        if quantized:
+            return 'fused'
+        if (decode_pool is not None
+                and decode_kernel_refusal(decode_pool) is None):
+            return 'decode'
+        return 'kernel'
+    if impl in ('decode', 'kernel') and quantized:
         raise ValueError(
-            "paged-attention impl 'kernel' (the upstream Pallas kernel) "
-            "reads bf16 pools only; an int8 pool needs 'fused'")
-    if impl in ('kernel', 'fused') and not available():
+            f"paged-attention impl {impl!r} reads unquantized pools "
+            f"only; an int8 pool needs 'fused'")
+    if impl in _COMPILED and not available():
         raise ValueError(
             f'paged-attention impl {impl!r} was selected but cannot '
             f'run: {unavailable_reason()}')
@@ -384,6 +423,263 @@ def fused_paged_attention(q: jax.Array, k_pages: jax.Array,
                                out_specs=qspec)(*args)
 
 
+# -- bf16 decode read: one copy a page, all heads ---------------------------
+#: VMEM the decode kernel's page buffers may take: two pools (K, V)
+#: times two slots (the block being multiplied and the one in flight)
+#: times the block; a quarter of a v5e's 16 MiB default scoped limit.
+#: At 8 bf16 heads of 128 it gives 32 pages (512 tokens) a step. A
+#: step multiplies its whole block whatever part of it is live, so a
+#: larger block pays in a row's tail and a smaller one in steps: on a
+#: v5e, 32 rows of chat-length contexts, a half of this budget ran 7%
+#: slower, a quarter 22% and twice 25% (my chip run, PR 30).
+_DECODE_VMEM_BUDGET = 4 << 20
+#: Sublane tile by itemsize: a page must be whole tiles for the
+#: [pages, page, D] -> [tokens, D] view of a block to be free.
+_SUBLANES = {4: 8, 2: 16}
+
+
+def decode_kernel_refusal(pool: Any) -> Optional[str]:
+    """Why `paged_decode_kernel` does not take `pool` (a K or V pool
+    [Hkv, pages, page, D], an array or its ShapeDtypeStruct: only the
+    static shape is read; that shape keeps the upstream call), or None
+    when it does. A page of one head must be whole (sublane, 128-lane)
+    tiles: the kernel views a block's pages as one [tokens, D]
+    matrix."""
+    _, _, page_size, head_dim = pool.shape
+    dtype = jnp.dtype(pool.dtype)
+    if dtype.itemsize not in _SUBLANES or not jnp.issubdtype(
+            dtype, jnp.floating):
+        return f'pool dtype {dtype.name} is not bf16/f16/f32'
+    if head_dim % 128 != 0:
+        return f'head_dim {head_dim} is not a multiple of 128 lanes'
+    if page_size % _SUBLANES[dtype.itemsize] != 0:
+        return (f'page_size {page_size} is not a multiple of the '
+                f'{_SUBLANES[dtype.itemsize]}-sublane tile of a '
+                f'{dtype.itemsize}-byte dtype')
+    return None
+
+
+def decode_block_pages(pool: Any, pages_per_seq: int) -> int:
+    """Pages a step of the decode kernel multiplies, from the static
+    shapes alone (`pool`: the K pool as the chip holds it): the
+    largest power of two whose four buffers fit `_DECODE_VMEM_BUDGET`,
+    at most the row's table."""
+    num_kv_heads, _, page_size, head_dim = pool.shape
+    page_bytes = (num_kv_heads * page_size * head_dim
+                  * jnp.dtype(pool.dtype).itemsize)
+    fit = max(1, _DECODE_VMEM_BUDGET // (4 * page_bytes))
+    pages = 1
+    while pages * 2 <= min(fit, pages_per_seq):
+        pages *= 2
+    return pages
+
+
+def _decode_kernel(sm_scale, block_pages, pages_per_seq, perturb,
+                   lengths_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   kbuf, vbuf, sems):
+    """One invocation walks every (row, block) with block * tokens <
+    length; a row of length 0 is stepped over by the scalar scan and
+    costs no copy and no product.
+
+    Refs: lengths i32[B] and the flat table i32[B * pages_per_seq] in
+    SMEM; q/o [B, Hq, D] whole in VMEM; the pools [Hkv, P, page, D]
+    where they lie (HBM); k/vbuf [2, Hkv, block_pages, page, D]; DMA
+    semaphores [slot, pool]. A block is fetched as ONE strided copy a
+    page and pool covering every KV head (`pool.at[:, page]`), and
+    only for the row's live pages: a table entry past ceil(length /
+    page) is never read, so the trash page behind it is not either.
+    While a block is multiplied the next one (of this row, or the
+    first of the next row with length > 0) is in flight."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    batch, num_q_heads, head_dim = q_ref.shape
+    num_kv_heads, _, page_size, _ = k_hbm.shape
+    group = num_q_heads // num_kv_heads
+    block = block_pages * page_size
+
+    def each_copy(b, i, slot, act):
+        """act(descriptor) for the live pages of block i of row b."""
+        first = i * block_pages
+        live = jnp.minimum(
+            (lengths_ref[b] + page_size - 1) // page_size - first,
+            block_pages)
+
+        def page_copies(j, _):
+            page = tbl_ref[b * pages_per_seq + first + j]
+            act(pltpu.make_async_copy(
+                k_hbm.at[:, page], kbuf.at[slot, :, j], sems.at[slot, 0]))
+            act(pltpu.make_async_copy(
+                v_hbm.at[:, page], vbuf.at[slot, :, j], sems.at[slot, 1]))
+
+        jax.lax.fori_loop(0, live, page_copies, None)
+
+    def start(b, i, slot):
+        each_copy(b, i, slot, lambda copy: copy.start())
+
+    def wait(b, i, slot):
+        each_copy(b, i, slot, lambda copy: copy.wait())
+
+    def next_live(b):
+        """The first row >= b with length > 0, else `batch`."""
+        return jax.lax.while_loop(
+            lambda r: jnp.logical_and(
+                r < batch,
+                lengths_ref[jnp.minimum(r, batch - 1)] == 0),
+            lambda r: r + 1, b)
+
+    # A dead row's output is zeros; a page slot no copy has filled
+    # must not hold a NaN pattern for a zero weight to multiply.
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+    vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
+    head_of_row = jax.lax.broadcasted_iota(
+        jnp.int32, (num_q_heads, 1), 0) // group
+
+    def by_head(parts):
+        """[Hq, n] whose row r is parts[r // group][r]: every head's
+        product is taken for all query rows (the matrix unit is paid
+        by the keys it loads, not by 4 or 32 query rows) and each row
+        keeps its own head's."""
+        out = parts[0]
+        for h in range(1, num_kv_heads):
+            out = jnp.where(head_of_row >= h, parts[h], out)
+        return out
+
+    def row(carry):
+        b, slot0 = carry
+        length = lengths_ref[b]
+        blocks = (length + block - 1) // block
+        after = next_live(b + 1)
+        q = q_ref[b]                                # [Hq, D] as stored
+
+        def step(i, state):
+            m_prev, l_prev, acc = state
+            slot = (slot0 + i) % 2
+
+            @pl.when(i + 1 < blocks)
+            def _():
+                start(b, i + 1, 1 - slot)
+
+            @pl.when(jnp.logical_and(i + 1 == blocks, after < batch))
+            def _():
+                start(after, 0, 1 - slot)
+
+            wait(b, i, slot)
+            s = by_head([
+                jax.lax.dot_general(
+                    q, kbuf[slot, h].reshape(block, head_dim),
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                for h in range(num_kv_heads)]) * sm_scale
+            if perturb:
+                # Non-vacuity hook, as `_attention_kernel`'s.
+                s = s * (1.0 + perturb)
+            t_idx = (i * block +
+                     jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+            s = jnp.where(t_idx < length, s, -jnp.inf)
+            # A walked block holds at least one live token: m_new is
+            # finite and every exp() argument finite or -inf.
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            w = jnp.exp(s - m_new)                  # [Hq, block] f32
+            l_new = l_prev * alpha + jnp.sum(w, axis=-1, keepdims=True)
+            pv = by_head([
+                jnp.dot(w, vbuf[slot, h].reshape(block, head_dim)
+                        .astype(jnp.float32),
+                        preferred_element_type=jnp.float32)
+                for h in range(num_kv_heads)])
+            return m_new, l_new, acc * alpha + pv
+
+        _, l, acc = jax.lax.fori_loop(0, blocks, step, (
+            jnp.full((num_q_heads, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((num_q_heads, 1), jnp.float32),
+            jnp.zeros((num_q_heads, head_dim), jnp.float32)))
+        o_ref[b] = (acc / l).astype(o_ref.dtype)
+        return after, (slot0 + blocks) % 2
+
+    first = next_live(jnp.int32(0))
+
+    @pl.when(first < batch)
+    def _():
+        start(first, 0, 0)
+
+    jax.lax.while_loop(lambda carry: carry[0] < batch, row,
+                       (first, jnp.int32(0)))
+
+
+@functools.partial(jax.jit, static_argnames=('block_pages', 'interpret',
+                                             'perturb'))
+def _decode_call(q, k_pages, v_pages, lengths, page_indices, *,
+                 block_pages, interpret, perturb):
+    """Jitted on its own, as `paged_attention._write_pool` is: a
+    program of L layers traces and lowers the kernel once, not L
+    times (16 lowerings were 33 s of the server's warm-up on the
+    chip, compile cache or not: the cache is keyed on what lowering
+    returns)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    num_kv_heads, _, page_size, head_dim = k_pages.shape
+    pages_per_seq = page_indices.shape[1]
+    kernel = functools.partial(
+        _decode_kernel, 1.0 / (head_dim ** 0.5), block_pages,
+        pages_per_seq, perturb)
+    whole = pl.BlockSpec(q.shape, lambda i, *_: (0, 0, 0))
+    buf = pltpu.VMEM((2, num_kv_heads, block_pages, page_size, head_dim),
+                     k_pages.dtype)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(1,),
+            in_specs=[whole, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=whole,
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',)),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name='paged_decode_attention',
+    )(lengths.astype(jnp.int32),
+      page_indices.astype(jnp.int32).reshape(-1), q, k_pages, v_pages)
+
+
+def paged_decode_kernel(q: jax.Array, k_pages: jax.Array,
+                        v_pages: jax.Array, lengths: jax.Array,
+                        page_indices: jax.Array, *,
+                        interpret: bool = False,
+                        perturb: float = 0.0) -> jax.Array:
+    """The decode read of an unquantized pool (route 'decode'): one
+    query token a row over its paged history.
+
+    q: [B, Hq, D]; k/v_pages: [Hkv, total_pages, page_size, D] of a
+    shape `decode_kernel_refusal` takes; lengths i32[B] (0: the row is
+    skipped and returns zeros); page_indices i32[B, pages_per_seq].
+    Returns [B, Hq, D] in q.dtype, `_reference_paged_attention`'s
+    semantics. Keys, values and the query reach the matrix unit as
+    stored; scores, softmax state and the accumulator are float32.
+    Under a tensor mesh each chip runs it on its own kv-head slice
+    (`shard_over_kv_heads`)."""
+    assert q.ndim == 3 and k_pages.ndim == 4, (q.shape, k_pages.shape)
+    refusal = decode_kernel_refusal(k_pages)
+    if refusal is not None:
+        raise ValueError(f"paged-attention impl 'decode': {refusal}")
+    from jax.sharding import PartitionSpec as P
+    heads = P(None, 'tensor', None)
+    pool = P('tensor', None, None, None)
+
+    def per_chip(q_, k_, v_, lengths_, tbl):
+        # The block follows the heads THIS chip holds.
+        return _decode_call(
+            q_, k_, v_, lengths_, tbl, interpret=interpret,
+            perturb=perturb,
+            block_pages=decode_block_pages(k_, tbl.shape[1]))
+
+    return shard_over_kv_heads(
+        per_chip, k_pages.shape[0],
+        in_specs=(heads, pool, pool, P(None), P(None, None)),
+        out_specs=heads)(q, k_pages, v_pages, lengths, page_indices)
+
+
 def shard_over_kv_heads(fn, num_kv_heads: int, *, in_specs, out_specs):
     """`fn` shard_mapped over the active mesh's `tensor` axis when the
     kv-heads axis divides it, else `fn` unchanged (no mesh context, a
@@ -480,9 +776,10 @@ def bytes_per_token_model(*, num_layers: int, num_kv_heads: int,
     """Modeled HBM bytes one decode step moves PER SEQUENCE (= per
     generated token), from the engine's actual page geometry.
 
-    Both routes walk the row's FULL page table every step (the length
-    mask shapes the math, not the reads), so context traffic is
-    static per config. Per layer:
+    The model charges every row its FULL page table (the XLA gather
+    does read it whole; the kernels stop at the row's length), so
+    context traffic is static per config and an upper bound. Per
+    layer:
 
       pool reads    2 * pages_per_seq * page_size * Hkv * D * elem
       scale rows    2 * pages_per_seq * page_size * 4        (int8)

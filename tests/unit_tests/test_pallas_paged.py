@@ -1,7 +1,7 @@
-"""Fused pallas paged-attention + QKV LoRA kernels
+"""Pallas paged-attention + QKV LoRA kernels
 (ops/pallas_paged.py), interpret mode on CPU.
 
-Four contracts:
+Five contracts:
 
   - PARITY MATRIX: the interpret-mode kernel matches the XLA
     reference over {bf16-style, int8} x {GQA divisible, GQA
@@ -13,6 +13,11 @@ Four contracts:
   - DISPATCH: resolve_impl's auto rules, the $SKYPILOT_TPU_PAGED_IMPL
     override, impl_scope, a missing selected route raising instead of
     degrading to 'xla', and unavailable_reason;
+  - DECODE READ (`paged_decode_kernel`, route 'decode'): parity over
+    the head shapes the repo serves at lengths around a block's
+    edges, rows of length 0 that read no page, the perturbed control,
+    the static-shape route rules and the shard map under a tensor
+    mesh;
   - BIT IDENTITY end to end: an int8 + active-LoRA engine on the
     fused interpret path emits byte-identical greedy tokens to the
     XLA engine, and the mesh-sharded (tensor-2 host devices) kernel
@@ -139,6 +144,149 @@ def test_perturbed_kernel_fails_the_pin():
                                    atol=ATOL)
 
 
+# -- the decode read: one copy a page, all heads ----------------------------
+DPAGE, DPSEQ = 16, 8          # 128 tokens a row
+DBLOCK_PAGES = 2              # the tests' block: 32 tokens
+
+
+def _decode_inputs(hkv, hq, d, lengths, dtype, seed=5):
+    """A pool whose page 0 (the engine's trash page) is NaN, and a
+    table that names real pages only below ceil(length / page): every
+    other entry, and every entry of a row of length 0, is the trash
+    page, as the engine leaves them. Returns (q, k, v, lengths,
+    table) and the same pool with the trash page zeroed, for the
+    reference (which gathers the whole table)."""
+    batch = len(lengths)
+    total = batch * DPSEQ + 1
+    rng = np.random.default_rng(seed)
+    shape = (hkv, total, DPAGE, d)
+    k = rng.standard_normal(shape).astype(np.float32)
+    v = rng.standard_normal(shape).astype(np.float32)
+    q = jnp.asarray(rng.standard_normal((batch, hq, d)), dtype)
+    perm = (rng.permutation(total - 1) + 1).reshape(batch, DPSEQ)
+    live = -(-np.asarray(lengths) // DPAGE)
+    tbl = np.where(np.arange(DPSEQ)[None] < live[:, None], perm, 0)
+    k_nan, v_nan = k.copy(), v.copy()
+    k_nan[:, 0] = v_nan[:, 0] = np.nan
+    k[:, 0] = v[:, 0] = 0.0
+    as_pool = lambda x: jnp.asarray(x, dtype)  # noqa: E731
+    return ((q, as_pool(k_nan), as_pool(v_nan),
+             jnp.asarray(lengths, jnp.int32), jnp.asarray(tbl, jnp.int32)),
+            (as_pool(k), as_pool(v)))
+
+
+def _small_blocks(monkeypatch, hkv, d, dtype):
+    """Steer the block to DBLOCK_PAGES so that a 128-token row is
+    four blocks (the budget is the one static input a test can move
+    without a chip-sized pool)."""
+    page_bytes = hkv * DPAGE * d * jnp.dtype(dtype).itemsize
+    monkeypatch.setattr(pp, '_DECODE_VMEM_BUDGET',
+                        4 * DBLOCK_PAGES * page_bytes)
+    pool = jax.ShapeDtypeStruct((hkv, 9, DPAGE, d), dtype)
+    assert pp.decode_block_pages(pool, DPSEQ) == DBLOCK_PAGES
+
+
+DECODE_EDGE_LENGTHS = [0, 1, 31, 32, 33, 128, 0, 101]
+
+
+@pytest.mark.parametrize(
+    'hkv,hq,d,dtype,atol',
+    [(8, 32, 128, jnp.float32, ATOL),
+     (2, 8, 128, jnp.float32, ATOL),
+     (4, 4, 128, jnp.float32, ATOL),
+     (8, 32, 128, jnp.bfloat16, 2e-2)],
+    ids=['mistral_8x4', 'tensor4_slice_2x4', 'group1_4x1',
+         'mistral_8x4_bf16'])
+def test_decode_kernel_parity(monkeypatch, hkv, hq, d, dtype, atol):
+    """Lengths 0, 1, one under / exactly / one over a block, the full
+    table and a ragged one, against the XLA reference; rows of length
+    0 return zeros and NO row reads a page past its length: the trash
+    page behind every unused table entry holds NaN."""
+    _small_blocks(monkeypatch, hkv, d, dtype)
+    args, (k, v) = _decode_inputs(hkv, hq, d, DECODE_EDGE_LENGTHS, dtype)
+    q, _, _, lengths, tbl = args
+    out = np.asarray(pp.paged_decode_kernel(*args, interpret=True),
+                     np.float32)
+    assert out.dtype == np.float32 and np.all(np.isfinite(out))
+    ref = np.asarray(pa._reference_paged_attention(
+        q, k, v, jnp.maximum(lengths, 1), tbl), np.float32)
+    live = np.asarray(lengths) > 0
+    assert not np.any(out[~live])
+    np.testing.assert_allclose(out[live], ref[live], atol=atol,
+                               rtol=atol if atol > ATOL else 0)
+
+
+def test_decode_kernel_output_dtype_and_budgeted_block():
+    """At the budget the code states, a bf16 pool of 8 heads walks a
+    row in whole-table blocks here (8 pages < the budget's), returns
+    q's dtype, and an all-dead batch starts no copy at all."""
+    args, (k, v) = _decode_inputs(8, 32, 128, [77, 0, 128],
+                                  jnp.bfloat16)
+    assert pp.decode_block_pages(args[1], DPSEQ) == DPSEQ
+    out = pp.paged_decode_kernel(*args, interpret=True)
+    assert out.dtype == jnp.bfloat16
+    ref = pa._reference_paged_attention(args[0], k, v,
+                                        jnp.maximum(args[3], 1), args[4])
+    np.testing.assert_allclose(np.asarray(out, np.float32)[[0, 2]],
+                               np.asarray(ref, np.float32)[[0, 2]],
+                               atol=2e-2, rtol=2e-2)
+    dead = pp.paged_decode_kernel(args[0], args[1], args[2],
+                                  jnp.zeros((3,), jnp.int32),
+                                  jnp.zeros_like(args[4]),
+                                  interpret=True)
+    assert not np.any(np.asarray(dead, np.float32))
+
+
+def test_perturbed_decode_kernel_fails_the_pin(monkeypatch):
+    """Non-vacuity control for the decode read's pin."""
+    _small_blocks(monkeypatch, 2, 128, jnp.float32)
+    args, (k, v) = _decode_inputs(2, 8, 128, [1, 33, 128], jnp.float32)
+    ref = pa._reference_paged_attention(args[0], k, v, args[3], args[4])
+    good = pp.paged_decode_kernel(*args, interpret=True)
+    np.testing.assert_allclose(np.asarray(good), np.asarray(ref),
+                               atol=ATOL)
+    bad = pp.paged_decode_kernel(*args, interpret=True, perturb=0.5)
+    with pytest.raises(AssertionError):
+        np.testing.assert_allclose(np.asarray(bad), np.asarray(ref),
+                                   atol=ATOL)
+
+
+@pytest.mark.parametrize(
+    'hkv,page,d,dtype,pages_per_seq,want',
+    [(8, 16, 128, jnp.bfloat16, 128, 32),    # the benchmark's cells
+     (2, 16, 128, jnp.bfloat16, 128, 128),   # their --tensor 4 slice
+     (8, 16, 128, jnp.bfloat16, 4, 4),       # never past the table
+     (8, 16, 128, jnp.float32, 128, 16),
+     (64, 64, 256, jnp.float32, 128, 1)],    # never under one page
+    ids=['8_heads', '2_heads', 'short_table', 'f32', 'huge_page'])
+def test_decode_block_follows_the_static_shapes(hkv, page, d, dtype,
+                                                pages_per_seq, want):
+    pool = jax.ShapeDtypeStruct((hkv, 9, page, d), dtype)
+    assert pp.decode_block_pages(pool, pages_per_seq) == want
+
+
+@pytest.mark.parametrize(
+    'page,d,dtype,why',
+    [(16, 128, jnp.bfloat16, None), (8, 128, jnp.float32, None),
+     (16, 256, jnp.bfloat16, None),
+     (16, 64, jnp.bfloat16, '128 lanes'),    # GPT-2's heads
+     (8, 128, jnp.bfloat16, '16-sublane'),   # half a bf16 tile
+     (32, 128, jnp.int8, 'int8')],
+    ids=['bf16_16x128', 'f32_8x128', 'bf16_16x256', 'd64', 'page8_bf16',
+         'int8'])
+def test_decode_kernel_refusal_rules(page, d, dtype, why):
+    got = pp.decode_kernel_refusal(
+        jax.ShapeDtypeStruct((2, 3, page, d), dtype))
+    assert (got is None) if why is None else (why in got)
+    if why is not None and dtype != jnp.int8:
+        pool = jnp.zeros((2, 3, page, d), dtype)
+        with pytest.raises(ValueError, match="impl 'decode'"):
+            pp.paged_decode_kernel(
+                jnp.zeros((1, 2, d), dtype), pool, pool,
+                jnp.ones((1,), jnp.int32), jnp.zeros((1, 2), jnp.int32),
+                interpret=True)
+
+
 # -- parity matrix: fused QKV LoRA ------------------------------------------
 def test_fused_qkv_lora_matches_apply_delta():
     rng = np.random.default_rng(7)
@@ -186,17 +334,18 @@ def test_resolve_impl_raises_when_selected_route_is_missing(monkeypatch):
     """A route selected BY NAME that cannot run is an error, never a
     quiet 'xla': on the chip that switch is what would let a kernel
     Mosaic refused go unnoticed behind a server that still answers."""
-    for impl in ('kernel', 'fused'):
+    for impl in ('decode', 'kernel', 'fused'):
         with pytest.raises(ValueError, match='cannot run'):
             pp.resolve_impl(impl)
         monkeypatch.setenv(pp.ENV_VAR, impl)
         with pytest.raises(ValueError, match='cannot run'):
             pp.resolve_impl('auto')
         monkeypatch.delenv(pp.ENV_VAR)
-    # The upstream kernel reads bf16 pools only — also an error, on
-    # any backend, instead of the old degrade.
-    with pytest.raises(ValueError, match='bf16 pools only'):
-        pp.resolve_impl('kernel', quantized=True)
+    # The decode read and the upstream kernel read unquantized pools
+    # only — also an error, on any backend, instead of the old degrade.
+    for impl in ('decode', 'kernel'):
+        with pytest.raises(ValueError, match='unquantized pools only'):
+            pp.resolve_impl(impl, quantized=True)
 
 
 def test_resolve_impl_tpu_rules(monkeypatch):
@@ -204,8 +353,29 @@ def test_resolve_impl_tpu_rules(monkeypatch):
     is the one thing faked: CPU tests cannot have a TPU)."""
     monkeypatch.setattr(pp.jax, 'default_backend', lambda: 'tpu')
     assert pp.unavailable_reason() is None
+    # The decode read hands its K pool over; what the kernel takes is
+    # its static shape (a page of one head in whole tiles).
+    pool = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.bfloat16)
+    assert pp.resolve_impl('auto', decode_pool=pool(
+        8, 5120, 16, 128)) == 'decode'           # Mistral, Llama
+    assert pp.resolve_impl('auto', decode_pool=pool(
+        2, 5120, 16, 128)) == 'decode'           # their --tensor 4 slice
+    assert pp.resolve_impl('auto', decode_pool=pool(
+        12, 512, 16, 64)) == 'kernel'            # GPT-2: 64-wide heads
+    assert pp.resolve_impl('auto', decode_pool=pool(
+        8, 512, 8, 128)) == 'kernel'             # half-tile pages
+    # An S>1 chunk read passes no pool: the route of before.
     assert pp.resolve_impl('auto', quantized=False) == 'kernel'
     assert pp.resolve_impl('auto', quantized=True) == 'fused'
+    assert pp.resolve_impl('auto', quantized=True, decode_pool=jax.
+                           ShapeDtypeStruct((8, 64, 32, 128),
+                                            jnp.int8)) == 'fused'
+    # By name both unquantized routes stay selectable (kernel_check
+    # compares them), whatever 'auto' would take.
+    assert pp.resolve_impl('kernel', decode_pool=pool(
+        8, 5120, 16, 128)) == 'kernel'
+    assert pp.resolve_impl('decode') == 'decode'
     assert pp.resolve_impl('fused', quantized=False) == 'fused'
     assert pp.lora_fusion_impl(quantized=True) == 'fused'
     assert pp.lora_fusion_impl(quantized=False) is None
@@ -320,6 +490,69 @@ def test_upstream_kernel_is_shard_mapped_under_a_tensor_mesh(monkeypatch):
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=2e-2, rtol=2e-2)
+
+
+def test_decode_kernel_is_shard_mapped_under_a_tensor_mesh(monkeypatch):
+    """The same for the route 'auto' now takes at these shapes: the
+    in-repo decode read runs per chip on its own kv-head slice, and
+    the engine-facing wrapper picks it from the pool's static shape."""
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from skypilot_tpu.parallel import mesh as mesh_lib
+    if len(jax.devices()) < 2:
+        pytest.skip('needs >= 2 host devices')
+    monkeypatch.setattr(pp, 'available', lambda: True)
+    mesh = mesh_lib.make_mesh(mesh_lib.MeshConfig(tensor=2),
+                              devices=jax.devices()[:2])
+    args, (k, v) = _decode_inputs(2, 8, 128, [37, 0, 128],
+                                  jnp.bfloat16)
+    q, k_nan, v_nan, lengths, tbl = args
+    ref = pa._reference_paged_attention(q, k, v,
+                                        jnp.maximum(lengths, 1), tbl)
+    pool = NamedSharding(mesh, P('tensor'))
+    heads = NamedSharding(mesh, P(None, 'tensor', None))
+    placed = (jax.device_put(q, heads), jax.device_put(k_nan, pool),
+              jax.device_put(v_nan, pool), lengths, tbl)
+    fn = jax.jit(lambda *a: pa.paged_decode_attention(*a))   # 'auto'
+    with pltpu.force_tpu_interpret_mode(), mesh:
+        out = fn(*placed)
+        hlo = fn.lower(*placed).compile().as_text()
+    assert out.sharding.spec == P(None, 'tensor', None)
+    assert 'all-gather' not in hlo and 'all-to-all' not in hlo
+    out = np.asarray(out, np.float32)
+    assert np.all(np.isfinite(out)) and not np.any(out[1])
+    np.testing.assert_allclose(out[[0, 2]],
+                               np.asarray(ref, np.float32)[[0, 2]],
+                               atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize('heads,page,want',
+                         [(1, 16, 'decode'),     # 128-wide heads
+                          (2, 16, 'kernel'),     # 64-wide heads
+                          (1, 8, 'kernel')],     # half-tile pages
+                         ids=['d128', 'd64', 'page8'])
+def test_engine_names_the_route_its_decode_holds(monkeypatch, heads,
+                                                 page, want):
+    """`attention_impl()` (what /stats and the gauge report) follows
+    the pool's static shape as the traced decode does, where the
+    compiled routes exist."""
+    from skypilot_tpu.models.batching import ContinuousBatchingEngine
+    from skypilot_tpu.models.llama import Llama, LlamaConfig
+    cfg = LlamaConfig(vocab_size=64, max_seq_len=64, num_layers=1,
+                      num_heads=heads, num_kv_heads=heads, embed_dim=128,
+                      mlp_dim=128, dtype=jnp.bfloat16, kv_page_size=page,
+                      kv_total_pages=16)
+    model = Llama(cfg)
+    params = nn.meta.unbox(model.init(
+        jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))['params'])
+    eng = ContinuousBatchingEngine(model, params, num_slots=2,
+                                   max_total_len=64)
+    try:
+        assert eng.paged and eng.attention_impl() == 'xla'   # this CPU
+        monkeypatch.setattr(pp, 'available', lambda: True)
+        assert eng.attention_impl() == want
+    finally:
+        eng.stop()
 
 
 # -- end-to-end engine bit identity (int8 KV + active LoRA) -----------------

@@ -3,8 +3,9 @@ copy around it.
 
 XLA:TPU gave the scatter form of the write a page-major layout of its
 own, so every scatter was wrapped in two copies of the whole pool
-array (PERF.md, PR 26). These tests compile the write as it is today
-for a described v5e at the benchmark cell's shapes
+array (PERF.md, PR 26). These tests compile the write as it is today,
+and the decode read behind it (PR 30), for a described v5e at the
+benchmark cell's shapes
 (`mistral-7b-l16`: pool bf16[8, 5120, 16, 128], 32 slots, 128 pages a
 row) and hold `parallel/serving.pool_copy_lines` to zero. Nothing runs:
 a compile says nothing about results or times.
@@ -74,28 +75,61 @@ def _assert_in_place(compiled, dtype):
     assert text.count(' scatter(') == (2 if dtype == jnp.int8 else 0)
 
 
-def test_decode_write_and_kernel_copy_no_pool(compile_for_chip):
-    """One layer of a decode round: the token-wise write, then the
-    upstream Pallas kernel the chip's route reads the pool with."""
-    from jax.experimental.pallas.ops.tpu.paged_attention import (
-        paged_attention)
-
+def _decode_layer(read):
+    """One layer of a decode round: the token-wise write, then `read`
+    of the pool as the model's block calls it."""
     def layer(k_pages, v_pages, q, k_new, v_new, positions, table):
         k_pages, v_pages = pa.write_kv(k_pages, v_pages, k_new, v_new,
                                        positions, table)
-        out = paged_attention(q * (POOL[3] ** -0.5), k_pages, v_pages,
-                              positions + 1, table,
-                              pages_per_compute_block=8)
+        out = read(q, k_pages, v_pages, positions + 1, table)
         return k_pages, v_pages, out
+    return layer
 
-    bf16, i32 = jnp.bfloat16, jnp.int32
+
+DECODE_LAYER_AVALS = (
+    (POOL, jnp.bfloat16), (POOL, jnp.bfloat16),
+    ((SLOTS, HQ, POOL[3]), jnp.bfloat16),
+    ((SLOTS, POOL[0], POOL[3]), jnp.bfloat16),
+    ((SLOTS, POOL[0], POOL[3]), jnp.bfloat16), ((SLOTS,), jnp.int32),
+    ((SLOTS, PAGES_PER_ROW), jnp.int32))
+
+
+def test_decode_write_and_decode_kernel_copy_no_pool(compile_for_chip,
+                                                     monkeypatch):
+    """The route 'auto' takes on the chip at the cell's shapes, through
+    the wrapper the models call: the in-repo decode read, held by its
+    `name=`, with the pool operands where they lie (no pool-shaped
+    copy) and the output in the query's dtype. The backend is the one
+    thing steered: this process compiles for a chip it does not have."""
+    from skypilot_tpu.ops import pallas_paged
+    monkeypatch.setattr(pallas_paged, 'available', lambda: True)
     compiled = compile_for_chip(
-        layer, (0, 1), (POOL, bf16), (POOL, bf16),
-        ((SLOTS, HQ, POOL[3]), bf16), ((SLOTS, POOL[0], POOL[3]), bf16),
-        ((SLOTS, POOL[0], POOL[3]), bf16), ((SLOTS,), i32),
-        ((SLOTS, PAGES_PER_ROW), i32))
+        _decode_layer(pa.paged_decode_attention), (0, 1),
+        *DECODE_LAYER_AVALS)
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1, calls
+    assert '/paged_attention/' in calls[0]               # the scope
+    assert 'paged_decode_attention' in calls[0]          # the name=
+    assert f'= bf16[{SLOTS},{HQ},{POOL[3]}]' in calls[0]
+    _assert_in_place(compiled, jnp.bfloat16)
+
+
+def test_decode_write_and_upstream_kernel_copy_no_pool(compile_for_chip):
+    """The same layer with the upstream Pallas call (`impl='kernel'`:
+    what pools of other shapes keep, and kernel_check's comparison)."""
+    from jax.experimental.pallas.ops.tpu.paged_attention import (
+        paged_attention)
+
+    def read(q, k_pages, v_pages, lengths, table):
+        return paged_attention(q * (POOL[3] ** -0.5), k_pages, v_pages,
+                               lengths, table, pages_per_compute_block=8)
+
+    compiled = compile_for_chip(_decode_layer(read), (0, 1),
+                                *DECODE_LAYER_AVALS)
     assert 'tpu_custom_call' in compiled.as_text()
-    _assert_in_place(compiled, bf16)
+    _assert_in_place(compiled, jnp.bfloat16)
 
 
 @pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.int8],
