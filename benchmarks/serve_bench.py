@@ -118,11 +118,6 @@ def _server_env(args) -> dict:
     multi-device-without-TPUs harness)."""
     env = dict(os.environ)
     env['PYTHONPATH'] = f"{REPO}:{env.get('PYTHONPATH', '')}"
-    if getattr(args, 'paged_impl', None):
-        # The paged-attention impl is resolved at trace time from
-        # this env var (ops/pallas_paged.resolve_impl) — serve_lm
-        # needs no flag of its own.
-        env['SKYPILOT_TPU_PAGED_IMPL'] = args.paged_impl
     chips = max(args.tensor, 1) * max(getattr(args, 'stages', 1), 1)
     if chips > 1:
         flags = env.get('XLA_FLAGS', '')
@@ -1403,135 +1398,6 @@ def run_spill_ab(args) -> dict:
     }
 
 
-def _run_kernel_arm(args, impl, adapter_dir, names) -> dict:
-    """One --kernel-ab arm: boot serve_lm pinned to `impl` (via
-    SKYPILOT_TPU_PAGED_IMPL), run the deterministic greedy workload
-    NON-streamed (exact token rows back), return tokens + the
-    server's resolved impl and bytes/token model."""
-    arm = _with(args, paged_impl=impl)
-    port = _free_port()
-    cmd = _build_server_cmd(arm, adapter_dir) + ['--port', str(port)]
-    server = subprocess.Popen(cmd, env=_server_env(arm),
-                              stdout=subprocess.DEVNULL,
-                              stderr=subprocess.STDOUT)
-    url = f'http://127.0.0.1:{port}'
-    try:
-        deadline = time.time() + 300
-        info = None
-        while time.time() < deadline:
-            try:
-                info = requests.get(url, timeout=2).json()
-                break
-            except requests.RequestException:
-                time.sleep(1)
-                if server.poll() is not None:
-                    raise RuntimeError('serve_lm died')
-        if info is None:
-            raise RuntimeError('serve_lm not ready within 300s')
-        vocab = int(info['vocab_size'])
-        rng = random.Random(0)
-        prompts = [[rng.randrange(1, vocab)
-                    for _ in range(rng.randrange(4, 16))]
-                   for _ in range(args.requests)]
-        # Round-robin over base + every adapter: the fused QKV LoRA
-        # path and the base fast path both sit in the comparison.
-        targets = [None] + list(names)
-        t0 = time.perf_counter()
-        token_rows = []
-        for i, p in enumerate(prompts):
-            body = {'tokens': [p],
-                    'max_new_tokens': args.max_new_tokens}
-            tgt = targets[i % len(targets)]
-            if tgt:
-                body['model'] = tgt
-            resp = requests.post(f'{url}/generate', json=body,
-                                 timeout=600)
-            resp.raise_for_status()
-            token_rows.append(resp.json()['tokens'][0])
-        elapsed = time.perf_counter() - t0
-        stats = requests.get(f'{url}/stats', timeout=30).json()
-        return {
-            'impl_requested': impl,
-            'impl_resolved': stats.get('attention_impl'),
-            'kv_dtype': (stats.get('storage') or {}).get('kv_dtype'),
-            'requests': len(token_rows),
-            'elapsed_s': round(elapsed, 2),
-            'bytes_per_token_model':
-                stats.get('attention_bytes_per_token'),
-            'tokens': token_rows,
-        }
-    finally:
-        server.terminate()
-        try:
-            server.wait(timeout=10)
-        except subprocess.TimeoutExpired:
-            server.kill()
-
-
-def run_kernel_ab(args) -> dict:
-    """The fused-kernel A/B (the committed BENCH_kernel record): the
-    IDENTICAL int8-KV + multi-LoRA greedy workload against a server
-    on the fused interpret-mode Pallas path vs the XLA
-    dequantize-and-gather path. The record asserts the acceptance
-    gates itself: byte-identical greedy tokens, strictly fewer
-    modeled HBM bytes/token on the fused path (the dequantized
-    [T,Hq,D] materialization it deletes), and the 3->1 QKV LoRA
-    dispatch fusion."""
-    import hashlib
-    import tempfile
-    from skypilot_tpu.ops import pallas_paged as pp
-
-    adapter_dir = tempfile.mkdtemp(prefix='serve_bench_kernel_')
-    names = _make_adapter_artifacts(args, adapter_dir)
-    arms = {impl: _run_kernel_arm(args, impl, adapter_dir, names)
-            for impl in ('fused_interpret', 'xla')}
-    fused, xla = arms['fused_interpret'], arms['xla']
-
-    identical = fused['tokens'] == xla['tokens']
-    assert identical, (
-        'fused kernel diverged from the XLA reference on greedy '
-        'tokens — the bit-identity acceptance gate failed')
-    fb = fused['bytes_per_token_model']
-    xb = xla['bytes_per_token_model']
-    assert (fb['total_bytes_per_token'] <
-            xb['total_bytes_per_token']), (
-        'fused path must model strictly fewer HBM bytes/token than '
-        'the XLA dequantize route at int8')
-    digest = hashlib.sha256(
-        json.dumps(fused['tokens']).encode()).hexdigest()[:16]
-    for rec in arms.values():
-        rec['tokens_sha256_16'] = digest
-        rec['tokens_sample'] = rec['tokens'][0]
-        del rec['tokens']      # the digest pins identity; keep the
-        #                        committed record readable
-    return {
-        'bench': 'serve_kernel',
-        'engine': args.engine,
-        'model': args.model,
-        'kv_dtype': 'int8',
-        'adapters': args.adapters,
-        'adapter_rank': args.adapter_rank,
-        'requests': args.requests,
-        'max_new_tokens': args.max_new_tokens,
-        'greedy_tokens_bit_identical': identical,
-        'modeled_bytes_per_token': {
-            'fused_interpret': fb['total_bytes_per_token'],
-            'xla': xb['total_bytes_per_token'],
-        },
-        'hbm_bytes_per_token_saved_frac': round(
-            1.0 - fb['total_bytes_per_token'] /
-            xb['total_bytes_per_token'], 4),
-        'dequant_materialize_bytes_deleted':
-            xb['dequant_materialize_bytes'],
-        'qkv_lora_dispatches_per_layer': {
-            'fused_interpret':
-                pp.qkv_lora_dispatches_per_layer('fused_interpret'),
-            'xla': pp.qkv_lora_dispatches_per_layer('xla'),
-        },
-        'runs': arms,
-    }
-
-
 def main() -> None:
     parser = argparse.ArgumentParser()
     parser.add_argument('--engine', choices=['continuous', 'simple'],
@@ -1771,28 +1637,12 @@ def main() -> None:
                              'and emit one combined JSON object '
                              '(the committed BENCH_quant record). '
                              'Requires --kv-pool-bytes')
-    parser.add_argument('--paged-impl', default=None,
-                        choices=['auto', 'xla', 'decode', 'kernel',
-                                 'fused', 'fused_interpret'],
-                        help='pin the server\'s paged-attention '
-                             'implementation (exported as '
-                             'SKYPILOT_TPU_PAGED_IMPL; see '
-                             'ops/pallas_paged.py)')
     parser.add_argument('--hbm-peak-gbps', type=float, default=2765.0,
                         metavar='GBPS',
                         help='per-chip HBM peak bandwidth for the '
                              'roofline block (default: TPU v5p '
                              '2765 GB/s; on CPU the fraction is a '
                              'sanity denominator only)')
-    parser.add_argument('--kernel-ab', action='store_true',
-                        help='run the identical int8-KV + multi-LoRA '
-                             'greedy workload on the fused '
-                             'interpret-mode Pallas path AND the XLA '
-                             'path, assert byte-identical tokens + '
-                             'the modeled HBM and dispatch deltas, '
-                             'and emit one combined JSON object (the '
-                             'committed BENCH_kernel record). '
-                             'Requires --adapters N')
     parser.add_argument('--tensor-ab', action='store_true',
                         help='run --tensor 1 vs --tensor N over the '
                              'identical workload and emit one '
@@ -1912,17 +1762,6 @@ def main() -> None:
                          'spill tier lives in the paged slot '
                          'engine)')
         _emit(run_spill_ab(args))
-        return
-
-    if args.kernel_ab:
-        if args.replicas or args.quant_ab or args.tensor_ab:
-            parser.error('--kernel-ab is a single-server mode')
-        if not args.adapters:
-            parser.error('--kernel-ab needs --adapters N (the fused '
-                         'QKV LoRA path must sit in the comparison)')
-        if args.engine != 'continuous':
-            parser.error('--kernel-ab needs --engine continuous')
-        _emit(run_kernel_ab(_with(args, kv_dtype='int8')))
         return
 
     if args.quant_ab:

@@ -1557,16 +1557,17 @@ class ContinuousBatchingEngine:
         return out
 
     def attention_impl(self) -> str:
-        """Resolved paged-attention implementation this engine's traced
-        forwards dispatch to (ops/pallas_paged.resolve_impl under the
-        current process-wide dispatch state), or 'dense' when the
-        engine runs the dense per-slot cache — no paged kernel in
-        play. Surfaced via the attention_impl_info gauge and /stats."""
+        """The route this engine's traced decode read takes:
+        ops/pallas_paged.resolve_impl given what that read gives it
+        (whether the pool is int8, the K pool's static shape), so the
+        name reported is the program compiled. 'dense' when the
+        engine runs the dense per-slot cache — no paged read in play.
+        Surfaced via the attention_impl_info gauge and /stats."""
         if not self.paged:
             return 'dense'
         from skypilot_tpu.ops import pallas_paged
         return pallas_paged.resolve_impl(
-            'auto', quantized=self.kv_dtype == 'int8',
+            quantized=self.kv_dtype == 'int8',
             decode_pool=self._pool_aval)
 
     def _compile_decode(self):

@@ -187,12 +187,13 @@ class Attention(nn.Module):
         v = _proj(cfg.num_kv_heads * hd, ('embed', 'heads'),
                   cfg.dtype, 'wv', cfg.qkv_bias)(x)
         # Multi-tenant QKV LoRA: when the fused kernel path is active
-        # (ops/pallas_paged.py dispatch state, resolved at trace time)
-        # and all three projections carry stacked per-slot factors, the
-        # three gather+matmul chains collapse into ONE pallas dispatch.
-        # The caller-side scale/cast below matches lora.apply_delta
-        # numerics exactly; wq/wk/wv fall back to per-projection
-        # apply_delta otherwise (training, single-adapter, XLA impl).
+        # (an int8 pool on a TPU: ops/pallas_paged.lora_fusion_impl,
+        # at trace time) and all three projections carry stacked
+        # per-slot factors, the three gather+matmul chains collapse
+        # into ONE pallas dispatch. The caller-side scale/cast below
+        # matches lora.apply_delta numerics exactly; wq/wk/wv fall
+        # back to per-projection apply_delta otherwise (training,
+        # single-adapter, the XLA route).
         fused_lora = None
         if (lora is not None and adapter_ids is not None
                 and all(t in lora for t in ('wq', 'wk', 'wv'))):

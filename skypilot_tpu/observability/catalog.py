@@ -117,7 +117,7 @@ SPECS: Dict[str, Tuple] = {
     'skypilot_serving_attention_impl_info': (
         'gauge', 'Resolved paged-attention implementation in effect '
                  '(always 1; read the labels — impl is xla | decode | '
-                 'kernel | fused | fused_interpret, or dense when the '
+                 'fused | fused_interpret, or dense when the '
                  'engine runs the dense KV cache; ops/pallas_paged.py '
                  'dispatch rules)',
         ('engine', 'impl', 'kv_dtype')),

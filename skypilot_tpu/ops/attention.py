@@ -10,7 +10,6 @@ Layout convention: q/k/v are [batch, seq, heads, head_dim] (BSHD).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -18,9 +17,8 @@ import jax.numpy as jnp
 # Sequence length from which 'auto' takes the Pallas flash kernel on
 # TPU: its O(S) memory pays once the S x S scores stop fitting
 # VMEM-friendly XLA fusions. Where the crossover sits on a v5e is NOT
-# MEASURED (ROADMAP S2; benchmarks/flash_crossover.py is the tool);
-# override via SKYPILOT_TPU_FLASH_MIN_SEQ.
-_FLASH_MIN_SEQ = int(os.environ.get('SKYPILOT_TPU_FLASH_MIN_SEQ') or 2048)
+# MEASURED: ROADMAP S7 sets this from `kernel_check` on the chip.
+_FLASH_MIN_SEQ = 2048
 
 
 @jax.named_scope('attention')

@@ -8,15 +8,19 @@ nothing about what Mosaic accepts: block shapes, tile alignment,
 scalar-prefetch reads and VMEM limits are only checked by the real
 compiler. This runs each call at Llama-3-8B serving shapes (32 query /
 8 KV heads of 128, page 16, 128 pages per sequence, batch 16) through
-the same wrappers the models call, compiled, and reports per kernel
-either `ok` with the largest error against the reference and the
-compiled call's run time (`run_us`: a call of twenty enqueued back to
-back, the median of five such batches), `mismatch`, or `refused` with
-the compiler's own message.
-The `.../32rows` cases run the two unquantized decode routes on the
-SAME inputs (32 rows, every fourth of length 0), so their `run_us`
-are the in-repo decode read (also under a tensor mesh of the host's
-chips) against the upstream call. It
+the same wrappers the models call (a kernel no route reaches on its
+own, by its own name), compiled, and reports per case either `ok`
+with the largest error against the reference and the compiled call's
+run time (`run_us`: a call of twenty enqueued back to back, the
+median of five such batches), `mismatch` (also: the wrapper took
+another route than the case names), or `refused` with the compiler's
+own message.
+The `.../32rows` cases run the in-repo decode read at the benchmark
+cells' 32 slots (every fourth of length 0), alone and under a tensor
+mesh of the host's chips. `xla_paged_attention/bf16/D=64/S=1` is the
+read a pool of GPT-2's 64-wide heads takes, which no Pallas kernel
+here compiles: the XLA gather, compiled for the chip and held to the
+same reference in float32. It
 exits non-zero when any case is not `ok`, and when the backend is not
 a TPU: a CPU run of this file would check nothing.
 
@@ -47,7 +51,8 @@ PAGE, PAGES_PER_SEQ, BATCH = 16, 128, 16
 D_MODEL, LORA_RANK, LORA_SLOTS = 4096, 16, 8
 
 
-def _pool(key, quantized: bool, batch: int = BATCH):
+def _pool(key, quantized: bool, batch: int = BATCH,
+          kv_heads: int = KV_HEADS, head_dim: int = HEAD_DIM):
     """(k_pages, v_pages, k_scales, v_scales, page_indices): a pool in
     which every sequence owns distinct pages, as the allocator hands
     them out (page 0 is the engine's trash page)."""
@@ -56,7 +61,7 @@ def _pool(key, quantized: bool, batch: int = BATCH):
     from skypilot_tpu.ops import paged_attention as paged_ops
     total_pages = batch * PAGES_PER_SEQ + 1
     kk, kv, kp = jax.random.split(key, 3)
-    shape = (total_pages, PAGE, KV_HEADS, HEAD_DIM)
+    shape = (total_pages, PAGE, kv_heads, head_dim)
     k = jax.random.normal(kk, shape, jnp.bfloat16)
     v = jax.random.normal(kv, shape, jnp.bfloat16)
     perm = jax.random.permutation(kp, total_pages - 1) + 1
@@ -69,38 +74,60 @@ def _pool(key, quantized: bool, batch: int = BATCH):
     return to_pool(qk), to_pool(qv), sk, sv, page_indices
 
 
-def _paged_decode(impl: str, quantized: bool, batch: int = BATCH,
-                  dead_every: int = 0, over_tensor_mesh: bool = False
+def _wrong_route(want: str, got: str) -> Dict[str, Any]:
+    return {'verdict': 'mismatch', 'detail':
+            f'the wrapper resolves this read to {got!r}, not {want!r}'}
+
+
+def _paged_decode(route: str, quantized: bool, batch: int = BATCH,
+                  dead_every: int = 0, over_tensor_mesh: bool = False,
+                  heads: tuple = (Q_HEADS, KV_HEADS, HEAD_DIM)
                   ) -> Callable[[Any], Dict]:
-    """`dead_every` n: every n-th row has length 0 (a lane that is
+    """The S=1 read through `paged_decode_attention`, which must take
+    `route` for this pool; 'fused' of an unquantized pool is reached
+    by no route and is called by name.
+    `dead_every` n: every n-th row has length 0 (a lane that is
     not decoding); such rows are compared as zeros, since the
     reference's softmax over no token is not a number.
     `over_tensor_mesh`: pool and heads sharded over all the host's
     chips (a one-chip host runs the same case unsharded)."""
+    q_heads, kv_heads, head_dim = heads
+
     def case(key):
         import jax
         import jax.numpy as jnp
         from skypilot_tpu.ops import paged_attention as paged_ops
+        from skypilot_tpu.ops import pallas_paged
         kq, kl, kpool = jax.random.split(key, 3)
-        k_pages, v_pages, ks, vs, tbl = _pool(kpool, quantized, batch)
-        q = jax.random.normal(kq, (batch, Q_HEADS, HEAD_DIM),
+        k_pages, v_pages, ks, vs, tbl = _pool(kpool, quantized, batch,
+                                              kv_heads, head_dim)
+        q = jax.random.normal(kq, (batch, q_heads, head_dim),
                               jnp.bfloat16)
         lengths = jax.random.randint(
             kl, (batch,), 1, PAGE * PAGES_PER_SEQ + 1, jnp.int32)
         if dead_every:
             lengths = jnp.where(
                 jnp.arange(batch) % dead_every == 0, 0, lengths)
+        if route == 'fused' and not quantized:
+            def read(q, k_pages, v_pages, lengths, tbl, **scales):
+                return pallas_paged.fused_paged_attention(
+                    q[:, None], k_pages, v_pages,
+                    (lengths - 1)[:, None], tbl, **scales)[:, 0]
+        else:
+            read = paged_ops.paged_decode_attention
+            got = pallas_paged.resolve_impl(quantized=quantized,
+                                            decode_pool=k_pages)
+            if got != route:
+                return _wrong_route(route, got)
 
-        def run(route):
-            def fn(q, k_pages, v_pages, lengths, tbl):
-                out = paged_ops.paged_decode_attention(
-                    q, k_pages, v_pages, lengths, tbl, k_scales=ks,
-                    v_scales=vs, impl=route)
-                return jnp.where((lengths > 0)[:, None, None], out, 0)
-            return jax.jit(fn)
+        def run(fn):
+            return jax.jit(lambda *a: jnp.where(
+                (a[3] > 0)[:, None, None],
+                fn(*a, k_scales=ks, v_scales=vs), 0))
+        reference = run(paged_ops._reference_paged_attention)
         args = (q, k_pages, v_pages, lengths, tbl)
         if not over_tensor_mesh:
-            return _compare(run(impl), run('xla'), args)
+            return _compare(run(read), reference, args)
         # `--tensor N` as the server runs it: kv heads (and their
         # query groups) over every chip of the host, the call under
         # the mesh context so that it is shard-mapped.
@@ -114,8 +141,8 @@ def _paged_decode(impl: str, quantized: bool, batch: int = BATCH,
                 jax.device_put(k_pages, pool),
                 jax.device_put(v_pages, pool), lengths, tbl)
         with mesh:
-            res = _compare(run(impl), run('xla'), args)
-            hlo = run(impl).lower(*args).compile().as_text()
+            res = _compare(run(read), reference, args)
+            hlo = run(read).lower(*args).compile().as_text()
         if 'all-gather' in hlo or 'all-to-all' in hlo:
             res.update(verdict='mismatch', detail='the compiled call '
                        'moves the head-sharded pool between chips')
@@ -125,10 +152,15 @@ def _paged_decode(impl: str, quantized: bool, batch: int = BATCH,
 
 def _paged_chunk(quantized: bool, chunk: int, batch: int
                  ) -> Callable[[Any], Dict]:
+    """The S>1 read of the fused kernel: through
+    `paged_chunk_attention` for an int8 pool (which must take 'fused'
+    there), by name for an unquantized one (whose chunks take the
+    gather)."""
     def case(key):
         import jax
         import jax.numpy as jnp
         from skypilot_tpu.ops import paged_attention as paged_ops
+        from skypilot_tpu.ops import pallas_paged
         kq, ko, kpool = jax.random.split(key, 3)
         k_pages, v_pages, ks, vs, tbl = _pool(kpool, quantized)
         tbl = tbl[:batch]
@@ -139,13 +171,19 @@ def _paged_chunk(quantized: bool, chunk: int, batch: int
         offset = jax.random.randint(
             ko, (batch, 1), 0, PAGE * PAGES_PER_SEQ - chunk, jnp.int32)
         positions = offset + jnp.arange(chunk, dtype=jnp.int32)[None]
+        if quantized:
+            got = pallas_paged.resolve_impl(quantized=True)
+            if got != 'fused':
+                return _wrong_route('fused', got)
+            read = paged_ops.paged_chunk_attention
+        else:
+            read = pallas_paged.fused_paged_attention
 
-        def run(route):
-            return jax.jit(lambda *a: paged_ops.paged_chunk_attention(
-                a[0], a[1], a[2], a[3], a[4], k_scales=ks, v_scales=vs,
-                impl=route))
+        def run(fn):
+            return jax.jit(lambda *a: fn(*a, k_scales=ks, v_scales=vs))
         args = (q, k_pages, v_pages, positions, tbl)
-        return _compare(run('fused'), run('xla'), args)
+        return _compare(run(read),
+                        run(paged_ops._reference_chunk_attention), args)
     return case
 
 
@@ -267,7 +305,7 @@ def cases() -> List[tuple]:
     arrays."""
     return [
         ('paged_decode_attention/bf16/S=1',
-         "resolve_impl('auto') bf16 pool of 128-wide heads: decode",
+         '`resolve_impl` bf16 pool of 128-wide heads: decode',
          _paged_decode('decode', quantized=False)),
         ('paged_decode_attention/bf16/S=1/32rows',
          "the same at the benchmark cells' 32 slots, 8 of them dead",
@@ -277,25 +315,20 @@ def cases() -> List[tuple]:
          'the same under --tensor N: each chip on its kv-head slice',
          _paged_decode('decode', quantized=False, batch=32,
                        dead_every=4, over_tensor_mesh=True), '32rows'),
-        ('upstream_paged_attention/bf16/S=1/32rows',
-         "impl='kernel' by name on the same inputs: the comparison",
-         _paged_decode('kernel', quantized=False, batch=32,
-                       dead_every=4), '32rows'),
-        ('upstream_paged_attention/bf16/S=1',
-         "resolve_impl('auto') bf16 pool whose pages are not whole "
-         "tiles a head (8-token pages): decode; here at 16",
-         _paged_decode('kernel', quantized=False)),
+        ('xla_paged_attention/bf16/D=64/S=1',
+         '`resolve_impl` bf16 pool of 64-wide heads: xla',
+         _paged_decode('xla', quantized=False, heads=(12, 12, 64))),
         ('fused_paged_attention/int8/S=1',
-         "resolve_impl('auto') int8 pool: decode",
+         '`resolve_impl` int8 pool: decode',
          _paged_decode('fused', quantized=True)),
         ('fused_paged_attention/int8/S=256',
-         "resolve_impl('auto') int8 pool: suffix prefill chunk",
+         '`resolve_impl` int8 pool: suffix prefill chunk',
          _paged_chunk(quantized=True, chunk=256, batch=2)),
         ('fused_paged_attention/bf16/S=1',
-         "impl='fused' by name on a bf16 pool: decode",
+         '`fused_paged_attention` by name on a bf16 pool: decode',
          _paged_decode('fused', quantized=False)),
         ('fused_paged_attention/bf16/S=256',
-         "impl='fused' by name on a bf16 pool: chunk",
+         '`fused_paged_attention` by name on a bf16 pool: chunk',
          _paged_chunk(quantized=False, chunk=256, batch=2)),
         ('fused_qkv_lora_delta/S=1',
          'int8 pool + --adapter-dir: decode',

@@ -7,19 +7,20 @@ is sized for the *aggregate* live tokens, so many short sequences fit
 where the dense layout would exhaust HBM — more decode slots, higher
 serving throughput.
 
-On TPU the decode reads dispatch to a Pallas kernel: an unquantized
-pool whose pages are whole tiles a head (128-wide heads, 16-token
-bf16 pages) to ops/pallas_paged.paged_decode_kernel, which fetches a
-page for every KV head of the chip in one copy, multiplies all of
-the row's head groups in one step and walks live rows only;
-unquantized pools of other shapes (64-wide heads) to the upstream
-jax.experimental.pallas.ops.tpu.paged_attention; int8 pools to
-ops/pallas_paged.fused_paged_attention. On the CPU test backend a
-pure-XLA reference (gather + masked attention) runs instead. The
-reference also defines the semantics the kernels are checked against
-on the chip (ops/kernel_check.py). The route is chosen once, at trace
-time and from static shapes (`pallas_paged.resolve_impl`); nothing
-switches routes at run time, and /stats names the one compiled.
+On a TPU the decode read of an unquantized pool whose pages are
+whole tiles a head (128-wide heads, 16-token bf16 pages) is
+ops/pallas_paged.paged_decode_kernel, which fetches a page for every
+KV head of the chip in one copy, multiplies all of the row's head
+groups in one step and walks live rows only; every read of an int8
+pool is ops/pallas_paged.fused_paged_attention. Every other read (an
+unquantized pool of another shape, 64-wide heads among them; an S>1
+chunk of an unquantized pool; anything on the CPU test backend) is
+the pure-XLA gather + masked attention below, which compiles
+everywhere. That reference also defines the semantics the kernels are
+checked against on the chip (ops/kernel_check.py). The route is
+chosen once, at trace time, by `pallas_paged.resolve_impl` from the
+backend and the static shapes and from nothing else; nothing switches
+routes at run time, and /stats names the one compiled.
 
 Layouts (matching the pallas kernel):
   q            [B, num_q_heads, head_dim]      one decode token per row
@@ -93,30 +94,29 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, lengths: jax.Array,
                            page_indices: jax.Array,
                            *, k_scales: Optional[jax.Array] = None,
-                           v_scales: Optional[jax.Array] = None,
-                           impl: str = 'auto') -> jax.Array:
+                           v_scales: Optional[jax.Array] = None
+                           ) -> jax.Array:
     """Attention of one query token per row over its paged KV history.
 
     Returns [B, num_q_heads, head_dim] (q.dtype). GQA: num_q_heads may
     be a multiple of num_kv_heads. `k_scales`/`v_scales`
     (f32[total_pages, page_size]) mark int8 pages.
 
-    `impl` resolves through `pallas_paged.resolve_impl` (overridable
-    process-wide via $SKYPILOT_TPU_PAGED_IMPL / `impl_scope`), at
-    trace time and from static shapes: 'decode' is the in-repo kernel
-    for unquantized pools (ops/pallas_paged.paged_decode_kernel: what
-    'auto' takes on a TPU where a page of one head is whole tiles),
-    'kernel' the upstream pallas kernel (the other unquantized
-    shapes, 64-wide heads among them), 'fused' / 'fused_interpret'
-    the in-repo kernel that dequantizes int8 pages in-register, 'xla'
-    the gather reference — which dequantizes in HBM, the traffic the
-    fused path deletes. Under a tensor mesh context every kernel runs
-    per chip on that chip's kv-head slice of the pool
+    The route is `pallas_paged.resolve_impl`'s, at trace time and
+    from the backend and the static shapes: on a TPU 'decode' (the
+    in-repo kernel, ops/pallas_paged.paged_decode_kernel) for an
+    unquantized pool where a page of one head is whole tiles, 'fused'
+    (the in-repo kernel that dequantizes int8 pages in-register) for
+    an int8 pool, and 'xla', the gather reference, for the other
+    unquantized shapes (64-wide heads among them) and off a TPU. The
+    reference dequantizes an int8 pool in HBM, the traffic the fused
+    path deletes. Under a tensor mesh context each kernel runs per
+    chip on that chip's kv-head slice of the pool
     (`shard_over_kv_heads`).
     """
     assert q.ndim == 3 and k_pages.ndim == 4, (q.shape, k_pages.shape)
     from skypilot_tpu.ops import pallas_paged
-    impl = pallas_paged.resolve_impl(impl, quantized=k_scales is not None,
+    impl = pallas_paged.resolve_impl(quantized=k_scales is not None,
                                      decode_pool=k_pages)
     if impl == 'decode':
         return pallas_paged.paged_decode_kernel(
@@ -127,29 +127,6 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
             page_indices, k_scales=k_scales, v_scales=v_scales,
             interpret=impl == 'fused_interpret')
         return out[:, 0]
-    if impl == 'kernel':
-        from jax.experimental.pallas.ops.tpu.paged_attention import (
-            paged_attention)
-        pages_per_seq = page_indices.shape[1]
-        # Block size must divide the per-sequence page walk.
-        block = min(8, pages_per_seq)
-        while pages_per_seq % block != 0:
-            block -= 1
-        # The pallas kernel applies NO attention scaling internally
-        # (its qk is a raw einsum) — pre-scale q to match the
-        # reference semantics (MaxText does the same).
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-
-        def call(q_, k_, v_, lengths_, tbl):
-            return paged_attention(q_ * scale, k_, v_, lengths_, tbl,
-                                   pages_per_compute_block=block)
-
-        heads = P(None, 'tensor', None)
-        pool = P('tensor', None, None, None)
-        return pallas_paged.shard_over_kv_heads(
-            call, k_pages.shape[0],
-            in_specs=(heads, pool, pool, P(None), P(None, None)),
-            out_specs=heads)(q, k_pages, v_pages, lengths, page_indices)
     return _reference_paged_attention(q, k_pages, v_pages, lengths,
                                       page_indices,
                                       k_scales=k_scales,
@@ -453,30 +430,42 @@ def paged_chunk_attention(q: jax.Array, k_pages: jax.Array,
                           v_pages: jax.Array, positions: jax.Array,
                           page_indices: jax.Array,
                           k_scales: Optional[jax.Array] = None,
-                          v_scales: Optional[jax.Array] = None,
-                          impl: str = 'auto') -> jax.Array:
+                          v_scales: Optional[jax.Array] = None
+                          ) -> jax.Array:
     """S queries per row over the row's FULL paged history.
 
     The paged analog of ops.attention.chunked_cache_attention's read
     side: query s of row b attends every cache index <= positions[b, s]
     — what speculative-decoding verification chunks need (the chunk's
     K/V must already be written via `write_kv_chunk`). Chunk sizes are
-    small (draft_k + 1), so the gather-based XLA path is a fine shape;
-    the fused kernel (ops/pallas_paged.py) handles S>1 blocks natively
-    and takes over when `impl` resolves to it — on int8 pools that
-    again skips the HBM dequantize-materialize step.
+    small (draft_k + 1), so the gather-based XLA path (route 'xla') is
+    a fine shape for an unquantized pool; on a TPU an int8 pool takes
+    the fused kernel (ops/pallas_paged.py), which handles S>1 blocks
+    natively and skips the HBM dequantize-materialize step.
 
     q: [B, S, num_q_heads, head_dim]; positions: i32[B, S].
     Returns [B, S, num_q_heads, head_dim] (q.dtype).
     """
     from skypilot_tpu.ops import pallas_paged
-    resolved = pallas_paged.resolve_impl(impl,
-                                         quantized=k_scales is not None)
-    if resolved in ('fused', 'fused_interpret'):
+    impl = pallas_paged.resolve_impl(quantized=k_scales is not None)
+    if impl in ('fused', 'fused_interpret'):
         return pallas_paged.fused_paged_attention(
             q, k_pages, v_pages, positions, page_indices,
             k_scales=k_scales, v_scales=v_scales,
-            interpret=resolved == 'fused_interpret')
+            interpret=impl == 'fused_interpret')
+    return _reference_chunk_attention(q, k_pages, v_pages, positions,
+                                      page_indices, k_scales=k_scales,
+                                      v_scales=v_scales)
+
+
+def _reference_chunk_attention(q: jax.Array, k_pages: jax.Array,
+                               v_pages: jax.Array, positions: jax.Array,
+                               page_indices: jax.Array,
+                               k_scales: Optional[jax.Array] = None,
+                               v_scales: Optional[jax.Array] = None
+                               ) -> jax.Array:
+    """Pure-XLA semantics of the chunk read: gather each row's pages,
+    softmax under each query's own causal bound."""
     head_dim = k_pages.shape[-1]
     max_len = page_indices.shape[1] * k_pages.shape[2]
     k_all, v_all = _gather_kv(q.shape[2], k_pages, v_pages,

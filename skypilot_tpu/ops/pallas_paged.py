@@ -1,19 +1,15 @@
-"""In-repo Pallas paged-attention + LoRA kernels and the impl-dispatch
-plumbing that selects between them.
+"""In-repo Pallas paged-attention + LoRA kernels and the one function
+that says which of them a paged read takes.
 
 WHY. Decode is memory-bound: per-chip tokens/s is HBM bytes/token or
-nothing. The upstream pallas paged-attention kernel
-(jax.experimental.pallas.ops.tpu.paged_attention) misses that twice.
-It reads unquantized pools only, so the int8 KV pool — the config that
-doubled pool capacity — used to fall back to the XLA gather route,
-which DEQUANTIZES IN HBM: it materializes f32 copies of every
-gathered page (then GQA-expands them) each step; per-slot LoRA
-likewise paid one batched gather+matmul chain per projection. And on a
-bf16 pool it is bound by the count of its copies, not by their bytes:
-a 4 KB copy and a wait a page AND head, one loop over the row's
-context a head, every block cast to f32 (5-11% of the HBM roofline in
-the benchmark's serving cells; PERF.md, PR 27). The kernels here close
-these gaps:
+nothing. The XLA gather route DEQUANTIZES an int8 KV pool IN HBM: it
+materializes f32 copies of every gathered page (then GQA-expands
+them) each step; per-slot LoRA likewise paid one batched
+gather+matmul chain per projection. JAX's own pallas paged-attention
+call is not used for the unquantized pool: it is bound by the count
+of its copies, not by their bytes, a 4 KB copy and a wait a page AND
+head (940 us against `paged_decode_kernel`'s 231 us on the same
+inputs on a v5e; PERF.md, PR 30). The kernels here close these gaps:
 
   paged_decode_kernel     the S=1 decode read of an unquantized pool
                           (route 'decode'). One invocation walks the
@@ -53,24 +49,28 @@ these gaps:
                           body instead of three separate gather+matmul
                           dispatches per layer.
 
-DISPATCH. `resolve_impl(impl, quantized=..., decode_pool=...)` maps a
-requested impl to the concrete route; 'auto' consults, in order: an
-explicit `set_default_impl()` / `impl_scope()` override, the
-SKYPILOT_TPU_PAGED_IMPL environment variable, then the backend and
-the static shapes (TPU quantized -> 'fused'; TPU unquantized, the
-one-token decode read of a pool `decode_kernel_refusal` takes ->
-'decode'; TPU unquantized otherwise -> upstream 'kernel', which for an
-S>1 chunk means the XLA gather; the CPU test backend -> 'xla'). A
-route selected by name that cannot run here is a ValueError, never a
-silent switch to another route. `unavailable_reason()` says WHY the
-compiled kernel path is off so /stats and test skip messages can say
-so.
+DISPATCH. `resolve_impl(quantized=..., decode_pool=...)` names the
+route of a read from what the code observes, the backend and the
+pool's static shape, and from nothing else: on a TPU an int8 pool ->
+'fused'; the one-token decode read of an unquantized pool that
+`decode_kernel_refusal` takes -> 'decode'; every other unquantized
+read (a refused shape such as 64-wide heads, every S>1 chunk) ->
+'xla', the gather; off a TPU (the CPU test backend) -> 'xla'. No
+argument, setter or environment variable selects a route; whoever
+wants one kernel by name calls that kernel. The one override is
+`impl_scope`, the tests' way to run the engine through the
+interpreter on a CPU; a route forced through it that cannot run here
+is a ValueError, never a silent switch to another route.
+`unavailable_reason()` says WHY the compiled kernel path is off so
+/stats and test skip messages can say so.
 
 INTERPRET-MODE CONTRACT. Every pallas_call here takes
 `interpret=<kwarg>` (enforced repo-wide by `stpu check` rule SKY006),
-so the kernels run on CPU (`impl='fused_interpret'`;
-`paged_decode_kernel(..., interpret=True)`, whose DMAs take the TPU
-interpreter) — bit-tolerance pinned against the XLA reference in
+so the kernels run on CPU (`fused_paged_attention(...,
+interpret=True)`, or a whole engine under
+`impl_scope('fused_interpret')`; `paged_decode_kernel(...,
+interpret=True)`, whose DMAs take the TPU interpreter) —
+bit-tolerance pinned against the XLA reference in
 tests/unit_tests/test_pallas_paged.py, with a deliberately perturbed
 kernel (the `perturb` hooks below) proving the pins are non-vacuous.
 
@@ -81,8 +81,8 @@ GQA-remainder rule (kv-heads not divisible by tensor -> replicated
 pool) falls out as the unsharded call. Without a mesh context (the
 GSPMD-propagation serving path) the call runs as a single program —
 correct everywhere, though GSPMD treats it as an opaque replicated
-region, so sharded-pool TPU deployments should enter the mesh context
-before forcing 'fused'.
+region, so sharded-pool TPU deployments trace their forwards under
+the mesh context.
 
 ROOFLINE. `bytes_per_token_model()` is the analytic HBM-traffic model
 (pool reads + scale rows + XLA dequant materialization + amortized
@@ -94,30 +94,25 @@ from __future__ import annotations
 
 import contextlib
 import functools
-import os
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-ENV_VAR = 'SKYPILOT_TPU_PAGED_IMPL'
-
-#: Accepted impl names: 'auto' resolves per backend/config; 'xla' is
-#: the gather reference; 'decode' this module's decode read of an
-#: unquantized pool (`paged_decode_kernel`); 'kernel' the upstream
-#: bf16 pallas kernel; 'fused' this module's int8-capable kernel;
-#: 'fused_interpret' the same kernel in pallas interpret mode (runs
-#: anywhere, CPU included).
-IMPLS: Tuple[str, ...] = ('auto', 'xla', 'decode', 'kernel', 'fused',
-                          'fused_interpret')
+#: The routes a paged read can take: 'xla' is the gather reference
+#: (compiles everywhere); 'decode' this module's decode read of an
+#: unquantized pool (`paged_decode_kernel`); 'fused' this module's
+#: int8-capable kernel; 'fused_interpret' the same kernel in pallas
+#: interpret mode (runs anywhere, CPU included; `impl_scope` only).
+IMPLS: Tuple[str, ...] = ('xla', 'decode', 'fused', 'fused_interpret')
 
 #: The routes Mosaic compiles: a TPU backend only.
-_COMPILED = ('decode', 'kernel', 'fused')
+_COMPILED = ('decode', 'fused')
 
 # -- availability -----------------------------------------------------------
 def available() -> bool:
-    """True when the COMPILED kernel routes ('decode', 'kernel',
-    'fused') can run here: Mosaic compiles for a TPU backend only."""
+    """True when the COMPILED kernel routes ('decode', 'fused') can
+    run here: Mosaic compiles for a TPU backend only."""
     return jax.default_backend() == 'tpu'
 
 
@@ -127,96 +122,76 @@ def unavailable_reason() -> Optional[str]:
     backend = jax.default_backend()
     if backend != 'tpu':
         return (f"backend is {backend!r}: the fused kernel compiles on "
-                f"TPU only (impl='fused_interpret' still runs here)")
+                f"TPU only (`impl_scope('fused_interpret')` still runs "
+                f"here)")
     return None
 
 
 # -- impl selection ---------------------------------------------------------
-_default_impl: Optional[str] = None
-
-
-def _validate(impl: str) -> None:
-    if impl not in IMPLS:
-        raise ValueError(
-            f'unknown paged-attention impl {impl!r} (choices: '
-            f'{", ".join(IMPLS)}; also accepted via ${ENV_VAR})')
-
-
-def default_impl() -> str:
-    """The impl 'auto' resolves through: the `set_default_impl()`
-    override, else $SKYPILOT_TPU_PAGED_IMPL, else 'auto' itself."""
-    if _default_impl is not None:
-        return _default_impl
-    env = os.environ.get(ENV_VAR, '').strip()
-    if env:
-        _validate(env)
-        return env
-    return 'auto'
-
-
-def set_default_impl(impl: Optional[str]) -> None:
-    """Process-wide impl override (None clears it). Set BEFORE the
-    first traced forward pass: dispatch resolves at trace time, so a
-    change after jit caches are warm does not retrace."""
-    if impl is not None:
-        _validate(impl)
-    global _default_impl
-    _default_impl = impl
+_scoped_impl: Optional[str] = None
 
 
 @contextlib.contextmanager
 def impl_scope(impl: str):
-    """Scoped `set_default_impl` — the test/bench A/B hook."""
-    prev = _default_impl
-    set_default_impl(impl)
+    """The tests' one override: every paged read traced inside the
+    scope takes route `impl`, whatever the backend and the pool. It is
+    how the CPU tests run the engine through the Pallas interpreter
+    ('fused_interpret'). Nothing outside tests/ enters it. Dispatch
+    resolves at trace time, so enter it BEFORE the first traced
+    forward pass: warm jit caches do not retrace."""
+    if impl not in IMPLS:
+        raise ValueError(
+            f'unknown paged-attention impl {impl!r} (choices: '
+            f'{", ".join(IMPLS)})')
+    global _scoped_impl
+    prev, _scoped_impl = _scoped_impl, impl
     try:
         yield
     finally:
-        set_default_impl(prev)
+        _scoped_impl = prev
 
 
-def resolve_impl(impl: str = 'auto', *, quantized: bool = False,
+def resolve_impl(*, quantized: bool = False,
                  decode_pool: Any = None) -> str:
-    """Concrete route for a requested impl: one of 'xla' | 'decode' |
-    'kernel' | 'fused' | 'fused_interpret'.
+    """The route of one paged read: 'xla' | 'decode' | 'fused' (or,
+    inside an `impl_scope`, the route the scope names).
 
-    'auto' is decided by what the code can observe: off TPU (the CPU
-    test backend) the XLA gather reference; on TPU the fused kernel
-    for int8 pools and, for unquantized ones, this module's decode
-    kernel where `decode_pool` (the K pool, an array or its
+    Decided by what the code can observe and by nothing else: off a
+    TPU (the CPU test backend) the XLA gather; on a TPU the fused
+    kernel for an int8 pool and, for an unquantized one, this module's
+    decode kernel where `decode_pool` (the K pool, an array or its
     ShapeDtypeStruct: only the one-token decode read passes it) has a
     static shape the kernel takes (`decode_kernel_refusal`), else the
-    upstream kernel. A route asked for BY NAME that cannot run here
-    raises — it never degrades to another route, so what /stats
-    reports is what was compiled."""
-    _validate(impl)
-    if impl == 'auto':
-        impl = default_impl()
-    if impl == 'auto':
-        if not available():
-            return 'xla'
-        if quantized:
-            return 'fused'
-        if (decode_pool is not None
-                and decode_kernel_refusal(decode_pool) is None):
-            return 'decode'
-        return 'kernel'
-    if impl in ('decode', 'kernel') and quantized:
-        raise ValueError(
-            f"paged-attention impl {impl!r} reads unquantized pools "
-            f"only; an int8 pool needs 'fused'")
-    if impl in _COMPILED and not available():
-        raise ValueError(
-            f'paged-attention impl {impl!r} was selected but cannot '
-            f'run: {unavailable_reason()}')
-    return impl
+    XLA gather: a refused shape (64-wide heads) and every S>1 chunk.
+    A route forced through `impl_scope` that cannot run here raises —
+    it never degrades to another route, so what /stats reports is what
+    was compiled."""
+    impl = _scoped_impl
+    if impl is not None:
+        if impl == 'decode' and quantized:
+            raise ValueError(
+                "paged-attention impl 'decode' reads unquantized pools "
+                "only; an int8 pool needs 'fused'")
+        if impl in _COMPILED and not available():
+            raise ValueError(
+                f'paged-attention impl {impl!r} was selected but '
+                f'cannot run: {unavailable_reason()}')
+        return impl
+    if not available():
+        return 'xla'
+    if quantized:
+        return 'fused'
+    if (decode_pool is not None
+            and decode_kernel_refusal(decode_pool) is None):
+        return 'decode'
+    return 'xla'
 
 
 def lora_fusion_impl(quantized: bool = False) -> Optional[str]:
     """'fused' / 'fused_interpret' when the QKV LoRA fusion should
-    engage under the current dispatch state, else None (models call
-    this at trace time next to the attention dispatch)."""
-    impl = resolve_impl('auto', quantized=quantized)
+    engage beside the attention route of such a pool, else None
+    (models call this at trace time next to the attention dispatch)."""
+    impl = resolve_impl(quantized=quantized)
     return impl if impl in ('fused', 'fused_interpret') else None
 
 
@@ -441,7 +416,7 @@ _SUBLANES = {4: 8, 2: 16}
 def decode_kernel_refusal(pool: Any) -> Optional[str]:
     """Why `paged_decode_kernel` does not take `pool` (a K or V pool
     [Hkv, pages, page, D], an array or its ShapeDtypeStruct: only the
-    static shape is read; that shape keeps the upstream call), or None
+    static shape is read; that shape takes the XLA gather), or None
     when it does. A page of one head must be whole (sublane, 128-lane)
     tiles: the kernel views a block's pages as one [tokens, D]
     matrix."""
@@ -687,9 +662,9 @@ def shard_over_kv_heads(fn, num_kv_heads: int, *, in_specs, out_specs):
 
     GSPMD treats a Pallas call as opaque: left alone under a sharded
     jit it gathers the head-sharded pool onto every chip each layer,
-    each step. Both paged-attention kernels (this module's and the
-    upstream bf16 one) are per-kv-head independent, so each chip runs
-    the kernel on its own head slice of the pool."""
+    each step. Both paged-attention kernels of this module are
+    per-kv-head independent, so each chip runs the kernel on its own
+    head slice of the pool."""
     from skypilot_tpu.ops.attention import _active_mesh
     mesh = _active_mesh()
     tensor = mesh.shape.get('tensor', 1) if mesh is not None else 1
@@ -755,13 +730,6 @@ def fused_qkv_lora_delta(x: jax.Array, wq_factors: Dict,
         _qkv_lora_kernel, grid_spec=grid_spec, out_shape=out_shapes,
         interpret=interpret, name='fused_qkv_lora',
     )(adapter_ids.astype(jnp.int32), *operands)
-
-
-def qkv_lora_dispatches_per_layer(impl: str) -> int:
-    """Batched-LoRA dispatch count for the three QKV projections of
-    one layer: the fused kernel folds them into ONE call; the unfused
-    route issues one gather+matmul chain per projection."""
-    return 1 if impl in ('fused', 'fused_interpret') else 3
 
 
 # -- analytic HBM roofline --------------------------------------------------

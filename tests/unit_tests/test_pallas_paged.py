@@ -10,9 +10,9 @@ Five contracts:
   - NON-VACUITY: a deliberately perturbed kernel FAILS the same pin
     (the PR 15 collective-guard discipline — a pin that cannot fail
     proves nothing);
-  - DISPATCH: resolve_impl's auto rules, the $SKYPILOT_TPU_PAGED_IMPL
-    override, impl_scope, a missing selected route raising instead of
-    degrading to 'xla', and unavailable_reason;
+  - DISPATCH: resolve_impl's rules (the backend and the pool's static
+    shape, nothing a user sets), impl_scope, a missing forced route
+    raising instead of degrading to 'xla', and unavailable_reason;
   - DECODE READ (`paged_decode_kernel`, route 'decode'): parity over
     the head shapes the repo serves at lengths around a block's
     edges, rows of length 0 that read no page, the perturbed control,
@@ -92,9 +92,8 @@ def test_chunk_parity(quantized, hkv, hq):
                     jnp.float32)
     positions = jnp.asarray(
         rng.integers(0, PSEQ * PAGE, (batch, chunk)), jnp.int32)
-    ref = pa.paged_chunk_attention(q, k, v, positions, tbl,
-                                   k_scales=ks, v_scales=vs,
-                                   impl='xla')
+    ref = pa._reference_chunk_attention(q, k, v, positions, tbl,
+                                        k_scales=ks, v_scales=vs)
     out = pp.fused_paged_attention(q, k, v, positions, tbl,
                                    k_scales=ks, v_scales=vs,
                                    interpret=True)
@@ -103,22 +102,16 @@ def test_chunk_parity(quantized, hkv, hq):
 
 
 def test_dispatch_entrypoints_route_to_fused():
-    """paged_decode_attention / paged_chunk_attention themselves pick
-    the fused kernel under impl='fused_interpret' (same numbers as the
-    explicit call above — the integration llama/gpt decode uses)."""
+    """paged_decode_attention itself picks the fused kernel under
+    `impl_scope('fused_interpret')` (same numbers as the explicit
+    call — the integration llama/gpt decode uses)."""
     batch = 4
     tbl, k, v, ks, vs = _paged_inputs(batch, 2, 1, True)
     rng = np.random.default_rng(2)
     q = jnp.asarray(rng.standard_normal((batch, 4, D)), jnp.float32)
     lengths = jnp.asarray([1, 7, 20, 32], jnp.int32)
-    ref = pa.paged_decode_attention(q, k, v, lengths, tbl,
-                                    k_scales=ks, v_scales=vs,
-                                    impl='xla')
-    out = pa.paged_decode_attention(q, k, v, lengths, tbl,
-                                    k_scales=ks, v_scales=vs,
-                                    impl='fused_interpret')
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=ATOL)
+    ref = pa._reference_paged_attention(q, k, v, lengths, tbl,
+                                        k_scales=ks, v_scales=vs)
     with pp.impl_scope('fused_interpret'):
         auto = pa.paged_decode_attention(q, k, v, lengths, tbl,
                                          k_scales=ks, v_scales=vs)
@@ -312,44 +305,47 @@ def test_fused_qkv_lora_matches_apply_delta():
         got = y + (scale * d).astype(y.dtype)
         # Same contraction order in f32 -> exact, not just close.
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    assert pp.qkv_lora_dispatches_per_layer('fused_interpret') == 1
-    assert pp.qkv_lora_dispatches_per_layer('xla') == 3
 
 
 # -- dispatch resolution ----------------------------------------------------
 def test_resolve_impl_cpu_rules():
-    # CPU: 'auto' observes the backend and takes the XLA reference;
-    # the interpret route runs anywhere.
-    assert pp.resolve_impl('auto', quantized=True) == 'xla'
-    assert pp.resolve_impl('auto', quantized=False) == 'xla'
-    assert pp.resolve_impl('xla', quantized=True) == 'xla'
-    assert pp.resolve_impl('fused_interpret') == 'fused_interpret'
+    # CPU: the backend is observed and the XLA reference taken,
+    # whatever the pool; the interpret route runs anywhere, through
+    # the scope.
+    bf16_pool = jax.ShapeDtypeStruct((8, 64, 16, 128), jnp.bfloat16)
+    assert pp.resolve_impl(quantized=True) == 'xla'
+    assert pp.resolve_impl(quantized=False) == 'xla'
+    assert pp.resolve_impl(decode_pool=bf16_pool) == 'xla'
+    with pp.impl_scope('xla'):
+        assert pp.resolve_impl(quantized=True) == 'xla'
+    with pp.impl_scope('fused_interpret'):
+        assert pp.resolve_impl() == 'fused_interpret'
     with pytest.raises(ValueError):
-        pp.resolve_impl('bogus')
+        with pp.impl_scope('bogus'):
+            pass
     with pytest.raises(ValueError):
-        pp.set_default_impl('bogus')
+        with pp.impl_scope('kernel'):       # the upstream route: gone
+            pass
+    assert 'kernel' not in pp.IMPLS and 'auto' not in pp.IMPLS
 
 
-def test_resolve_impl_raises_when_selected_route_is_missing(monkeypatch):
-    """A route selected BY NAME that cannot run is an error, never a
+def test_resolve_impl_raises_when_selected_route_is_missing():
+    """A route forced BY NAME that cannot run is an error, never a
     quiet 'xla': on the chip that switch is what would let a kernel
     Mosaic refused go unnoticed behind a server that still answers."""
-    for impl in ('decode', 'kernel', 'fused'):
-        with pytest.raises(ValueError, match='cannot run'):
-            pp.resolve_impl(impl)
-        monkeypatch.setenv(pp.ENV_VAR, impl)
-        with pytest.raises(ValueError, match='cannot run'):
-            pp.resolve_impl('auto')
-        monkeypatch.delenv(pp.ENV_VAR)
-    # The decode read and the upstream kernel read unquantized pools
-    # only — also an error, on any backend, instead of the old degrade.
-    for impl in ('decode', 'kernel'):
+    for impl in ('decode', 'fused'):
+        with pp.impl_scope(impl):
+            with pytest.raises(ValueError, match='cannot run'):
+                pp.resolve_impl()
+    # The decode read takes unquantized pools only — also an error,
+    # on any backend, instead of a degrade.
+    with pp.impl_scope('decode'):
         with pytest.raises(ValueError, match='unquantized pools only'):
-            pp.resolve_impl(impl, quantized=True)
+            pp.resolve_impl(quantized=True)
 
 
 def test_resolve_impl_tpu_rules(monkeypatch):
-    """What 'auto' picks where the compiled routes exist (the backend
+    """What a read takes where the compiled routes exist (the backend
     is the one thing faked: CPU tests cannot have a TPU)."""
     monkeypatch.setattr(pp.jax, 'default_backend', lambda: 'tpu')
     assert pp.unavailable_reason() is None
@@ -357,42 +353,67 @@ def test_resolve_impl_tpu_rules(monkeypatch):
     # its static shape (a page of one head in whole tiles).
     pool = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
         shape, jnp.bfloat16)
-    assert pp.resolve_impl('auto', decode_pool=pool(
+    assert pp.resolve_impl(decode_pool=pool(
         8, 5120, 16, 128)) == 'decode'           # Mistral, Llama
-    assert pp.resolve_impl('auto', decode_pool=pool(
+    assert pp.resolve_impl(decode_pool=pool(
         2, 5120, 16, 128)) == 'decode'           # their --tensor 4 slice
-    assert pp.resolve_impl('auto', decode_pool=pool(
-        12, 512, 16, 64)) == 'kernel'            # GPT-2: 64-wide heads
-    assert pp.resolve_impl('auto', decode_pool=pool(
-        8, 512, 8, 128)) == 'kernel'             # half-tile pages
-    # An S>1 chunk read passes no pool: the route of before.
-    assert pp.resolve_impl('auto', quantized=False) == 'kernel'
-    assert pp.resolve_impl('auto', quantized=True) == 'fused'
-    assert pp.resolve_impl('auto', quantized=True, decode_pool=jax.
+    assert pp.resolve_impl(decode_pool=pool(
+        12, 512, 16, 64)) == 'xla'               # GPT-2: 64-wide heads
+    assert pp.resolve_impl(decode_pool=pool(
+        8, 512, 8, 128)) == 'xla'                # half-tile pages
+    # An S>1 chunk read passes no pool: the gather, under its name.
+    assert pp.resolve_impl(quantized=False) == 'xla'
+    assert pp.resolve_impl(quantized=True) == 'fused'
+    assert pp.resolve_impl(quantized=True, decode_pool=jax.
                            ShapeDtypeStruct((8, 64, 32, 128),
                                             jnp.int8)) == 'fused'
-    # By name both unquantized routes stay selectable (kernel_check
-    # compares them), whatever 'auto' would take.
-    assert pp.resolve_impl('kernel', decode_pool=pool(
-        8, 5120, 16, 128)) == 'kernel'
-    assert pp.resolve_impl('decode') == 'decode'
-    assert pp.resolve_impl('fused', quantized=False) == 'fused'
+    # Forced through the scope a compiled route is what is named,
+    # whatever the observations would give.
+    with pp.impl_scope('fused'):
+        assert pp.resolve_impl(quantized=False) == 'fused'
+    with pp.impl_scope('decode'):
+        assert pp.resolve_impl() == 'decode'
     assert pp.lora_fusion_impl(quantized=True) == 'fused'
     assert pp.lora_fusion_impl(quantized=False) is None
 
 
 def test_env_and_scope_overrides(monkeypatch):
-    monkeypatch.setenv(pp.ENV_VAR, 'fused_interpret')
-    assert pp.resolve_impl('auto', quantized=True) == 'fused_interpret'
-    monkeypatch.setenv(pp.ENV_VAR, 'nope')
-    with pytest.raises(ValueError):
-        pp.resolve_impl('auto')
-    monkeypatch.delenv(pp.ENV_VAR)
-    with pp.impl_scope('fused_interpret'):
-        assert pp.resolve_impl('auto') == 'fused_interpret'
-        assert pp.lora_fusion_impl() == 'fused_interpret'
-    assert pp.default_impl() == 'auto'
+    """The scope is the one override, and it unwinds; the environment
+    variable the ladder used to read changes nothing."""
+    monkeypatch.setenv('SKYPILOT_TPU_PAGED_IMPL', 'fused_interpret')
+    assert pp.resolve_impl(quantized=True) == 'xla'
     assert pp.lora_fusion_impl() is None
+    with pp.impl_scope('fused_interpret'):
+        assert pp.resolve_impl() == 'fused_interpret'
+        assert pp.lora_fusion_impl() == 'fused_interpret'
+        with pp.impl_scope('xla'):
+            assert pp.resolve_impl(quantized=True) == 'xla'
+        assert pp.resolve_impl() == 'fused_interpret'
+    assert pp.resolve_impl() == 'xla'
+    assert pp.lora_fusion_impl() is None
+
+
+def test_chunk_read_of_a_bf16_pool_is_the_gather_on_a_tpu(monkeypatch):
+    """With the compiled routes there (the backend faked as a TPU), an
+    S>1 chunk of an unquantized pool traces the XLA gather and no
+    Pallas call, and `resolve_impl` calls that read 'xla': one name,
+    one program."""
+    monkeypatch.setattr(pp.jax, 'default_backend', lambda: 'tpu')
+    assert pp.resolve_impl(quantized=False) == 'xla'
+    batch, chunk, hkv, hq, hd, page = 2, 4, 2, 4, 128, 16
+    pool = jax.ShapeDtypeStruct((hkv, 9, page, hd), jnp.bfloat16)
+    rest = (jax.ShapeDtypeStruct((batch, chunk), jnp.int32),    # positions
+            jax.ShapeDtypeStruct((batch, 4), jnp.int32))        # table
+    q = jax.ShapeDtypeStruct((batch, chunk, hq, hd), jnp.bfloat16)
+    text = str(jax.make_jaxpr(pa.paged_chunk_attention)(
+        q, pool, pool, *rest))
+    assert 'pallas_call' not in text and 'gather' in text
+    # The same read of an int8 pool is the fused kernel.
+    scales = jax.ShapeDtypeStruct((9, page), jnp.float32)
+    int8_pool = jax.ShapeDtypeStruct(pool.shape, jnp.int8)
+    fused = jax.make_jaxpr(pa.paged_chunk_attention)(
+        q, int8_pool, int8_pool, *rest, scales, scales)
+    assert 'pallas_call' in str(fused)
 
 
 def test_reports_why_kernel_is_off():
@@ -453,49 +474,14 @@ def test_mesh_sharded_kernel_bit_identical():
     np.testing.assert_array_equal(np.asarray(out3), np.asarray(ref3))
 
 
-def test_upstream_kernel_is_shard_mapped_under_a_tensor_mesh(monkeypatch):
-    """The route the one-chip server takes on TPU (bf16 pool ->
-    upstream kernel) under --tensor N: the call must be shard_mapped
-    over kv heads like the in-repo kernel is, or GSPMD — to which a
-    Pallas call is opaque — gathers the head-sharded pool onto every
-    chip, each layer, each step. Runs the real upstream kernel in the
-    TPU interpreter (the one way a CPU can execute its DMAs)."""
-    from jax.experimental.pallas import tpu as pltpu
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from skypilot_tpu.parallel import mesh as mesh_lib
-    if len(jax.devices()) < 2:
-        pytest.skip('needs >= 2 host devices')
-    monkeypatch.setattr(pp, 'available', lambda: True)
-    mesh = mesh_lib.make_mesh(mesh_lib.MeshConfig(tensor=2),
-                              devices=jax.devices()[:2])
-    batch, hq, hkv, hd, page, total = 2, 8, 2, 128, 16, 9
-    keys = jax.random.split(jax.random.PRNGKey(0), 3)
-    k = jax.random.normal(keys[0], (hkv, total, page, hd), jnp.bfloat16)
-    v = jax.random.normal(keys[1], (hkv, total, page, hd), jnp.bfloat16)
-    q = jax.random.normal(keys[2], (batch, hq, hd), jnp.bfloat16)
-    tbl = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
-    lengths = jnp.asarray([37, 64], jnp.int32)
-    ref = pa.paged_decode_attention(q, k, v, lengths, tbl, impl='xla')
-    pool = NamedSharding(mesh, P('tensor'))
-    heads = NamedSharding(mesh, P(None, 'tensor', None))
-    args = (jax.device_put(q, heads), jax.device_put(k, pool),
-            jax.device_put(v, pool), lengths, tbl)
-    fn = jax.jit(lambda *a: pa.paged_decode_attention(*a, impl='kernel'))
-    with pltpu.force_tpu_interpret_mode(), mesh:
-        out = fn(*args)
-        hlo = fn.lower(*args).compile().as_text()
-    assert out.sharding.spec == P(None, 'tensor', None)
-    assert 'all-gather' not in hlo and 'all-to-all' not in hlo
-    # bf16 operands, f32 accumulation on both sides.
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               atol=2e-2, rtol=2e-2)
-
-
 def test_decode_kernel_is_shard_mapped_under_a_tensor_mesh(monkeypatch):
-    """The same for the route 'auto' now takes at these shapes: the
-    in-repo decode read runs per chip on its own kv-head slice, and
-    the engine-facing wrapper picks it from the pool's static shape."""
+    """The route a bf16 pool of 128-wide heads takes on a TPU, under
+    --tensor N: the call must be shard_mapped over kv heads, or GSPMD
+    — to which a Pallas call is opaque — gathers the head-sharded pool
+    onto every chip, each layer, each step. The in-repo decode read
+    runs per chip on its own kv-head slice (in the TPU interpreter,
+    the one way a CPU can execute its DMAs), and the engine-facing
+    wrapper picks it from the pool's static shape."""
     from jax.experimental.pallas import tpu as pltpu
     from jax.sharding import NamedSharding, PartitionSpec as P
     from skypilot_tpu.parallel import mesh as mesh_lib
@@ -513,7 +499,7 @@ def test_decode_kernel_is_shard_mapped_under_a_tensor_mesh(monkeypatch):
     heads = NamedSharding(mesh, P(None, 'tensor', None))
     placed = (jax.device_put(q, heads), jax.device_put(k_nan, pool),
               jax.device_put(v_nan, pool), lengths, tbl)
-    fn = jax.jit(lambda *a: pa.paged_decode_attention(*a))   # 'auto'
+    fn = jax.jit(lambda *a: pa.paged_decode_attention(*a))
     with pltpu.force_tpu_interpret_mode(), mesh:
         out = fn(*placed)
         hlo = fn.lower(*placed).compile().as_text()
@@ -528,8 +514,8 @@ def test_decode_kernel_is_shard_mapped_under_a_tensor_mesh(monkeypatch):
 
 @pytest.mark.parametrize('heads,page,want',
                          [(1, 16, 'decode'),     # 128-wide heads
-                          (2, 16, 'kernel'),     # 64-wide heads
-                          (1, 8, 'kernel')],     # half-tile pages
+                          (2, 16, 'xla'),        # 64-wide heads
+                          (1, 8, 'xla')],        # half-tile pages
                          ids=['d128', 'd64', 'page8'])
 def test_engine_names_the_route_its_decode_holds(monkeypatch, heads,
                                                  page, want):

@@ -25,6 +25,10 @@ from skypilot_tpu.parallel.serving import pool_copy_lines
 POOL = (8, 5120, 16, 128)      # [Hkv, pages, page, D]
 SLOTS, PAGES_PER_ROW, HQ = 32, 128, 32
 CHUNK = 256
+# GPT-2 124M's pool (12 heads of 64, no GQA) at 8 slots of 1024 tokens:
+# a shape neither Pallas read compiles, so its read is the XLA gather.
+POOL_D64 = (12, 513, 16, 64)
+SLOTS_D64, PAGES_PER_ROW_D64 = 8, 64
 
 
 @pytest.fixture(scope='module')
@@ -86,12 +90,11 @@ def _decode_layer(read):
     return layer
 
 
-DECODE_LAYER_AVALS = (
-    (POOL, jnp.bfloat16), (POOL, jnp.bfloat16),
-    ((SLOTS, HQ, POOL[3]), jnp.bfloat16),
-    ((SLOTS, POOL[0], POOL[3]), jnp.bfloat16),
-    ((SLOTS, POOL[0], POOL[3]), jnp.bfloat16), ((SLOTS,), jnp.int32),
-    ((SLOTS, PAGES_PER_ROW), jnp.int32))
+def _decode_layer_avals(pool, slots, q_heads, pages_per_row):
+    new = ((slots, pool[0], pool[3]), jnp.bfloat16)
+    return ((pool, jnp.bfloat16), (pool, jnp.bfloat16),
+            ((slots, q_heads, pool[3]), jnp.bfloat16), new, new,
+            ((slots,), jnp.int32), ((slots, pages_per_row), jnp.int32))
 
 
 def test_decode_write_and_decode_kernel_copy_no_pool(compile_for_chip,
@@ -105,7 +108,7 @@ def test_decode_write_and_decode_kernel_copy_no_pool(compile_for_chip,
     monkeypatch.setattr(pallas_paged, 'available', lambda: True)
     compiled = compile_for_chip(
         _decode_layer(pa.paged_decode_attention), (0, 1),
-        *DECODE_LAYER_AVALS)
+        *_decode_layer_avals(POOL, SLOTS, HQ, PAGES_PER_ROW))
     text = compiled.as_text()
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
@@ -116,20 +119,30 @@ def test_decode_write_and_decode_kernel_copy_no_pool(compile_for_chip,
     _assert_in_place(compiled, jnp.bfloat16)
 
 
-def test_decode_write_and_upstream_kernel_copy_no_pool(compile_for_chip):
-    """The same layer with the upstream Pallas call (`impl='kernel'`:
-    what pools of other shapes keep, and kernel_check's comparison)."""
-    from jax.experimental.pallas.ops.tpu.paged_attention import (
-        paged_attention)
-
-    def read(q, k_pages, v_pages, lengths, table):
-        return paged_attention(q * (POOL[3] ** -0.5), k_pages, v_pages,
-                               lengths, table, pages_per_compute_block=8)
-
-    compiled = compile_for_chip(_decode_layer(read), (0, 1),
-                                *DECODE_LAYER_AVALS)
-    assert 'tpu_custom_call' in compiled.as_text()
-    _assert_in_place(compiled, jnp.bfloat16)
+def test_decode_layer_of_64_wide_heads_compiles_as_the_gather(
+        compile_for_chip, monkeypatch):
+    """A pool the decode kernel refuses (GPT-2's 64-wide heads: a page
+    of one head is half a lane tile) through the same wrapper, the
+    backend steered as above: the read is the XLA gather, so the layer
+    compiles for the chip (the Pallas reads do not, at this shape) and
+    holds no Pallas call; the write in front of it is still the
+    aliasing form. NOT held to `pool_copy_lines`: XLA:TPU gives a
+    gather over 64-wide rows a page-major layout of the pool, a
+    whole-pool copy in and, behind the write, one out per pool array
+    (PERF.md section 7); no cell serves such a pool."""
+    from skypilot_tpu.ops import pallas_paged
+    monkeypatch.setattr(pallas_paged, 'available', lambda: True)
+    pool = jax.ShapeDtypeStruct(POOL_D64, jnp.bfloat16)
+    assert pallas_paged.decode_kernel_refusal(pool) is not None
+    assert pallas_paged.resolve_impl(decode_pool=pool) == 'xla'
+    compiled = compile_for_chip(
+        _decode_layer(pa.paged_decode_attention), (0, 1),
+        *_decode_layer_avals(POOL_D64, SLOTS_D64, POOL_D64[0],
+                             PAGES_PER_ROW_D64))
+    text = compiled.as_text()
+    assert 'tpu_custom_call' not in text
+    assert f'bf16[{SLOTS_D64},{POOL_D64[0]},{POOL_D64[3]}]' in text
+    assert ' dynamic-update-slice(' in text and ' scatter(' not in text
 
 
 @pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.int8],
