@@ -695,7 +695,8 @@ def check_stats(ctx: Ctx, stats: Dict[str, Any]) -> None:
     keep = {k: stats.get(k) for k in (
         'engine', 'attention_impl', 'kv_cache', 'engine_restarts',
         'soft_errors', 'healthy', 'decode_calls', 'tokens_committed',
-        'preemptions', 'prefill_chunks_run')}
+        'preemptions', 'prefill_chunks_run', 'first_tokens_deferred',
+        'first_tokens_synced')}
     keep['storage'] = stats.get('storage')
     keep['page_pool'] = stats.get('page_pool')
     keep['prefix_cache'] = stats.get('prefix_cache')
@@ -718,6 +719,13 @@ def check_stats(ctx: Ctx, stats: Dict[str, Any]) -> None:
     expect('engine_restarts', stats.get('engine_restarts'), 0)
     expect('soft_errors', stats.get('soft_errors'), 0)
     expect('healthy', stats.get('healthy'), True)
+    # serve_lm's defaults run the plain pipelined loop: no finished
+    # prompt's first token goes through the scheduler's blocking fetch.
+    expect('first_tokens_synced', stats.get('first_tokens_synced'), 0)
+    if not stats.get('first_tokens_deferred', 0) > 0:
+        problems.append(f'first_tokens_deferred='
+                        f'{stats.get("first_tokens_deferred")!r} '
+                        f'(expected every finished prompt)')
     expect('storage.kv_dtype', storage.get('kv_dtype'), 'bf16')
     expect('storage.weight_dtype', storage.get('weight_dtype'), 'bf16')
     expect('storage.weight_bytes', storage.get('weight_bytes'),

@@ -421,6 +421,11 @@ def make_server(rt: InferenceRuntime,
                 'prefill_token_budget': engine.prefill_budget,
                 'pipeline_decode': engine.pipeline_decode,
                 'prefill_chunks_run': engine.prefill_chunks_run,
+                # Finished prompts by the way their first token took:
+                # to the next round on the device, or through the
+                # scheduler's blocking fetch.
+                'first_tokens_deferred': engine.first_tokens_deferred,
+                'first_tokens_synced': engine.first_tokens_synced,
                 'prefill_backlog_tokens':
                     engine.prefill_backlog_tokens(),
                 # The scheduler loop's phases (docs/guides.md

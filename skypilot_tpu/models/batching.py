@@ -28,7 +28,11 @@ Two stall-free-scheduler mechanisms (Sarathi/vLLM split-fuse style):
     stays non-empty. Greedy outputs are token-for-token identical to
     the unpipelined loop; lanes that finish mid-pipeline leave one
     junk write past their last committed position (the same
-    write-before-read contract speculation relies on).
+    write-before-read contract speculation relies on). A prompt that
+    finishes prefilling joins the same way: its first token is
+    sampled on the device, fed to the next round from there and
+    fetched with that round's tokens, so the scheduler never waits
+    for a chunk.
 
 Use via `ContinuousBatchingEngine.submit(prompt) -> Future`, or the
 HTTP server in recipes/serve_lm.py (--continuous-batching).
@@ -68,6 +72,26 @@ def _bucket(n: int, cap: int) -> int:
     while b < n:
         b *= 2
     return min(b, cap)
+
+
+# The two small programs of the first-token handoff. Every operand has
+# the engine's [num_slots] (or scalar) shape and the slot is traced,
+# so neither compiles anew for another slot or another count of
+# prompts finished in a pass.
+@jax.jit
+def _stash_first_token(first_tokens, slot, token):
+    """`first_tokens` with `token` at `slot`; not donated: a round in
+    flight keeps the vector it was dispatched with."""
+    return first_tokens.at[slot].set(token.astype(jnp.int32))
+
+
+@jax.jit
+def _merge_cur_tokens(cont, sampled, joined, first_tokens, cur_token):
+    """A round's input tokens from their three sources: the round in
+    flight for continuing lanes, the handoff vector for lanes that
+    joined since, the host's `cur_token` for the rest."""
+    return jnp.where(cont, sampled,
+                     jnp.where(joined, first_tokens, cur_token))
 
 
 class PrefixCache:
@@ -269,6 +293,7 @@ class ContinuousBatchingEngine:
         'slot_keys': 'scheduler',
         # dispatch plumbing
         '_rng': 'scheduler', '_inflight': 'scheduler',
+        '_first_tokens': 'scheduler', '_first_pending': 'scheduler',
         '_prefill_fns': 'scheduler', '_scatter_fns': 'scheduler',
         '_cache_shardings': 'scheduler',
         # pipeline-stage dispatch state (PR 19): the per-group
@@ -280,6 +305,8 @@ class ContinuousBatchingEngine:
         # counters (scrape threads read these racily, on purpose)
         'decode_calls': 'scheduler', 'tokens_committed': 'scheduler',
         'preemptions': 'scheduler', 'prefill_chunks_run': 'scheduler',
+        'first_tokens_deferred': 'scheduler',
+        'first_tokens_synced': 'scheduler',
         'phases': 'scheduler',
         'last_prefill_tokens': 'scheduler',
         'kv_restored_pages': 'scheduler',
@@ -645,6 +672,11 @@ class ContinuousBatchingEngine:
         self.tokens_committed = 0
         self.preemptions = 0
         self.prefill_chunks_run = 0
+        # Finished prompts by how their first token reached the decode
+        # loop: handed to the next round on the device, or fetched in
+        # the blocking sync (/stats, /metrics).
+        self.first_tokens_deferred = 0
+        self.first_tokens_synced = 0
         # The scheduler loop's phases (observability/tracing.phase):
         # self seconds and counts per name, served by /stats
         # (`phases`, `loop_s`, `decode_stall_s`) and /metrics.
@@ -704,6 +736,17 @@ class ContinuousBatchingEngine:
         # Pipelined decode: the dispatched-but-not-committed round
         # (device token array + the host state it was built from).
         self._inflight: Optional[Dict[str, Any]] = None
+        # The plain pipelined loop (one program, one token a round:
+        # the constructor refuses pipeline_decode beside spec_k or
+        # decode_chunk) takes a finished prompt's first token from the
+        # device: `_first_tokens[slot]` holds it, `_first_pending`
+        # marks the lanes whose token no round has carried yet. The
+        # loops that read `cur_token` on the host (speculative drafts,
+        # decode chunks, the unpipelined step) or keep a ring of their
+        # own (stages) fetch it in `_sync_first_tokens`.
+        self._defer_first = self.pipeline_decode and self.stages == 1
+        self._first_tokens = jnp.zeros((num_slots,), jnp.int32)
+        self._first_pending = np.zeros((num_slots,), bool)
         # Staged decode ring: one in-flight round per slot GROUP
         # (contiguous num_slots/stages slice) — up to S rounds in
         # flight, each occupying a different stage of the chain.
@@ -2281,7 +2324,11 @@ class ContinuousBatchingEngine:
         chunked prefill -> one decode round for the active slots. Long
         prompts therefore interleave with decoding instead of stalling
         it; with pipelining the decode round's host commit overlaps
-        the NEXT round's device compute.
+        the NEXT round's device compute, and a prompt that finishes
+        hands its first token to that round on the device
+        (`_hand_first_tokens`), so the iteration waits for the device
+        once, in the commit's fetch. The other decode loops fetch the
+        first token before their round (`_sync_first_tokens`).
 
         Every stretch of it is one of the `engine.*` phases
         (docs/guides.md "Scheduler phases"): exclusive, and together
@@ -2377,6 +2424,11 @@ class ContinuousBatchingEngine:
         self._soft_errors = 0
         self._inflight = None
         self._group_inflight = [None] * self.stages
+        # First tokens not yet carried by a round die with the cache
+        # they were sampled from (a fresh vector: the old one may hang
+        # on the program that failed).
+        self._first_pending[:] = False
+        self._first_tokens = jnp.zeros((self.num_slots,), jnp.int32)
         try:
             self.cache = self._fresh_cache()
         except Exception:  # pylint: disable=broad-except
@@ -2769,8 +2821,7 @@ class ContinuousBatchingEngine:
 
     def _sample_first(self, slot: int, last_logits):
         """The continuation token from the final chunk's last-position
-        logits (device value; fetched in one batched device_get per
-        round by _prefill_work)."""
+        logits: a device scalar, enqueued and not waited for."""
         temp = float(self.temps[slot])
         if temp > 0:
             self._rng, sub = jax.random.split(self._rng)
@@ -2794,7 +2845,7 @@ class ContinuousBatchingEngine:
         budget = self.prefill_budget if self.prefill_chunk else None
         spent = 0
         chunks0 = self.prefill_chunks_run
-        done: List[Any] = []    # (slot, first-token device scalar)
+        done: List[Any] = []    # (slot, last-position logits)
         while self._prefill_order:
             slot = self._prefill_order[0]
             plen = int(self.prompt_len[slot])
@@ -2805,8 +2856,7 @@ class ContinuousBatchingEngine:
             if budget is not None and spent + n > budget:
                 break   # budget spent: decode steps run first
             # One phase a chunk: the dispatch (which returns at
-            # enqueue), the slot's bookkeeping and, after a prompt's
-            # last chunk, the enqueue of its first-token sampling.
+            # enqueue) and the slot's bookkeeping.
             with tracing.phase('engine.prefill_dispatch',
                                self.phases) as chunk:
                 self.flight.record('chunk_dispatch', slot=slot,
@@ -2833,7 +2883,7 @@ class ContinuousBatchingEngine:
                 self.pos[slot] = offset
                 if offset >= plen:
                     self._prefill_order.popleft()
-                    done.append((slot, self._sample_first(slot, last)))
+                    done.append((slot, last))
             self.metrics.prefill_chunk_seconds.observe(chunk.dur)
             tracing.record_span('engine.prefill_chunk',
                                 self._slot_ctx[slot], chunk.dur,
@@ -2854,19 +2904,49 @@ class ContinuousBatchingEngine:
             self._prefill_bubble = sched.bubble_fraction
         if not done:
             return
-        # ONE host/device sync for every prompt that completed this
-        # round (not one per admission). The scheduler stands still
-        # for it: no round is dispatched meanwhile.
+        # One phase a pass that finishes prompts: each one's sampling
+        # enqueue (in completion order: the `_rng` splits follow it)
+        # and its slot's activation, around the handoff of the token.
         with tracing.phase('engine.first_token_sync', self.phases):
-            firsts = jax.device_get([first for _, first in done])
-            now = time.perf_counter()
-            for (slot, _), first in zip(done, firsts):
-                self.cur_token[slot] = int(first)
+            firsts = [(slot, self._sample_first(slot, last))
+                      for slot, last in done]
+            if self._defer_first:
+                self._hand_first_tokens(firsts)
+            else:
+                self._sync_first_tokens(firsts)
+            for slot, _ in firsts:
                 self.pos[slot] = int(self.prompt_len[slot])
                 self.prefilling[slot] = False
                 self.active[slot] = True
-                self.metrics.prefill_seconds.observe(
-                    now - self._prefill_t0[slot])
+
+    def _hand_first_tokens(self, firsts: List[Any]) -> None:
+        """The plain pipelined loop's handoff: each first token stays
+        on the device, in engine state, so that a dispatch retried
+        after a fault finds it again. `_dispatch_round` feeds it to
+        the lane's first round and `_commit_round` fetches it with
+        that round's tokens: the scheduler waits for nothing here."""
+        for slot, first in firsts:
+            self._first_tokens = _stash_first_token(
+                self._first_tokens, slot, first)
+            self._first_pending[slot] = True
+        self.first_tokens_deferred += len(firsts)
+        self.metrics.first_tokens_deferred.inc(len(firsts))
+
+    def _sync_first_tokens(self, firsts: List[Any]) -> None:
+        """The blocking handoff of the loops that need `cur_token` on
+        the host before their next dispatch (speculative drafts,
+        decode chunks, the unpipelined step) or keep a ring of their
+        own (stages): ONE device_get for every prompt the pass
+        finished. The scheduler stands still for it, behind whatever
+        the device still has queued."""
+        tokens = jax.device_get([first for _, first in firsts])
+        now = time.perf_counter()
+        for (slot, _), first in zip(firsts, tokens):
+            self.cur_token[slot] = int(first)
+            self.metrics.prefill_seconds.observe(
+                now - self._prefill_t0[slot])
+        self.first_tokens_synced += len(firsts)
+        self.metrics.first_tokens_synced.inc(len(firsts))
 
     def prefill_backlog_tokens(self) -> int:
         """Prompt-suffix tokens admitted but not yet prefilled (the
@@ -3096,10 +3176,12 @@ class ContinuousBatchingEngine:
             if not self.active.any():
                 return  # _grow_pages may have failed the last slot
             extra = (jnp.asarray(self.page_table),)
-        # Inactive slots decode at position 0 as a no-op: dense caches
-        # get their row scribbled at position 0 (zeroed on prefill);
-        # paged writes land in the trash page. PREFILLING slots ride
-        # at their frontier, which the next chunk overwrites before
+        # Every lane rides the round; an inactive one's token is
+        # dropped at the commit. Its write lands at whatever `pos`
+        # holds: an empty slot's last position (its page-table row is
+        # zeroed on release, so a paged write goes to the trash page;
+        # a dense row is zeroed by the next prefill), a PREFILLING
+        # slot's frontier, which the next chunk overwrites before
         # attending.
         self.cache, sampled = self._decode(
             self.params, self.cache,
@@ -3135,7 +3217,9 @@ class ContinuousBatchingEngine:
     def _fetch_tokens(self, dev) -> 'np.ndarray':
         """device_get with decode-stall accounting: the wall time the
         host spends blocked here is exactly the serial host/device
-        bubble pipelining exists to hide."""
+        bubble pipelining exists to hide. `dev` is a round's token
+        array, or a tuple of same-shaped arrays fetched together and
+        returned stacked."""
         faults.point('engine.device_get')
         with tracing.phase('engine.fetch_wait', self.phases) as wait:
             out = np.asarray(jax.device_get(dev))
@@ -3158,10 +3242,12 @@ class ContinuousBatchingEngine:
     def _dispatch_round(self, inflight: Optional[Dict[str, Any]]
                         ) -> Optional[Dict[str, Any]]:
         """Dispatch the next decode round WITHOUT waiting for the
-        in-flight one: continuing lanes feed the in-flight round's
-        (device-resident) sampled tokens straight back as inputs —
-        no host round-trip — at position +1; lanes that joined since
-        (fresh prefills) take their host-side first token. A lane the
+        in-flight one or for a prompt's last chunk: continuing lanes
+        feed the in-flight round's (device-resident) sampled tokens
+        straight back as inputs — no host round-trip — at position
+        +1; lanes that joined since take their first token from the
+        handoff vector, on the device too (`_hand_first_tokens`); any
+        other lane rides with the host's `cur_token`. A lane the
         pending commit will retire gets a junk write one past its
         last position (write-before-read keeps it harmless)."""
         if self.paged:
@@ -3171,9 +3257,11 @@ class ContinuousBatchingEngine:
                              else 1)
             if not self.active.any():
                 return None
+        joined = self._first_pending & self.active
         if inflight is None:
-            cur = jnp.asarray(self.cur_token)
+            cont = np.zeros((self.num_slots,), bool)
             pos = self.pos.copy()
+            sampled = self._first_tokens    # no lane reads it
         else:
             cont = np.array(
                 [bool(inflight['mask'][s]) and bool(self.active[s])
@@ -3181,8 +3269,9 @@ class ContinuousBatchingEngine:
                  for s in range(self.num_slots)])
             pos = np.where(cont, inflight['pos'] + 1,
                            self.pos).astype(np.int32)
-            cur = jnp.where(jnp.asarray(cont), inflight['sampled'],
-                            jnp.asarray(self.cur_token))
+            sampled = inflight['sampled']
+        cur = _merge_cur_tokens(cont, sampled, joined,
+                                self._first_tokens, self.cur_token)
         extra = (jnp.asarray(self.page_table),) if self.paged else ()
         self._rng, sub = jax.random.split(self._rng)
         self.cache, sampled = self._decode(
@@ -3190,16 +3279,31 @@ class ContinuousBatchingEngine:
             jnp.asarray(self.temps), jnp.asarray(self.top_ks),
             jnp.asarray(self.top_ps), sub, *extra,
             **self._lora_args())
+        # This round carries the joined lanes' first tokens; its
+        # commit fetches them from the vector as it stands now.
+        self._first_pending[:] = False
         self.decode_calls += 1
         self.metrics.decode_steps.inc()
         return {'sampled': sampled, 'mask': self.active.copy(),
-                'pos': pos, 'futs': list(self.futures)}
+                'pos': pos, 'futs': list(self.futures),
+                'joined': joined, 'first_tokens': self._first_tokens}
 
     def _commit_round(self, inflight: Dict[str, Any]) -> None:
         """Fetch + commit a dispatched round. Lanes whose request
         finished, was preempted, or was replaced since dispatch are
-        discarded (their round-N+1 token belongs to nobody)."""
-        sampled = self._fetch_tokens(inflight['sampled'])
+        discarded (their round-N+1 token belongs to nobody). A lane
+        that joined in this round learns its first token here, in the
+        same fetch: the host's `cur_token` and the admission-to-
+        first-token histogram take it before the lane's first commit
+        streams it."""
+        joined = inflight['joined']
+        firsts = None
+        if joined.any():
+            sampled, firsts = self._fetch_tokens(
+                (inflight['sampled'], inflight['first_tokens']))
+        else:
+            sampled = self._fetch_tokens(inflight['sampled'])
+        now = time.perf_counter()
         with tracing.phase('engine.commit', self.phases):
             for slot in range(self.num_slots):
                 if not inflight['mask'][slot]:
@@ -3207,6 +3311,10 @@ class ContinuousBatchingEngine:
                 if not self.active[slot] or \
                         self.futures[slot] is not inflight['futs'][slot]:
                     continue
+                if joined[slot]:
+                    self.cur_token[slot] = int(firsts[slot])
+                    self.metrics.prefill_seconds.observe(
+                        now - self._prefill_t0[slot])
                 self._commit_token(slot, int(sampled[slot]))
 
     def _pipelined_decode_step(self) -> None:

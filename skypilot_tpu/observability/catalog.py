@@ -51,6 +51,15 @@ SPECS: Dict[str, Tuple] = {
     'skypilot_serving_preemptions_total': (
         'counter', 'Requests preempted by KV page-pool pressure '
                    '(re-queued for recompute)', ('engine',)),
+    'skypilot_serving_first_tokens_deferred_total': (
+        'counter', 'Finished prompts whose first token went to the '
+                   'next decode round on the device (the plain '
+                   'pipelined loop)', ('engine',)),
+    'skypilot_serving_first_tokens_synced_total': (
+        'counter', 'Finished prompts whose first token the scheduler '
+                   'fetched in a blocking sync before its next '
+                   'dispatch (speculative, chunked, unpipelined and '
+                   'staged loops)', ('engine',)),
     'skypilot_serving_decode_steps_total': (
         'counter', 'Jitted decode dispatches (plain, chunked, or '
                    'speculative-verify rounds)', ('engine',)),
@@ -471,6 +480,11 @@ class EngineMetrics:
             'skypilot_serving_admissions_total').labels(**lab)
         self.preemptions = counter(
             'skypilot_serving_preemptions_total').labels(**lab)
+        self.first_tokens_deferred = counter(
+            'skypilot_serving_first_tokens_deferred_total').labels(
+                **lab)
+        self.first_tokens_synced = counter(
+            'skypilot_serving_first_tokens_synced_total').labels(**lab)
         self.decode_steps = counter(
             'skypilot_serving_decode_steps_total').labels(**lab)
         self.tokens_committed = counter(

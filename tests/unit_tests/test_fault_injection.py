@@ -344,6 +344,42 @@ def test_poison_decode_step_outputs_bit_identical(tiny_model):
         eng.stop()
 
 
+@pytest.mark.parametrize('temperature', [0.0, 0.8])
+def test_poison_decode_step_right_after_a_prompt_finishes(
+        tiny_model, temperature):
+    """The first decode step of a request comes right after its
+    prompt's last chunk, with the first token handed over on the
+    device and unknown to the host. A fault raised there, before the
+    dispatch, leaves the token in the engine's state: the retried
+    round finds it and serves what a clean engine serves, greedy and
+    sampled (the sampling's split was drawn once, before the fault)."""
+    def run(poison):
+        eng = _engine(tiny_model)
+        try:
+            if poison:
+                faults.install_plan({'rules': [
+                    {'point': 'engine.decode_step', 'action': 'raise',
+                     'exc': 'RuntimeError',
+                     'message': 'poison first step', 'times': 1}]})
+            out = eng.submit([1, 2, 3, 4], max_new_tokens=10,
+                             temperature=temperature).result(
+                                 timeout=120)
+            fired = faults.stats()['engine.decode_step']['fired'] \
+                if poison else 0
+            assert eng.engine_restarts == 0 and eng.healthy()
+            assert (eng.first_tokens_deferred,
+                    eng.first_tokens_synced) == (1, 0)
+            return out, fired, eng.soft_errors_total
+        finally:
+            faults.clear()
+            eng.stop()
+
+    clean, _, _ = run(False)
+    poisoned, fired, soft_errors = run(True)
+    assert (fired, soft_errors) == (1, 1)
+    assert poisoned == clean and len(clean) == 4 + 10
+
+
 def test_poison_prefill_chunk_fails_only_that_slot(tiny_model):
     """(b): crash-only isolation — the poisoned request fails with
     the injected error; a sibling admitted alongside completes, and

@@ -251,6 +251,25 @@ def _run_engine(model, params, prompts, *, mesh=None, n=8, slots=2,
         eng.stop()
 
 
+def test_staged_ring_keeps_the_blocking_first_token_fetch(setup):
+    """The S-deep ring has in-flight rounds of its own a group, so it
+    does not take the plain pipelined loop's first-token handoff on
+    the device (ROADMAP S5b): a finished prompt's token is fetched,
+    and counted as synced."""
+    model, params, mesh = setup
+    eng = ContinuousBatchingEngine(model, params, num_slots=2,
+                                   max_total_len=48, mesh=mesh)
+    try:
+        assert eng.pipeline_decode and eng.stages == 2
+        row = eng.submit(list(PROMPTS[0]), max_new_tokens=2).result(
+            timeout=300)
+        assert len(row) == len(PROMPTS[0]) + 2
+        assert (eng.first_tokens_deferred, eng.first_tokens_synced) \
+            == (0, 1)
+    finally:
+        eng.stop()
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize('variant', ['bf16', 'int8kv', 'chunk_prefill',
                                      'spec'])
