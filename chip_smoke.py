@@ -36,6 +36,11 @@ What runs (and nothing else: no sweeps, no A/B, no metrics table):
            a compiled attention route, a paged pool, a prefix hit,
            true weight bytes, no engine restart and no contained
            error.
+  serve-latent  (one chip) the serve stage again on
+           `deepseek-v32-tiny`, whose page pool holds MLA's latent
+           rows and the indexer's keys (the model's own page layout):
+           the same checks, the route `sparse_latent_xla`, no
+           pool-shaped copy around either array's write.
   (4 chips) one `train_lm --zero1 --overlap` step, per-device bytes
            from both processes (no chip empty, chip 0 at most twice
            the mean) and the sharded-pool guard on the decode function
@@ -116,6 +121,21 @@ def llama_params(vocab, embed, layers, heads, kv_heads, mlp) -> int:
 
 LLAMA3_8B = dict(vocab=128256, embed=4096, heads=32, kv_heads=8,
                  mlp=14336)
+
+
+#: The latent page layout's stage (one chip): a short request through
+#: `deepseek-v32-tiny`, every V3.2 mechanism at a size that compiles
+#: in seconds (MLA rows and indexer keys in the page pool, the top-16
+#: selection, routed experts by share). 1,475,200 parameters, counted
+#: by `jax.eval_shape` (tests/unit_tests/test_deepseek_v32.py).
+LATENT = dict(
+    serve_model='deepseek-v32-tiny', serve_params=1475200,
+    serve_args=['--max-total-len', '256', '--num-slots', '4',
+                '--prefill-chunk', '32', '--kv-pool-bytes',
+                str(8 << 20)],
+    lengths=[(5, 8), (17, 30), (70, 90), (33, 47)],
+    max_new=6, attention_impl='sparse_latent_xla', default_pages=128,
+    prompt_vocab=512)
 
 
 def preset(chips: int, rehearse: bool) -> Dict[str, Any]:
@@ -499,7 +519,8 @@ class Client:
 
 def make_prompts(ctx: Ctx, seed: int) -> List[List[int]]:
     rng = random.Random(seed)
-    vocab = LLAMA3_8B['vocab'] if not ctx.args.rehearse else 512
+    vocab = ctx.cfg.get('prompt_vocab') or (
+        LLAMA3_8B['vocab'] if not ctx.args.rehearse else 512)
     return [[rng.randrange(1, vocab) for _ in range(rng.randint(lo, hi))]
             for lo, hi in ctx.cfg['lengths']]
 
@@ -750,6 +771,19 @@ def check_stats(ctx: Ctx, stats: Dict[str, Any]) -> None:
         raise SmokeFailure('serve: /stats: ' + '; '.join(problems))
 
 
+def stage_serve_latent(ctx: Ctx) -> None:
+    """The serve stage again, on the model whose page pool holds
+    latent rows: the same checks (the scored completion, the prefix
+    hit, the in-place write of BOTH of its pool arrays, no blocking
+    first-token fetch), the route `sparse_latent_xla`."""
+    saved = ctx.cfg
+    ctx.cfg = dict(saved, **LATENT)
+    try:
+        stage_serve(ctx)
+    finally:
+        ctx.cfg = saved
+
+
 # -- main ---------------------------------------------------------------------
 def main() -> int:
     global PREFIX
@@ -812,6 +846,8 @@ def main() -> int:
     if args.chips > 1:
         stages.append(stage_overlap)
     stages.append(stage_serve)
+    if args.chips == 1:
+        stages.append(stage_serve_latent)
     t0 = time.monotonic()
     ok = False
     try:

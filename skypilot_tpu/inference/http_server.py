@@ -476,6 +476,13 @@ def make_server(rt: InferenceRuntime,
                 'max_queue_requests': engine.max_queue_requests,
                 'max_queue_tokens': engine.max_queue_tokens,
             })
+            # A model's own device counters (a routed model's
+            # `expert_tokens` / `expert_calls_touched`, each
+            # {block: [[decode...], [prefill...]]} over the held
+            # experts; `sparse_decode_tokens`): top-level keys.
+            for name, blocks in engine.model_counters().items():
+                body[name] = (blocks[''] if set(blocks) == {''}
+                              else blocks)
             if engine.paged:
                 free = int(engine.allocator.free_pages)
                 body['page_pool'] = {
@@ -494,6 +501,12 @@ def make_server(rt: InferenceRuntime,
                     'pool_bytes_per_device':
                         engine.kv_cache_bytes_per_device(),
                     'shard_ways': engine.kv_shard_ways,
+                    # What a cached token's row is made of, as the
+                    # model's page layout gives it, and its bytes
+                    # over all layers.
+                    'row_layout': engine.page_layout.describe(
+                        engine.model.config.num_layers,
+                        1 if engine.kv_dtype == 'int8' else 2),
                 }
                 if engine.stages > 1:
                     # Staged pool split: every stage stores the same
@@ -1163,7 +1176,16 @@ def make_server(rt: InferenceRuntime,
                 final_rows = [h.future.result() for h in handles]
             # Full rows in the terminal event: stream consumers get
             # the same payload the non-streaming endpoint returns.
-            self.sse_send({'done': True, 'tokens': final_rows})
+            # Under `--stream-final lengths` it gives the rows' lengths
+            # instead: the stream has carried every generated token
+            # and the prompt is the caller's own, and a 14k-token row
+            # is an 85 KB line, past what a line-buffered client takes
+            # (asyncio's stream reader stops at 64 KiB).
+            if rt.stream_final == 'lengths':
+                self.sse_send({'done': True,
+                               'lengths': [len(r) for r in final_rows]})
+            else:
+                self.sse_send({'done': True, 'tokens': final_rows})
             self.sse_done()
             rt.metrics.record(time.monotonic() - t0, n_gen,
                               ttft_s=ttft,

@@ -167,22 +167,32 @@ def kv_page_bytes(cfg, kv_dtype: str, shard_ways: int = 1,
     remainder) bounds the per-chip cost, so an S-stage T-way mesh
     holds ~S·T x the pages at the same per-chip budget."""
     import jax.numpy as jnp
-    per_layer = 2 * cfg.num_kv_heads * cfg.kv_page_size * cfg.head_dim
-    if cfg.num_kv_heads % shard_ways:
+    # The token's row comes from the model (ops/paged_attention
+    # .PageLayout): K and V heads, or a latent row and an indexer key.
+    layout = cfg.page_layout()
+    per_layer = layout.row_values * cfg.kv_page_size
+    item = jnp.dtype(cfg.dtype).itemsize
+    heads = layout.arrays[0].heads
+    if heads % shard_ways:
         raise ValueError(
             f'shard_ways={shard_ways} does not divide num_kv_heads='
-            f'{cfg.num_kv_heads} (the GQA remainder rule replicates '
+            f'{heads} (the GQA remainder rule replicates '
             f'instead — pass shard_ways=1)')
+    if kv_dtype == 'int8' and layout.kind != 'kv':
+        raise ValueError(
+            f'--kv-dtype int8 stores K/V pages with per-token scales; '
+            f'a {layout.kind!r} page layout has no int8 form yet '
+            f'(ROADMAP R-M1: int8 latent pages)')
     if stages < 1 or stages > cfg.num_layers:
         raise ValueError(
             f'stages={stages} must be in [1, num_layers='
             f'{cfg.num_layers}]')
     if kv_dtype == 'int8':
         value_bytes = per_layer // shard_ways
-        scale_bytes = 2 * cfg.kv_page_size * 4
+        scale_bytes = len(layout.arrays) * cfg.kv_page_size * 4
     else:
-        value_bytes = (per_layer // shard_ways *
-                       jnp.dtype(cfg.dtype).itemsize)
+        value_bytes = (layout.row_bytes(item) * cfg.kv_page_size
+                       // shard_ways)
         scale_bytes = 0
     stage_layers = -(-cfg.num_layers // stages)  # ceil: widest stage
     return stage_layers * (value_bytes + scale_bytes)

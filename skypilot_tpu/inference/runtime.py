@@ -226,6 +226,10 @@ def iter_interleaved(handles: List[StreamHandle]):
             time.sleep(0.005)
 
 
+#: What a token stream's terminal event may carry (--stream-final).
+STREAM_FINAL = ('rows', 'lengths')
+
+
 class InferenceRuntime:
     """Everything needed to execute generation requests.
 
@@ -252,8 +256,14 @@ class InferenceRuntime:
                  weight_dtype: str = 'bf16',
                  role: str = '',
                  decode_peers: Optional[List[str]] = None,
-                 mesh=None) -> None:
+                 mesh=None, stream_final: str = 'rows') -> None:
         import jax
+        if stream_final not in STREAM_FINAL:
+            raise ValueError(f'stream_final {stream_final!r}: one of '
+                             f'{STREAM_FINAL}')
+        # What a token stream's terminal event carries
+        # (http_server: --stream-final).
+        self.stream_final = stream_final
         self.model = model
         self.params = params
         # Tensor-parallel serving mesh (None = single device): the
@@ -837,11 +847,17 @@ def build_runtime(args) -> InferenceRuntime:
     if kv_dtype != 'bf16' or kv_pool_bytes:
         cfg = model.config
         if getattr(cfg, 'kv_dtype', None) is None or \
-                getattr(cfg, 'kv_total_pages', 0) <= 0:
+                not hasattr(cfg, 'page_layout'):
             raise SystemExit(
-                f'--kv-dtype/--kv-pool-bytes need a paged-KV model '
-                f'config with a kv_dtype field (the Llama family); '
-                f'{type(cfg).__name__} has none')
+                f'--kv-dtype/--kv-pool-bytes need a model config that '
+                f'gives a page layout and a kv_dtype field (the Llama '
+                f'and DeepSeek families); {type(cfg).__name__} has none')
+        if kv_dtype == 'int8' and cfg.page_layout().kind != 'kv':
+            raise SystemExit(
+                f'--kv-dtype int8 stores K/V pages; '
+                f'{type(cfg).__name__} keeps '
+                f'{cfg.page_layout().kind!r} pages, which have no '
+                f'int8 form yet')
         if kv_dtype == 'int8' and not args.continuous_batching:
             raise SystemExit(
                 '--kv-dtype int8 requires --continuous-batching: the '
@@ -1112,7 +1128,8 @@ def build_runtime(args) -> InferenceRuntime:
         kv_dtype=kv_dtype,
         weight_dtype=('int8' if weight_dtype == 'int8'
                       else param_dtype),
-        role=role, decode_peers=decode_peers, mesh=mesh)
+        role=role, decode_peers=decode_peers, mesh=mesh,
+        stream_final=getattr(args, 'stream_final', 'rows'))
     from skypilot_tpu.observability import catalog as _obs_catalog
     _obs_catalog.gauge('skypilot_serving_weight_bytes').set(
         rt.weight_bytes)

@@ -449,8 +449,14 @@ class ContinuousBatchingEngine:
         # incremental page allocation. Auto-on for models that declare
         # kv_page_size/kv_total_pages (llama/gpt/mixtral) when the
         # pool can hold a full-depth sequence.
-        cfg_page = getattr(model.config, 'kv_page_size', 0)
-        cfg_pool = getattr(model.config, 'kv_total_pages', 0)
+        # What a cached token's row is made of comes from the model
+        # (ops/paged_attention.PageLayout): K and V heads, or MLA's
+        # latent row and indexer key. None: the model has no pool.
+        self.page_layout = (model.config.page_layout()
+                            if hasattr(model.config, 'page_layout')
+                            else None)
+        cfg_page = self.page_layout.page_size if self.page_layout else 0
+        cfg_pool = self.page_layout.total_pages if self.page_layout else 0
         # Speculative verify chunks write K tokens — and decode chunks
         # N-1 tokens — past the last committed one: the pool and each
         # row's page table carry that headroom.
@@ -508,6 +514,24 @@ class ContinuousBatchingEngine:
                 'kv_dtype=int8 requires the paged KV cache: the '
                 'dense per-slot cache has no scale storage (size the '
                 'kv page pool to hold max_total_len, or serve bf16)')
+        if self.paged and self.page_layout.kind != 'kv':
+            # What cannot take another layout than K/V yet refuses
+            # here, by name, and not silently (ROADMAP R-M1, D3a).
+            asked = {
+                'an int8 pool (--kv-dtype int8)': self.kv_dtype == 'int8',
+                'pipeline stages (--stages)': self.stages > 1,
+                'speculative decoding (--speculative)': bool(speculative_k),
+                'decode chunks (--decode-chunk)': decode_chunk > 1,
+                'the spill tier (--kv-spill-bytes, --kv-cold-dir)':
+                    bool(kv_spill_bytes or kv_cold_dir),
+                'LoRA adapters (--adapter-dir)': adapter_store is not None,
+            }
+            refused = [name for name, on in asked.items() if on]
+            if refused:
+                raise ValueError(
+                    f'a {self.page_layout.kind!r} page layout '
+                    f'({type(model.config).__name__}) does not serve '
+                    f'with {", ".join(refused)} yet')
         if self.paged:
             # A prompt's first chunk starts at 0. A later one starts a
             # whole number of pages (the prefix hit) and of chunks in:
@@ -620,8 +644,15 @@ class ContinuousBatchingEngine:
         # the scheduler's `self.cache`. None without a paged pool.
         self._pool_aval = next(
             (jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
-             for leaf in jax.tree.leaves(self.cache) if leaf.ndim == 4),
+             for leaf in jax.tree.leaves(self.cache)
+             if leaf.shape == self.page_layout.shape(
+                 self.page_layout.arrays[0])),
             None) if self.paged else None
+        # A model whose layers tell live tokens from the junk lanes
+        # that ride every round (routed experts, which would read an
+        # expert's weights for a junk token; its device counters) is
+        # handed the mask: `live` in its call.
+        self._takes_live = bool(getattr(model, 'takes_live_mask', False))
 
         # Host-side slot bookkeeping (device work stays fixed-shape).
         # A slot is OCCUPIED when `prefilling` (admitted, prompt
@@ -1093,10 +1124,12 @@ class ContinuousBatchingEngine:
                            **self._pin_cache_out(None))
         def decode(params, cache, cur_token, pos, temps, top_ks,
                    top_ps, rng, page_indices=None, lora=None,
-                   adapter_ids=None):
+                   adapter_ids=None, live=None):
             extra = {'page_indices': page_indices} if paged else {}
             if lora is not None:
                 extra.update(lora=lora, adapter_ids=adapter_ids)
+            if live is not None:
+                extra.update(live=live[:, None])
             logits, mutated = model.apply(
                 {'params': params, 'cache': cache},
                 cur_token[:, None], positions=pos[:, None], decode=True,
@@ -1245,6 +1278,7 @@ class ContinuousBatchingEngine:
             self._prefill_fns[bucket_len] = fn
             return fn
         model = self.model
+        takes_live = self._takes_live
         positions = jnp.arange(bucket_len, dtype=jnp.int32)[None, :]
         if self.paged:
 
@@ -1260,6 +1294,8 @@ class ContinuousBatchingEngine:
                 # stays chunk-local.
                 extra = ({'lora': lora, 'adapter_ids': adapter_ids}
                          if lora is not None else {})
+                if takes_live:
+                    extra['live'] = positions < plen
                 logits, mutated = model.apply(
                     {'params': params, 'cache': cache},
                     prompt[None, :], positions=positions,
@@ -1327,6 +1363,7 @@ class ContinuousBatchingEngine:
             return fn
         model = self.model
         aligned = self._suffix_page_aligned
+        takes_live = self._takes_live
 
         @functools.partial(jax.jit, donate_argnums=(1,),
                            **self._pin_cache_out(None))
@@ -1336,6 +1373,8 @@ class ContinuousBatchingEngine:
                      if lora is not None else {})
             positions = (offset +
                          jnp.arange(bucket_len, dtype=jnp.int32))[None, :]
+            if takes_live:
+                extra['live'] = positions < offset + suffix_len
             logits, mutated = model.apply(
                 {'params': params, 'cache': cache},
                 suffix[None, :], positions=positions,
@@ -1599,6 +1638,31 @@ class ContinuousBatchingEngine:
                         'pool_bytes_per_device': int(total)})
         return out
 
+    def model_counters(self) -> Dict[str, Any]:
+        """The model's device-side accumulators (`model.counter_leaves`:
+        a routed model's tokens and touched calls per held expert, the
+        sparse decode tokens), fetched now, as {leaf name: {block:
+        nested lists}} ({} for a model without any). The arrays ride
+        in the donated cache, so the read hops onto the scheduler
+        thread and waits for the program in flight: only /stats asks."""
+        names = tuple(getattr(self.model, 'counter_leaves', ()))
+        if not names or self.stages > 1:
+            return {}
+
+        def op():
+            flat, _ = jax.tree_util.tree_flatten_with_path(self.cache)
+            picked = {}
+            for path, leaf in flat:
+                keys = [getattr(e, 'key', None) for e in path]
+                if keys and keys[-1] in names:
+                    picked[(keys[-1], '/'.join(keys[:-1]))] = leaf
+            return jax.device_get(picked)
+
+        out: Dict[str, Any] = {}
+        for (name, block), value in self.run_on_scheduler(op).items():
+            out.setdefault(name, {})[block] = np.asarray(value).tolist()
+        return out
+
     def attention_impl(self) -> str:
         """The route this engine's traced decode read takes:
         ops/pallas_paged.resolve_impl given what that read gives it
@@ -1611,7 +1675,8 @@ class ContinuousBatchingEngine:
         from skypilot_tpu.ops import pallas_paged
         return pallas_paged.resolve_impl(
             quantized=self.kv_dtype == 'int8',
-            decode_pool=self._pool_aval)
+            decode_pool=self._pool_aval,
+            layout=self.page_layout.kind)
 
     def _compile_decode(self):
         """This engine's decode dispatch, lowered at its own shapes
@@ -1629,7 +1694,7 @@ class ContinuousBatchingEngine:
                 jax.random.PRNGKey(0)]
         if self.paged:
             args.append(jnp.asarray(self.page_table))
-        return self._decode.lower(*args).compile()
+        return self._decode.lower(*args, **self._live_args()).compile()
 
     def decode_pool_collectives(self) -> Optional[List[str]]:
         """The zero-resharding guard (parallel/serving
@@ -1687,6 +1752,17 @@ class ContinuousBatchingEngine:
         engines model their full-cache walk with no dequant term."""
         from skypilot_tpu.ops import pallas_paged
         cfg = self.model.config
+        if self.paged and self.page_layout.kind != 'kv':
+            # No K/V heads to model: the row's own bytes over the whole
+            # page table (what an index read walks), nothing else.
+            item = jnp.dtype(getattr(cfg, 'dtype', jnp.bfloat16)).itemsize
+            walked = self.pages_per_seq * self.page_size
+            pool = (walked * self.page_layout.row_bytes(item)
+                    * cfg.num_layers)
+            return {'impl': self.attention_impl(),
+                    'context_tokens_walked': walked,
+                    'kv_pool_bytes': pool,
+                    'total_bytes_per_token': float(pool)}
         if self._weight_bytes is None:
             from skypilot_tpu.inference import quant as quant_lib
             # Staged engines stream only ONE stage's weights per chip
@@ -1815,6 +1891,17 @@ class ContinuousBatchingEngine:
                 if self._cache_lost():
                     raise
 
+    def _refuse_other_layouts(self, what: str) -> None:
+        """The wire and spill formats pack K/V pages (and their int8
+        scales) with their geometry; a pool of another layout refuses
+        by name instead of shipping rows a peer would misread."""
+        if self.paged and self.page_layout.kind != 'kv':
+            raise ValueError(
+                f'{what} packs K/V pages; the '
+                f'{self.page_layout.kind!r} page layout of '
+                f'{type(self.model.config).__name__} has no wire form '
+                f'yet (ROADMAP R-M1)')
+
     def _gather_page_blobs(self, pages: List[int]
                            ) -> Dict[str, 'np.ndarray']:
         """Exact device bytes of physical pages `pages`, as
@@ -1827,6 +1914,8 @@ class ContinuousBatchingEngine:
         cross-device fetch; the decode path never does). Scheduler
         thread only."""
         from skypilot_tpu.ops import paged_attention as paged_ops
+        self._refuse_other_layouts('a page export (handoff, migration, '
+                                   'spill)')
         idx = jnp.asarray(pages, jnp.int32)
         # Staged engines: the per-stage trees use ABSOLUTE layer
         # names, so the union of their leaf paths IS the single-mesh
@@ -1925,6 +2014,7 @@ class ContinuousBatchingEngine:
         cached chain prefix, or None when nothing is cached (or
         prefix caching is off). Thread-safe: hops onto the scheduler
         thread; the chain is reference-pinned during the gather."""
+        self._refuse_other_layouts('export_chain')
         if not self.prefix_caching:
             return None
         toks = [int(t) for t in tokens]
@@ -1983,6 +2073,7 @@ class ContinuousBatchingEngine:
         dropped (chain order — a dropped page also drops its
         suffix's usefulness, counted for the caller). Raises
         ValueError on any geometry/dtype mismatch. Thread-safe."""
+        self._refuse_other_layouts('import_chain')
         if not self.prefix_caching:
             raise ValueError(
                 'import_chain needs the paged engine with prefix '
@@ -3142,6 +3233,16 @@ class ContinuousBatchingEngine:
                 'adapter_ids': jnp.asarray(self.slot_adapter,
                                            jnp.int32)}
 
+    def _live_args(self, staying: Optional[np.ndarray] = None
+                   ) -> Dict[str, Any]:
+        """`live` for a decode round of a model that takes it: the
+        lanes that hold a request (the others ride with junk), less
+        those the caller knows will have left it (`staying` False)."""
+        if not self._takes_live:
+            return {}
+        live = self.active if staying is None else self.active & staying
+        return {'live': jnp.asarray(live)}
+
     def _slot_lora_args(self, slot: int) -> Dict[str, Any]:
         """Extra kwargs for a batch-1 prefill dispatch of `slot`."""
         aid = int(self.slot_adapter[slot])
@@ -3188,7 +3289,7 @@ class ContinuousBatchingEngine:
             jnp.asarray(self.cur_token), jnp.asarray(self.pos),
             jnp.asarray(self.temps), jnp.asarray(self.top_ks),
             jnp.asarray(self.top_ps), sub, *extra,
-            **self._lora_args())
+            **self._lora_args(), **self._live_args())
         sampled = self._fetch_tokens(sampled)
         self.decode_calls += 1
         self.metrics.decode_steps.inc()
@@ -3249,7 +3350,9 @@ class ContinuousBatchingEngine:
         handoff vector, on the device too (`_hand_first_tokens`); any
         other lane rides with the host's `cur_token`. A lane the
         pending commit will retire gets a junk write one past its
-        last position (write-before-read keeps it harmless)."""
+        last position (write-before-read keeps it harmless); where the
+        in-flight token is the last its limit allows, that is known
+        now, and a model that takes `live` is told the lane is dead."""
         if self.paged:
             # +1 lookahead when a round is still uncommitted: this
             # dispatch writes at pos+1 for continuing lanes.
@@ -3270,6 +3373,12 @@ class ContinuousBatchingEngine:
             pos = np.where(cont, inflight['pos'] + 1,
                            self.pos).astype(np.int32)
             sampled = inflight['sampled']
+        staying = None
+        if self._takes_live:
+            staying = np.array(
+                [not cont[s] or
+                 len(self.outputs[s]) + 1 < int(self.limits[s])
+                 for s in range(self.num_slots)])
         cur = _merge_cur_tokens(cont, sampled, joined,
                                 self._first_tokens, self.cur_token)
         extra = (jnp.asarray(self.page_table),) if self.paged else ()
@@ -3278,7 +3387,7 @@ class ContinuousBatchingEngine:
             self.params, self.cache, cur, jnp.asarray(pos),
             jnp.asarray(self.temps), jnp.asarray(self.top_ks),
             jnp.asarray(self.top_ps), sub, *extra,
-            **self._lora_args())
+            **self._lora_args(), **self._live_args(staying))
         # This round carries the joined lanes' first tokens; its
         # commit fetches them from the vector as it stands now.
         self._first_pending[:] = False

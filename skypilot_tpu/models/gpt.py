@@ -56,6 +56,12 @@ class GPTConfig:
     def head_dim(self) -> int:
         return self.embed_dim // self.num_heads
 
+    def page_layout(self):
+        """A cached token's row in the page pool: K and V."""
+        from skypilot_tpu.ops import paged_attention as paged_ops
+        return paged_ops.kv_layout(self.num_heads, self.head_dim,
+                                   self.kv_page_size, self.kv_total_pages)
+
     @property
     def max_seq_len(self) -> int:
         """Alias matching the llama/mixtral configs (serving engines
@@ -97,12 +103,10 @@ class CausalSelfAttention(nn.Module):
         shape = (batch, seq, cfg.num_heads, cfg.head_dim)
         q, k, v = (t.reshape(shape) for t in (q, k, v))
         def _page_vars():
-            shape = (cfg.num_heads, cfg.kv_total_pages,
-                     cfg.kv_page_size, cfg.head_dim)
-            return (self.variable('cache', 'k_pages', jnp.zeros, shape,
-                                  cfg.dtype),
-                    self.variable('cache', 'v_pages', jnp.zeros, shape,
-                                  cfg.dtype))
+            layout = cfg.page_layout()
+            return tuple(self.variable('cache', a.name, jnp.zeros,
+                                       layout.shape(a), cfg.dtype)
+                         for a in layout.arrays)
 
         if decode and seq > 1:
             # CHUNKED decode (same contract as models/llama.py):

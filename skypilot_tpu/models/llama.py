@@ -92,6 +92,12 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.embed_dim // self.num_heads
 
+    def page_layout(self):
+        """A cached token's row in the page pool: K and V."""
+        from skypilot_tpu.ops import paged_attention as paged_ops
+        return paged_ops.kv_layout(self.num_kv_heads, self.head_dim,
+                                   self.kv_page_size, self.kv_total_pages)
+
 
 def rope_inv_freq(d_half: int, theta: float,
                   scaling: Optional[RopeScaling] = None) -> jax.Array:
@@ -229,14 +235,11 @@ class Attention(nn.Module):
                 '--continuous-batching and a paged-capable pool')
 
         def _page_vars():
-            shape = (cfg.num_kv_heads, cfg.kv_total_pages,
-                     cfg.kv_page_size, hd)
-            k_pages = self.variable(
-                'cache', 'k_pages', jnp.zeros, shape,
-                jnp.int8 if kv_quant else cfg.dtype)
-            v_pages = self.variable(
-                'cache', 'v_pages', jnp.zeros, shape,
-                jnp.int8 if kv_quant else cfg.dtype)
+            layout = cfg.page_layout()
+            k_pages, v_pages = (
+                self.variable('cache', a.name, jnp.zeros, layout.shape(a),
+                              jnp.int8 if kv_quant else cfg.dtype)
+                for a in layout.arrays)
             if not kv_quant:
                 return k_pages, v_pages, None, None
             # Parallel scale pages: one f32 per cached token (page

@@ -60,6 +60,10 @@ class MixtralConfig:
     def head_dim(self) -> int:
         return self.embed_dim // self.num_heads
 
+    def page_layout(self):
+        """A cached token's row in the page pool: K and V."""
+        return self.as_llama().page_layout()
+
     def as_llama(self) -> llama_lib.LlamaConfig:
         return llama_lib.LlamaConfig(
             vocab_size=self.vocab_size, max_seq_len=self.max_seq_len,

@@ -20,7 +20,11 @@ cells' 32 slots (every fourth of length 0), alone and under a tensor
 mesh of the host's chips. `xla_paged_attention/bf16/D=64/S=1` is the
 read a pool of GPT-2's 64-wide heads takes, which no Pallas kernel
 here compiles: the XLA gather, compiled for the chip and held to the
-same reference in float32. It
+same reference in float32. The `sparse_latent/...` cases are the three
+device paths of a latent page pool (DeepSeek-V3.2's widths, the
+benchmark cell's 48 slots, two in three of length 0, and 512-token
+chunks), plain XLA too, each
+held to itself in float32. It
 exits non-zero when any case is not `ok`, and when the backend is not
 a TPU: a CPU run of this file would check nothing.
 
@@ -257,6 +261,109 @@ def _flash(batch: int, heads: int, kv_heads: int, head_dim: int,
     return case
 
 
+# DeepSeek-V3.2's latent page layout at the benchmark cell's shapes:
+# 128 heads over a row of 512 + 64 values in 640, 64 index heads of 128, the 2,048
+# best of up to 16,384 positions, 48 slots of which every third holds a
+# request (the others have length 0), 512-token chunks.
+V32_HEADS, V32_RANK, V32_ROW = 128, 512, 640   # 512 + 64 in 640
+V32_INDEX_HEADS, V32_INDEX_DIM, V32_TOPK = 64, 128, 2048
+V32_PAGES_PER_SEQ, V32_BATCH, V32_CHUNK = 1024, 48, 512
+
+
+def _sparse_latent(path: str) -> Callable[[Any], Dict]:
+    """One of ops/sparse_latent.py's three device paths (the route
+    'sparse_latent_xla' of a latent pool: bf16 latent rows, float32
+    index queries and keys) against the same path on the operands in
+    float32 at `highest` precision:
+    'index' the decode round's index scores over the paged keys,
+    'attend' its selection and the attention over the selected rows
+    (both sides select on the same float32 scores), 'chunk' a
+    512-token prefill chunk 5,632 tokens into its context."""
+
+    def case(key):
+        import jax
+        import jax.numpy as jnp
+        from skypilot_tpu.ops import pallas_paged, sparse_latent
+        got = pallas_paged.resolve_impl(layout='latent')
+        if got != 'sparse_latent_xla':
+            return _wrong_route('sparse_latent_xla', got)
+        batch = 1 if path == 'chunk' else V32_BATCH
+        total = batch * V32_PAGES_PER_SEQ + 1
+        keys = jax.random.split(key, 8)
+        bf16 = jnp.bfloat16
+        latent = jax.random.normal(
+            keys[0], (1, total, PAGE, V32_ROW), bf16)
+        index_k = jax.random.normal(           # float32, as cached
+            keys[1], (1, total, PAGE, V32_INDEX_DIM), jnp.float32)
+        tbl = (jax.random.permutation(keys[2], total - 1) + 1).reshape(
+            batch, V32_PAGES_PER_SEQ).astype(jnp.int32)
+        scale = 192 ** -0.5
+        f32 = lambda *xs: [x.astype(jnp.float32) for x in xs]  # noqa: E731
+        if path == 'chunk':
+            q = jax.random.normal(
+                keys[3], (1, V32_CHUNK, V32_HEADS, V32_ROW),
+                bf16)
+            q_idx = jax.random.normal(
+                keys[4], (1, V32_CHUNK, V32_INDEX_HEADS, V32_INDEX_DIM),
+                jnp.float32)
+            w_idx = jax.random.normal(
+                keys[5], (1, V32_CHUNK, V32_INDEX_HEADS), jnp.float32)
+            positions = (5632 + jnp.arange(V32_CHUNK))[None]
+
+            def chunk(q, q_idx, w_idx, latent, index_k, positions, tbl):
+                return sparse_latent.sparse_latent_chunk(
+                    q, q_idx, w_idx, latent, index_k, positions, tbl,
+                    topk=V32_TOPK, scale=scale, value_dim=V32_RANK)
+
+            def reference(q, q_idx, w_idx, latent, index_k, *rest):
+                return chunk(*f32(q, q_idx), w_idx,
+                             *f32(latent, index_k), *rest)
+
+            return _compare(jax.jit(chunk), reference,
+                            (q, q_idx, w_idx, latent, index_k, positions,
+                             tbl))
+        lengths = jnp.where(
+            jnp.arange(batch) % 3 == 0,
+            jax.random.randint(keys[3], (batch,), V32_TOPK,
+                               PAGE * V32_PAGES_PER_SEQ + 1, jnp.int32), 0)
+        q_idx = jax.random.normal(
+            keys[4], (batch, V32_INDEX_HEADS, V32_INDEX_DIM), jnp.float32)
+        w_idx = jax.random.normal(keys[5], (batch, V32_INDEX_HEADS),
+                                  jnp.float32)
+        if path == 'index':
+            def scores(q_idx, w_idx, index_k, tbl, lengths):
+                s = sparse_latent.index_scores_decode(
+                    q_idx, w_idx, index_k, tbl, lengths)
+                return jnp.where(jnp.isfinite(s), s, 0.0)
+
+            def reference(q_idx, w_idx, index_k, tbl, lengths):
+                return scores(*f32(q_idx), w_idx, *f32(index_k), tbl,
+                              lengths)
+
+            return _compare(jax.jit(scores), reference,
+                            (q_idx, w_idx, index_k, tbl, lengths),
+                            relative_to_max=True)
+        q = jax.random.normal(
+            keys[6], (batch, V32_HEADS, V32_ROW), bf16)
+        picks = jnp.where(
+            jnp.arange(PAGE * V32_PAGES_PER_SEQ)[None] < lengths[:, None],
+            jax.random.normal(keys[7], (batch, PAGE * V32_PAGES_PER_SEQ)),
+            -jnp.inf)
+
+        def attend(q, latent, tbl, picks):
+            idx, valid = sparse_latent.select_topk(picks, V32_TOPK)
+            return sparse_latent.sparse_latent_decode(
+                q, latent, tbl, idx, valid, scale=scale,
+                value_dim=V32_RANK)
+
+        def reference(q, latent, tbl, picks):
+            return attend(*f32(q, latent), tbl, picks)
+
+        return _compare(jax.jit(attend), reference,
+                        (q, latent, tbl, picks))
+    return case
+
+
 def _compare(kernel_fn, ref_fn, args, relative_to_max: bool = False
              ) -> Dict[str, Any]:
     """Compile + run both; the kernel's compile is where Mosaic
@@ -336,6 +443,15 @@ def cases() -> List[tuple]:
         ('fused_qkv_lora_delta/S=256',
          'int8 pool + --adapter-dir: prefill chunk',
          _qkv_lora(chunk=256, batch=1)),
+        ('sparse_latent/index_scores/bf16/S=1/48rows',
+         '`resolve_impl` latent pool: the decode round\'s index read',
+         _sparse_latent('index')),
+        ('sparse_latent/select_attend/bf16/S=1/48rows',
+         'the same: top-2048 selection and attention over the rows',
+         _sparse_latent('attend')),
+        ('sparse_latent/chunk/bf16/S=512',
+         'the same: a prefill chunk 5,632 tokens into its context',
+         _sparse_latent('chunk')),
         ('upstream_flash_attention/fwd+bwd/D=64/S=2048',
          'train_lm --seq >= 2048, GPT-2 heads',
          _flash(batch=2, heads=12, kv_heads=12, head_dim=64)),

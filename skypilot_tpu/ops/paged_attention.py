@@ -59,8 +59,9 @@ token's already-quantized values.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -416,6 +417,82 @@ class PageAllocator:
 
     def pages_needed(self, num_tokens: int, page_size: int) -> int:
         return -(-num_tokens // page_size)  # ceil div
+
+
+@dataclasses.dataclass(frozen=True)
+class PoolArray:
+    """One array of a layer's page pool: its leaf name in the model's
+    `cache` collection and a cached token's row in it, `heads` rows of
+    `width` values ([heads, total_pages, page_size, width]), kept in
+    `dtype` (None: the model's compute dtype, or int8 with scales)."""
+    name: str
+    heads: int
+    width: int
+    dtype: Any = None
+
+
+@dataclasses.dataclass(frozen=True)
+class PageLayout:
+    """What a model keeps in the page pool for a cached token (D3a):
+    the MODEL supplies it (`config.page_layout()`), and the engine, the
+    pool's sizing (`--kv-pool-bytes`), its placement and its guards
+    read the arrays from here instead of spelling K and V out.
+
+    `kind` 'kv': keys and values, [Hkv, pages, page, D] each (Llama,
+    GPT-2, Mixtral). `kind` 'latent': MLA's compressed row, one
+    [1, pages, page, kv_lora_rank + rope_head_dim (in whole lane tiles:
+    models/deepseek.py `latent_width`)] array a layer, and
+    beside it, where the model has a lightning indexer, that token's
+    indexer key, [1, pages, page, index_head_dim]. Every array has the
+    same four axes, so allocation, the page table, `_write_pool`, the
+    prefix keys and `pool_copy_lines` are the same code for both."""
+    kind: str
+    arrays: Tuple[PoolArray, ...]
+    page_size: int
+    total_pages: int
+
+    def shape(self, array: PoolArray) -> Tuple[int, int, int, int]:
+        return (array.heads, self.total_pages, self.page_size,
+                array.width)
+
+    @property
+    def row_values(self) -> int:
+        """Values a cached token takes in one layer."""
+        return sum(a.heads * a.width for a in self.arrays)
+
+    @staticmethod
+    def array_bytes(a: PoolArray, itemsize: int) -> int:
+        """Bytes a cached token takes in array `a` of one layer,
+        `itemsize` being that of an array that states no dtype."""
+        return a.heads * a.width * (jnp.dtype(a.dtype).itemsize
+                                    if a.dtype else itemsize)
+
+    def row_bytes(self, itemsize: int) -> int:
+        """Bytes a cached token takes in one layer."""
+        return sum(self.array_bytes(a, itemsize) for a in self.arrays)
+
+    def describe(self, num_layers: int, itemsize: int) -> Dict[str, Any]:
+        """The row as /stats `page_pool.row_layout` reports it."""
+        return {'kind': self.kind,
+                'arrays': {a.name: [a.heads, a.width]
+                           for a in self.arrays},
+                # A token's bytes in each array, one layer.
+                'array_bytes': {a.name: self.array_bytes(a, itemsize)
+                                for a in self.arrays},
+                'bytes_per_token': self.row_bytes(itemsize) * num_layers}
+
+
+def kv_layout(num_kv_heads: int, head_dim: int, page_size: int,
+              total_pages: int) -> PageLayout:
+    """The K/V layout of models/{llama,gpt,mixtral}.py."""
+    return PageLayout('kv', (PoolArray('k_pages', num_kv_heads, head_dim),
+                             PoolArray('v_pages', num_kv_heads, head_dim)),
+                      page_size, total_pages)
+
+
+#: Every leaf name a pool array may have: parallel/serving.py finds
+#: the pool's arrays in a cache tree by these.
+POOL_LEAF_NAMES = ('k_pages', 'v_pages', 'latent_pages', 'index_k_pages')
 
 
 def init_pages(num_kv_heads: int, total_pages: int, page_size: int,

@@ -152,9 +152,16 @@ def impl_scope(impl: str):
 
 
 def resolve_impl(*, quantized: bool = False,
-                 decode_pool: Any = None) -> str:
-    """The route of one paged read: 'xla' | 'decode' | 'fused' (or,
-    inside an `impl_scope`, the route the scope names).
+                 decode_pool: Any = None, layout: str = 'kv') -> str:
+    """The route of one paged read: 'xla' | 'decode' | 'fused' |
+    'sparse_latent_xla' (or, inside an `impl_scope`, the route the
+    scope names).
+
+    A pool whose model's page layout is 'latent' (MLA's compressed
+    rows, ops/paged_attention.PageLayout) has one route on every
+    backend, 'sparse_latent_xla': the index-score read, the selection
+    and attention over the selected rows of ops/sparse_latent.py, as
+    plain XLA. The rest is about K/V pools.
 
     Decided by what the code can observe and by nothing else: off a
     TPU (the CPU test backend) the XLA gather; on a TPU the fused
@@ -166,6 +173,10 @@ def resolve_impl(*, quantized: bool = False,
     A route forced through `impl_scope` that cannot run here raises —
     it never degrades to another route, so what /stats reports is what
     was compiled."""
+    if layout == 'latent':
+        if quantized:
+            raise ValueError('a latent page pool has no int8 form')
+        return 'sparse_latent_xla'
     impl = _scoped_impl
     if impl is not None:
         if impl == 'decode' and quantized:

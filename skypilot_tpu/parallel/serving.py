@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from skypilot_tpu.ops.paged_attention import POOL_LEAF_NAMES
 from skypilot_tpu.parallel import mesh as mesh_lib
 
 
@@ -78,7 +79,11 @@ def shard_params_for_serving(model, params: Any, mesh: Mesh,
 #: values are [num_kv_heads, total_pages, page_size, head_dim]
 #: (ops/paged_attention.py); dense per-slot rows are
 #: [slots, max_seq, num_kv_heads, head_dim] (models/llama.py).
-_PAGED_VALUE_LEAVES = ('k_pages', 'v_pages')
+#: Every pool array a model's page layout can name (K and V pages,
+#: MLA's latent rows and indexer keys; ops/paged_attention.PageLayout):
+#: all [heads, pages, page, width]. A latent array has one head for
+#: all query heads, so the remainder rule below replicates it.
+_PAGED_VALUE_LEAVES = POOL_LEAF_NAMES
 #: Parallel int8 scale pages [total_pages, page_size]: ONE f32 scale
 #: per token slot, shared by every kv head — always replicated (a
 #: head-sharded device still needs the whole scale row to
@@ -113,7 +118,8 @@ def serving_cache_shardings(cache: Any, mesh: Mesh) -> Any:
     """NamedShardings for an engine's cache collection: paged pool
     values shard their kv-heads axis (axis 0) over `tensor`, dense
     rows shard theirs (axis 2), scale pages and every other leaf
-    (MLA latents, bookkeeping scalars) replicate. The engine pins
+    (MLA latents, paged or dense, which have one head for all query
+    heads; bookkeeping scalars and counters) replicate. The engine pins
     these on the donated cache of every jitted dispatch, so an
     N-chip mesh stores 1/N of each value page per chip and never
     reshards the pool between steps."""

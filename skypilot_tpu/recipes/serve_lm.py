@@ -245,6 +245,17 @@ def main() -> None:
                              'anything larger) is clamped here. '
                              'Expired requests are reaped mid-decode '
                              'and answered 504')
+    parser.add_argument('--stream-final', default='rows',
+                        choices=('rows', 'lengths'),
+                        help='what a token stream\'s terminal event '
+                             'carries: `rows` = {"done": true, '
+                             '"tokens": [full rows]} (prompt and '
+                             'generated, as the non-streaming '
+                             'endpoint returns); `lengths` = {"done": '
+                             'true, "lengths": [n, ...]} — for long '
+                             'prompts, whose rows make one SSE line '
+                             'of tens of KB that a line-buffered '
+                             'client refuses (asyncio: 64 KiB)')
     parser.add_argument('--max-queue-requests', type=int, default=0,
                         metavar='N',
                         help='admission control: shed (429 + '

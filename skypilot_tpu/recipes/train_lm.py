@@ -15,6 +15,7 @@ Usage (see examples/*.yaml):
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
@@ -92,6 +93,22 @@ def _build_model(name: str, seq: int, remat: bool):
     if name == 'deepseek-tiny':
         from skypilot_tpu.models.deepseek import Deepseek, DeepseekConfig
         cfg = DeepseekConfig.tiny(remat=remat)
+        return Deepseek(cfg), cfg.vocab_size, None
+    if name == 'deepseek-v32-l5-ep16':
+        # DeepSeek-V3.2 at every published width as ONE chip's share of
+        # a 16-way expert-parallel deployment: experts 0-15 of 256, an
+        # eighth of the vocabulary, one dense and four expert layers
+        # (perfbench/configs/deepseek-v32-l5-ep16.json). Serving only:
+        # its attention reads the page pool.
+        from skypilot_tpu.models.deepseek import Deepseek, DeepseekConfig
+        cfg = DeepseekConfig.v32_l5_ep16(max_seq_len=max(seq, 4096),
+                                         remat=remat)
+        return Deepseek(cfg), cfg.vocab_size, None
+    if name == 'deepseek-v32-tiny':
+        from skypilot_tpu.models.deepseek import Deepseek, DeepseekConfig
+        cfg = DeepseekConfig.v32_tiny(remat=remat)
+        if seq > cfg.max_seq_len:
+            cfg = dataclasses.replace(cfg, max_seq_len=seq)
         return Deepseek(cfg), cfg.vocab_size, None
     if name == 'qwen2-7b':
         from skypilot_tpu.models.llama import Llama, LlamaConfig

@@ -272,3 +272,56 @@ from skypilot_tpu.observability import tracing
 def loop(clock):
     tracing.phase('engine.admit', clock)
 ''') == [('SKY007', 4)]
+
+
+# ---------------------------------------------------------------------------
+# A token stream's terminal event (serve_lm --stream-final)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize('stream_final', ['rows', 'lengths'])
+def test_a_streams_terminal_event_is_what_stream_final_says(stream_final):
+    """`rows` (the default) repeats the full rows, as the non-streaming
+    endpoint returns them; `lengths` gives their lengths and no
+    `tokens` key: every generated token has been streamed either way."""
+    from skypilot_tpu.inference.http_server import make_server
+    from skypilot_tpu.inference.runtime import InferenceRuntime
+    from skypilot_tpu.models.batching import ContinuousBatchingEngine
+    model, params = _tiny()
+    engine = ContinuousBatchingEngine(model, params, num_slots=2,
+                                      max_total_len=64, prefill_chunk=8)
+    rt = InferenceRuntime(
+        model=model, params=params,
+        vocab_size=model.config.vocab_size, model_name='llama-tiny',
+        max_total_len=64, spec_total=64, speculative=0, engine=engine,
+        stream_final=stream_final)
+    server = make_server(rt, 0)
+    url = f'http://127.0.0.1:{server.server_address[1]}'
+    threading.Thread(target=server.serve_forever, daemon=True).start()
+    prompt = list(range(1, 12))
+    try:
+        req = urllib.request.Request(
+            f'{url}/generate',
+            data=json.dumps({'tokens': [prompt], 'max_new_tokens': 5,
+                             'temperature': 0, 'stream': True}).encode(),
+            headers={'Content-Type': 'application/json'})
+        lines = urllib.request.urlopen(req, timeout=240).read().decode()
+    finally:
+        engine.stop()
+        server.shutdown()
+    events = [json.loads(line[len('data: '):])
+              for line in lines.splitlines()
+              if line.startswith('data: ') and line != 'data: [DONE]']
+    streamed = [e['token'] for e in events if 'token' in e]
+    terminal = events[-1]
+    assert terminal['done'] is True and len(streamed) == 5
+    if stream_final == 'rows':
+        assert terminal == {'done': True, 'tokens': [prompt + streamed]}
+    else:
+        assert terminal == {'done': True, 'lengths': [len(prompt) + 5]}
+
+
+def test_stream_final_takes_its_two_values_only():
+    from skypilot_tpu.inference.runtime import InferenceRuntime
+    with pytest.raises(ValueError, match='stream_final'):
+        InferenceRuntime(model=None, params=None, vocab_size=1,
+                         model_name='x', max_total_len=8, spec_total=8,
+                         speculative=0, stream_final='none')

@@ -185,3 +185,58 @@ def test_guard_sees_a_pool_copy(compile_for_chip):
         ((SLOTS, POOL[0], POOL[3]), jnp.bfloat16),
         ((SLOTS,), jnp.int32), ((SLOTS,), jnp.int32))
     assert len(pool_copy_lines(compiled, _cache(jnp.bfloat16))) == 2
+
+
+# DeepSeek-V3.2's latent page layout at its cell's shapes
+# (`deepseek-v32-l5-ep16`: a 4 GiB pool of 29,959 pages, 48 slots,
+# 1,024 pages a row, 512-token chunks).
+LATENT_POOL = (1, 29959, 16, 640)     # 512 + 64 values in 640, bf16
+INDEX_POOL = (1, 29959, 16, 128)      # float32
+LATENT_SLOTS, LATENT_PAGES_PER_ROW, LATENT_CHUNK = 48, 1024, 512
+
+
+@pytest.mark.parametrize('chunk', [1, LATENT_CHUNK],
+                         ids=['decode', 'prefill_chunk'])
+def test_latent_layout_write_and_reads_copy_no_pool(compile_for_chip,
+                                                    chunk):
+    """The other page layout (a latent row and an indexer key a token,
+    models/deepseek.py): one layer's write and its three reads
+    (ops/sparse_latent.py), a decode round and a page-aligned prefill
+    chunk, compile for the chip with both pool arrays written where
+    they lie."""
+    from skypilot_tpu.ops import sparse_latent as sl
+    rows = LATENT_SLOTS if chunk == 1 else 1
+    kw = dict(scale=0.1, value_dim=512)
+
+    def layer(latent, index_k, new_latent, new_key, q, q_idx, w_idx,
+              positions, table):
+        latent, index_k = sl.write_rows(
+            latent, index_k, new_latent, new_key, positions, table,
+            page_aligned=chunk > 1)
+        if chunk > 1:
+            out = sl.sparse_latent_chunk(q, q_idx, w_idx, latent, index_k,
+                                         positions, table, topk=2048, **kw)
+        else:
+            scores = sl.index_scores_decode(
+                q_idx[:, 0], w_idx[:, 0], index_k, table,
+                positions[:, 0] + 1)
+            idx, valid = sl.select_topk(scores, 2048)
+            out = sl.sparse_latent_decode(q[:, 0], latent, table, idx,
+                                          valid, **kw)
+        return latent, index_k, out
+
+    bf16 = jnp.bfloat16
+    compiled = compile_for_chip(
+        layer, (0, 1), (LATENT_POOL, bf16), (INDEX_POOL, jnp.float32),
+        ((rows, chunk, 640), bf16), ((rows, chunk, 128), jnp.float32),
+        ((rows, chunk, 128, 640), bf16),
+        ((rows, chunk, 64, 128), jnp.float32),
+        ((rows, chunk, 64), jnp.float32), ((rows, chunk), jnp.int32),
+        ((rows, LATENT_PAGES_PER_ROW), jnp.int32))
+    cache = {'layer_0': {'attn': {
+        'latent_pages': jax.ShapeDtypeStruct(LATENT_POOL, bf16),
+        'index_k_pages': jax.ShapeDtypeStruct(INDEX_POOL, jnp.float32)}}}
+    assert pool_copy_lines(compiled, cache) == []
+    text = compiled.as_text()
+    assert ' dynamic-update-slice(' in text and ' scatter(' not in text
+    assert 'tpu_custom_call' not in text         # plain XLA, all of it
