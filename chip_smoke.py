@@ -39,8 +39,10 @@ What runs (and nothing else: no sweeps, no A/B, no metrics table):
   serve-latent  (one chip) the serve stage again on
            `deepseek-v32-tiny`, whose page pool holds MLA's latent
            rows and the indexer's keys (the model's own page layout):
-           the same checks, the route `sparse_latent_xla`, no
-           pool-shaped copy around either array's write.
+           the same checks, the route `sparse_latent_xla` for the
+           decode read and for a prefill chunk (ops/pallas_latent.py's
+           kernel refuses the tiny model's widths), no pool-shaped
+           copy around either array's write.
   (4 chips) one `train_lm --zero1 --overlap` step, per-device bytes
            from both processes (no chip empty, chip 0 at most twice
            the mean) and the sharded-pool guard on the decode function
@@ -134,7 +136,10 @@ LATENT = dict(
                 '--prefill-chunk', '32', '--kv-pool-bytes',
                 str(8 << 20)],
     lengths=[(5, 8), (17, 30), (70, 90), (33, 47)],
-    max_new=6, attention_impl='sparse_latent_xla', default_pages=128,
+    max_new=6, attention_impl='sparse_latent_xla',
+    # The chunk read's kernel refuses the tiny model's 112 summed
+    # values (no whole lane tile): its chunks keep the XLA walk.
+    chunk_attention_impl='sparse_latent_xla', default_pages=128,
     prompt_vocab=512)
 
 
@@ -151,11 +156,13 @@ def preset(chips: int, rehearse: bool) -> Dict[str, Any]:
             # (lo, hi) prompt-length ranges, one request each; the
             # last is the prompt sent twice.
             lengths=[(5, 8), (17, 30), (40, 60), (33, 47)],
-            max_new=6, attention_impl='xla', default_pages=128)
+            max_new=6, attention_impl='xla',
+            chunk_attention_impl='xla', default_pages=128)
     cfg = dict(
         train_model='gpt2-124m', seq=1024, vocab=50304, steps=30,
         resume_steps=40, ckpt_every=10, log_every=5,
-        attention_impl='decode', default_pages=128, max_new=16)
+        attention_impl='decode', chunk_attention_impl='xla',
+        default_pages=128, max_new=16)
     if chips == 1:
         cfg.update(
             serve_model='llama3-8b-l8',
@@ -714,7 +721,8 @@ def serve_checks(ctx: Ctx, client: Client, ready_s: float) -> None:
 def check_stats(ctx: Ctx, stats: Dict[str, Any]) -> None:
     c = ctx.cfg
     keep = {k: stats.get(k) for k in (
-        'engine', 'attention_impl', 'kv_cache', 'engine_restarts',
+        'engine', 'attention_impl', 'chunk_attention_impl', 'kv_cache',
+        'engine_restarts',
         'soft_errors', 'healthy', 'decode_calls', 'tokens_committed',
         'preemptions', 'prefill_chunks_run', 'first_tokens_deferred',
         'first_tokens_synced')}
@@ -737,6 +745,10 @@ def check_stats(ctx: Ctx, stats: Dict[str, Any]) -> None:
     # reference, the dense cache or interpret mode on the chip.
     expect('attention_impl', stats.get('attention_impl'),
            c['attention_impl'])
+    # And for a whole prefill chunk's attention (a bf16 K/V pool's
+    # chunks take the gather; a latent pool's the kernel or the walk).
+    expect('chunk_attention_impl', stats.get('chunk_attention_impl'),
+           c['chunk_attention_impl'])
     expect('engine_restarts', stats.get('engine_restarts'), 0)
     expect('soft_errors', stats.get('soft_errors'), 0)
     expect('healthy', stats.get('healthy'), True)
@@ -775,7 +787,8 @@ def stage_serve_latent(ctx: Ctx) -> None:
     """The serve stage again, on the model whose page pool holds
     latent rows: the same checks (the scored completion, the prefix
     hit, the in-place write of BOTH of its pool arrays, no blocking
-    first-token fetch), the route `sparse_latent_xla`."""
+    first-token fetch), the route `sparse_latent_xla` for both the
+    decode read and a chunk's attention."""
     saved = ctx.cfg
     ctx.cfg = dict(saved, **LATENT)
     try:

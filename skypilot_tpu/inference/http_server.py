@@ -458,6 +458,9 @@ def make_server(rt: InferenceRuntime,
                 # (ops/pallas_paged.py; serve_bench scores achieved
                 # tokens/s against bytes_per_token * HBM peak).
                 'attention_impl': engine.attention_impl(),
+                # The route of a whole prefill chunk's attention: a
+                # chip run that fell back to the XLA walk reads so.
+                'chunk_attention_impl': engine.chunk_attention_impl(),
                 'attention_bytes_per_token':
                     engine.attention_bytes_per_token(),
                 # Robustness plane (docs/guides.md serving-robustness

@@ -1678,6 +1678,33 @@ class ContinuousBatchingEngine:
             decode_pool=self._pool_aval,
             layout=self.page_layout.kind)
 
+    def chunk_attention_impl(self) -> str:
+        """The route the attention of a whole `prefill_chunk` takes in
+        this engine's traced prefill programs, beside
+        `attention_impl()`'s decode read: for a latent pool
+        ops/sparse_latent.chunk_route given what the model's chunk
+        read gives it ('sparse_latent_pallas' where the kernel of
+        ops/pallas_latent.py is compiled, 'sparse_latent_xla' for the
+        walk: a refused shape, and a ladder's tail chunk of fewer
+        queries than a tile, which /stats does not name); for a K/V
+        pool `resolve_impl`'s answer for a read that passes no decode
+        pool. 'dense' without a page pool. Surfaced in /stats."""
+        if not self.paged:
+            return 'dense'
+        if self.page_layout.kind == 'latent':
+            from skypilot_tpu.ops import sparse_latent
+            cfg = self.model.config
+            chunk = self.prefill_chunk or self.page_size
+            return sparse_latent.chunk_route(
+                jax.ShapeDtypeStruct(
+                    (chunk, cfg.num_heads, self._pool_aval.shape[-1]),
+                    self._pool_aval.dtype),
+                self._pool_aval, self.pages_per_seq, cfg.kv_lora_rank)
+        from skypilot_tpu.ops import pallas_paged
+        return pallas_paged.resolve_impl(
+            quantized=self.kv_dtype == 'int8',
+            layout=self.page_layout.kind)
+
     def _compile_decode(self):
         """This engine's decode dispatch, lowered at its own shapes
         and compiled for the backend it serves on (scheduler thread:

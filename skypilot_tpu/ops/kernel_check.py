@@ -23,8 +23,10 @@ here compiles: the XLA gather, compiled for the chip and held to the
 same reference in float32. The `sparse_latent/...` cases are the three
 device paths of a latent page pool (DeepSeek-V3.2's widths, the
 benchmark cell's 48 slots, two in three of length 0, and 512-token
-chunks), plain XLA too, each
-held to itself in float32. It
+chunks), each held to the plain-XLA path in float32: the decode
+round's two reads are plain XLA themselves, a chunk's attention is
+the kernel of ops/pallas_latent.py (at two depths, 12 and 28 blocks
+of keys, so that the time a block is on record). It
 exits non-zero when any case is not `ok`, and when the backend is not
 a TPU: a CPU run of this file would check nothing.
 
@@ -270,15 +272,19 @@ V32_INDEX_HEADS, V32_INDEX_DIM, V32_TOPK = 64, 128, 2048
 V32_PAGES_PER_SEQ, V32_BATCH, V32_CHUNK = 1024, 48, 512
 
 
-def _sparse_latent(path: str) -> Callable[[Any], Dict]:
-    """One of ops/sparse_latent.py's three device paths (the route
-    'sparse_latent_xla' of a latent pool: bf16 latent rows, float32
-    index queries and keys) against the same path on the operands in
-    float32 at `highest` precision:
+def _sparse_latent(path: str, offset: int = 5632
+                   ) -> Callable[[Any], Dict]:
+    """One of ops/sparse_latent.py's three device paths (a latent
+    pool: bf16 latent rows, float32 index queries and keys) against
+    the plain-XLA path on the operands in float32 at `highest`
+    precision:
     'index' the decode round's index scores over the paged keys,
     'attend' its selection and the attention over the selected rows
-    (both sides select on the same float32 scores), 'chunk' a
-    512-token prefill chunk 5,632 tokens into its context."""
+    (both sides select on the same float32 scores; these two are
+    route 'sparse_latent_xla'), 'chunk' a 512-token prefill chunk
+    `offset` tokens into its context, whose attention must take the
+    kernel of ops/pallas_latent.py ('sparse_latent_pallas') and is
+    held to the XLA walk."""
 
     def case(key):
         import jax
@@ -308,16 +314,23 @@ def _sparse_latent(path: str) -> Callable[[Any], Dict]:
                 jnp.float32)
             w_idx = jax.random.normal(
                 keys[5], (1, V32_CHUNK, V32_INDEX_HEADS), jnp.float32)
-            positions = (5632 + jnp.arange(V32_CHUNK))[None]
+            positions = (offset + jnp.arange(V32_CHUNK))[None]
+            got = sparse_latent.chunk_route(q, latent, V32_PAGES_PER_SEQ,
+                                            V32_RANK)
+            if got != 'sparse_latent_pallas':
+                return _wrong_route('sparse_latent_pallas', got)
 
-            def chunk(q, q_idx, w_idx, latent, index_k, positions, tbl):
+            def chunk(q, q_idx, w_idx, latent, index_k, positions, tbl,
+                      route=None):
                 return sparse_latent.sparse_latent_chunk(
                     q, q_idx, w_idx, latent, index_k, positions, tbl,
-                    topk=V32_TOPK, scale=scale, value_dim=V32_RANK)
+                    topk=V32_TOPK, scale=scale, value_dim=V32_RANK,
+                    route=route)
 
             def reference(q, q_idx, w_idx, latent, index_k, *rest):
                 return chunk(*f32(q, q_idx), w_idx,
-                             *f32(latent, index_k), *rest)
+                             *f32(latent, index_k), *rest,
+                             route='sparse_latent_xla')
 
             return _compare(jax.jit(chunk), reference,
                             (q, q_idx, w_idx, latent, index_k, positions,
@@ -450,8 +463,12 @@ def cases() -> List[tuple]:
          'the same: top-2048 selection and attention over the rows',
          _sparse_latent('attend')),
         ('sparse_latent/chunk/bf16/S=512',
-         'the same: a prefill chunk 5,632 tokens into its context',
+         'the same: a prefill chunk 5,632 tokens into its context '
+         '(12 blocks of keys), its attention the Pallas kernel',
          _sparse_latent('chunk')),
+        ('sparse_latent/chunk/bf16/S=512/28blocks',
+         'the same 13,824 tokens in: the slope a block of keys',
+         _sparse_latent('chunk', offset=13824)),
         ('upstream_flash_attention/fwd+bwd/D=64/S=2048',
          'train_lm --seq >= 2048, GPT-2 heads',
          _flash(batch=2, heads=12, kv_heads=12, head_dim=64)),

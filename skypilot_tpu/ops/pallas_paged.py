@@ -55,7 +55,11 @@ pool's static shape, and from nothing else: on a TPU an int8 pool ->
 'fused'; the one-token decode read of an unquantized pool that
 `decode_kernel_refusal` takes -> 'decode'; every other unquantized
 read (a refused shape such as 64-wide heads, every S>1 chunk) ->
-'xla', the gather; off a TPU (the CPU test backend) -> 'xla'. No
+'xla', the gather; off a TPU (the CPU test backend) -> 'xla'. A
+latent pool (`layout='latent'`, ops/sparse_latent.py) -> plain XLA,
+'sparse_latent_xla', but for a prefill chunk's attention on a TPU
+whose static shapes `pallas_latent.chunk_kernel_refusal` takes ->
+'sparse_latent_pallas', the kernel of ops/pallas_latent.py. No
 argument, setter or environment variable selects a route; whoever
 wants one kernel by name calls that kernel. The one override is
 `impl_scope`, the tests' way to run the engine through the
@@ -98,6 +102,8 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from skypilot_tpu.ops import pallas_latent
 
 #: The routes a paged read can take: 'xla' is the gather reference
 #: (compiles everywhere); 'decode' this module's decode read of an
@@ -152,16 +158,24 @@ def impl_scope(impl: str):
 
 
 def resolve_impl(*, quantized: bool = False,
-                 decode_pool: Any = None, layout: str = 'kv') -> str:
+                 decode_pool: Any = None, layout: str = 'kv',
+                 latent_chunk: Any = None) -> str:
     """The route of one paged read: 'xla' | 'decode' | 'fused' |
-    'sparse_latent_xla' (or, inside an `impl_scope`, the route the
-    scope names).
+    'sparse_latent_xla' | 'sparse_latent_pallas' (or, inside an
+    `impl_scope`, the route the scope names).
 
     A pool whose model's page layout is 'latent' (MLA's compressed
-    rows, ops/paged_attention.PageLayout) has one route on every
-    backend, 'sparse_latent_xla': the index-score read, the selection
-    and attention over the selected rows of ops/sparse_latent.py, as
-    plain XLA. The rest is about K/V pools.
+    rows, ops/paged_attention.PageLayout) is read by
+    ops/sparse_latent.py: the index-score read, the selection and
+    attention over the selected rows, as plain XLA on every backend,
+    'sparse_latent_xla'. One of its reads has a kernel: on a TPU a
+    prefill chunk's attention (`latent_chunk`: the chunk's queries,
+    the keys a step and the row's summed values, which only that read
+    passes) whose static shapes `pallas_latent.chunk_kernel_refusal`
+    takes is 'sparse_latent_pallas'; a refused shape (a row or a
+    `value_dim` that is no whole number of lane tiles, a chunk of
+    fewer queries than a sublane tile) keeps the walk. The rest is
+    about K/V pools.
 
     Decided by what the code can observe and by nothing else: off a
     TPU (the CPU test backend) the XLA gather; on a TPU the fused
@@ -176,6 +190,10 @@ def resolve_impl(*, quantized: bool = False,
     if layout == 'latent':
         if quantized:
             raise ValueError('a latent page pool has no int8 form')
+        if (latent_chunk is not None and available()
+                and pallas_latent.chunk_kernel_refusal(
+                    *latent_chunk) is None):
+            return 'sparse_latent_pallas'
         return 'sparse_latent_xla'
     impl = _scoped_impl
     if impl is not None:

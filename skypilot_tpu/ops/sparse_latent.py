@@ -1,4 +1,5 @@
-"""Learned sparse attention over a paged latent cache, as plain XLA.
+"""Learned sparse attention over a paged latent cache: plain XLA, but
+for the attention of a prefill chunk where the kernel takes it.
 
 DeepSeek-V3.2 keeps two rows a cached token and layer in the page pool
 (ops/paged_attention.PageLayout, kind 'latent'): MLA's compressed row
@@ -16,21 +17,27 @@ context in three steps, and these are the three device paths here:
 
 One decode token a row (`*_decode`) gathers its selected rows by index.
 A prefill chunk (`sparse_latent_chunk`) has hundreds of queries with a
-selection each, so it walks the context in blocks of keys instead: a
+selection each, so it takes the context in blocks of keys instead: a
 first pass leaves every query's index scores, their `index_topk`-th
 largest is found exactly by bisection on the float's bits
-(`kth_largest`), and a second pass attends with an online softmax under
-the mask of the scores above it and, of those equal to it, the first
-few (`topk_mask`'s rule, which is `lax.top_k`'s order). Both loops end
-at the chunk's last position, so a chunk costs what its context costs,
-and no compiled
-shape depends on how long a context is or on whether it exceeds
-`index_topk`. Without indexer keys (`index_pages=None`: MLA as
-DeepSeek-V2 has it) every causal position is selected.
+(`kth_largest`), and the selection becomes a mask, bool [chunk, keys]
+(`chunk_keep`): the scores above it and, of those equal to it, the
+first few (`topk_mask`'s rule, which is `lax.top_k`'s order). Attention
+under that mask with an online softmax is either the kernel of
+ops/pallas_latent.py, in which a block of scores never leaves VMEM, or
+the blocked XLA walk here, which carries it through HBM between its
+two products. Both passes end at the chunk's last position, so a chunk
+costs what its context costs, and no compiled shape depends on how
+long a context is or on whether it exceeds `index_topk`. Without
+indexer keys (`index_pages=None`: MLA as DeepSeek-V2 has it) every
+causal position is selected.
 
-The route is `pallas_paged.resolve_impl(layout='latent')`'s one answer,
-'sparse_latent_xla', on every backend; ops/kernel_check.py compares the
-three paths with float32 on the chip.
+The route is `pallas_paged.resolve_impl(layout='latent')`'s answer:
+'sparse_latent_xla' for the decode round's reads on every backend and
+for a chunk whose static shapes the kernel refuses (and off a TPU),
+'sparse_latent_pallas' for a chunk it takes (`chunk_route`);
+ops/kernel_check.py compares the three paths with float32 on the chip,
+the kernel with the walk.
 """
 from __future__ import annotations
 
@@ -244,61 +251,99 @@ def sparse_latent_decode(q: jax.Array, latent_pages: jax.Array,
         jnp.zeros((batch, heads, value_dim), F32))
 
 
+def chunk_route(q, latent_pages, pages_per_seq: int, value_dim: int,
+                block_pages: int = BLOCK_PAGES) -> str:
+    """The route a chunk's attention takes, `pallas_paged.resolve_impl`'s
+    answer for what the read hands it: `q` [.., S, H, W] and
+    `latent_pages` [1, P, page, W], arrays or their ShapeDtypeStructs
+    (static shapes and the dtype only), the pages of a row's table and
+    the row's summed values. The engine asks with the same four for
+    /stats (`chunk_attention_impl`), so the name reported is the
+    program compiled."""
+    from skypilot_tpu.ops import pallas_paged
+    block = min(block_pages, pages_per_seq) * latent_pages.shape[2]
+    return pallas_paged.resolve_impl(
+        layout='latent', latent_chunk=(q, block, value_dim))
+
+
+def _whole_blocks(page_row, positions, page, block_pages):
+    """(the row's table padded to whole blocks, the pages of a block,
+    the blocks up to the chunk's last position)."""
+    block_pages = min(block_pages, page_row.shape[0])
+    page_row = jnp.pad(page_row, (0, -page_row.shape[0] % block_pages))
+    return (page_row, block_pages,
+            jnp.max(positions) // (block_pages * page) + 1)
+
+
+def chunk_keep(q_idx, w_idx, positions, page_row, index_pages, *,
+               page: int, topk: int,
+               block_pages: int = BLOCK_PAGES) -> jax.Array:
+    """bool[S, keys]: the keys each query of one row's chunk attends,
+    over the row's table in whole blocks: the causal ones and, with an
+    indexer, of those the `topk` best by index score (`topk_mask`'s
+    rule, to the last tie). It is H times smaller than a block of
+    scores, made once a layer, and is all of the selection that the
+    attention, walk or kernel, is handed."""
+    page_row, block_pages, n_blocks = _whole_blocks(
+        page_row, positions, page, block_pages)
+    seq, block = positions.shape[0], block_pages * page
+    total = page_row.shape[0] * page
+    causal = jnp.arange(total)[None, :] <= positions[:, None]
+    if index_pages is None:
+        return causal
+    with jax.named_scope('indexer'):
+        def score_block(i, buf):
+            pages = jax.lax.dynamic_slice(page_row, (i * block_pages,),
+                                          (block_pages,))
+            keys = index_pages[0][pages].reshape(block, -1)
+            s = jnp.einsum('shd,kd->shk', q_idx, keys, precision=EXACT,
+                           preferred_element_type=F32)
+            at = jax.lax.dynamic_slice(causal, (0, i * block),
+                                       (seq, block))
+            s = jnp.where(at, _weighted_relu(s, w_idx), -jnp.inf)
+            return jax.lax.dynamic_update_slice(buf, s, (0, i * block))
+
+        index_scores = jax.lax.fori_loop(
+            0, n_blocks, score_block,
+            jnp.full((seq, total), -jnp.inf, F32))
+        with jax.named_scope('topk_select'):
+            # A score past a query's position is -inf, and `topk_mask`
+            # keeps finite scores only.
+            return topk_mask(index_scores, topk)
+
+
 def _chunk_row(q, q_idx, w_idx, positions, page_row, latent_pages,
-               index_pages, *, topk, scale, value_dim, block_pages):
+               index_pages, *, topk, scale, value_dim, block_pages,
+               kernel, interpret):
     """One row of `sparse_latent_chunk`."""
     seq, heads, _ = q.shape
     page = latent_pages.shape[2]
-    block_pages = min(block_pages, page_row.shape[0])
-    page_row = jnp.pad(page_row, (0, -page_row.shape[0] % block_pages))
+    page_row, block_pages, n_blocks = _whole_blocks(
+        page_row, positions, page, block_pages)
     block = block_pages * page
-    total = page_row.shape[0] * page
-    n_blocks = jnp.max(positions) // block + 1
-
-    def pages_of(i):
-        return jax.lax.dynamic_slice(page_row, (i * block_pages,),
-                                     (block_pages,))
-
-    def causal(i):
-        return (i * block + jnp.arange(block))[None, :] <= positions[:, None]
-
-    threshold = index_scores = quota = None
-    if index_pages is not None:
-        with jax.named_scope('indexer'):
-            def score_block(i, buf):
-                keys = index_pages[0][pages_of(i)].reshape(block, -1)
-                s = jnp.einsum('shd,kd->shk', q_idx, keys,
-                               precision=EXACT,
-                               preferred_element_type=F32)
-                s = jnp.where(causal(i), _weighted_relu(s, w_idx), -jnp.inf)
-                return jax.lax.dynamic_update_slice(buf, s, (0, i * block))
-
-            index_scores = jax.lax.fori_loop(
-                0, n_blocks, score_block,
-                jnp.full((seq, total), -jnp.inf, F32))
-            with jax.named_scope('topk_select'):
-                # `topk_mask`, a block of keys at a time: scores above
-                # the k-th largest, and of those equal to it the first
-                # `quota`, counted along the walk.
-                threshold = kth_largest(index_scores,
-                                        min(topk, total))[:, None]
-                quota = topk - jnp.sum(index_scores > threshold, axis=-1)
+    keep = chunk_keep(q_idx, w_idx, positions, page_row, index_pages,
+                      page=page, topk=topk, block_pages=block_pages)
 
     with jax.named_scope('latent_attention'):
+        if kernel:
+            from skypilot_tpu.ops import pallas_latent
+            # The row's pages once a layer, as one [keys, W] matrix
+            # every head's walk reads (21 MB at 16,384 positions).
+            rows = latent_pages[0][page_row].reshape(keep.shape[1], -1)
+            out = pallas_latent.latent_chunk_attention(
+                jnp.swapaxes(q, 0, 1), rows, keep, n_blocks, block=block,
+                value_dim=value_dim, scale=scale, interpret=interpret)
+            return jnp.swapaxes(out, 0, 1)
+
         def attend_block(i, carry):
-            m, l, acc, ties_seen = carry
-            rows = latent_pages[0][pages_of(i)].reshape(block, -1)
+            m, l, acc = carry
+            pages = jax.lax.dynamic_slice(page_row, (i * block_pages,),
+                                          (block_pages,))
+            rows = latent_pages[0][pages].reshape(block, -1)
             s = jnp.einsum('shw,kw->hsk', q, rows,
                            preferred_element_type=F32) * scale
-            keep = causal(i)
-            if threshold is not None:
-                picks = jax.lax.dynamic_slice(
-                    index_scores, (0, i * block), (seq, block))
-                tie = (picks == threshold) & keep
-                rank = ties_seen[:, None] + jnp.cumsum(tie, axis=-1) - tie
-                keep &= (picks > threshold) | (tie & (rank < quota[:, None]))
-                ties_seen = ties_seen + jnp.sum(tie, axis=-1)
-            s = jnp.where(keep[None], s, -jnp.inf)
+            at = jax.lax.dynamic_slice(keep, (0, i * block), (seq, block))
+            s = jnp.where(at[None], s, -jnp.inf)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             # A query with nothing selected so far keeps -inf: shift by
             # 0 there, so that exp(-inf - m) stays 0 and never NaN.
@@ -309,14 +354,13 @@ def _chunk_row(q, q_idx, w_idx, positions, page_row, latent_pages,
             acc = acc * fade[..., None] + jnp.einsum(
                 'hsk,kc->hsc', p.astype(rows.dtype), rows[:, :value_dim],
                 preferred_element_type=F32)
-            return m_new, l, acc, ties_seen
+            return m_new, l, acc
 
-        _, l, acc, _ = jax.lax.fori_loop(
+        _, l, acc = jax.lax.fori_loop(
             0, n_blocks, attend_block,
             (jnp.full((heads, seq), -jnp.inf, F32),
              jnp.zeros((heads, seq), F32),
-             jnp.zeros((heads, seq, value_dim), F32),
-             jnp.zeros((seq,), jnp.int32)))
+             jnp.zeros((heads, seq, value_dim), F32)))
         return jnp.swapaxes(acc / l[..., None], 0, 1)
 
 
@@ -326,7 +370,9 @@ def sparse_latent_chunk(q: jax.Array, q_idx: Optional[jax.Array],
                         index_pages: Optional[jax.Array],
                         positions: jax.Array, page_indices: jax.Array, *,
                         topk: int, scale: float, value_dim: int,
-                        block_pages: int = BLOCK_PAGES) -> jax.Array:
+                        block_pages: int = BLOCK_PAGES,
+                        route: Optional[str] = None,
+                        interpret: bool = False) -> jax.Array:
     """S queries a row over the row's paged history, each over its own
     selection (the chunk's rows are already written).
 
@@ -334,10 +380,26 @@ def sparse_latent_chunk(q: jax.Array, q_idx: Optional[jax.Array],
     f32[B, S, Hi] (None with `index_pages` None: no selection);
     positions i32[B, S], rising within a row; page_indices
     i32[B, pages]. Returns f32[B, S, H, value_dim] as
-    `sparse_latent_decode` does."""
-    def row(q, q_idx, w_idx, positions, page_row):
-        return _chunk_row(q, q_idx, w_idx, positions, page_row,
-                          latent_pages, index_pages, topk=topk, scale=scale,
-                          value_dim=value_dim, block_pages=block_pages)
+    `sparse_latent_decode` does.
 
-    return jax.vmap(row)(q, q_idx, w_idx, positions, page_indices)
+    The attention takes `chunk_route`'s route. ops/kernel_check.py and
+    the tests name one (`route`) to hold the kernel to the walk, and
+    run the kernel through the Pallas interpreter (`interpret`)."""
+    if route is None:
+        route = chunk_route(q, latent_pages, page_indices.shape[1],
+                            value_dim, block_pages)
+    kernel = route == 'sparse_latent_pallas'
+
+    def row(*args):
+        return _chunk_row(*args, latent_pages, index_pages, topk=topk,
+                          scale=scale, value_dim=value_dim,
+                          block_pages=block_pages, kernel=kernel,
+                          interpret=interpret)
+
+    args = (q, q_idx, w_idx, positions, page_indices)
+    if not kernel:
+        return jax.vmap(row)(*args)
+    # A Pallas call is not batched: the rows take the kernel in turn
+    # (the engine's chunks are one row each, and stacking one is free).
+    return jnp.stack([row(*(None if a is None else a[b] for a in args))
+                      for b in range(q.shape[0])])

@@ -132,6 +132,34 @@ def test_prefill_chunks_then_decode_through_the_pool(dtype, tolerance, why):
     assert int(cache['sparse_decode_tokens']) == 32
 
 
+def test_prefill_chunks_take_the_chunk_kernel_where_it_fits(monkeypatch):
+    """The tiny model with a row the kernel of ops/pallas_latent.py
+    takes (128 summed values in a 256-wide row; `deepseek-v32-tiny`'s
+    112 are refused and keep the walk), chunks and decode through the
+    pool: with the backend steered to a TPU's answer and the kernel
+    run by the Pallas interpreter, the logits are the walk's."""
+    from skypilot_tpu.ops import pallas_latent, pallas_paged
+    cfg = dataclasses.replace(CFG, kv_lora_rank=128, kv_total_pages=16)
+    p = _init(cfg)
+    toks = _tokens(3, 128)       # 8 pages a row: a block of 128 keys
+    walk, _ = _through_the_pool(cfg, p, toks, chunk=32, n_prefill=96)
+    calls = []
+    compiled = pallas_latent._chunk_call
+
+    def interpreted(*args, **kw):
+        calls.append(args[0].shape)
+        return compiled(*args, **dict(kw, interpret=True))
+
+    monkeypatch.setattr(pallas_paged, 'available', lambda: True)
+    monkeypatch.setattr(pallas_latent, '_chunk_call', interpreted)
+    kernel, cache = _through_the_pool(cfg, p, toks, chunk=32, n_prefill=96)
+    # Traced once a layer for the chunk behind a history (the first
+    # chunk's program is another trace): [heads, chunk, width].
+    assert calls and set(calls) == {(4, 32, 256)}, calls
+    assert float(jnp.max(jnp.abs(kernel - walk))) < 2e-5
+    assert int(cache['sparse_decode_tokens']) == 32
+
+
 def test_the_engine_serves_it_through_the_page_pool(params):
     """Admission, chunked prefill, the pipelined loop and the first
     token's handoff over the model's own page layout."""
@@ -141,6 +169,8 @@ def test_the_engine_serves_it_through_the_page_pool(params):
     try:
         assert engine.paged and engine.page_layout.kind == 'latent'
         assert engine.attention_impl() == 'sparse_latent_xla'
+        # Off a TPU, and 112 summed values are no whole lane tile.
+        assert engine.chunk_attention_impl() == 'sparse_latent_xla'
         prompts = [_tokens(10 + i, n) for i, n in enumerate((70, 40, 100))]
         futs = [engine.submit(p, max_new_tokens=12) for p in prompts]
         rows = [f.result(timeout=300) for f in futs]

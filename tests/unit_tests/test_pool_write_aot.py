@@ -14,6 +14,8 @@ All ahead-of-time compiles of the repo live in THIS file: the TPU
 library belongs to one process, so only the worker that is given this
 file loads it (from the fixture, never at import).
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -195,17 +197,10 @@ INDEX_POOL = (1, 29959, 16, 128)      # float32
 LATENT_SLOTS, LATENT_PAGES_PER_ROW, LATENT_CHUNK = 48, 1024, 512
 
 
-@pytest.mark.parametrize('chunk', [1, LATENT_CHUNK],
-                         ids=['decode', 'prefill_chunk'])
-def test_latent_layout_write_and_reads_copy_no_pool(compile_for_chip,
-                                                    chunk):
-    """The other page layout (a latent row and an indexer key a token,
-    models/deepseek.py): one layer's write and its three reads
-    (ops/sparse_latent.py), a decode round and a page-aligned prefill
-    chunk, compile for the chip with both pool arrays written where
-    they lie."""
+def _latent_layer(chunk):
+    """One layer over a latent pool: the write, then a decode round's
+    three reads (chunk 1) or a page-aligned prefill chunk's."""
     from skypilot_tpu.ops import sparse_latent as sl
-    rows = LATENT_SLOTS if chunk == 1 else 1
     kw = dict(scale=0.1, value_dim=512)
 
     def layer(latent, index_k, new_latent, new_key, q, q_idx, w_idx,
@@ -224,19 +219,79 @@ def test_latent_layout_write_and_reads_copy_no_pool(compile_for_chip,
             out = sl.sparse_latent_decode(q[:, 0], latent, table, idx,
                                           valid, **kw)
         return latent, index_k, out
+    return layer
 
+
+def _latent_layer_avals(chunk):
+    rows = LATENT_SLOTS if chunk == 1 else 1
     bf16 = jnp.bfloat16
-    compiled = compile_for_chip(
-        layer, (0, 1), (LATENT_POOL, bf16), (INDEX_POOL, jnp.float32),
-        ((rows, chunk, 640), bf16), ((rows, chunk, 128), jnp.float32),
-        ((rows, chunk, 128, 640), bf16),
-        ((rows, chunk, 64, 128), jnp.float32),
-        ((rows, chunk, 64), jnp.float32), ((rows, chunk), jnp.int32),
-        ((rows, LATENT_PAGES_PER_ROW), jnp.int32))
-    cache = {'layer_0': {'attn': {
-        'latent_pages': jax.ShapeDtypeStruct(LATENT_POOL, bf16),
-        'index_k_pages': jax.ShapeDtypeStruct(INDEX_POOL, jnp.float32)}}}
-    assert pool_copy_lines(compiled, cache) == []
+    return ((LATENT_POOL, bf16), (INDEX_POOL, jnp.float32),
+            ((rows, chunk, 640), bf16), ((rows, chunk, 128), jnp.float32),
+            ((rows, chunk, 128, 640), bf16),
+            ((rows, chunk, 64, 128), jnp.float32),
+            ((rows, chunk, 64), jnp.float32), ((rows, chunk), jnp.int32),
+            ((rows, LATENT_PAGES_PER_ROW), jnp.int32))
+
+
+LATENT_CACHE = {'layer_0': {'attn': {
+    'latent_pages': jax.ShapeDtypeStruct(LATENT_POOL, jnp.bfloat16),
+    'index_k_pages': jax.ShapeDtypeStruct(INDEX_POOL, jnp.float32)}}}
+# A result the size of a block of scores: float32 [.., 128 heads, 512
+# queries, 512 keys]. (A chunk's output in latent terms, 512 values a
+# query and head, has the same shape: the kernel's one result.)
+SCORES_SHAPED = re.compile(r'= f32\[(\d+,)*128,512,512\]')
+
+
+@pytest.mark.parametrize('chunk', [1, LATENT_CHUNK],
+                         ids=['decode', 'prefill_chunk'])
+def test_latent_layout_write_and_reads_copy_no_pool(compile_for_chip,
+                                                    chunk):
+    """The other page layout (a latent row and an indexer key a token,
+    models/deepseek.py): one layer's write and its three reads
+    (ops/sparse_latent.py), a decode round and a page-aligned prefill
+    chunk, compile for the chip with both pool arrays written where
+    they lie. Plain XLA as this backend resolves them (the chunk's
+    walk: the route a shape the kernel refuses takes on the chip), and
+    the walk is what carries blocks of scores through HBM."""
+    compiled = compile_for_chip(_latent_layer(chunk), (0, 1),
+                                *_latent_layer_avals(chunk))
+    assert pool_copy_lines(compiled, LATENT_CACHE) == []
     text = compiled.as_text()
     assert ' dynamic-update-slice(' in text and ' scatter(' not in text
     assert 'tpu_custom_call' not in text         # plain XLA, all of it
+    # The chunk's walk: a dozen results a block of keys, in its loop.
+    assert len(SCORES_SHAPED.findall(text)) >= (10 if chunk > 1 else 0)
+    assert bool(SCORES_SHAPED.search(text)) == (chunk > 1)
+
+
+def test_latent_chunk_takes_the_kernel_and_keeps_scores_out_of_hbm(
+        compile_for_chip, monkeypatch):
+    """The route a prefill chunk takes on the chip at the cell's shapes
+    (`deepseek-v32-l5-ep16`: 128 heads over a 640-wide row, a 512-token
+    chunk, 1,024 pages a row), the backend steered as above: its
+    attention is the kernel of ops/pallas_latent.py, held by its
+    `name=` inside the scope the trace is read by; both pool arrays are
+    still written where they lie, the row's pages reach the kernel as
+    a gather of the row and no copy of the pool; and the one
+    instruction of the program with a result the size of a block of
+    scores is the kernel itself, whose result is the chunk's output."""
+    from skypilot_tpu.ops import pallas_paged
+    from skypilot_tpu.ops import sparse_latent as sl
+    monkeypatch.setattr(pallas_paged, 'available', lambda: True)
+    assert sl.chunk_route(
+        jax.ShapeDtypeStruct((1, LATENT_CHUNK, 128, 640), jnp.bfloat16),
+        jax.ShapeDtypeStruct(LATENT_POOL, jnp.bfloat16),
+        LATENT_PAGES_PER_ROW, 512) == 'sparse_latent_pallas'
+    compiled = compile_for_chip(_latent_layer(LATENT_CHUNK), (0, 1),
+                                *_latent_layer_avals(LATENT_CHUNK))
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1, calls
+    assert '/latent_attention/' in calls[0]               # the scope
+    assert 'latent_chunk_attention' in calls[0]           # the name=
+    assert f'= f32[128,{LATENT_CHUNK},512]' in calls[0]
+    assert pool_copy_lines(compiled, LATENT_CACHE) == []
+    assert ' dynamic-update-slice(' in text and ' scatter(' not in text
+    assert [line for line in text.splitlines()
+            if SCORES_SHAPED.search(line)] == calls
