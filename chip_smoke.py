@@ -43,6 +43,12 @@ What runs (and nothing else: no sweeps, no A/B, no metrics table):
            decode read and for a prefill chunk (ops/pallas_latent.py's
            kernel refuses the tiny model's widths), no pool-shaped
            copy around either array's write.
+  serve-state   (one chip) the serve stage once more on
+           `nemotron-h-tiny`, which keeps a Mamba-2 state and a
+           convolution's tail BY SLOT beside the K/V pages of its
+           attention layer: the same checks without the prefix hit
+           (its prefix cache is off), `/stats state_pool`, and no copy
+           of a page array or a slot array in the compiled programs.
   (4 chips) one `train_lm --zero1 --overlap` step, per-device bytes
            from both processes (no chip empty, chip 0 at most twice
            the mean) and the sharded-pool guard on the decode function
@@ -141,6 +147,27 @@ LATENT = dict(
     # values (no whole lane tile): its chunks keep the XLA walk.
     chunk_attention_impl='sparse_latent_xla', default_pages=128,
     prompt_vocab=512)
+
+
+#: The stage of a model with state BY SLOT beside its pages (one
+#: chip): `nemotron-h-tiny`, pattern ME*EM (Mamba-2 state and the
+#: convolution's tail a slot, K/V pages of 128-wide heads for the one
+#: attention layer, latent experts by share). 648,144 parameters,
+#: counted by `jax.eval_shape` (tests/perfbench). No prefix cache: a
+#: shared page holds no state to resume from.
+STATE = dict(
+    serve_model='nemotron-h-tiny', serve_params=648144,
+    serve_args=['--max-total-len', '256', '--num-slots', '4',
+                '--prefill-chunk', '32', '--kv-pool-bytes',
+                str(8 << 20)],
+    lengths=[(5, 8), (17, 30), (70, 90), (33, 47)],
+    max_new=6, attention_impl='decode', chunk_attention_impl='xla',
+    default_pages=128, prompt_vocab=512, prefix_hit=False,
+    state_pool={'arrays': {'ssm_state': [8, 16, 16],
+                           'conv_state': [576]},
+                'layers': 2, 'slots': 4,
+                'bytes_per_slot': 2 * (8 * 16 * 16 * 4 + 576 * 2),
+                'bytes': 4 * 2 * (8 * 16 * 16 * 4 + 576 * 2)})
 
 
 def preset(chips: int, rehearse: bool) -> Dict[str, Any]:
@@ -703,6 +730,12 @@ def serve_checks(ctx: Ctx, client: Client, ready_s: float) -> None:
         f'compiled decode and prefill-chunk programs: '
         f'{json.dumps(guard)}')
     copies = guard['copies']
+    if ctx.args.rehearse and c.get('state_pool') and copies:
+        # XLA:CPU copies the arrays that the live rows' loop of a
+        # decode round carries (ops/ssm.ssm_update), every step; the
+        # chip's compiler updates them where they lie, which is what
+        # this check is for and what the rehearsal cannot show.
+        copies = {k: v for k, v in copies.items() if k != 'decode'}
     if not copies or any(copies.values()):
         raise SmokeFailure(
             f'serve: a compiled program copies a whole pool-shaped '
@@ -729,6 +762,7 @@ def check_stats(ctx: Ctx, stats: Dict[str, Any]) -> None:
     keep['storage'] = stats.get('storage')
     keep['page_pool'] = stats.get('page_pool')
     keep['prefix_cache'] = stats.get('prefix_cache')
+    keep['state_pool'] = stats.get('state_pool')
     keep['requests'] = (stats.get('serving') or {}).get('requests')
     say(f'serve: /stats excerpt {json.dumps(keep)}')
     storage = stats.get('storage') or {}
@@ -772,9 +806,15 @@ def check_stats(ctx: Ctx, stats: Dict[str, Any]) -> None:
         problems.append(f'page_pool={pool} (expected a pool sized by '
                         f'--kv-pool-bytes, above the default '
                         f'{c["default_pages"]} pages)')
-    if not (stats.get('prefix_cache') or {}).get('hits', 0) > 0:
-        problems.append(f'prefix_cache={stats.get("prefix_cache")} '
-                        f'(expected a hit)')
+    if c.get('prefix_hit', True):
+        if not (stats.get('prefix_cache') or {}).get('hits', 0) > 0:
+            problems.append(f'prefix_cache={stats.get("prefix_cache")} '
+                            f'(expected a hit)')
+    else:
+        expect('prefix_cache', stats.get('prefix_cache'), None)
+    # A model with state by slot says what a slot keeps beside its
+    # pages; every other model says nothing.
+    expect('state_pool', stats.get('state_pool'), c.get('state_pool'))
     if ctx.device['platform'] == 'tpu' and storage.get(
             'attention_kernel_unavailable_reason') is not None:
         problems.append('attention_kernel_unavailable_reason='
@@ -791,6 +831,25 @@ def stage_serve_latent(ctx: Ctx) -> None:
     decode read and a chunk's attention."""
     saved = ctx.cfg
     ctx.cfg = dict(saved, **LATENT)
+    try:
+        stage_serve(ctx)
+    finally:
+        ctx.cfg = saved
+
+
+def stage_serve_state(ctx: Ctx) -> None:
+    """The serve stage once more, on the model that keeps recurrent
+    state by slot beside its K/V pages: the scored completions (the
+    plain forward pass starts every row from an empty state), no
+    prefix cache and no hit, `/stats state_pool`, and no copy of the
+    pages, the state or the tails in the compiled decode and
+    prefill-chunk programs."""
+    saved = ctx.cfg
+    cfg = dict(saved, **STATE)
+    if ctx.args.rehearse:
+        # Off a TPU the decode read is the XLA gather.
+        cfg['attention_impl'] = 'xla'
+    ctx.cfg = cfg
     try:
         stage_serve(ctx)
     finally:
@@ -861,6 +920,7 @@ def main() -> int:
     stages.append(stage_serve)
     if args.chips == 1:
         stages.append(stage_serve_latent)
+        stages.append(stage_serve_state)
     t0 = time.monotonic()
     ok = False
     try:

@@ -511,6 +511,10 @@ def make_server(rt: InferenceRuntime,
                         engine.model.config.num_layers,
                         1 if engine.kv_dtype == 'int8' else 2),
                 }
+                if engine.slot_state:
+                    # What a sequence keeps by slot beside its pages
+                    # (a model with state-space layers).
+                    body['state_pool'] = engine.state_pool_stats()
                 if engine.stages > 1:
                     # Staged pool split: every stage stores the same
                     # page indices (one shared allocator) but only

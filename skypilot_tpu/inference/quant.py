@@ -194,7 +194,9 @@ def kv_page_bytes(cfg, kv_dtype: str, shard_ways: int = 1,
         value_bytes = (layout.row_bytes(item) * cfg.kv_page_size
                        // shard_ways)
         scale_bytes = 0
-    stage_layers = -(-cfg.num_layers // stages)  # ceil: widest stage
+    # The layers that keep a row (a hybrid model: its attention layers).
+    pool_layers = layout.layers or cfg.num_layers
+    stage_layers = -(-pool_layers // stages)  # ceil: widest stage
     return stage_layers * (value_bytes + scale_bytes)
 
 

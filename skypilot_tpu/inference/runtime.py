@@ -836,6 +836,38 @@ def build_runtime(args) -> InferenceRuntime:
                                             args.max_total_len,
                                             remat=False)
 
+    # A model whose sequences keep recurrent state by slot beside
+    # their pages (ops/paged_attention.SlotArray: state-space layers)
+    # refuses here, by flag, what assumes that a sequence is its pages;
+    # the engine's constructor refuses the same by name.
+    layout = (model.config.page_layout()
+              if hasattr(model.config, 'page_layout') else None)
+    if layout is not None and layout.slot_arrays:
+        asked = {
+            'the one-shot engine (give --continuous-batching)':
+                not args.continuous_batching,
+            '--kv-dtype int8': (getattr(args, 'kv_dtype', 'bf16')
+                                or 'bf16') == 'int8',
+            '--tensor': int(getattr(args, 'tensor', 1) or 1) > 1,
+            '--stages': int(getattr(args, 'stages', 1) or 1) > 1,
+            '--speculative': args.speculative > 0,
+            '--decode-chunk': getattr(args, 'decode_chunk', 1) > 1,
+            '--kv-spill-bytes / --kv-cold-dir': bool(
+                getattr(args, 'kv_spill_bytes', 0)
+                or getattr(args, 'kv_cold_dir', None)),
+            '--role / --decode-peers (handoff ships pages)': bool(
+                getattr(args, 'role', '')
+                or getattr(args, 'decode_peers', None)),
+        }
+        wrong = [flag for flag, on in asked.items() if on]
+        if wrong:
+            raise SystemExit(
+                f'{type(model.config).__name__} keeps recurrent state '
+                f'by slot beside its pages '
+                f'({", ".join(a.name for a in layout.slot_arrays)}): it '
+                f'does not serve with {", ".join(wrong)} yet (docs/'
+                f'guides.md "State by slot"; ROADMAP R-M5)')
+
     # Quantized serving knobs (inference/quant.py): KV page storage
     # format + pool sizing in BYTES (so bf16/int8 A/B runs spend the
     # same HBM — int8 buys ~2x the pages), and int8 projection

@@ -514,6 +514,32 @@ class ContinuousBatchingEngine:
                 'kv_dtype=int8 requires the paged KV cache: the '
                 'dense per-slot cache has no scale storage (size the '
                 'kv page pool to hold max_total_len, or serve bf16)')
+        # State BY SLOT beside the pages (a model with state-space
+        # layers: ops/paged_attention.SlotArray): a sequence is then
+        # more than its pages, and what assumes otherwise is refused
+        # by name or turned off here.
+        self.slot_state = bool(self.page_layout is not None
+                               and self.page_layout.slot_arrays)
+        if self.slot_state:
+            self._refuse_slot_state({
+                'the dense per-slot cache (size the page pool: '
+                '--kv-pool-bytes)': not self.paged,
+                'an int8 pool (--kv-dtype int8)': self.kv_dtype == 'int8',
+                'pipeline stages (--stages)': self.stages > 1,
+                'a tensor mesh (--tensor)': self.mesh_devices > 1,
+                'speculative decoding (--speculative)': bool(speculative_k),
+                'decode chunks (--decode-chunk)': decode_chunk > 1,
+                'the spill tier (--kv-spill-bytes, --kv-cold-dir)':
+                    bool(kv_spill_bytes or kv_cold_dir),
+            })
+            if prefix_caching:
+                # A cached prefix is pages; the state after it is not
+                # in them, so a hit could not be resumed from.
+                print(f'engine: {type(model.config).__name__} keeps '
+                      f'recurrent state by slot: prefix caching is off '
+                      f'(a shared page holds no state to resume from; '
+                      f'ROADMAP R-M5)', flush=True)
+                prefix_caching = False
         if self.paged and self.page_layout.kind != 'kv':
             # What cannot take another layout than K/V yet refuses
             # here, by name, and not silently (ROADMAP R-M1, D3a).
@@ -1285,7 +1311,7 @@ class ContinuousBatchingEngine:
             @functools.partial(jax.jit, donate_argnums=(1,),
                                **self._pin_cache_out(None))
             def prefill_paged(params, cache, prompt, plen, page_row,
-                              lora=None, adapter_ids=None):
+                              lora=None, adapter_ids=None, slot=None):
                 # CHUNKED prefill: the whole (padded) prompt in ONE
                 # forward pass; the model writes K/V for every
                 # position (write_kv_chunk). Junk past plen lands in
@@ -1296,6 +1322,10 @@ class ContinuousBatchingEngine:
                          if lora is not None else {})
                 if takes_live:
                     extra['live'] = positions < plen
+                if slot is not None:
+                    # State by slot: the row's slot (its state starts
+                    # from zeros: prefill=True).
+                    extra['slots'] = slot[None]
                 logits, mutated = model.apply(
                     {'params': params, 'cache': cache},
                     prompt[None, :], positions=positions,
@@ -1368,13 +1398,16 @@ class ContinuousBatchingEngine:
         @functools.partial(jax.jit, donate_argnums=(1,),
                            **self._pin_cache_out(None))
         def prefill_suffix(params, cache, suffix, suffix_len, offset,
-                           page_row, lora=None, adapter_ids=None):
+                           page_row, lora=None, adapter_ids=None,
+                           slot=None):
             extra = ({'lora': lora, 'adapter_ids': adapter_ids}
                      if lora is not None else {})
             positions = (offset +
                          jnp.arange(bucket_len, dtype=jnp.int32))[None, :]
             if takes_live:
                 extra['live'] = positions < offset + suffix_len
+            if slot is not None:
+                extra['slots'] = slot[None]
             logits, mutated = model.apply(
                 {'params': params, 'cache': cache},
                 suffix[None, :], positions=positions,
@@ -1583,9 +1616,22 @@ class ContinuousBatchingEngine:
         (skypilot_serving_kv_pool_bytes)."""
         # Metadata-only read (shape/dtype, never buffer contents):
         # safe from scrape threads even though the cache is donated.
+        # State by slot is no KV cache: /stats `state_pool` has it.
         return int(sum(
             leaf.size * jnp.dtype(leaf.dtype).itemsize
-            for leaf in jax.tree_util.tree_leaves(self.cache)))  # stpu: ignore[SKY008]
+            for leaf in jax.tree_util.tree_leaves(self.cache))  # stpu: ignore[SKY008]
+            ) - self.state_pool_stats().get('bytes', 0)
+
+    def state_pool_stats(self) -> Dict[str, Any]:
+        """/stats `state_pool`: what a sequence keeps by slot beside
+        its pages (arrays with a slot's row shape, the layers that
+        have them, bytes a slot, slots, bytes); {} for a model whose
+        sequences are their pages."""
+        if not self.slot_state:
+            return {}
+        item = jnp.dtype(getattr(self.model.config, 'dtype',
+                                 jnp.bfloat16)).itemsize
+        return self.page_layout.describe_slots(self.num_slots, item)
 
     def kv_cache_bytes_per_device(self) -> int:
         """Bytes of the KV cache resident on ONE device: sharded pool
@@ -1762,7 +1808,9 @@ class ContinuousBatchingEngine:
                 self.params, self.cache,
                 jnp.zeros((chunk,), jnp.int32), jnp.int32(chunk),
                 jnp.int32(self.page_size),
-                jnp.asarray(self.page_table[:1])).compile()
+                jnp.asarray(self.page_table[:1]),
+                **({'slot': jnp.int32(0)} if self.slot_state else {})
+                ).compile()
             return {
                 'decode': _tp_serving.pool_copy_lines(
                     self._compile_decode(), self.cache),
@@ -1815,8 +1863,10 @@ class ContinuousBatchingEngine:
             page_size, pages_per_seq = 1, self.max_total_len
         # Per-stage layer split: a chip walks only its stage's layers'
         # KV pages (ceil — the widest stage, matching the weight term).
-        num_layers = (-(-cfg.num_layers // self.stages)
-                      if self.stages > 1 else cfg.num_layers)
+        pool_layers = ((self.page_layout.layers if self.paged else None)
+                       or cfg.num_layers)
+        num_layers = (-(-pool_layers // self.stages)
+                      if self.stages > 1 else pool_layers)
         return pallas_paged.bytes_per_token_model(
             num_layers=num_layers,
             num_kv_heads=getattr(cfg, 'num_kv_heads', cfg.num_heads),
@@ -1918,10 +1968,27 @@ class ContinuousBatchingEngine:
                 if self._cache_lost():
                     raise
 
+    def _refuse_slot_state(self, asked: Dict[str, bool]) -> None:
+        """Refuse, by name, what a model with state by slot does not
+        serve with yet (`asked`: {what: whether it was asked for})."""
+        refused = [name for name, on in asked.items() if on]
+        if refused:
+            raise ValueError(
+                f'{type(self.model.config).__name__} keeps recurrent '
+                f'state by slot beside its pages '
+                f'({", ".join(a.name for a in self.page_layout.slot_arrays)}) '
+                f'and does not serve with {", ".join(refused)} yet: '
+                f'a sequence is then more than its pages '
+                f'(ROADMAP R-M5)')
+
     def _refuse_other_layouts(self, what: str) -> None:
         """The wire and spill formats pack K/V pages (and their int8
         scales) with their geometry; a pool of another layout refuses
-        by name instead of shipping rows a peer would misread."""
+        by name instead of shipping rows a peer would misread, and so
+        does a model whose sequences keep state by slot: the pages
+        alone would resume nothing."""
+        if self.slot_state:
+            self._refuse_slot_state({what: True})
         if self.paged and self.page_layout.kind != 'kv':
             raise ValueError(
                 f'{what} packs K/V pages; the '
@@ -2315,6 +2382,7 @@ class ContinuousBatchingEngine:
         migration — and leaves the queue alone. Thread-safe: hops
         onto the scheduler thread. Returns
         {'evacuated', 'chains', 'queued'}."""
+        self._refuse_other_layouts('live migration (evacuate_chains)')
 
         def op():
             evacuated = 0
@@ -2912,28 +2980,30 @@ class ContinuousBatchingEngine:
         shape = self._chunk_shape(n, offset)
         chunk = self.outputs[slot][offset:offset + n]
         padded = jnp.asarray(chunk + [0] * (shape - n), jnp.int32)
-        lora_kw = self._slot_lora_args(slot)
+        kw = self._slot_lora_args(slot)
+        if self.slot_state:
+            kw['slot'] = jnp.int32(slot)
         if self.paged and offset:
             fn = self._prefill_suffix_fn(shape)
             self.cache, last = fn(
                 self.params, self.cache, padded, jnp.int32(n),
                 jnp.int32(offset),
-                jnp.asarray(self.page_table[slot:slot + 1]), **lora_kw)
+                jnp.asarray(self.page_table[slot:slot + 1]), **kw)
         elif self.paged:
             fn = self._prefill_fn(shape)
             self.cache, last = fn(
                 self.params, self.cache, padded, jnp.int32(n),
-                jnp.asarray(self.page_table[slot:slot + 1]), **lora_kw)
+                jnp.asarray(self.page_table[slot:slot + 1]), **kw)
         elif offset:
             fn = self._dense_suffix_fn(shape)
             self.cache, last = fn(
                 self.params, self.cache, jnp.int32(slot), padded,
-                jnp.int32(n), jnp.int32(offset), **lora_kw)
+                jnp.int32(n), jnp.int32(offset), **kw)
         else:
             fn = self._prefill_fn(shape)
             self.cache, last = fn(
                 self.params, self.cache, jnp.int32(slot), padded,
-                jnp.int32(n), **lora_kw)
+                jnp.int32(n), **kw)
         self.prefill_chunks_run += 1
         return last
 

@@ -43,7 +43,6 @@ same logical-axis sharding scheme as models/{gpt,llama,mixtral}.py.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Optional
 
@@ -613,7 +612,7 @@ def route(cfg: DeepseekConfig, logits: jax.Array, bias: jax.Array):
     return chosen, weights * cfg.routed_scaling_factor
 
 
-def _swiglu(x, *, w_gate, w_up, w_down):
+def _swiglu(x, w_gate, w_up, w_down):
     gate = jnp.dot(x, w_gate, preferred_element_type=jnp.float32)
     up = jnp.dot(x, w_up, preferred_element_type=jnp.float32)
     h = (nn.silu(gate) * up).astype(x.dtype)
@@ -621,17 +620,35 @@ def _swiglu(x, *, w_gate, w_up, w_down):
                    ).astype(x.dtype)
 
 
+def _relu2(x, w_up, w_down):
+    h = jnp.dot(x, w_up, preferred_element_type=jnp.float32)
+    h = jnp.square(nn.relu(h)).astype(x.dtype)
+    return jnp.dot(h, w_down, preferred_element_type=jnp.float32
+                   ).astype(x.dtype)
+
+
+#: An expert's function by name, with its matrices' names in the order
+#: it takes them: `swiglu`, three of them (w_gate, w_up [d, f]; w_down
+#: [f, d]); `relu2`, two (w_up [d, f]; w_down [f, d]), the square of
+#: the ReLU between them.
+EXPERT_FNS = {'swiglu': (_swiglu, ('w_gate', 'w_up', 'w_down')),
+              'relu2': (_relu2, ('w_up', 'w_down'))}
+
+
 def grouped_experts(x_sorted: jax.Array, counts: jax.Array,
-                    experts, row_block: int) -> jax.Array:
-    """SwiGLU experts over tokens sorted by expert, dropless.
+                    experts, row_block: int,
+                    expert_fn=_swiglu) -> jax.Array:
+    """Experts over tokens sorted by expert, dropless.
 
     x_sorted [M, d]: expert 0's `counts[0]` rows first, then expert
-    1's, ...; rows past sum(counts) belong to nobody. `experts`: a
-    (w_gate [d, f], w_up [d, f], w_down [f, d]) an expert. One loop
-    over blocks of `row_block` rows, each inside ONE expert's rows, so
-    an expert is multiplied as often as its tokens need and one that
-    received none is never read; the trip count is known on the device
-    only. Returns [M, d] (rows past sum(counts): zeros)."""
+    1's, ...; rows past sum(counts) belong to nobody. `experts`: the
+    matrices of each expert held, in the order `expert_fn(x, *matrices)`
+    takes them ([rows, d] -> [rows, d]: `_swiglu` with three,
+    `_relu2` with two). One loop over blocks of `row_block` rows, each
+    inside ONE expert's rows, so an expert is multiplied as often as
+    its tokens need and one that received none is never read; the trip
+    count is known on the device only. Returns [M, d] (rows past
+    sum(counts): zeros)."""
     held = counts.shape[0]
     rows, dim = x_sorted.shape
     starts = jnp.cumsum(counts) - counts
@@ -648,10 +665,11 @@ def grouped_experts(x_sorted: jax.Array, counts: jax.Array,
         # One branch an expert, over that expert's OWN arrays: XLA:TPU
         # copies a slice of stacked weights out whole before it
         # multiplies by it, at a traced index and at a static one
-        # alike (three times an expert's bytes moved).
-        y = jax.lax.switch(e, [
-            functools.partial(_swiglu, w_gate=g, w_up=u, w_down=d)
-            for g, u, d in experts], x)
+        # alike (as many times an expert's bytes moved as it has
+        # matrices).
+        y = jax.lax.switch(
+            e, [lambda x, w=tuple(w): expert_fn(x, *w) for w in experts],
+            x)
         # The block's tail may reach into the next expert's rows:
         # those keep what they hold.
         mine = (offset + jnp.arange(row_block) < counts[e])[:, None]
@@ -666,34 +684,71 @@ def grouped_experts(x_sorted: jax.Array, counts: jax.Array,
 
 
 class ExpertWeights(nn.Module):
-    """One routed expert's SwiGLU matrices (w_gate, w_up [d, f];
-    w_down [f, d]) in the compute dtype."""
-    config: DeepseekConfig
+    """One routed expert's matrices in the compute dtype, as
+    `EXPERT_FNS[act]` names and orders them: `width` wide, on an input
+    of `in_dim` values."""
+    config: Any
+    in_dim: int
+    width: int
+    act: str = 'swiglu'
 
     @nn.compact
-    def __call__(self):
+    def __call__(self, drawn=None):
+        """`drawn` ({matrix name: its values}, at initialisation only):
+        this expert's slice of one draw for all of a layer's experts,
+        in place of a draw of its own."""
         cfg = self.config
 
         def matrix(name, shape, axes):
+            init = nn.initializers.normal(stddev=0.02)
+            if drawn is not None:
+                init = lambda key, shape, dtype: (  # noqa: E731
+                    drawn[name].astype(dtype))
             return self.param(
-                name, nn.with_logical_partitioning(
-                    nn.initializers.normal(stddev=0.02), axes),
+                name, nn.with_logical_partitioning(init, axes),
                 shape, jnp.float32).astype(cfg.dtype)
 
-        return (matrix('w_gate', (cfg.embed_dim, cfg.moe_dim),
-                       ('embed', 'mlp')),
-                matrix('w_up', (cfg.embed_dim, cfg.moe_dim),
-                       ('embed', 'mlp')),
-                matrix('w_down', (cfg.moe_dim, cfg.embed_dim),
-                       ('mlp', 'embed')))
+        return tuple(
+            matrix(name, (self.width, self.in_dim), ('mlp', 'embed'))
+            if name == 'w_down' else
+            matrix(name, (self.in_dim, self.width), ('embed', 'mlp'))
+            for name in EXPERT_FNS[self.act][1])
+
+
+class Relu2MLP(nn.Module):
+    """`w_down relu(w_up x)^2`, no bias: a shared expert of a model
+    whose experts are `relu2`."""
+    embed_dim: int
+    width: int
+    dtype: Dtype
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        h = _proj(self.width, ('embed', 'mlp'), self.dtype, 'w_up')(x)
+        h = nn.with_logical_constraint(jnp.square(nn.relu(h)),
+                                       ('batch', 'seq', 'mlp'))
+        return _proj(self.embed_dim, ('mlp', 'embed'), self.dtype,
+                     'w_down')(h)
 
 
 class MoEByShare(nn.Module):
-    """V3's expert layer as ONE chip's share of an expert-parallel
-    deployment holds it: the router over all `n_routed_experts`, the
-    weights normalized over all chosen, and the parts of this chip's
-    `experts_held` experts (from `expert_offset`) plus the shared
-    expert. Dropless. What the absent experts would add is left out.
+    """A routed expert layer as ONE chip's share of an expert-parallel
+    deployment holds it: the router over all `n_routed_experts`
+    (sigmoid scores, `route`), the weights normalized over all chosen,
+    and the parts of this chip's `experts_held` experts (from
+    `expert_offset`) plus the shared expert. Dropless. What the absent
+    experts would add is left out.
+
+    The configuration gives the counts and the widths (`embed_dim`,
+    `moe_dim`, `n_routed_experts`, `num_experts_per_tok`, `num_held`,
+    `expert_offset`, `n_group`, `topk_group`, `routed_scaling_factor`,
+    `n_shared_experts`, `dtype`); the module's own fields say what an
+    expert is. `expert_act`: `swiglu` (three matrices) or `relu2`
+    (two). `latent_dim` > 0: the routed experts work on a LATENT of
+    that width (`latent_down`, no bias, before them; `latent_up` after
+    their weighted sum, which is linear, so shares still add up); the
+    router and the shared expert keep the full width. `shared_dim`:
+    the shared expert's width (0: `moe_dim` x `n_shared_experts`).
 
     `live` (bool [B,S], or None = all) marks real tokens: a junk lane
     or a padded tail is sent to no expert, so it reads no weights and
@@ -702,7 +757,17 @@ class MoEByShare(nn.Module):
     calls (decode) and row 1 for chunks (prefill): `expert_tokens`
     (assignments an expert received) and `expert_calls_touched` (calls
     in which it received any)."""
-    config: DeepseekConfig
+    config: Any
+    expert_act: str = 'swiglu'
+    latent_dim: int = 0
+    shared_dim: int = 0
+    #: Seeded weights of all held experts from ONE draw a matrix name
+    #: (sliced an expert each) instead of a draw an expert: the values
+    #: are as random, and the program that makes them compiles in
+    #: seconds where 1,280 draws of their own took seven minutes
+    #: (a threefry fusion a leaf; PERF.md, PR 35). Off: a model whose
+    #: seeded weights a benchmark cell already has keeps them.
+    stacked_init: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array, live: Optional[jax.Array] = None,
@@ -714,17 +779,22 @@ class MoEByShare(nn.Module):
         held, offset = cfg.num_held, cfg.expert_offset
         flat = x.reshape(n, dim)
         with jax.named_scope('shared_expert'):
-            shared = SwiGLU(dataclasses.replace(
-                cfg, mlp_dim=cfg.moe_dim * cfg.n_shared_experts),
-                name='shared')(x)
+            shared_dim = (self.shared_dim
+                          or cfg.moe_dim * cfg.n_shared_experts)
+            if self.expert_act == 'swiglu':
+                shared = SwiGLU(dataclasses.replace(
+                    cfg, mlp_dim=shared_dim), name='shared')(x)
+            else:
+                shared = Relu2MLP(dim, shared_dim, cfg.dtype,
+                                  name='shared')(x)
         with jax.named_scope('router'):
             # The router's logits in float32 all the way, as
             # published: from the norm's float32 output where the
             # block hands it over (`x_router`), at full precision (a
             # TPU's default float32 product rounds its operands to
-            # bf16). A rounded logit flips the last of the 8 experts,
-            # and a flipped expert moves the output more than all of
-            # a dense block's rounding.
+            # bf16). A rounded logit flips the last of the chosen
+            # experts, and a flipped expert moves the output more than
+            # all of a dense block's rounding.
             logits = nn.Dense(
                 cfg.n_routed_experts, use_bias=False, dtype=jnp.float32,
                 precision=jax.lax.Precision.HIGHEST, name='router',
@@ -739,9 +809,26 @@ class MoEByShare(nn.Module):
                 (cfg.n_routed_experts,), jnp.float32)
             chosen, weights = route(cfg, logits, bias.astype(jnp.float32))
 
+        if self.latent_dim:
+            with jax.named_scope('latent_down'):
+                flat = _proj(self.latent_dim, ('embed', 'kv'), cfg.dtype,
+                             'latent_down')(flat)
+        width = flat.shape[-1]
         # An expert's matrices are arrays of their own, under the
         # expert's number in the whole model (`expert_<n>`).
-        experts = [ExpertWeights(cfg, name=f'expert_{offset + i}')()
+        drawn = None
+        if self.stacked_init and self.is_initializing():
+            key = self.make_rng('params')
+            drawn = {
+                name: 0.02 * jax.random.normal(
+                    jax.random.fold_in(key, j),
+                    (held, cfg.moe_dim, width) if name == 'w_down'
+                    else (held, width, cfg.moe_dim), jnp.float32)
+                for j, name in enumerate(EXPERT_FNS[self.expert_act][1])}
+        experts = [ExpertWeights(cfg, width, cfg.moe_dim, self.expert_act,
+                                 name=f'expert_{offset + i}')(
+                       None if drawn is None else
+                       {name: values[i] for name, values in drawn.items()})
                    for i in range(held)]
         with jax.named_scope('experts'):
             here = (chosen >= offset) & (chosen < offset + held)
@@ -756,8 +843,8 @@ class MoEByShare(nn.Module):
             ).astype(jnp.int32)
             y_sorted = grouped_experts(
                 flat[order // top], counts, experts,
-                min(n, EXPERT_ROW_BLOCK))
-            parts = y_sorted[jnp.argsort(order)].reshape(n, top, dim)
+                min(n, EXPERT_ROW_BLOCK), EXPERT_FNS[self.expert_act][0])
+            parts = y_sorted[jnp.argsort(order)].reshape(n, top, width)
             routed = jnp.sum(
                 parts.astype(jnp.float32)
                 * jnp.where(here, weights, 0.0)[..., None], axis=1)
@@ -770,7 +857,12 @@ class MoEByShare(nn.Module):
             tokens.value = tokens.value.at[phase].add(counts)
             touched.value = touched.value.at[phase].add(
                 (counts > 0).astype(jnp.int32))
-        return shared + routed.astype(cfg.dtype).reshape(batch, seq, dim)
+        routed = routed.astype(cfg.dtype)
+        if self.latent_dim:
+            with jax.named_scope('latent_up'):
+                routed = _proj(dim, ('kv', 'embed'), cfg.dtype,
+                               'latent_up')(routed)
+        return shared + routed.reshape(batch, seq, dim)
 
 
 class Block(nn.Module):

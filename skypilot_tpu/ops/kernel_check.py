@@ -26,7 +26,13 @@ benchmark cell's 48 slots, two in three of length 0, and 512-token
 chunks), each held to the plain-XLA path in float32: the decode
 round's two reads are plain XLA themselves, a chunk's attention is
 the kernel of ops/pallas_latent.py (at two depths, 12 and 28 blocks
-of keys, so that the time a block is on record). It
+of keys, so that the time a block is on record). The `ssm/...` cases
+are ops/ssm.py at Nemotron 3 Super's widths (128 heads of 64, state
+128, 8 groups): a decode round's step over the cell's 128 slots with a
+third and all of the lanes live, the state and the tails donated from
+call to call so that the update in place is what is timed, and a
+512-token prefill chunk, each against the float32 recurrence step by
+step. It
 exits non-zero when any case is not `ok`, and when the backend is not
 a TPU: a CPU run of this file would check nothing.
 
@@ -377,6 +383,116 @@ def _sparse_latent(path: str, offset: int = 5632
     return case
 
 
+# Nemotron 3 Super's Mamba-2 mixer and the benchmark cell's 128 slots.
+SSM_HEADS, SSM_HEAD_DIM, SSM_STATE, SSM_GROUPS, SSM_TAPS = 128, 64, 128, 8, 4
+SSM_SLOTS, SSM_CHUNK, SSM_SUB = 128, 512, 128
+
+
+def _ssm(path: str, live_lanes: int = 0) -> Callable[[Any], Dict]:
+    """ops/ssm.py at the published widths against the float32
+    recurrence step by step (`ssm_reference`): 'update' a decode
+    round's step over 128 slots of which `live_lanes` are live (the
+    state and the tails donated and handed from call to call, as the
+    engine does: what is timed is the update in place), 'scan' a
+    512-token prefill chunk from a random state."""
+
+    def case(key):
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        from skypilot_tpu.ops import ssm
+        heads, hd, n, g = SSM_HEADS, SSM_HEAD_DIM, SSM_STATE, SSM_GROUPS
+        inner, bc = heads * hd, g * n
+        width = inner + 2 * bc
+        keys = jax.random.split(key, 10)
+        a = -jnp.exp(jnp.log(jax.random.uniform(keys[0], (heads,),
+                                                minval=1.0, maxval=16.0)))
+        d = jax.random.normal(keys[1], (heads,))
+        if path == 'scan':
+            x = jax.random.normal(keys[2], (1, SSM_CHUNK, heads, hd),
+                                  jnp.bfloat16)
+            b = jax.random.normal(keys[3], (1, SSM_CHUNK, g, n),
+                                  jnp.bfloat16)
+            c = jax.random.normal(keys[4], (1, SSM_CHUNK, g, n),
+                                  jnp.bfloat16)
+            dt = jax.nn.softplus(
+                jax.random.normal(keys[5], (1, SSM_CHUNK, heads)) - 3.0)
+            state = jax.random.normal(keys[6], (1, heads, hd, n))
+            lengths = jnp.asarray([SSM_CHUNK - 37], jnp.int32)
+
+            def scan(x, dt, b, c, state, lengths):
+                y, h = ssm.ssm_scan(x, dt, a, b, c, d, state, lengths,
+                                    SSM_SUB)
+                valid = jnp.arange(SSM_CHUNK)[None, :] < lengths[:, None]
+                return jnp.where(valid[..., None, None], y, 0.0), h
+
+            def reference(x, dt, b, c, state, lengths):
+                y, h = ssm.ssm_reference(x, dt, a, b, c, d, state, lengths)
+                valid = jnp.arange(SSM_CHUNK)[None, :] < lengths[:, None]
+                return jnp.where(valid[..., None, None], y, 0.0), h
+
+            return _compare(jax.jit(scan), reference,
+                            (x, dt, b, c, state, lengths),
+                            relative_to_max=True)
+
+        slots = SSM_SLOTS
+        state = jax.random.normal(keys[2], (slots, heads, hd, n))
+        tail = jax.random.normal(keys[3], (slots, (SSM_TAPS - 1) * width),
+                                 jnp.bfloat16)
+        xbc = jax.random.normal(keys[4], (slots, width), jnp.bfloat16)
+        dt = jax.nn.softplus(jax.random.normal(keys[5], (slots, heads)) - 3.0)
+        weight = jax.random.uniform(keys[6], (SSM_TAPS, width),
+                                    minval=-0.5, maxval=0.5)
+        bias = jax.random.uniform(keys[7], (width,), minval=-0.5,
+                                  maxval=0.5)
+        live = jax.random.permutation(
+            keys[8], jnp.arange(slots) < live_lanes)
+
+        def update(state, tail, xbc, dt, live):
+            return ssm.ssm_update(state, tail, xbc, dt, a, d, weight, bias,
+                                  live, groups=g)
+
+        # The reference first: the call below gives its arrays away.
+        with jax.default_matmul_precision('highest'):
+            conv, _ = ssm.causal_conv(
+                xbc[:, None], tail.reshape(slots, SSM_TAPS - 1, width),
+                weight, bias, jnp.ones((slots,), jnp.int32))
+            act = jax.nn.silu(conv).astype(jnp.bfloat16)
+            y_ref, h_ref = jax.jit(ssm.ssm_reference)(
+                act[..., :inner].reshape(slots, 1, heads, hd), dt[:, None],
+                a, act[..., inner:inner + bc].reshape(slots, 1, g, n),
+                act[..., inner + bc:].reshape(slots, 1, g, n), d, state,
+                jnp.ones((slots,), jnp.int32))
+            keep = np.asarray(live)
+            want_y = np.where(keep[:, None, None], np.asarray(y_ref[:, 0]),
+                              0.0)
+            want_h = np.where(keep[:, None, None, None], np.asarray(h_ref),
+                              np.asarray(state))
+        t0 = time.perf_counter()
+        compiled = jax.jit(update, donate_argnums=(0, 1)).lower(
+            state, tail, xbc, dt, live).compile()
+        compile_s = time.perf_counter() - t0
+        y, state, tail = compiled(state, tail, xbc, dt, live)
+        err_y = float(np.max(np.abs(np.asarray(y) - want_y)))
+        err_h = float(np.max(np.abs(np.asarray(state) - want_h)))
+        scale = max(1.0, float(np.max(np.abs(want_y))))
+        ok = (err_y <= ATOL * scale and err_h <= ATOL * max(
+            1.0, float(np.max(np.abs(want_h)))))
+        batches = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(RUNS):
+                y, state, tail = compiled(state, tail, xbc, dt, live)
+            jax.block_until_ready(y)
+            batches.append((time.perf_counter() - t0) / RUNS)
+        return {'verdict': 'ok' if ok else 'mismatch',
+                'max_abs_err': round(max(err_y, err_h) / scale, 6),
+                'compile_s': round(compile_s, 2),
+                'run_us': round(sorted(batches)[2] * 1e6, 1),
+                'live_lanes': int(live_lanes)}
+    return case
+
+
 def _compare(kernel_fn, ref_fn, args, relative_to_max: bool = False
              ) -> Dict[str, Any]:
     """Compile + run both; the kernel's compile is where Mosaic
@@ -469,6 +585,17 @@ def cases() -> List[tuple]:
         ('sparse_latent/chunk/bf16/S=512/28blocks',
          'the same 13,824 tokens in: the slope a block of keys',
          _sparse_latent('chunk', offset=13824)),
+        ('ssm/update/f32/S=1/128rows/43live',
+         'a model with state by slot (models/nemotron_h.py): a decode '
+         'round\'s step, a third of the 128 lanes live',
+         _ssm('update', live_lanes=43), 'ssm_update'),
+        ('ssm/update/f32/S=1/128rows/128live',
+         'the same with every lane live: the slope a live row',
+         _ssm('update', live_lanes=128), 'ssm_update'),
+        ('ssm/scan/f32/S=512',
+         'the same: a 512-token prefill chunk from a given state, 37 '
+         'positions of padding',
+         _ssm('scan')),
         ('upstream_flash_attention/fwd+bwd/D=64/S=2048',
          'train_lm --seq >= 2048, GPT-2 heads',
          _flash(batch=2, heads=12, kv_heads=12, head_dim=64)),

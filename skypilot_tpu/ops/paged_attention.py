@@ -61,6 +61,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -432,6 +433,22 @@ class PoolArray:
 
 
 @dataclasses.dataclass(frozen=True)
+class SlotArray:
+    """One array of a layer's state BY SLOT: its leaf name in the
+    model's `cache` collection, the shape of one slot's row and its
+    dtype (None: the model's compute dtype). The array is [num_slots,
+    *shape]; a sequence keeps one row of it however long it is (a
+    state-space layer's recurrent state, its convolution's tail)."""
+    name: str
+    shape: Tuple[int, ...]
+    dtype: Any = None
+
+    def row_bytes(self, itemsize: int) -> int:
+        return math.prod(self.shape) * (
+            jnp.dtype(self.dtype).itemsize if self.dtype else itemsize)
+
+
+@dataclasses.dataclass(frozen=True)
 class PageLayout:
     """What a model keeps in the page pool for a cached token (D3a):
     the MODEL supplies it (`config.page_layout()`), and the engine, the
@@ -445,11 +462,23 @@ class PageLayout:
     beside it, where the model has a lightning indexer, that token's
     indexer key, [1, pages, page, index_head_dim]. Every array has the
     same four axes, so allocation, the page table, `_write_pool`, the
-    prefix keys and `pool_copy_lines` are the same code for both."""
+    prefix keys and `pool_copy_lines` are the same code for both.
+
+    `layers`: how many of the model's layers keep such a row (None:
+    every one). `slot_arrays` / `slot_layers`: what a SEQUENCE keeps
+    beside its pages, one row a slot in each of `slot_layers` layers
+    (a model with state-space layers; none for the others). The engine
+    allocates them with its slots, tells the model which slot a
+    prefill row belongs to and which decode lanes are live, and turns
+    off what assumes that a sequence is its pages (models/batching.py
+    `_refuse_slot_state`)."""
     kind: str
     arrays: Tuple[PoolArray, ...]
     page_size: int
     total_pages: int
+    layers: Optional[int] = None
+    slot_arrays: Tuple[SlotArray, ...] = ()
+    slot_layers: int = 0
 
     def shape(self, array: PoolArray) -> Tuple[int, int, int, int]:
         return (array.heads, self.total_pages, self.page_size,
@@ -471,8 +500,25 @@ class PageLayout:
         """Bytes a cached token takes in one layer."""
         return sum(self.array_bytes(a, itemsize) for a in self.arrays)
 
+    def slot_bytes(self, itemsize: int) -> int:
+        """Bytes a slot's state takes over all `slot_layers` layers."""
+        return self.slot_layers * sum(a.row_bytes(itemsize)
+                                      for a in self.slot_arrays)
+
+    def describe_slots(self, num_slots: int, itemsize: int
+                       ) -> Dict[str, Any]:
+        """The state by slot as /stats `state_pool` reports it."""
+        return {'arrays': {a.name: list(a.shape)
+                           for a in self.slot_arrays},
+                'layers': self.slot_layers,
+                'bytes_per_slot': self.slot_bytes(itemsize),
+                'slots': num_slots,
+                'bytes': num_slots * self.slot_bytes(itemsize)}
+
     def describe(self, num_layers: int, itemsize: int) -> Dict[str, Any]:
-        """The row as /stats `page_pool.row_layout` reports it."""
+        """The row as /stats `page_pool.row_layout` reports it, over
+        the `layers` that keep one (of the model's `num_layers`)."""
+        num_layers = self.layers or num_layers
         return {'kind': self.kind,
                 'arrays': {a.name: [a.heads, a.width]
                            for a in self.arrays},
@@ -493,6 +539,8 @@ def kv_layout(num_kv_heads: int, head_dim: int, page_size: int,
 #: Every leaf name a pool array may have: parallel/serving.py finds
 #: the pool's arrays in a cache tree by these.
 POOL_LEAF_NAMES = ('k_pages', 'v_pages', 'latent_pages', 'index_k_pages')
+#: Every leaf name an array of state by slot may have.
+SLOT_LEAF_NAMES = ('ssm_state', 'conv_state')
 
 
 def init_pages(num_kv_heads: int, total_pages: int, page_size: int,

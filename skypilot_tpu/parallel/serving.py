@@ -35,7 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from skypilot_tpu.ops.paged_attention import POOL_LEAF_NAMES
+from skypilot_tpu.ops.paged_attention import (POOL_LEAF_NAMES,
+                                               SLOT_LEAF_NAMES)
 from skypilot_tpu.parallel import mesh as mesh_lib
 
 
@@ -89,6 +90,10 @@ _PAGED_VALUE_LEAVES = POOL_LEAF_NAMES
 #: head-sharded device still needs the whole scale row to
 #: quantize/dequantize its own heads).
 _PAGED_SCALE_LEAVES = ('k_scales', 'v_scales')
+#: State by slot ([slots, *a slot's row]; ops/paged_attention
+#: .SlotArray): replicated (a tensor mesh is refused for such a model),
+#: and held to the same two guards as the pool, by its whole shape.
+_SLOT_LEAVES = SLOT_LEAF_NAMES
 _DENSE_LEAVES = ('cached_key', 'cached_value')
 
 
@@ -264,7 +269,7 @@ def pool_collective_lines(compiled: Any, cache: Any,
     for a in axes + axes:
         ways |= {w * a for w in ways}
     kv_names = (_PAGED_VALUE_LEAVES + _PAGED_SCALE_LEAVES +
-                _DENSE_LEAVES)
+                _DENSE_LEAVES + _SLOT_LEAVES)
     sizes = set()
     flat, _ = jax.tree_util.tree_flatten_with_path(cache)
     for path, leaf in flat:
@@ -304,13 +309,16 @@ def pool_copy_lines(compiled: Any, cache: Any) -> List[str]:
     a 5 GiB pool of 16 layers), which donation could not remove.
     `cache` supplies the k_pages / v_pages shapes (arrays or
     ShapeDtypeStructs); a shard of one along its leading kv-heads
-    axis counts as pool-shaped too. Returns the offending lines
-    (empty = guard green)."""
-    shapes = set()
+    axis counts as pool-shaped too, and so does a whole array of
+    state by slot (`ssm_state`, `conv_state`). Returns the offending
+    lines (empty = guard green)."""
+    shapes, whole = set(), set()
     flat, _ = jax.tree_util.tree_flatten_with_path(cache)
     for path, leaf in flat:
         if _leaf_name(path) in _PAGED_VALUE_LEAVES and len(leaf.shape) == 4:
             shapes.add(tuple(leaf.shape))
+        elif _leaf_name(path) in _SLOT_LEAVES:
+            whole.add(tuple(leaf.shape))
     text = compiled.as_text() if hasattr(compiled, 'as_text') \
         else str(compiled)
     hits = []
@@ -319,7 +327,8 @@ def pool_copy_lines(compiled: Any, cache: Any) -> List[str]:
         if m is None:
             continue
         dims = tuple(int(d) for d in m.group(1).split(','))
-        if any(len(dims) == 4 and dims[1:] == shape[1:] and
-               shape[0] % dims[0] == 0 for shape in shapes):
+        if dims in whole or any(
+                len(dims) == 4 and dims[1:] == shape[1:] and
+                shape[0] % dims[0] == 0 for shape in shapes):
             hits.append(line.strip())
     return hits

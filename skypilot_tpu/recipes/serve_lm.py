@@ -32,7 +32,21 @@ import os
 
 def main() -> None:
     parser = argparse.ArgumentParser()
-    parser.add_argument('--model', default='llama-tiny')
+    parser.add_argument('--model', default='llama-tiny',
+                        help='registry name (recipes/train_lm.py). A '
+                             'model with state-space layers '
+                             '(nemotron3-super-l11-ep4, '
+                             'nemotron-h-tiny) keeps recurrent state '
+                             'by SLOT beside its K/V pages (/stats '
+                             'state_pool): a cached, spilled or '
+                             'exported page holds no state to resume '
+                             'from, so prefix caching is off and '
+                             '--kv-spill-bytes/--kv-cold-dir, --role/'
+                             '--decode-peers, --stages, --tensor > 1, '
+                             '--speculative, --decode-chunk > 1, '
+                             '--kv-dtype int8 and the one-shot engine '
+                             'are refused by name (docs/guides.md '
+                             '"State by slot")')
     parser.add_argument('--hf', default=None, metavar='DIR',
                         help='serve a HuggingFace checkpoint from a '
                              'local directory (e.g. the target of an '

@@ -110,6 +110,25 @@ def _build_model(name: str, seq: int, remat: bool):
         if seq > cfg.max_seq_len:
             cfg = dataclasses.replace(cfg, max_seq_len=seq)
         return Deepseek(cfg), cfg.vocab_size, None
+    if name == 'nemotron3-super-l11-ep4':
+        # NVIDIA-Nemotron-3-Super-120B-A12B at every published width as
+        # ONE chip's share of a 4-way expert-parallel deployment: one
+        # period of 11 layers (5 Mamba-2, 5 expert, 1 attention),
+        # experts 0-127 of 512, a quarter of the vocabulary
+        # (perfbench/configs/nemotron3-super-l11-ep4.json). Serving
+        # only: its state lives in the engine's slots and pages.
+        from skypilot_tpu.models.nemotron_h import (NemotronH,
+                                                    NemotronHConfig)
+        cfg = NemotronHConfig.super_l11_ep4(max_seq_len=max(seq, 4096),
+                                            remat=remat)
+        return NemotronH(cfg), cfg.vocab_size, None
+    if name == 'nemotron-h-tiny':
+        from skypilot_tpu.models.nemotron_h import (NemotronH,
+                                                    NemotronHConfig)
+        cfg = NemotronHConfig.tiny(remat=remat)
+        if seq > cfg.max_seq_len:
+            cfg = dataclasses.replace(cfg, max_seq_len=seq)
+        return NemotronH(cfg), cfg.vocab_size, None
     if name == 'qwen2-7b':
         from skypilot_tpu.models.llama import Llama, LlamaConfig
         cfg = LlamaConfig(vocab_size=152064, num_layers=28,

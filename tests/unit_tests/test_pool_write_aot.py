@@ -295,3 +295,74 @@ def test_latent_chunk_takes_the_kernel_and_keeps_scores_out_of_hbm(
     assert ' dynamic-update-slice(' in text and ' scatter(' not in text
     assert [line for line in text.splitlines()
             if SCORES_SHAPED.search(line)] == calls
+
+
+# -- state by slot (models/nemotron_h.py, ops/ssm.py) -------------------------
+STATE_SLOTS, STATE_CHUNK = 128, 512
+
+
+def _mamba_layer(chunk):
+    """One Mamba-2 mixer of `nemotron3-super-l11-ep4` through the
+    engine's cache: a decode round over 128 slots, or a 512-token
+    prefill chunk behind its history in a slot given at run time."""
+    import flax.linen as nn
+    from skypilot_tpu.models import nemotron_h as nh
+    mixer = nh.Mamba2Mixer(nh.NemotronHConfig.super_l11_ep4())
+    rows = STATE_SLOTS if chunk == 1 else 1
+    u = jax.ShapeDtypeStruct((rows, chunk, 4096), jnp.bfloat16)
+    variables = nn.meta.unbox(jax.eval_shape(
+        lambda: mixer.init(jax.random.PRNGKey(0), jnp.zeros(
+            (STATE_SLOTS, 1, 4096), jnp.bfloat16), decode=True)))
+    params = jax.tree.map(
+        lambda s: (s.shape, jnp.bfloat16 if len(s.shape) > 1 else s.dtype),
+        variables['params'])
+    cache = jax.tree.map(lambda s: (s.shape, s.dtype), variables['cache'])
+
+    def fn(cache, params, u, live, slot):
+        out, mutated = mixer.apply(
+            {'params': params, 'cache': cache}, u, decode=True,
+            prefill=False, live=live,
+            slots=None if chunk == 1 else slot[None], mutable=['cache'])
+        return mutated['cache'], out
+
+    flat_cache, cache_def = jax.tree.flatten(
+        cache, is_leaf=lambda x: isinstance(x, tuple))
+    flat_params, params_def = jax.tree.flatten(
+        params, is_leaf=lambda x: isinstance(x, tuple))
+
+    def flat_fn(*args):
+        n, m = len(flat_cache), len(flat_params)
+        return fn(jax.tree.unflatten(cache_def, args[:n]),
+                  jax.tree.unflatten(params_def, args[n:n + m]),
+                  *args[n + m:])
+
+    avals = (*flat_cache, *flat_params, (u.shape, u.dtype),
+             ((rows, chunk), jnp.bool_), ((), jnp.int32))
+    return flat_fn, tuple(range(len(flat_cache))), avals, variables['cache']
+
+
+@pytest.mark.parametrize('chunk', [1, STATE_CHUNK],
+                         ids=['decode', 'prefill_chunk'])
+def test_state_by_slot_is_updated_where_it_lies(compile_for_chip, chunk):
+    """The second kind of cache at the cell's shapes (128 slots of
+    f32[128, 64, 128] state, 512 MiB a layer, and of bf16[30720]
+    convolution tail): a decode round's live rows' loop and a prefill
+    chunk's row, read at its slot and written back, compile for the
+    chip with no copy of either array and, on one chip, no
+    collective."""
+    from skypilot_tpu.parallel.serving import pool_collective_lines
+    fn, donate, avals, cache = _mamba_layer(chunk)
+    compiled = compile_for_chip(fn, donate, *avals)
+    assert {k: tuple(v.shape) for k, v in cache.items()
+            if k.endswith('_state')} == {
+        'ssm_state': (STATE_SLOTS, 128, 64, 128),
+        'conv_state': (STATE_SLOTS, 30720)}
+    assert pool_copy_lines(compiled, cache) == []
+    mesh = jax.sharding.Mesh([jax.devices()[0]], ('tensor',))
+    assert pool_collective_lines(compiled, cache, mesh) == []
+    text = compiled.as_text()
+    assert 'tpu_custom_call' not in text         # plain XLA, all of it
+    assert ' dynamic-update-slice(' in text
+    assert ' scatter(' not in text
+    # Each array goes in and comes out as the same buffer.
+    assert text.count('f32[128,128,64,128]') >= 2
