@@ -461,6 +461,8 @@ def make_server(rt: InferenceRuntime,
                 # The route of a whole prefill chunk's attention: a
                 # chip run that fell back to the XLA walk reads so.
                 'chunk_attention_impl': engine.chunk_attention_impl(),
+                # The route of a routed model's grouped expert pass.
+                'expert_impl': engine.expert_impl(),
                 'attention_bytes_per_token':
                     engine.attention_bytes_per_token(),
                 # Robustness plane (docs/guides.md serving-robustness

@@ -1724,6 +1724,19 @@ class ContinuousBatchingEngine:
             decode_pool=self._pool_aval,
             layout=self.page_layout.kind)
 
+    def expert_impl(self) -> str:
+        """The route of a routed model's grouped expert pass in this
+        engine's programs, as the model resolves it from its static
+        shapes inside the engine's mesh ('grouped_pallas': the kernel
+        of ops/pallas_experts.py; 'switch_loop'); 'none' for a model
+        that declares no `expert_impl`. Surfaced in /stats."""
+        route = getattr(self.model, 'expert_impl', None)
+        if route is None:
+            return 'none'
+        with (self.mesh if self.mesh is not None
+              else contextlib.nullcontext()):
+            return route()
+
     def chunk_attention_impl(self) -> str:
         """The route the attention of a whole `prefill_chunk` takes in
         this engine's traced prefill programs, beside

@@ -54,6 +54,7 @@ from skypilot_tpu.models.llama import (FeedForward as SwiGLU, RMSNorm,
                                        _proj)
 from skypilot_tpu.ops import attention as attention_ops
 from skypilot_tpu.ops import paged_attention as paged_ops
+from skypilot_tpu.ops import pallas_experts
 from skypilot_tpu.ops import sparse_latent
 
 Dtype = Any
@@ -612,39 +613,49 @@ def route(cfg: DeepseekConfig, logits: jax.Array, bias: jax.Array):
     return chosen, weights * cfg.routed_scaling_factor
 
 
-def _swiglu(x, w_gate, w_up, w_down):
-    gate = jnp.dot(x, w_gate, preferred_element_type=jnp.float32)
-    up = jnp.dot(x, w_up, preferred_element_type=jnp.float32)
-    h = (nn.silu(gate) * up).astype(x.dtype)
-    return jnp.dot(h, w_down, preferred_element_type=jnp.float32
-                   ).astype(x.dtype)
+def _expert_fn(act: str):
+    """One expert, [rows, d] -> [rows, d]: `pallas_experts.expert_hidden`
+    of the inner matrices, rounded to the rows' dtype, times `w_down`,
+    products accumulated in float32."""
+    def expert(x, *matrices):
+        h = pallas_experts.expert_hidden(act, x, *matrices[:-1])
+        return jnp.dot(h.astype(x.dtype), matrices[-1],
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+    return expert
 
 
-def _relu2(x, w_up, w_down):
-    h = jnp.dot(x, w_up, preferred_element_type=jnp.float32)
-    h = jnp.square(nn.relu(h)).astype(x.dtype)
-    return jnp.dot(h, w_down, preferred_element_type=jnp.float32
-                   ).astype(x.dtype)
-
+_swiglu = _expert_fn('swiglu')
 
 #: An expert's function by name, with its matrices' names in the order
 #: it takes them: `swiglu`, three of them (w_gate, w_up [d, f]; w_down
 #: [f, d]); `relu2`, two (w_up [d, f]; w_down [f, d]), the square of
 #: the ReLU between them.
 EXPERT_FNS = {'swiglu': (_swiglu, ('w_gate', 'w_up', 'w_down')),
-              'relu2': (_relu2, ('w_up', 'w_down'))}
+              'relu2': (_expert_fn('relu2'), ('w_up', 'w_down'))}
+
+
+def expert_route(cfg: Any, act: str, width: int) -> str:
+    """The route `MoEByShare`'s grouped pass takes for held experts of
+    `act` on rows `width` wide: `pallas_experts.resolve_route`'s answer
+    for the matrices' static shapes ('grouped_pallas' or
+    'switch_loop')."""
+    return pallas_experts.resolve_route(
+        width, cfg.moe_dim, len(EXPERT_FNS[act][1]), cfg.dtype)
 
 
 def grouped_experts(x_sorted: jax.Array, counts: jax.Array,
                     experts, row_block: int,
                     expert_fn=_swiglu) -> jax.Array:
-    """Experts over tokens sorted by expert, dropless.
+    """Experts over tokens sorted by expert, dropless: route
+    'switch_loop', taken on the CPU backend and for a shape
+    `pallas_experts.kernel_refusal` refuses, and `kernel_check`'s
+    reference for the kernel of ops/pallas_experts.py.
 
     x_sorted [M, d]: expert 0's `counts[0]` rows first, then expert
     1's, ...; rows past sum(counts) belong to nobody. `experts`: the
     matrices of each expert held, in the order `expert_fn(x, *matrices)`
-    takes them ([rows, d] -> [rows, d]: `_swiglu` with three,
-    `_relu2` with two). One loop over blocks of `row_block` rows, each
+    takes them ([rows, d] -> [rows, d]: `swiglu` with three, `relu2`
+    with two). One loop over blocks of `row_block` rows, each
     inside ONE expert's rows, so an expert is multiplied as often as
     its tokens need and one that received none is never read; the trip
     count is known on the device only. Returns [M, d] (rows past
@@ -666,7 +677,9 @@ def grouped_experts(x_sorted: jax.Array, counts: jax.Array,
         # copies a slice of stacked weights out whole before it
         # multiplies by it, at a traced index and at a static one
         # alike (as many times an expert's bytes moved as it has
-        # matrices).
+        # matrices). A program carries every branch of every layer,
+        # and no read overlaps the previous expert's product: on a TPU
+        # the kernel of ops/pallas_experts.py takes the pass instead.
         y = jax.lax.switch(
             e, [lambda x, w=tuple(w): expert_fn(x, *w) for w in experts],
             x)
@@ -841,9 +854,17 @@ class MoEByShare(nn.Module):
             counts = jnp.sum(
                 local[:, None] == jnp.arange(held)[None, :], axis=0
             ).astype(jnp.int32)
-            y_sorted = grouped_experts(
-                flat[order // top], counts, experts,
-                min(n, EXPERT_ROW_BLOCK), EXPERT_FNS[self.expert_act][0])
+            if expert_route(cfg, self.expert_act, width) == 'switch_loop':
+                y_sorted = grouped_experts(
+                    flat[order // top], counts, experts,
+                    min(n, EXPERT_ROW_BLOCK),
+                    EXPERT_FNS[self.expert_act][0])
+            else:
+                # Off a TPU only a test reaches this: the interpreter.
+                y_sorted = pallas_experts.grouped_experts_pallas(
+                    flat[order // top], counts, experts,
+                    act=self.expert_act,
+                    interpret=not pallas_experts.available())
             parts = y_sorted[jnp.argsort(order)].reshape(n, top, width)
             routed = jnp.sum(
                 parts.astype(jnp.float32)
@@ -908,6 +929,11 @@ class Deepseek(nn.Module):
     takes_live_mask = True
     counter_leaves = ('expert_tokens', 'expert_calls_touched',
                       'sparse_decode_tokens')
+
+    def expert_impl(self) -> str:
+        """The route of the routed layers' grouped expert pass
+        (`expert_route`; /stats `expert_impl`)."""
+        return expert_route(self.config, 'swiglu', self.config.embed_dim)
 
     @nn.compact
     def __call__(self, tokens: jax.Array,

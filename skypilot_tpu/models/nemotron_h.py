@@ -43,7 +43,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from skypilot_tpu.models.deepseek import MoEByShare
+from skypilot_tpu.models.deepseek import MoEByShare, expert_route
 from skypilot_tpu.models.llama import RMSNorm, _proj
 from skypilot_tpu.ops import attention as attention_ops
 from skypilot_tpu.ops import paged_attention as paged_ops
@@ -397,6 +397,12 @@ class NemotronH(nn.Module):
     takes_live_mask = True
     counter_leaves = ('expert_tokens', 'expert_calls_touched',
                       'ssm_update_tokens', 'ssm_scan_tokens')
+
+    def expert_impl(self) -> str:
+        """The route of the expert layers' grouped pass, in the
+        latent (models/deepseek.expert_route; /stats `expert_impl`)."""
+        return expert_route(self.config, 'relu2',
+                            self.config.moe_latent_dim)
 
     @nn.compact
     def __call__(self, tokens: jax.Array,

@@ -32,7 +32,12 @@ are ops/ssm.py at Nemotron 3 Super's widths (128 heads of 64, state
 third and all of the lanes live, the state and the tails donated from
 call to call so that the update in place is what is timed, and a
 512-token prefill chunk, each against the float32 recurrence step by
-step. It
+step. The `experts/...` cases are one routed layer's held experts
+through the kernel of ops/pallas_experts.py at the two routed cells'
+published widths (Nemotron 3 Super's 128 `relu2` experts of 1,024 x
+2,688, DeepSeek-V3.2's 16 `swiglu` experts of 7,168 x 2,048), a decode
+round and a 512-token chunk each, against the `lax.switch` loop on the
+operands in float32, with the bf16 loop's own run time (`loop_us`). It
 exits non-zero when any case is not `ok`, and when the backend is not
 a TPU: a CPU run of this file would check nothing.
 
@@ -46,6 +51,7 @@ a TPU's default f32 matmul is itself bf16-pass.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import sys
 import time
@@ -493,6 +499,90 @@ def _ssm(path: str, live_lanes: int = 0) -> Callable[[Any], Dict]:
     return case
 
 
+# The held experts of the two routed cells, at their published widths:
+# (activation, experts held, experts routed over, chosen a token, rows
+# of `dim`, expert width, tokens of a decode round and of a chunk).
+EXPERTS = {
+    'relu2': ('relu2', 128, 512, 22, 1024, 2688, 128, 512),   # Nemotron 3
+    'swiglu': ('swiglu', 16, 256, 8, 7168, 2048, 48, 512),    # DeepSeek-V3.2
+}
+
+
+def _experts(act: str, phase: str) -> Callable[[Any], Dict]:
+    """One routed layer's grouped expert pass (ops/pallas_experts.py,
+    route 'grouped_pallas') at a cell's published widths, against the
+    `lax.switch` loop it replaces (models/deepseek.grouped_experts) on
+    the operands in float32: 'decode' a round of the cell's slots, a
+    third of them live; 'chunk' a 512-token prefill chunk. Each token
+    picks its experts uniformly among all the router's; those this
+    chip holds are sorted as `MoEByShare` sorts them. Also times the
+    loop itself on the bf16 operands (`loop_us`)."""
+
+    def case(key):
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        from skypilot_tpu.models import deepseek as ds
+        from skypilot_tpu.ops import pallas_experts
+        _, held, routed, top, dim, width, slots, chunk = EXPERTS[act]
+        n_mat = pallas_experts.ACTS[act]
+        got = pallas_experts.resolve_route(dim, width, n_mat, jnp.bfloat16)
+        if got != 'grouped_pallas':
+            return _wrong_route('grouped_pallas', got)
+        tokens = slots if phase == 'decode' else chunk
+        keys = jax.random.split(key, 3 + held)
+        rng = np.random.default_rng(int(jax.random.randint(
+            keys[0], (), 0, 1 << 30)))
+        chosen = np.stack([rng.choice(routed, top, replace=False)
+                           for _ in range(tokens)])
+        live = (np.arange(tokens) % 3 == 0 if phase == 'decode'
+                else np.ones(tokens, bool))
+        here = (chosen < held) & live[:, None]
+        local = np.where(here, chosen, held).reshape(-1)
+        order = np.argsort(local, kind='stable')
+        counts = jnp.asarray(np.bincount(local, minlength=held + 1)[:held],
+                             jnp.int32)
+        x = jax.random.normal(keys[1], (tokens, dim), jnp.bfloat16)
+        x_sorted = x[jnp.asarray(order // top)]
+        experts = tuple(tuple(
+            (0.02 * jax.random.normal(
+                jax.random.fold_in(keys[3 + e], j),
+                (width, dim) if j == n_mat - 1 else (dim, width))
+             ).astype(jnp.bfloat16) for j in range(n_mat))
+            for e in range(held))
+        fn = ds.EXPERT_FNS[act][0]
+        row_block = min(tokens, ds.EXPERT_ROW_BLOCK)
+
+        def loop(x_sorted, counts, experts):
+            return ds.grouped_experts(x_sorted, counts, experts, row_block,
+                                      fn)
+
+        def reference(x_sorted, counts, experts):
+            return loop(x_sorted.astype(jnp.float32), counts,
+                        jax.tree.map(lambda w: w.astype(jnp.float32),
+                                     experts))
+
+        args = (x_sorted, counts, experts)
+        res = _compare(jax.jit(functools.partial(
+            pallas_experts.grouped_experts_pallas, act=act)), reference,
+            args, relative_to_max=True)
+        compiled = jax.jit(loop).lower(*args).compile()
+        jax.block_until_ready(compiled(*args))
+        batches = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(RUNS):
+                out = compiled(*args)
+            jax.block_until_ready(out)
+            batches.append((time.perf_counter() - t0) / RUNS)
+        res.update(loop_us=round(sorted(batches)[2] * 1e6, 1),
+                   rows=int(x_sorted.shape[0]),
+                   rows_held=int(counts.sum()),
+                   experts_touched=int((counts > 0).sum()))
+        return res
+    return case
+
+
 def _compare(kernel_fn, ref_fn, args, relative_to_max: bool = False
              ) -> Dict[str, Any]:
     """Compile + run both; the kernel's compile is where Mosaic
@@ -596,6 +686,21 @@ def cases() -> List[tuple]:
          'the same: a 512-token prefill chunk from a given state, 37 '
          'positions of padding',
          _ssm('scan')),
+        ('experts/relu2/decode',
+         'a routed model (models/deepseek.MoEByShare) on a TPU: the '
+         'held experts of nemotron3-super-l11-ep4, a decode round of '
+         '128 slots, a third live (2,816 rows)',
+         _experts('relu2', 'decode')),
+        ('experts/relu2/chunk',
+         'the same: a 512-token prefill chunk (11,264 rows)',
+         _experts('relu2', 'chunk')),
+        ('experts/swiglu/decode',
+         'the held experts of deepseek-v32-l5-ep16: a decode round of '
+         '48 slots, a third live (384 rows)',
+         _experts('swiglu', 'decode')),
+        ('experts/swiglu/chunk',
+         'the same: a 512-token prefill chunk (4,096 rows)',
+         _experts('swiglu', 'chunk')),
         ('upstream_flash_attention/fwd+bwd/D=64/S=2048',
          'train_lm --seq >= 2048, GPT-2 heads',
          _flash(batch=2, heads=12, kv_heads=12, head_dim=64)),

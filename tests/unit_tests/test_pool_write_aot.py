@@ -366,3 +366,52 @@ def test_state_by_slot_is_updated_where_it_lies(compile_for_chip, chunk):
     assert ' scatter(' not in text
     # Each array goes in and comes out as the same buffer.
     assert text.count('f32[128,128,64,128]') >= 2
+
+
+# -- the grouped expert pass (models/deepseek.MoEByShare, ops/pallas_experts.py)
+@pytest.mark.parametrize('model', ['nemotron3-super-l11-ep4',
+                                   'deepseek-v32-l5-ep16'])
+def test_expert_layer_takes_the_kernel_and_no_switch(compile_for_chip,
+                                                     monkeypatch, model):
+    """One routed layer of each cell's decode round at its published
+    widths (128 held `relu2` experts 1,024 x 2,688 in the latent at 128
+    slots; 16 held `swiglu` experts 7,168 x 2,048 at 48 slots), the
+    backend steered to the chip's route: the held experts are ONE call
+    of the kernel, by its `name=` inside the scope the trace is read by,
+    and the program holds no conditional (the `lax.switch` loop's 128
+    branches are gone)."""
+    import flax.linen as nn
+    from skypilot_tpu.models import deepseek as ds
+    from skypilot_tpu.models import nemotron_h as nh
+    from skypilot_tpu.ops import pallas_experts
+    monkeypatch.setattr(pallas_experts, 'available', lambda: True)
+    if model.startswith('nemotron'):
+        cfg, slots = nh.NemotronHConfig.super_l11_ep4(), 128
+        layer = ds.MoEByShare(cfg, expert_act='relu2',
+                              latent_dim=cfg.moe_latent_dim,
+                              shared_dim=cfg.moe_shared_dim,
+                              stacked_init=True)
+    else:
+        cfg, slots = ds.DeepseekConfig.v32_l5_ep16(), 48
+        layer = ds.MoEByShare(cfg)
+    x = jax.ShapeDtypeStruct((slots, 1, cfg.embed_dim), jnp.bfloat16)
+    live = jax.ShapeDtypeStruct((slots, 1), jnp.bool_)
+    params = jax.eval_shape(lambda: nn.unbox(layer.init(
+        jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype),
+        jnp.ones(live.shape, bool))))['params']
+    flat, tree = jax.tree.flatten(params)
+
+    def fn(*args):
+        *leaves, x, live = args
+        return layer.apply({'params': jax.tree.unflatten(tree, leaves)},
+                           x, live)
+
+    compiled = compile_for_chip(
+        fn, (), *[(a.shape, a.dtype) for a in flat],
+        (x.shape, x.dtype), (live.shape, live.dtype))
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1, calls
+    assert '/experts/' in calls[0] and 'grouped_experts' in calls[0]
+    assert ' conditional(' not in text
