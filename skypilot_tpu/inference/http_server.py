@@ -103,13 +103,28 @@ def _submit_all(engine, rows: List[List[int]], **kw):
     return futs
 
 
+class _Server(ThreadingHTTPServer):
+    """The replica's HTTP server; stopping it unhooks the process's
+    GC pause counter that `make_server` hooked in."""
+
+    def shutdown(self) -> None:
+        super().shutdown()
+        tracing.remove_gc_pauses()
+
+    def server_close(self) -> None:
+        super().server_close()
+        tracing.remove_gc_pauses()
+
+
 def make_server(rt: InferenceRuntime,
                 port: int) -> ThreadingHTTPServer:
     """Build the (not yet serving) HTTP server for `rt`. Split from
     `serve()` so tests can run it on an ephemeral port from a thread
     (serve() additionally installs the SIGTERM drain, which only
     works on the main thread). The in-flight POST count rides on the
-    returned server as `.inflight`/`.inflight_lock`."""
+    returned server as `.inflight`/`.inflight_lock`. Hooks the
+    process's GC pause counter (`/stats gc`) until the server's
+    shutdown."""
 
     # Live POSTs (graceful drain waits on this, covering the window
     # between accept and engine submit and the one-shot engine).
@@ -384,7 +399,11 @@ def make_server(rt: InferenceRuntime,
                     },
                     # Per-device bytes in use / peak, from the process
                     # that holds the chips (nulls off-accelerator).
-                    'device_memory': _mesh_lib.device_memory()}
+                    'device_memory': _mesh_lib.device_memory(),
+                    # This process's garbage collections by generation:
+                    # count, seconds, longest pause (docs/guides.md
+                    # "Scheduler phases").
+                    'gc': tracing.gc_pauses.stats()}
             if rt.role or rt.handoffs_total or rt.kv_imports_total:
                 body['handoff'] = rt.handoff_stats()
             mig = rt.migration_stats()
@@ -435,6 +454,10 @@ def make_server(rt: InferenceRuntime,
                 'decode_stall_s': round(engine.decode_stall_s, 6),
                 'phases': engine.phase_stats(),
                 'loop_s': round(engine.loop_s, 6),
+                # One level down: page growth, a round's inputs, each
+                # program's enqueue, the counter fetch below; each is
+                # also inside one of `phases`.
+                'dispatch_phases': engine.dispatch_phases.stats(),
                 'time': time.time(),
                 # Every request's intervals on the way to its first
                 # token, as bucket counts (the histograms /metrics
@@ -1553,7 +1576,8 @@ def make_server(rt: InferenceRuntime,
             rt.record_migration('local_fallback',
                                 time.monotonic() - t0, ok=True)
 
-    server = ThreadingHTTPServer(('0.0.0.0', port), Handler)
+    tracing.install_gc_pauses()
+    server = _Server(('0.0.0.0', port), Handler)
     server.inflight = _inflight            # type: ignore[attr-defined]
     server.inflight_lock = _inflight_lock  # type: ignore[attr-defined]
     server.draining = _draining            # type: ignore[attr-defined]

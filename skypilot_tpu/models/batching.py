@@ -738,6 +738,12 @@ class ContinuousBatchingEngine:
         # self seconds and counts per name, served by /stats
         # (`phases`, `loop_s`, `decode_stall_s`) and /metrics.
         self.phases = tracing.PhaseClock()
+        # One level down, on a clock of their own so that the phases
+        # above keep their self seconds: page growth, a round's inputs
+        # and each program's enqueue inside the dispatch phases, and
+        # /stats' counter fetch inside `engine.control` (/stats
+        # `dispatch_phases`).
+        self.dispatch_phases = tracing.PhaseClock()
         self.last_prefill_tokens = 0     # budget spent, last iteration
         # Live migration (PR 20): sessions evacuated off this engine
         # (drain / preemption notice / rebalance) and the subset whose
@@ -1696,13 +1702,15 @@ class ContinuousBatchingEngine:
             return {}
 
         def op():
-            flat, _ = jax.tree_util.tree_flatten_with_path(self.cache)
-            picked = {}
-            for path, leaf in flat:
-                keys = [getattr(e, 'key', None) for e in path]
-                if keys and keys[-1] in names:
-                    picked[(keys[-1], '/'.join(keys[:-1]))] = leaf
-            return jax.device_get(picked)
+            with tracing.phase('engine.stats_counters',
+                               self.dispatch_phases):
+                flat, _ = jax.tree_util.tree_flatten_with_path(self.cache)
+                picked = {}
+                for path, leaf in flat:
+                    keys = [getattr(e, 'key', None) for e in path]
+                    if keys and keys[-1] in names:
+                        picked[(keys[-1], '/'.join(keys[:-1]))] = leaf
+                return jax.device_get(picked)
 
         out: Dict[str, Any] = {}
         for (name, block), value in self.run_on_scheduler(op).items():
@@ -1939,8 +1947,7 @@ class ContinuousBatchingEngine:
         """{phase: {'n', 's'}} of the scheduler loop, self seconds;
         `loop_s` less their sum is what no phase covers (racy read:
         at worst one iteration apart)."""
-        return {name: {'n': int(rec[0]), 's': round(rec[1], 6)}
-                for name, rec in sorted(self.phases.totals.items())
+        return {name: rec for name, rec in self.phases.stats().items()
                 if name != 'engine.loop'}
 
     # -- KV page transfer + tiered cache ------------------------------------
@@ -2998,25 +3005,21 @@ class ContinuousBatchingEngine:
             kw['slot'] = jnp.int32(slot)
         if self.paged and offset:
             fn = self._prefill_suffix_fn(shape)
-            self.cache, last = fn(
-                self.params, self.cache, padded, jnp.int32(n),
-                jnp.int32(offset),
-                jnp.asarray(self.page_table[slot:slot + 1]), **kw)
+            args = (padded, jnp.int32(n), jnp.int32(offset),
+                    jnp.asarray(self.page_table[slot:slot + 1]))
         elif self.paged:
             fn = self._prefill_fn(shape)
-            self.cache, last = fn(
-                self.params, self.cache, padded, jnp.int32(n),
-                jnp.asarray(self.page_table[slot:slot + 1]), **kw)
+            args = (padded, jnp.int32(n),
+                    jnp.asarray(self.page_table[slot:slot + 1]))
         elif offset:
             fn = self._dense_suffix_fn(shape)
-            self.cache, last = fn(
-                self.params, self.cache, jnp.int32(slot), padded,
-                jnp.int32(n), jnp.int32(offset), **kw)
+            args = (jnp.int32(slot), padded, jnp.int32(n),
+                    jnp.int32(offset))
         else:
             fn = self._prefill_fn(shape)
-            self.cache, last = fn(
-                self.params, self.cache, jnp.int32(slot), padded,
-                jnp.int32(n), **kw)
+            args = (jnp.int32(slot), padded, jnp.int32(n))
+        with tracing.phase('engine.prefill_enqueue', self.dispatch_phases):
+            self.cache, last = fn(self.params, self.cache, *args, **kw)
         self.prefill_chunks_run += 1
         return last
 
@@ -3166,75 +3169,78 @@ class ContinuousBatchingEngine:
         instead of failing it. Requests that can never fit the pool
         fail loudly at admission. Sampled (temperature>0) requests may
         diverge across a preemption (fresh RNG); greedy decoding is
-        unaffected."""
-        preempted = []
-        for slot in range(self.num_slots):
-            if not self.active[slot]:
-                continue
-            # Clamp to the page-table row's capacity: the pipelined
-            # loop's trailing round can write ONE position past a
-            # finishing lane's limit, and legit writes never exceed
-            # the table (construction headroom) — an out-of-capacity
-            # junk write clamps into the lane's own released pages,
-            # which every next owner rewrites before attending.
-            need_tokens = min(int(self.pos[slot]) + lookahead,
-                              self.pages_per_seq * self.page_size)
-            exhausted = False
-            while int(self.allocated_tokens[slot]) < need_tokens:
-                # Allocation is logically contiguous: the next logical
-                # page index == pages already allocated.
-                logical = int(self.allocated_tokens[slot]) \
-                    // self.page_size
-                if not self.allocator.can_allocate(1) and \
-                        self.prefix_cache is not None:
-                    # Unreferenced cached prefixes yield before any
-                    # live sequence gets preempted.
-                    self.prefix_cache.evict_into(self.allocator, 1)
-                if not self.allocator.can_allocate(1):
-                    exhausted = True
-                    break
-                page = self.allocator.allocate(1)[0]
-                self.owned_pages[slot].append(page)
-                self.page_table[slot, logical] = page
-                self.allocated_tokens[slot] += self.page_size
-            if not exhausted:
-                continue
-            # Preempt: outputs-so-far become the prompt; the pending
-            # cur_token is regenerated by the re-prefill. The adapter
-            # ref drops with the slot (re-acquired — and reloaded if
-            # evicted meanwhile — at re-admission).
-            fut = self.futures[slot]
-            adapter_name = self.slot_adapter_name[slot]
-            remaining = int(self.limits[slot]) - len(self.outputs[slot])
-            self.futures[slot] = None
-            self.active[slot] = False
-            self.preemptions += 1
-            self.metrics.preemptions.inc()
-            self.flight.record(
-                'preempt', slot=slot,
-                generated=len(self.outputs[slot]) -
-                int(self.prompt_len[slot]))
-            ctx = self._slot_ctx[slot]
-            self._slot_ctx[slot] = None
-            self._release_adapter(slot)
-            self._release_slot_pages(slot, promote=False)
-            if fut is not None:
-                # The trace ctx rides the re-queued request: its
-                # re-admission emits a second queue-wait span.
-                tref = (ctx, time.perf_counter())
-                preempted.append((list(self.outputs[slot]),
-                                  max(remaining, 1),
-                                  float(self.temps[slot]),
-                                  int(self.top_ks[slot]),
-                                  float(self.top_ps[slot]),
-                                  self.stop_ids[slot],
-                                  adapter_name, tref,
-                                  self.on_tokens[slot],
-                                  float(self.deadlines[slot]), fut))
-                self._queued_tokens_add(len(self.outputs[slot]))
-        # Back to the HEAD preserving pass order (repeated appendleft
-        # would reverse it — an FCFS fairness inversion).
-        self._ready.extendleft(reversed(preempted))
+        unaffected. One `engine.grow_pages` phase a call."""
+        with tracing.phase('engine.grow_pages', self.dispatch_phases):
+            preempted = []
+            for slot in range(self.num_slots):
+                if not self.active[slot]:
+                    continue
+                # Clamp to the page-table row's capacity: the
+                # pipelined loop's trailing round can write ONE
+                # position past a finishing lane's limit, and legit
+                # writes never exceed the table (construction
+                # headroom) — an out-of-capacity junk write clamps
+                # into the lane's own released pages, which every
+                # next owner rewrites before attending.
+                need_tokens = min(int(self.pos[slot]) + lookahead,
+                                  self.pages_per_seq * self.page_size)
+                exhausted = False
+                while int(self.allocated_tokens[slot]) < need_tokens:
+                    # Allocation is logically contiguous: the next
+                    # logical page index == pages already allocated.
+                    logical = int(self.allocated_tokens[slot]) \
+                        // self.page_size
+                    if not self.allocator.can_allocate(1) and \
+                            self.prefix_cache is not None:
+                        # Unreferenced cached prefixes yield before
+                        # any live sequence gets preempted.
+                        self.prefix_cache.evict_into(self.allocator, 1)
+                    if not self.allocator.can_allocate(1):
+                        exhausted = True
+                        break
+                    page = self.allocator.allocate(1)[0]
+                    self.owned_pages[slot].append(page)
+                    self.page_table[slot, logical] = page
+                    self.allocated_tokens[slot] += self.page_size
+                if not exhausted:
+                    continue
+                # Preempt: outputs-so-far become the prompt; the
+                # pending cur_token is regenerated by the re-prefill.
+                # The adapter ref drops with the slot (re-acquired —
+                # and reloaded if evicted meanwhile — at re-admission).
+                fut = self.futures[slot]
+                adapter_name = self.slot_adapter_name[slot]
+                remaining = int(self.limits[slot]) - \
+                    len(self.outputs[slot])
+                self.futures[slot] = None
+                self.active[slot] = False
+                self.preemptions += 1
+                self.metrics.preemptions.inc()
+                self.flight.record(
+                    'preempt', slot=slot,
+                    generated=len(self.outputs[slot]) -
+                    int(self.prompt_len[slot]))
+                ctx = self._slot_ctx[slot]
+                self._slot_ctx[slot] = None
+                self._release_adapter(slot)
+                self._release_slot_pages(slot, promote=False)
+                if fut is not None:
+                    # The trace ctx rides the re-queued request: its
+                    # re-admission emits a second queue-wait span.
+                    tref = (ctx, time.perf_counter())
+                    preempted.append((list(self.outputs[slot]),
+                                      max(remaining, 1),
+                                      float(self.temps[slot]),
+                                      int(self.top_ks[slot]),
+                                      float(self.top_ps[slot]),
+                                      self.stop_ids[slot],
+                                      adapter_name, tref,
+                                      self.on_tokens[slot],
+                                      float(self.deadlines[slot]), fut))
+                    self._queued_tokens_add(len(self.outputs[slot]))
+            # Back to the HEAD preserving pass order (repeated
+            # appendleft would reverse it — an FCFS fairness inversion).
+            self._ready.extendleft(reversed(preempted))
 
     def _release_slot_pages(self, slot: int, promote: bool) -> None:
         """Return a slot's pages: shared refs drop (page stays cached),
@@ -3380,26 +3386,30 @@ class ContinuousBatchingEngine:
             else:
                 self._pipelined_decode_step()
             return
-        self._rng, sub = jax.random.split(self._rng)
-        extra = ()
-        if self.paged:
-            self._grow_pages()
-            if not self.active.any():
-                return  # _grow_pages may have failed the last slot
-            extra = (jnp.asarray(self.page_table),)
-        # Every lane rides the round; an inactive one's token is
-        # dropped at the commit. Its write lands at whatever `pos`
-        # holds: an empty slot's last position (its page-table row is
-        # zeroed on release, so a paged write goes to the trash page;
-        # a dense row is zeroed by the next prefill), a PREFILLING
-        # slot's frontier, which the next chunk overwrites before
-        # attending.
-        self.cache, sampled = self._decode(
-            self.params, self.cache,
-            jnp.asarray(self.cur_token), jnp.asarray(self.pos),
-            jnp.asarray(self.temps), jnp.asarray(self.top_ks),
-            jnp.asarray(self.top_ps), sub, *extra,
-            **self._lora_args(), **self._live_args())
+        # The round's inputs (page growth nests in them, on the same
+        # clock), then the enqueue alone.
+        with tracing.phase('engine.round_inputs', self.dispatch_phases):
+            self._rng, sub = jax.random.split(self._rng)
+            extra = ()
+            if self.paged:
+                self._grow_pages()
+                if not self.active.any():
+                    return  # _grow_pages may have failed the last slot
+                extra = (jnp.asarray(self.page_table),)
+            # Every lane rides the round; an inactive one's token is
+            # dropped at the commit. Its write lands at whatever `pos`
+            # holds: an empty slot's last position (its page-table row
+            # is zeroed on release, so a paged write goes to the trash
+            # page; a dense row is zeroed by the next prefill), a
+            # PREFILLING slot's frontier, which the next chunk
+            # overwrites before attending.
+            inputs = (jnp.asarray(self.cur_token), jnp.asarray(self.pos),
+                      jnp.asarray(self.temps), jnp.asarray(self.top_ks),
+                      jnp.asarray(self.top_ps), sub, *extra)
+            kw = {**self._lora_args(), **self._live_args()}
+        with tracing.phase('engine.decode_enqueue', self.dispatch_phases):
+            self.cache, sampled = self._decode(self.params, self.cache,
+                                               *inputs, **kw)
         sampled = self._fetch_tokens(sampled)
         self.decode_calls += 1
         self.metrics.decode_steps.inc()
@@ -3470,34 +3480,37 @@ class ContinuousBatchingEngine:
                              else 1)
             if not self.active.any():
                 return None
-        joined = self._first_pending & self.active
-        if inflight is None:
-            cont = np.zeros((self.num_slots,), bool)
-            pos = self.pos.copy()
-            sampled = self._first_tokens    # no lane reads it
-        else:
-            cont = np.array(
-                [bool(inflight['mask'][s]) and bool(self.active[s])
-                 and self.futures[s] is inflight['futs'][s]
-                 for s in range(self.num_slots)])
-            pos = np.where(cont, inflight['pos'] + 1,
-                           self.pos).astype(np.int32)
-            sampled = inflight['sampled']
-        staying = None
-        if self._takes_live:
-            staying = np.array(
-                [not cont[s] or
-                 len(self.outputs[s]) + 1 < int(self.limits[s])
-                 for s in range(self.num_slots)])
-        cur = _merge_cur_tokens(cont, sampled, joined,
-                                self._first_tokens, self.cur_token)
-        extra = (jnp.asarray(self.page_table),) if self.paged else ()
-        self._rng, sub = jax.random.split(self._rng)
-        self.cache, sampled = self._decode(
-            self.params, self.cache, cur, jnp.asarray(pos),
-            jnp.asarray(self.temps), jnp.asarray(self.top_ks),
-            jnp.asarray(self.top_ps), sub, *extra,
-            **self._lora_args(), **self._live_args(staying))
+        with tracing.phase('engine.round_inputs', self.dispatch_phases):
+            joined = self._first_pending & self.active
+            if inflight is None:
+                cont = np.zeros((self.num_slots,), bool)
+                pos = self.pos.copy()
+                sampled = self._first_tokens    # no lane reads it
+            else:
+                cont = np.array(
+                    [bool(inflight['mask'][s]) and bool(self.active[s])
+                     and self.futures[s] is inflight['futs'][s]
+                     for s in range(self.num_slots)])
+                pos = np.where(cont, inflight['pos'] + 1,
+                               self.pos).astype(np.int32)
+                sampled = inflight['sampled']
+            staying = None
+            if self._takes_live:
+                staying = np.array(
+                    [not cont[s] or
+                     len(self.outputs[s]) + 1 < int(self.limits[s])
+                     for s in range(self.num_slots)])
+            cur = _merge_cur_tokens(cont, sampled, joined,
+                                    self._first_tokens, self.cur_token)
+            extra = (jnp.asarray(self.page_table),) if self.paged else ()
+            self._rng, sub = jax.random.split(self._rng)
+            inputs = (cur, jnp.asarray(pos), jnp.asarray(self.temps),
+                      jnp.asarray(self.top_ks), jnp.asarray(self.top_ps),
+                      sub, *extra)
+            kw = {**self._lora_args(), **self._live_args(staying)}
+        with tracing.phase('engine.decode_enqueue', self.dispatch_phases):
+            self.cache, sampled = self._decode(self.params, self.cache,
+                                               *inputs, **kw)
         # This round carries the joined lanes' first tokens; its
         # commit fetches them from the vector as it stands now.
         self._first_pending[:] = False

@@ -46,10 +46,16 @@ its self time to a per-name accumulator its caller owns (served as
 counters through `/stats` and the `--metrics-file` records) and is a
 `jax.profiler.TraceAnnotation`, so that under any profiler session it
 lies in the host plane of the same trace as the device operations.
+
+The process's garbage collections are the third (`GcPauses`,
+installed by the inference server): each pause is an `engine.gc`
+annotation on the line of the thread that collected, and is counted
+per generation.
 """
 from __future__ import annotations
 
 import collections
+import gc
 import random
 import threading
 import time
@@ -291,9 +297,25 @@ class PhaseClock:
         """Accumulated whole durations of `name`, children included."""
         return self.totals.get(name, _ZERO)[2]
 
+    def stats(self) -> Dict[str, Dict[str, Any]]:
+        """{name: {'n', 's'}}, self seconds (racy read: at worst one
+        phase apart)."""
+        return {name: {'n': int(rec[0]), 's': round(rec[1], 6)}
+                for name, rec in sorted(self.totals.items())}
+
 
 _ZERO = (0, 0.0, 0.0)
 _annotation = None      # jax.profiler.TraceAnnotation, imported late
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None:
+        # Late: the LB and the CLI import this module and stay off
+        # JAX; only a loop that opens a phase needs it.
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
 
 
 class _Phase:
@@ -308,13 +330,7 @@ class _Phase:
         self._child = 0.0
 
     def __enter__(self) -> '_Phase':
-        global _annotation
-        if _annotation is None:
-            # Late: the LB and the CLI import this module and stay
-            # off JAX; only a loop that opens a phase needs it.
-            from jax.profiler import TraceAnnotation
-            _annotation = TraceAnnotation
-        self._ann = _annotation(self.name)
+        self._ann = (_annotation or _trace_annotation())(self.name)
         self._ann.__enter__()
         self._clock._open.append(self)
         self._t0 = time.perf_counter()
@@ -345,6 +361,73 @@ def phase(name: str, clock: PhaseClock) -> _Phase:
     `utils/timeline` enabled it is a Chrome event there too. No
     sampling, no store."""
     return _Phase(name, clock)
+
+
+#: The annotation a garbage collection's pause takes.
+GC_PHASE = 'engine.gc'
+
+
+class GcPauses:
+    """The process's garbage collections, as a `gc.callbacks` hook:
+    each pause is a `GC_PHASE` annotation on the line of the thread
+    that collected (which holds the GIL throughout, so every other
+    Python thread stands still too) and is added to `clock` under its
+    generation (`gen0`, `gen1`, `gen2`), with the longest pause in
+    `max_s`. Collections never overlap, so the clock has one writer at
+    a time whichever thread collects. Measures only: how often the
+    collector runs is left as it is."""
+
+    def __init__(self) -> None:
+        self.clock = PhaseClock()
+        self.max_s: Dict[str, float] = {}
+        self._ann: Any = None
+        self._t0 = 0.0
+
+    def __call__(self, stage: str, info: Dict[str, Any]) -> None:
+        if stage == 'start':
+            self._ann = (_annotation or _trace_annotation())(GC_PHASE)
+            self._ann.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        ann, self._ann = self._ann, None
+        if ann is None:
+            return      # hooked in between a collection's two calls
+        dur = time.perf_counter() - self._t0
+        ann.__exit__(None, None, None)
+        name = f'gen{info["generation"]}'
+        rec = self.clock.totals.get(name)
+        if rec is None:
+            rec = self.clock.totals[name] = [0, 0.0, 0.0]
+        rec[0] += 1
+        rec[1] += dur
+        rec[2] += dur
+        if dur > self.max_s.get(name, 0.0):
+            self.max_s[name] = dur
+
+    def stats(self) -> Dict[str, Dict[str, Any]]:
+        """{'gen0'|'gen1'|'gen2': {'n', 's', 'max_s'}}, all three."""
+        return {name: {'n': self.clock.n(name),
+                       's': round(self.clock.seconds(name), 6),
+                       'max_s': round(self.max_s.get(name, 0.0), 6)}
+                for name in ('gen0', 'gen1', 'gen2')}
+
+
+#: This process's one hook (`install_gc_pauses`).
+gc_pauses = GcPauses()
+
+
+def install_gc_pauses() -> None:
+    """Hook `gc_pauses` into the collector, once however often it is
+    called (every server of the process calls it)."""
+    _trace_annotation()
+    if gc_pauses not in gc.callbacks:
+        gc.callbacks.append(gc_pauses)
+
+
+def remove_gc_pauses() -> None:
+    """Unhook it (a server's shutdown); its counts stay."""
+    while gc_pauses in gc.callbacks:
+        gc.callbacks.remove(gc_pauses)
 
 
 def get_trace(trace_id: str) -> Optional[Dict[str, Any]]:

@@ -1,8 +1,11 @@
 """Loop phases (observability/tracing.phase): the primitive, the
-engine's scheduler loop as /stats serves it, the per-request
-histograms, the trainer's record fields and the SKY007 rule.
+engine's scheduler loop as /stats serves it, its dispatch phases one
+level down, the GC pause hook, the per-layer metrics that read them,
+the per-request histograms, the trainer's record fields and the SKY007
+rule.
 
-Everything runs on the CPU with the tiny llama engine.
+Everything runs on the CPU with the tiny llama engine (a routed tiny
+model for the counter fetch).
 """
 import json
 import threading
@@ -203,6 +206,137 @@ def test_other_decode_paths_carry_dispatch_wait_and_commit(kwargs):
         assert engine.phases.n(name) > 0, name
     total = sum(r['s'] for r in engine.phase_stats().values())
     assert total == pytest.approx(engine.loop_s, rel=0.02)
+
+
+DECODE_SUB_PHASES = ('engine.grow_pages', 'engine.round_inputs',
+                     'engine.decode_enqueue')
+
+
+@pytest.mark.parametrize('kwargs', [{}, {'pipeline_decode': False}],
+                         ids=['pipelined', 'plain'])
+def test_dispatch_phases_run_once_a_round_and_once_a_chunk(kwargs):
+    from skypilot_tpu.models.batching import ContinuousBatchingEngine
+    model, params = _tiny()
+    engine = ContinuousBatchingEngine(model, params, num_slots=2,
+                                      max_total_len=64, prefill_chunk=8,
+                                      **kwargs)
+    try:
+        futs = [engine.submit(list(range(1, 20 + i)), max_new_tokens=6)
+                for i in range(3)]
+        for fut in futs:
+            assert len(fut.result(timeout=240)) == 25 + futs.index(fut)
+    finally:
+        engine.stop()
+    clock = engine.dispatch_phases
+    assert engine.decode_calls > 0 and engine.prefill_chunks_run > 0
+    for name in DECODE_SUB_PHASES:
+        assert clock.n(name) == engine.decode_calls, name
+    assert clock.n('engine.prefill_enqueue') == engine.prefill_chunks_run
+    # One level down, on a clock of its own: the sub-phases fit in the
+    # self seconds of the phase they lie in, and those still partition
+    # the loop.
+    assert sum(clock.seconds(name) for name in DECODE_SUB_PHASES) <= \
+        engine.phases.seconds('engine.decode_dispatch')
+    assert clock.seconds('engine.prefill_enqueue') <= \
+        engine.phases.seconds('engine.prefill_dispatch')
+    total = sum(r['s'] for r in engine.phase_stats().values())
+    assert total == pytest.approx(engine.loop_s, rel=0.02)
+
+
+def test_stats_serves_the_dispatch_phases_and_the_gc_pauses(served_stats):
+    stats, engine = served_stats
+    served = stats['dispatch_phases']
+    for name in DECODE_SUB_PHASES + ('engine.prefill_enqueue',):
+        assert served[name]['n'] > 0 and served[name]['s'] > 0, name
+    assert served['engine.decode_enqueue']['n'] == stats['decode_calls']
+    assert served['engine.prefill_enqueue']['n'] == \
+        stats['prefill_chunks_run']
+    assert served == engine.dispatch_phases.stats()
+    # A model without device counters fetches none.
+    assert 'engine.stats_counters' not in served
+    assert set(stats['gc']) == {'gen0', 'gen1', 'gen2'}
+    for rec in stats['gc'].values():
+        assert set(rec) == {'n', 's', 'max_s'}
+        assert rec['max_s'] <= rec['s'] + 1e-6
+
+
+def test_a_routed_models_counter_fetch_is_one_stats_counters_phase():
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+
+    from skypilot_tpu.models import nemotron_h as nh
+    from skypilot_tpu.models.batching import ContinuousBatchingEngine
+    cfg = nh.NemotronHConfig.tiny(dtype=jnp.float32)
+    params = nn.meta.unbox(jax.jit(nh.NemotronH(cfg).init)(
+        jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))['params'])
+    engine = ContinuousBatchingEngine(nh.NemotronH(cfg), params,
+                                      num_slots=2, max_total_len=64)
+    try:
+        assert engine.model_counters()
+        assert engine.dispatch_phases.n('engine.stats_counters') == 1
+    finally:
+        engine.stop()
+
+
+@pytest.mark.parametrize('name', ['engine.grow_pages_ms',
+                                  'engine.round_inputs_ms',
+                                  'engine.decode_enqueue_ms',
+                                  'engine.prefill_enqueue_ms',
+                                  'runtime.gc_pause_pct'])
+def test_the_new_layer_metrics_read_the_served_stats(served_stats, name):
+    """Each file loads and reads a number from a served /stats against
+    the server's start (every accumulator at zero), and None from a
+    server without the keys it reads, as a parent's would be."""
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    sys.path.insert(0, root)
+    from perfbench import manifest as manifest_lib
+    spec = manifest_lib.per_layer(manifest_lib.load(),
+                                  'mistral-7b-l16.chat-r2')
+    spec = next(m['spec'] for m in spec if m['name'] == name)
+    assert spec['name'] == name
+    read = manifest_lib.reader(spec['reader']).read
+    stats, _ = served_stats
+    start = {'loop_s': 0.0, 'dispatch_phases': {}, 'gc': {}}
+    value = read({'stats_open': start, 'stats_close': stats},
+                 **spec['args'])
+    assert value is not None and value >= 0
+    if name.endswith('_ms'):
+        assert 0 < value < 10_000
+    older = {k: v for k, v in stats.items()
+             if k not in ('dispatch_phases', 'gc')}
+    assert read({'stats_open': {'loop_s': 0.0}, 'stats_close': older},
+                **spec['args']) is None
+
+
+def test_the_gc_hook_is_hooked_once_and_goes_with_the_server():
+    import gc
+
+    from skypilot_tpu.inference.http_server import make_server
+    from skypilot_tpu.inference.runtime import InferenceRuntime
+    model, params = _tiny()
+    rt = InferenceRuntime(
+        model=model, params=params,
+        vocab_size=model.config.vocab_size, model_name='llama-tiny',
+        max_total_len=64, spec_total=64, speculative=0)
+    servers = [make_server(rt, 0) for _ in range(2)]
+    for server in servers:
+        threading.Thread(target=server.serve_forever, daemon=True).start()
+    try:
+        assert gc.callbacks.count(tracing.gc_pauses) == 1
+        before = tracing.gc_pauses.stats()['gen2']
+        gc.collect()
+        after = tracing.gc_pauses.stats()['gen2']
+        assert after['n'] >= before['n'] + 1
+        assert after['s'] > before['s'] and after['max_s'] > 0
+    finally:
+        for server in servers:
+            server.shutdown()
+            server.server_close()
+    assert tracing.gc_pauses not in gc.callbacks
 
 
 def test_recovery_is_a_phase_of_the_loop():

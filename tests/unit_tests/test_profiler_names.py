@@ -1,6 +1,7 @@
 """What the phases and named scopes leave in a profiler trace.
 
-A tiny engine (in this process) and three trainer steps (`train_lm
+A tiny engine (in this process), a routed tiny engine's counter fetch
+with a forced collection, and three trainer steps (`train_lm
 --profile`, a child) are profiled on the CPU backend. The phases are
 `TraceAnnotation`s: they must lie on the host plane of the same
 `.xplane.pb` as the operations, on the thread that ran them. The
@@ -38,21 +39,31 @@ def _names_scope(path, scope):
     return re.search(rb'[/(]' + scope.encode() + rb'[/)]', blob)
 
 
-def _host_lines(path, prefix):
-    """{line index: {event name: count}} of the host plane's lines
-    that hold an event whose name starts with `prefix`."""
+def _host_events(path, prefix, skip=('engine.gc',)):
+    """{line index: [(name, start ns, end ns)]} of the host plane's
+    lines that hold an event whose name starts with `prefix`. A
+    collection's pause (`engine.gc`) lies on whichever thread collected
+    and is left out unless asked for."""
     from jax.profiler import ProfileData
     out = {}
     for plane in ProfileData.from_file(path).planes:
         if not plane.name.startswith('/host:CPU'):
             continue
         for i, line in enumerate(plane.lines):
-            names = collections.Counter(
-                ev.name for ev in line.events
-                if ev.name.startswith(prefix))
-            if names:
-                out[i] = names
+            events = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                      for ev in line.events
+                      if ev.name.startswith(prefix)
+                      and ev.name not in skip]
+            if events:
+                out[i] = events
     return out
+
+
+def _host_lines(path, prefix):
+    """{line index: {event name: count}} of the host plane's lines
+    that hold an event whose name starts with `prefix`."""
+    return {i: collections.Counter(name for name, _, _ in events)
+            for i, events in _host_events(path, prefix).items()}
 
 
 @pytest.fixture(scope='module')
@@ -117,6 +128,80 @@ def test_engine_phases_lie_on_the_scheduler_threads_line(engine_trace):
     # The operations are in the same file, on other lines of the
     # same plane.
     assert not _host_lines(engine_trace, 'train.')
+
+
+@pytest.fixture(scope='module')
+def control_trace(tmp_path_factory):
+    """A routed tiny model's engine (device counters in its cache)
+    under a profiler session in which `/stats`' counter fetch runs once
+    and the main thread forces a full collection with the GC hook in:
+    (trace file, the hook's `gen2` before and after)."""
+    import gc
+
+    import jax
+    jax.config.update('jax_platforms', 'cpu')
+    import flax.linen as nn
+    import jax.numpy as jnp
+
+    from skypilot_tpu.models import nemotron_h as nh
+    from skypilot_tpu.models.batching import ContinuousBatchingEngine
+    from skypilot_tpu.observability import tracing
+    cfg = nh.NemotronHConfig.tiny(dtype=jnp.float32)
+    params = nn.meta.unbox(jax.jit(nh.NemotronH(cfg).init)(
+        jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))['params'])
+    engine = ContinuousBatchingEngine(nh.NemotronH(cfg), params,
+                                      num_slots=2, max_total_len=64)
+    root = str(tmp_path_factory.mktemp('control_profile'))
+    hooked = tracing.gc_pauses in gc.callbacks
+    tracing.install_gc_pauses()
+    try:
+        engine.model_counters()       # the first fetch compiles nothing
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        jax.profiler.start_trace(root, profiler_options=options)
+        try:
+            engine.model_counters()
+            before = tracing.gc_pauses.stats()['gen2']
+            gc.collect()
+            after = tracing.gc_pauses.stats()['gen2']
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        if not hooked:
+            tracing.remove_gc_pauses()
+        engine.stop()
+    return _xplane(root), before, after
+
+
+@pytest.mark.parametrize('name, parent, trace', [
+    ('engine.grow_pages', 'engine.decode_dispatch', 'engine_trace'),
+    ('engine.round_inputs', 'engine.decode_dispatch', 'engine_trace'),
+    ('engine.decode_enqueue', 'engine.decode_dispatch', 'engine_trace'),
+    ('engine.prefill_enqueue', 'engine.prefill_dispatch', 'engine_trace'),
+    ('engine.stats_counters', 'engine.control', 'control_trace')])
+def test_dispatch_phases_nest_in_their_phase_on_the_schedulers_line(
+        request, name, parent, trace):
+    path = request.getfixturevalue(trace)
+    path = path if isinstance(path, str) else path[0]
+    lines = _host_events(path, 'engine.')
+    assert len(lines) == 1, lines        # the scheduler's thread
+    events = next(iter(lines.values()))
+    mine = [(a, b) for n, a, b in events if n == name]
+    parents = [(a, b) for n, a, b in events if n == parent]
+    assert mine, (name, collections.Counter(n for n, _, _ in events))
+    for a, b in mine:
+        assert any(pa <= a and b <= pb for pa, pb in parents), (name, a)
+
+
+def test_a_forced_collection_is_an_engine_gc_event(control_trace):
+    path, before, after = control_trace
+    assert after['n'] >= before['n'] + 1
+    assert after['max_s'] > 0
+    pauses = [ev for events in _host_events(path, 'engine.gc',
+                                            skip=()).values()
+              for ev in events]
+    assert pauses and all(b > a for _, a, b in pauses)
 
 
 def test_trainer_phases_lie_on_the_main_threads_line(trainer_trace):
