@@ -486,6 +486,8 @@ def make_server(rt: InferenceRuntime,
                 'chunk_attention_impl': engine.chunk_attention_impl(),
                 # The route of a routed model's grouped expert pass.
                 'expert_impl': engine.expert_impl(),
+                # The route of a decode round's state update.
+                'ssm_update_impl': engine.ssm_update_impl(),
                 'attention_bytes_per_token':
                     engine.attention_bytes_per_token(),
                 # Robustness plane (docs/guides.md serving-robustness

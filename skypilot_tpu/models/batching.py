@@ -1745,6 +1745,19 @@ class ContinuousBatchingEngine:
               else contextlib.nullcontext()):
             return route()
 
+    def ssm_update_impl(self) -> str:
+        """The route of a decode round's state update in this engine's
+        programs, as the model resolves it from its static shapes and
+        the engine's slots inside the engine's mesh ('state_pallas': the
+        kernel of ops/pallas_ssm.py; 'live_rows_loop'); 'none' for a
+        model that keeps no state by slot. Surfaced in /stats."""
+        route = getattr(self.model, 'ssm_update_impl', None)
+        if route is None:
+            return 'none'
+        with (self.mesh if self.mesh is not None
+              else contextlib.nullcontext()):
+            return route(self.num_slots)
+
     def chunk_attention_impl(self) -> str:
         """The route the attention of a whole `prefill_chunk` takes in
         this engine's traced prefill programs, beside

@@ -26,13 +26,13 @@ RMSNorm and an untied head.
 
 Serving keeps a SECOND kind of cache beside the pages: a Mamba layer's
 state is one row a sequence, not a row a token. `page_layout()` names
-it (`slot_arrays`: `ssm_state` [H, P, N] float32 and `conv_state`
-[(conv_kernel - 1) x conv width]); the engine allocates the rows with its
-slots, hands the model the slot a prefill row belongs to (`slots`) and
-the `live` mask. A decode lane is its slot; a prompt's first chunk
-(`prefill=True`) starts from zeros and never reads the old row, so slot
-reuse and preemption-by-recompute need no reset; a dead lane's rows are
-left as they are.
+it (`slot_arrays`: `ssm_state` [P, N, H] float32 and `conv_state`
+[(conv_kernel - 1) x conv width / H, H], ops/ssm.slot_shapes); the
+engine allocates the rows with its slots, hands the model the slot a
+prefill row belongs to (`slots`) and the `live` mask. A decode lane is
+its slot; a prompt's first chunk (`prefill=True`) starts from zeros and
+never reads the old row, so slot reuse and preemption-by-recompute need
+no reset; a dead lane's rows are left as they are.
 """
 from __future__ import annotations
 
@@ -47,7 +47,7 @@ from skypilot_tpu.models.deepseek import MoEByShare, expert_route
 from skypilot_tpu.models.llama import RMSNorm, _proj
 from skypilot_tpu.ops import attention as attention_ops
 from skypilot_tpu.ops import paged_attention as paged_ops
-from skypilot_tpu.ops import ssm
+from skypilot_tpu.ops import pallas_ssm, ssm
 
 Dtype = Any
 F32 = jnp.float32
@@ -142,22 +142,25 @@ class NemotronHConfig:
         """Channels the convolution runs over: x, B and C."""
         return self.mamba_inner + 2 * self.n_groups * self.ssm_state_size
 
+    def slot_shapes(self):
+        """A slot's rows of a Mamba layer (ops/ssm.slot_shapes)."""
+        return ssm.slot_shapes(self.mamba_num_heads, self.mamba_head_dim,
+                               self.ssm_state_size, self.conv_kernel,
+                               self.conv_dim)
+
     def page_layout(self) -> paged_ops.PageLayout:
         """K and V of the attention layers by page; beside them, by
         SLOT, each Mamba layer's state and its convolution's tail."""
         kv = paged_ops.kv_layout(self.num_kv_heads, self.head_dim,
                                  self.kv_page_size, self.kv_total_pages)
+        state, tail = self.slot_shapes()
         return dataclasses.replace(
             kv, layers=self.pattern.count('*'),
             slot_arrays=(
-                paged_ops.SlotArray(
-                    'ssm_state', (self.mamba_num_heads, self.mamba_head_dim,
-                                  self.ssm_state_size), F32),
-                # The convolution's K - 1 last inputs, one after the
-                # other in ONE axis (ops/ssm.ssm_update says why).
-                paged_ops.SlotArray(
-                    'conv_state',
-                    ((self.conv_kernel - 1) * self.conv_dim,))),
+                # In the layout of ops/ssm.slot_shapes: the state [P,
+                # N, H] float32, the tail's K - 1 inputs in rows of H.
+                paged_ops.SlotArray('ssm_state', state, F32),
+                paged_ops.SlotArray('conv_state', tail)),
             slot_layers=self.pattern.count('M'))
 
 
@@ -230,10 +233,11 @@ class Mamba2Mixer(nn.Module):
         if decode:
             # One row a SLOT (the engine's cache has its `num_slots`
             # rows: it is made by a call of one token a slot).
+            state_row, tail_row = cfg.slot_shapes()
             state = self.variable('cache', 'ssm_state', jnp.zeros,
-                                  (batch, heads, hd, n), F32)
+                                  (batch,) + state_row, F32)
             tail = self.variable('cache', 'conv_state', jnp.zeros,
-                                 (batch, (taps - 1) * conv_dim), cfg.dtype)
+                                 (batch,) + tail_row, cfg.dtype)
             # Device accumulators (/stats): live lane-steps this layer
             # updated in decode rounds, valid tokens it scanned in
             # prefill chunks. Both exist in every program, so the
@@ -246,7 +250,7 @@ class Mamba2Mixer(nn.Module):
             counter.value = counter.value + jnp.sum(lengths)
         if decode and seq == 1:
             # A decode round: a lane is its slot; the convolution is
-            # part of the live rows' loop (scope `ssm_update`).
+            # part of the live rows' step (scope `ssm_update`).
             y, state.value, tail.value = ssm.ssm_update(
                 state.value, tail.value, xbc[:, 0], dt[:, 0], a, d_skip,
                 conv_w, conv_b, mask[:, 0], groups=g)
@@ -263,7 +267,8 @@ class Mamba2Mixer(nn.Module):
                     jax.lax.dynamic_index_in_dim(var.value, slots[row], 0,
                                                  keepdims=False)
                     for row in range(batch)]) for var in (state, tail))
-                tail0 = tail0.reshape(batch, taps - 1, conv_dim)
+                h0 = ssm.state_from_slot(h0)
+                tail0 = ssm.tail_from_slot(tail0, taps)
             else:
                 # A sequence's start: zeros, whatever the slot's rows
                 # held (a finished or preempted request's).
@@ -279,11 +284,13 @@ class Mamba2Mixer(nn.Module):
                 xbc[..., inner + g * n:].reshape(batch, seq, g, n),
                 d_skip, h0, lengths, cfg.chunk_size)
             if decode:
+                h1 = ssm.state_to_slot(h1)
+                tail1 = ssm.tail_to_slot(tail1, heads)
                 for row in range(batch):
                     state.value = jax.lax.dynamic_update_index_in_dim(
                         state.value, h1[row], slots[row], 0)
                     tail.value = jax.lax.dynamic_update_index_in_dim(
-                        tail.value, tail1[row].reshape(-1), slots[row], 0)
+                        tail.value, tail1[row], slots[row], 0)
         with jax.named_scope('ssm_gate_norm'):
             # Gate first, then the norm over each group, float32.
             gated = (y.reshape(batch, seq, g, inner // g)
@@ -403,6 +410,14 @@ class NemotronH(nn.Module):
         latent (models/deepseek.expert_route; /stats `expert_impl`)."""
         return expert_route(self.config, 'relu2',
                             self.config.moe_latent_dim)
+
+    def ssm_update_impl(self, slots: int) -> str:
+        """The route of a decode round's state update over `slots`
+        lanes (ops/pallas_ssm.resolve_route; /stats `ssm_update_impl`)."""
+        cfg = self.config
+        return pallas_ssm.resolve_route(
+            cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size,
+            cfg.n_groups, cfg.conv_dim, cfg.conv_kernel, cfg.dtype, slots)
 
     @nn.compact
     def __call__(self, tokens: jax.Array,

@@ -29,16 +29,18 @@ the kernel of ops/pallas_latent.py (at two depths, 12 and 28 blocks
 of keys, so that the time a block is on record). The `ssm/...` cases
 are ops/ssm.py at Nemotron 3 Super's widths (128 heads of 64, state
 128, 8 groups): a decode round's step over the cell's 128 slots with a
-third and all of the lanes live, the state and the tails donated from
-call to call so that the update in place is what is timed, and a
-512-token prefill chunk, each against the float32 recurrence step by
-step. The `experts/...` cases are one routed layer's held experts
-through the kernel of ops/pallas_experts.py at the two routed cells'
-published widths (Nemotron 3 Super's 128 `relu2` experts of 1,024 x
-2,688, DeepSeek-V3.2's 16 `swiglu` experts of 7,168 x 2,048), a decode
-round and a 512-token chunk each, against the `lax.switch` loop on the
-operands in float32, with the bf16 loop's own run time (`loop_us`). It
-exits non-zero when any case is not `ok`, and when the backend is not
+third and all of the lanes live, through the kernel of
+ops/pallas_ssm.py (route `state_pallas`), the state and the tails
+donated from call to call so that the update in place is what is
+timed, held to the float32 recurrence step by step and to the live
+rows' loop, with the loop's own run time (`loop_us`); and a 512-token
+prefill chunk against the recurrence. The `experts/...` cases are one
+routed layer's held experts through the kernel of ops/pallas_experts.py
+at the two routed cells' published widths (Nemotron 3 Super's 128
+`relu2` experts of 1,024 x 2,688, DeepSeek-V3.2's 16 `swiglu` experts
+of 7,168 x 2,048), a decode round and a 512-token chunk each, against
+the `lax.switch` loop on the operands in float32, with the bf16 loop's
+own run time (`loop_us`). It exits non-zero when any case is not `ok`, and when the backend is not
 a TPU: a CPU run of this file would check nothing.
 
 Tolerances are set from the dtype: operands are bf16 (8 mantissa
@@ -397,10 +399,12 @@ SSM_SLOTS, SSM_CHUNK, SSM_SUB = 128, 512, 128
 def _ssm(path: str, live_lanes: int = 0) -> Callable[[Any], Dict]:
     """ops/ssm.py at the published widths against the float32
     recurrence step by step (`ssm_reference`): 'update' a decode
-    round's step over 128 slots of which `live_lanes` are live (the
-    state and the tails donated and handed from call to call, as the
-    engine does: what is timed is the update in place), 'scan' a
-    512-token prefill chunk from a random state."""
+    round's step over 128 slots of which `live_lanes` are live, by the
+    route a TPU takes ('state_pallas') and also held to the loop
+    ('live_rows_loop', timed as `loop_us`; the state and the tails
+    donated and handed from call to call, as the engine does: what is
+    timed is the update in place), 'scan' a 512-token prefill chunk
+    from a random state."""
 
     def case(key):
         import jax
@@ -441,9 +445,14 @@ def _ssm(path: str, live_lanes: int = 0) -> Callable[[Any], Dict]:
                             (x, dt, b, c, state, lengths),
                             relative_to_max=True)
 
+        from skypilot_tpu.ops import pallas_ssm
+        got = pallas_ssm.resolve_route(heads, hd, n, g, width, SSM_TAPS,
+                                       jnp.bfloat16, SSM_SLOTS)
+        if got != 'state_pallas':
+            return _wrong_route('state_pallas', got)
         slots = SSM_SLOTS
         state = jax.random.normal(keys[2], (slots, heads, hd, n))
-        tail = jax.random.normal(keys[3], (slots, (SSM_TAPS - 1) * width),
+        tail = jax.random.normal(keys[3], (slots, SSM_TAPS - 1, width),
                                  jnp.bfloat16)
         xbc = jax.random.normal(keys[4], (slots, width), jnp.bfloat16)
         dt = jax.nn.softplus(jax.random.normal(keys[5], (slots, heads)) - 3.0)
@@ -454,15 +463,9 @@ def _ssm(path: str, live_lanes: int = 0) -> Callable[[Any], Dict]:
         live = jax.random.permutation(
             keys[8], jnp.arange(slots) < live_lanes)
 
-        def update(state, tail, xbc, dt, live):
-            return ssm.ssm_update(state, tail, xbc, dt, a, d, weight, bias,
-                                  live, groups=g)
-
-        # The reference first: the call below gives its arrays away.
         with jax.default_matmul_precision('highest'):
-            conv, _ = ssm.causal_conv(
-                xbc[:, None], tail.reshape(slots, SSM_TAPS - 1, width),
-                weight, bias, jnp.ones((slots,), jnp.int32))
+            conv, _ = ssm.causal_conv(xbc[:, None], tail, weight, bias,
+                                      jnp.ones((slots,), jnp.int32))
             act = jax.nn.silu(conv).astype(jnp.bfloat16)
             y_ref, h_ref = jax.jit(ssm.ssm_reference)(
                 act[..., :inner].reshape(slots, 1, heads, hd), dt[:, None],
@@ -474,27 +477,51 @@ def _ssm(path: str, live_lanes: int = 0) -> Callable[[Any], Dict]:
                               0.0)
             want_h = np.where(keep[:, None, None, None], np.asarray(h_ref),
                               np.asarray(state))
-        t0 = time.perf_counter()
-        compiled = jax.jit(update, donate_argnums=(0, 1)).lower(
-            state, tail, xbc, dt, live).compile()
-        compile_s = time.perf_counter() - t0
-        y, state, tail = compiled(state, tail, xbc, dt, live)
-        err_y = float(np.max(np.abs(np.asarray(y) - want_y)))
-        err_h = float(np.max(np.abs(np.asarray(state) - want_h)))
-        scale = max(1.0, float(np.max(np.abs(want_y))))
-        ok = (err_y <= ATOL * scale and err_h <= ATOL * max(
-            1.0, float(np.max(np.abs(want_h)))))
-        batches = []
-        for _ in range(5):
+        # The rows in the slot's layout, as the engine keeps them.
+        state = ssm.state_to_slot(state)
+        tail = ssm.tail_to_slot(tail, heads)
+
+        def timed(route):
+            """(y, state, tail) of the first call of `route` on the
+            operands, as host arrays; its compile seconds and run_us,
+            the state and the tails donated from call to call."""
+            compiled = jax.jit(functools.partial(
+                ssm.ssm_update, a=a, d=d, weight=weight, bias=bias,
+                groups=g, route=route), donate_argnums=(0, 1))
+            s, t = state + 0, tail + 0        # its own, to give away
             t0 = time.perf_counter()
-            for _ in range(RUNS):
-                y, state, tail = compiled(state, tail, xbc, dt, live)
-            jax.block_until_ready(y)
-            batches.append((time.perf_counter() - t0) / RUNS)
+            compiled = compiled.lower(s, t, xbc, dt, live=live).compile()
+            compile_s = time.perf_counter() - t0
+            y, s, t = compiled(s, t, xbc, dt, live=live)
+            first = (np.asarray(y), np.asarray(ssm.state_from_slot(s)),
+                     np.asarray(t, np.float32))
+            batches = []
+            for _ in range(5):
+                t0 = time.perf_counter()
+                for _ in range(RUNS):
+                    y, s, t = compiled(s, t, xbc, dt, live=live)
+                jax.block_until_ready(y)
+                batches.append((time.perf_counter() - t0) / RUNS)
+            return first, compile_s, sorted(batches)[2] * 1e6
+
+        (y, h, new), compile_s, run_us = timed('state_pallas')
+        loop, _, loop_us = timed('live_rows_loop')
+        scale = max(1.0, float(np.max(np.abs(want_y))))
+        scale_h = max(1.0, float(np.max(np.abs(want_h))))
+        err_y = float(np.max(np.abs(y - want_y)))
+        err_h = float(np.max(np.abs(h - want_h)))
+        # Against the loop: the same arithmetic, the same tails.
+        loop_err = max(float(np.max(np.abs(y - loop[0]))) / scale,
+                       float(np.max(np.abs(h - loop[1]))) / scale_h,
+                       float(np.max(np.abs(new - loop[2]))))
+        ok = (err_y <= ATOL * scale and err_h <= ATOL * scale_h
+              and loop_err <= ATOL)
         return {'verdict': 'ok' if ok else 'mismatch',
-                'max_abs_err': round(max(err_y, err_h) / scale, 6),
+                'max_abs_err': round(max(err_y / scale, err_h / scale_h), 6),
+                'loop_max_abs_err': round(loop_err, 6),
                 'compile_s': round(compile_s, 2),
-                'run_us': round(sorted(batches)[2] * 1e6, 1),
+                'run_us': round(run_us, 1),
+                'loop_us': round(loop_us, 1),
                 'live_lanes': int(live_lanes)}
     return case
 

@@ -1,5 +1,6 @@
 """Mamba-2's state-space recurrence for serving: a chunked scan for a
-prefill chunk and a one-step update for a decode round. Plain XLA.
+prefill chunk and a one-step update for a decode round. Plain XLA, but
+for the decode round's route 'state_pallas' (ops/pallas_ssm.py).
 
 A layer keeps, a SEQUENCE and not a token, one state h [H, P, N]
 (heads x head size x state size, float32) and the last `conv_kernel -
@@ -19,10 +20,19 @@ neither decays nor feeds the state (exp(0) = 1, and the input term is
 
 `ssm_update` advances the live lanes of a decode round by one token:
 the convolution over the slot's tail, silu, and the recurrence's step.
-The state rows ([slots, H, P, N], 4 MB a slot at the published widths)
-and the tails are read and written in place, one live row a step of a
-loop whose trip count is the number of live lanes: a dead lane's rows
-are neither read nor written.
+The state rows (4 MB a slot at the published widths) and the tails are
+read and written in place, and a dead lane's rows are neither read nor
+written: route 'state_pallas' (ops/pallas_ssm.py) walks the live rows
+in one Pallas pass, route 'live_rows_loop' one live row a step of a
+loop whose trip count is the number of live lanes (the CPU, a shape the
+kernel refuses, and `kernel_check`'s reference).
+
+A slot keeps its rows in the layout the kernel reads (`slot_shapes`):
+the state as [P, N, H], heads on the lanes, so that dt x is a lane
+vector and y = h C a sum over sublanes; the tail as [(K - 1) C / H,
+H], the K - 1 last inputs one after the other in rows of H channels.
+`state_to_slot`, `tail_to_slot` and their inverses convert a prefill
+chunk's rows.
 
 State, A, dt and every product of the recurrence are float32 at full
 precision (a TPU's default float32 product rounds its operands to
@@ -30,7 +40,7 @@ bf16; the state is a running sum over the whole sequence).
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -125,17 +135,63 @@ def ssm_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     return y, state
 
 
+def slot_shapes(heads: int, hd: int, n: int, taps: int, width: int
+                ) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+    """The shapes of one slot's rows: the state [P, N, H] (float32)
+    and the convolution's tail [(K - 1) C / H, H] (`width` C channels,
+    the K - 1 last inputs one after the other)."""
+    if (taps - 1) * width % heads:
+        raise ValueError(
+            f'a tail of {taps - 1} x {width} channels does not fill rows '
+            f'of {heads} (the heads)')
+    return (hd, n, heads), ((taps - 1) * width // heads, heads)
+
+
+def state_to_slot(h: jax.Array) -> jax.Array:
+    """[..., H, P, N] -> the slot's [..., P, N, H]."""
+    return jnp.moveaxis(h, -3, -1)
+
+
+def state_from_slot(h: jax.Array) -> jax.Array:
+    """The slot's [..., P, N, H] -> [..., H, P, N]."""
+    return jnp.moveaxis(h, -1, -3)
+
+
+def tail_to_slot(tail: jax.Array, heads: int) -> jax.Array:
+    """[..., K - 1, C] -> the slot's [..., (K - 1) C / H, H]."""
+    return tail.reshape(tail.shape[:-2] + (-1, heads))
+
+
+def tail_from_slot(rows: jax.Array, taps: int) -> jax.Array:
+    """The slot's [..., (K - 1) C / H, H] -> [..., K - 1, C]."""
+    return rows.reshape(rows.shape[:-2] + (taps - 1, -1))
+
+
+def conv_step(previous, xbc: jax.Array, weight, bias: jax.Array,
+              dtype) -> jax.Array:
+    """The convolution's step for one token, float32 sums in tap order,
+    then silu, cast to `dtype`: previous the K - 1 last inputs (oldest
+    first), xbc the token's, weight the K taps' float32 weights (tap K
+    - 1 multiplies the token), bias float32; any matching shapes (both
+    routes of `ssm_update` call it, so both add in the same order)."""
+    acc = previous[0].astype(F32) * weight[0]
+    for k in range(1, len(previous)):
+        acc = acc + previous[k].astype(F32) * weight[k]
+    acc = acc + xbc.astype(F32) * weight[len(previous)]
+    return jax.nn.silu(bias + acc).astype(dtype)
+
+
 def _one_step(h, x, dt, a, b, c, d):
-    """One token of one row: h f32[H, P, N], x [H, P], dt f32[H],
-    b, c [G, N] -> (y f32[H, P], the new h)."""
-    heads = h.shape[0]
+    """One token of one row in the slot's layout: h f32[P, N, H], x
+    [P, H], dt, a, d f32[H], b, c [G, N] -> (y f32[P, H], the new h)."""
+    heads = h.shape[-1]
     groups = b.shape[0]
-    bh = jnp.repeat(b.astype(F32), heads // groups, axis=0)    # [H, N]
-    ch = jnp.repeat(c.astype(F32), heads // groups, axis=0)
+    bh = jnp.repeat(b.astype(F32), heads // groups, axis=0).T   # [N, H]
+    ch = jnp.repeat(c.astype(F32), heads // groups, axis=0).T
     x32 = x.astype(F32)
-    h = (h * jnp.exp(dt * a)[:, None, None]
-         + (dt[:, None] * x32)[:, :, None] * bh[:, None, :])
-    y = jnp.sum(h * ch[:, None, :], axis=-1) + d[:, None] * x32
+    h = (h * jnp.exp(dt * a)[None, None, :]
+         + bh[None, :, :] * (dt[None, :] * x32)[:, None, :])
+    y = jnp.sum(h * ch[None, :, :], axis=1) + d[None, :] * x32
     return y, h
 
 
@@ -143,50 +199,65 @@ def _one_step(h, x, dt, a, b, c, d):
 def ssm_update(state: jax.Array, tail: jax.Array, xbc: jax.Array,
                dt: jax.Array, a: jax.Array, d: jax.Array,
                weight: jax.Array, bias: jax.Array, live: jax.Array, *,
-               groups: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
+               groups: int, route: Optional[str] = None
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One token a lane, for the live lanes; a lane is a slot.
 
-    state f32[slots, H, P, N]; tail [slots, (K - 1) * C], a slot's
-    K - 1 last convolution inputs one after the other (two axes, so
-    that XLA:TPU leaves the array's layout alone); xbc [slots, C] the
-    token's input to the convolution (x, B and C before it: C = H P +
-    2 G N); dt f32[slots, H]; a, d f32[H]; weight [K, C], bias [C];
-    live bool[slots]. A live lane's token goes through the convolution
-    and silu, its tail is shifted by it, and its state is advanced.
-    Returns (y f32[slots, H, P], zeros on a dead lane; the state and
-    the tail, a live lane's rows updated in place and a dead lane's
-    neither read nor written). The live rows are taken first, one a
-    step, by a loop whose trip count is their number."""
-    slots, heads, hd, n = state.shape
+    state f32[slots, P, N, H] and tail [slots, (K - 1) C / H, H] in the
+    slot's layout (`slot_shapes`); xbc [slots, C] the token's input to
+    the convolution (x, B and C before it: C = H P + 2 G N, the model's
+    channel order); dt f32[slots, H]; a, d f32[H]; weight [K, C], bias
+    [C]; live bool[slots]. A live lane's token goes through the
+    convolution and silu, its tail is shifted by it, and its state is
+    advanced. Returns (y f32[slots, H, P], zeros on a dead lane; the
+    state and the tail, a live lane's rows updated in place and a dead
+    lane's neither read nor written). The live rows are taken in
+    `order`, live ones first; `route` (default: `pallas_ssm.
+    resolve_route` of the shapes) says by what."""
+    from skypilot_tpu.ops import pallas_ssm
+    slots, hd, n, heads = state.shape
     taps, width = weight.shape
     inner, bc = heads * hd, groups * n
+    if route is None:
+        route = pallas_ssm.resolve_route(heads, hd, n, groups, width, taps,
+                                         tail.dtype, slots)
+    act_dtype = xbc.dtype
     a, d, dt = a.astype(F32), d.astype(F32), dt.astype(F32)
+    xbc = xbc.astype(tail.dtype)
     w32, bias32 = weight.astype(F32), bias.astype(F32)
-    order = jnp.argsort(~live, stable=True)
+    order = jnp.argsort(~live, stable=True).astype(jnp.int32)
+    rows = jnp.sum(live, dtype=jnp.int32)
+    if route == 'state_pallas':
+        y, state, tail = pallas_ssm.state_update_pallas(
+            state, tail, xbc.reshape(slots, width // heads, heads), dt, a,
+            d, w32.reshape(taps, width // heads, heads),
+            bias32.reshape(width // heads, heads), order, rows,
+            groups=groups, act_dtype=act_dtype)
+        return jnp.swapaxes(y, 1, 2), state, tail
+    if route != 'live_rows_loop':
+        raise ValueError(f'unknown state update route {route!r}')
 
     def step(i, carry):
         state, tail, y = carry
         r = order[i]
-        row = jnp.concatenate([
-            jax.lax.dynamic_index_in_dim(tail, r, 0, keepdims=False),
-            xbc[r].astype(tail.dtype)])
-        conv = bias32 + jnp.sum(
-            row.reshape(taps, width).astype(F32) * w32, axis=0)
-        act = jax.nn.silu(conv).astype(xbc.dtype)
+        previous = tail_from_slot(
+            jax.lax.dynamic_index_in_dim(tail, r, 0, keepdims=False), taps)
+        act = conv_step(previous, xbc[r], w32, bias32, act_dtype)
         h = jax.lax.dynamic_index_in_dim(state, r, 0, keepdims=False)
         y_r, h = _one_step(
-            h, act[:inner].reshape(heads, hd), dt[r], a,
+            h, act[:inner].reshape(heads, hd).T, dt[r], a,
             act[inner:inner + bc].reshape(groups, n),
             act[inner + bc:].reshape(groups, n), d)
+        shifted = jnp.concatenate([previous[1:], xbc[r][None]])
         state = jax.lax.dynamic_update_index_in_dim(state, h, r, 0)
-        tail = jax.lax.dynamic_update_index_in_dim(tail, row[width:], r, 0)
+        tail = jax.lax.dynamic_update_index_in_dim(
+            tail, tail_to_slot(shifted, heads), r, 0)
         y = jax.lax.dynamic_update_index_in_dim(y, y_r, r, 0)
         return state, tail, y
 
     state, tail, y = jax.lax.fori_loop(
-        0, jnp.sum(live, dtype=jnp.int32), step,
-        (state, tail, jnp.zeros((slots, heads, hd), F32)))
-    return y, state, tail
+        0, rows, step, (state, tail, jnp.zeros((slots, hd, heads), F32)))
+    return jnp.swapaxes(y, 1, 2), state, tail
 
 
 def ssm_reference(x, dt, a, b, c, d, state, lengths):

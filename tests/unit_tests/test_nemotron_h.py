@@ -228,9 +228,11 @@ def test_a_dead_lanes_rows_are_unchanged_by_a_round():
     a, d = -jnp.exp(jax.random.normal(k[4], (heads,))), jnp.ones((heads,))
     w, bias = jax.random.normal(k[5], (4, width)), jnp.ones((width,))
     live = jnp.asarray([True, False, True, False])
-    y, h, new = ssm.ssm_update(state, tail.reshape(4, -1), xbc, dt, a, d,
+    # In the slot's layout (ops/ssm.slot_shapes), and back.
+    y, h, new = ssm.ssm_update(ssm.state_to_slot(state),
+                               ssm.tail_to_slot(tail, heads), xbc, dt, a, d,
                                w, bias, live, groups=groups)
-    new = new.reshape(4, 3, width)
+    h, new = ssm.state_from_slot(h), ssm.tail_from_slot(new, 4)
     conv, want_tail = ssm.causal_conv(xbc[:, None], tail, w, bias,
                                       jnp.ones(4, jnp.int32))
     act = jax.nn.silu(conv)
@@ -297,11 +299,11 @@ def test_the_engine_serves_it_with_state_by_slot(params):
         # too.
         copies = engine.pool_copy_lines()
         assert copies['prefill_suffix_32'] == []
-        assert all('f32[2,8,16,16]' in line or 'f32[2,576]' in line
+        assert all('f32[2,16,16,8]' in line or 'f32[2,72,8]' in line
                    for line in copies['decode'])
         state = engine.state_pool_stats()
         assert state == {
-            'arrays': {'ssm_state': [8, 16, 16], 'conv_state': [576]},
+            'arrays': {'ssm_state': [16, 16, 8], 'conv_state': [72, 8]},
             'layers': 2, 'slots': 2,
             'bytes_per_slot': 2 * (8 * 16 * 16 * 4 + 3 * 192 * 4),
             'bytes': 2 * 2 * (8 * 16 * 16 * 4 + 3 * 192 * 4)}
@@ -414,7 +416,7 @@ def test_registry_names_and_the_layout():
     assert [(a.name, a.heads, a.width) for a in layout.arrays] == [
         ('k_pages', 2, 128), ('v_pages', 2, 128)]
     assert [(a.name, a.shape) for a in layout.slot_arrays] == [
-        ('ssm_state', (128, 64, 128)), ('conv_state', (30720,))]
+        ('ssm_state', (64, 128, 128)), ('conv_state', (240, 128))]
     assert layout.slot_layers == 5
     # 4,194,304 B of state and 61,440 B of tail, a slot and layer.
     assert layout.slot_bytes(2) == 5 * (4194304 + 61440)

@@ -343,29 +343,51 @@ def _mamba_layer(chunk):
 
 @pytest.mark.parametrize('chunk', [1, STATE_CHUNK],
                          ids=['decode', 'prefill_chunk'])
-def test_state_by_slot_is_updated_where_it_lies(compile_for_chip, chunk):
+def test_state_by_slot_is_updated_where_it_lies(compile_for_chip,
+                                                monkeypatch, chunk):
     """The second kind of cache at the cell's shapes (128 slots of
-    f32[128, 64, 128] state, 512 MiB a layer, and of bf16[30720]
-    convolution tail): a decode round's live rows' loop and a prefill
-    chunk's row, read at its slot and written back, compile for the
-    chip with no copy of either array and, on one chip, no
-    collective."""
+    f32[64, 128, 128] state, 512 MiB a layer, and of bf16[240, 128]
+    convolution tail: ops/ssm.slot_shapes), the backend steered to the
+    chip's route: a decode round's state update is ONE call of the
+    kernel of ops/pallas_ssm.py, by its `name=` inside the scope the
+    trace is read by, with the state and the tail in and out as one
+    buffer each; a prefill chunk's row, read at its slot and written
+    back, is plain XLA. Both compile for the chip with no copy of
+    either array and, on one chip, no collective."""
+    from skypilot_tpu.ops import pallas_ssm
     from skypilot_tpu.parallel.serving import pool_collective_lines
+    monkeypatch.setattr(pallas_ssm, 'available', lambda: True)
     fn, donate, avals, cache = _mamba_layer(chunk)
     compiled = compile_for_chip(fn, donate, *avals)
     assert {k: tuple(v.shape) for k, v in cache.items()
             if k.endswith('_state')} == {
-        'ssm_state': (STATE_SLOTS, 128, 64, 128),
-        'conv_state': (STATE_SLOTS, 30720)}
+        'ssm_state': (STATE_SLOTS, 64, 128, 128),
+        'conv_state': (STATE_SLOTS, 240, 128)}
     assert pool_copy_lines(compiled, cache) == []
     mesh = jax.sharding.Mesh([jax.devices()[0]], ('tensor',))
     assert pool_collective_lines(compiled, cache, mesh) == []
     text = compiled.as_text()
-    assert 'tpu_custom_call' not in text         # plain XLA, all of it
-    assert ' dynamic-update-slice(' in text
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
     assert ' scatter(' not in text
-    # Each array goes in and comes out as the same buffer.
-    assert text.count('f32[128,128,64,128]') >= 2
+    if chunk > 1:
+        assert calls == []                       # plain XLA, all of it
+        assert ' dynamic-update-slice(' in text
+        # Each array goes in and comes out as the same buffer.
+        assert text.count('f32[128,64,128,128]') >= 2
+        return
+    assert len(calls) == 1, calls
+    assert '/ssm_update/' in calls[0]                      # the scope
+    assert 'ssm_state_update' in calls[0]                  # the name=
+    # The kernel's results: y, then the state and the tail, each the
+    # buffer of its operand (aliased), and no other state-sized value
+    # (the layouts, and the memory space XLA gives y, left aside).
+    results = calls[0].split(' custom-call(')[0].split(' = ', 1)[1]
+    assert re.findall(r'\w+\[[\d,]*\]', results) == [
+        'f32[128,64,128]', 'f32[128,64,128,128]', 'bf16[128,240,128]']
+    assert 'output_to_operand_aliasing={{1}: (7, {}), {2}: (8, {})}' \
+        in calls[0]
+    assert ' dynamic-update-slice(' not in text
 
 
 # -- the grouped expert pass (models/deepseek.MoEByShare, ops/pallas_experts.py)
