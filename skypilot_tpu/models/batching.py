@@ -1867,7 +1867,7 @@ class ContinuousBatchingEngine:
             item = jnp.dtype(getattr(cfg, 'dtype', jnp.bfloat16)).itemsize
             walked = self.pages_per_seq * self.page_size
             pool = (walked * self.page_layout.row_bytes(item)
-                    * cfg.num_layers)
+                    * (self.page_layout.layers or cfg.num_layers))
             return {'impl': self.attention_impl(),
                     'context_tokens_walked': walked,
                     'kv_pool_bytes': pool,

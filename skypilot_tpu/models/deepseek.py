@@ -761,7 +761,8 @@ class MoEByShare(nn.Module):
     that width (`latent_down`, no bias, before them; `latent_up` after
     their weighted sum, which is linear, so shares still add up); the
     router and the shared expert keep the full width. `shared_dim`:
-    the shared expert's width (0: `moe_dim` x `n_shared_experts`).
+    the shared expert's width (0: `moe_dim` x `n_shared_experts`; a
+    layer where that is 0 has no shared expert, LFM2's).
 
     `live` (bool [B,S], or None = all) marks real tokens: a junk lane
     or a padded tail is sent to no expert, so it reads no weights and
@@ -791,15 +792,16 @@ class MoEByShare(nn.Module):
         n, top = batch * seq, cfg.num_experts_per_tok
         held, offset = cfg.num_held, cfg.expert_offset
         flat = x.reshape(n, dim)
-        with jax.named_scope('shared_expert'):
-            shared_dim = (self.shared_dim
-                          or cfg.moe_dim * cfg.n_shared_experts)
-            if self.expert_act == 'swiglu':
-                shared = SwiGLU(dataclasses.replace(
-                    cfg, mlp_dim=shared_dim), name='shared')(x)
-            else:
-                shared = Relu2MLP(dim, shared_dim, cfg.dtype,
-                                  name='shared')(x)
+        shared_dim = self.shared_dim or cfg.moe_dim * cfg.n_shared_experts
+        shared = None
+        if shared_dim:
+            with jax.named_scope('shared_expert'):
+                if self.expert_act == 'swiglu':
+                    shared = SwiGLU(dataclasses.replace(
+                        cfg, mlp_dim=shared_dim), name='shared')(x)
+                else:
+                    shared = Relu2MLP(dim, shared_dim, cfg.dtype,
+                                      name='shared')(x)
         with jax.named_scope('router'):
             # The router's logits in float32 all the way, as
             # published: from the norm's float32 output where the
@@ -831,7 +833,14 @@ class MoEByShare(nn.Module):
         # expert's number in the whole model (`expert_<n>`).
         drawn = None
         if self.stacked_init and self.is_initializing():
-            key = self.make_rng('params')
+            # The draws wait for the layer's input, so after the layers
+            # below it: the values are the same, and the one init
+            # program holds one layer's draws at a time (LFM2's 8 layers
+            # of 64 held experts held all of them at once, 9.7 GB of
+            # scratch beside 10.5 GB of weights for a described v5e;
+            # 2.0 GB so).
+            key, _ = jax.lax.optimization_barrier(
+                (self.make_rng('params'), x))
             drawn = {
                 name: 0.02 * jax.random.normal(
                     jax.random.fold_in(key, j),
@@ -883,7 +892,8 @@ class MoEByShare(nn.Module):
             with jax.named_scope('latent_up'):
                 routed = _proj(dim, ('kv', 'embed'), cfg.dtype,
                                'latent_up')(routed)
-        return shared + routed.reshape(batch, seq, dim)
+        routed = routed.reshape(batch, seq, dim)
+        return routed if shared is None else shared + routed
 
 
 class Block(nn.Module):

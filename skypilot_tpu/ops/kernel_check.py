@@ -40,8 +40,18 @@ at the two routed cells' published widths (Nemotron 3 Super's 128
 `relu2` experts of 1,024 x 2,688, DeepSeek-V3.2's 16 `swiglu` experts
 of 7,168 x 2,048), a decode round and a 512-token chunk each, against
 the `lax.switch` loop on the operands in float32, with the bf16 loop's
-own run time (`loop_us`). It exits non-zero when any case is not `ok`, and when the backend is not
-a TPU: a CPU run of this file would check nothing.
+own run time (`loop_us`), and the 64 `swiglu` experts of 2,048 x
+1,536 of LFM2-24B-A2B at its 128 slots and 256-token chunks. The
+`packed_paged_attention/...` case is the decode read of a pool of
+64-wide heads packed a token a row (LFM2's 8 K/V heads in one 512-wide
+row), plain XLA, at that cell's 128 slots of 2,560 tokens, against the
+gather reference of the pool unpacked. The `streamed_matmul/...` cases
+are ops/pallas_streamed at LFM2's two conv projections for a decode
+round of 128 slots, the matrix read from HBM inside the call, against
+the float32 product; a call is shorter than the host's enqueue of it,
+so their `run_us` is the enqueue's. It exits non-zero when any case
+is not `ok`, and when the backend is not a TPU: a CPU run of this file
+would check nothing.
 
 Tolerances are set from the dtype: operands are bf16 (8 mantissa
 bits, eps 2^-8 ~ 4e-3) and both sides accumulate in f32, so outputs
@@ -532,15 +542,17 @@ def _ssm(path: str, live_lanes: int = 0) -> Callable[[Any], Dict]:
 EXPERTS = {
     'relu2': ('relu2', 128, 512, 22, 1024, 2688, 128, 512),   # Nemotron 3
     'swiglu': ('swiglu', 16, 256, 8, 7168, 2048, 48, 512),    # DeepSeek-V3.2
+    'lfm2': ('swiglu', 64, 64, 4, 2048, 1536, 128, 256),      # LFM2-24B-A2B
 }
 
 
-def _experts(act: str, phase: str) -> Callable[[Any], Dict]:
+def _experts(cell: str, phase: str) -> Callable[[Any], Dict]:
     """One routed layer's grouped expert pass (ops/pallas_experts.py,
     route 'grouped_pallas') at a cell's published widths, against the
     `lax.switch` loop it replaces (models/deepseek.grouped_experts) on
-    the operands in float32: 'decode' a round of the cell's slots, a
-    third of them live; 'chunk' a 512-token prefill chunk. Each token
+    the operands in float32: `cell` names the widths (`EXPERTS`);
+    'decode' a round of the cell's slots, a third of them live; 'chunk'
+    a prefill chunk of the cell's size. Each token
     picks its experts uniformly among all the router's; those this
     chip holds are sorted as `MoEByShare` sorts them. Also times the
     loop itself on the bf16 operands (`loop_us`)."""
@@ -551,7 +563,7 @@ def _experts(act: str, phase: str) -> Callable[[Any], Dict]:
         import numpy as np
         from skypilot_tpu.models import deepseek as ds
         from skypilot_tpu.ops import pallas_experts
-        _, held, routed, top, dim, width, slots, chunk = EXPERTS[act]
+        act, held, routed, top, dim, width, slots, chunk = EXPERTS[cell]
         n_mat = pallas_experts.ACTS[act]
         got = pallas_experts.resolve_route(dim, width, n_mat, jnp.bfloat16)
         if got != 'grouped_pallas':
@@ -607,6 +619,74 @@ def _experts(act: str, phase: str) -> Callable[[Any], Dict]:
                    rows_held=int(counts.sum()),
                    experts_touched=int((counts > 0).sum()))
         return res
+    return case
+
+
+def _packed_decode(batch: int, heads: tuple = (32, 8, 64),
+                   pages_per_seq: int = 160) -> Callable[[Any], Dict]:
+    """The decode read of a pool of 64-wide heads packed a token a row
+    (ops/paged_attention.packed_kv_layout, route 'packed_heads_xla'):
+    `lfm2-24b-a2b-l10`'s 32 query and 8 K/V heads of 64 at its 128
+    slots of 2,560 tokens, plain XLA compiled for the chip, held to the
+    gather reference of the same pool unpacked ([8, pages, page, 64]) in
+    float32, 16 rows at a time."""
+    q_heads, kv_heads, head_dim = heads
+
+    def case(key):
+        import jax
+        import jax.numpy as jnp
+        from skypilot_tpu.ops import paged_attention as paged_ops
+        from skypilot_tpu.ops import pallas_paged
+        got = pallas_paged.resolve_impl(layout='kv_packed')
+        if got != 'packed_heads_xla':
+            return _wrong_route('packed_heads_xla', got)
+        kq, kk, kv, kp, kl = jax.random.split(key, 5)
+        total = batch * pages_per_seq + 1
+        shape = (1, total, PAGE, kv_heads * head_dim)
+        k_pages = jax.random.normal(kk, shape, jnp.bfloat16)
+        v_pages = jax.random.normal(kv, shape, jnp.bfloat16)
+        tbl = (jax.random.permutation(kp, total - 1) + 1).reshape(
+            batch, pages_per_seq).astype(jnp.int32)
+        q = jax.random.normal(kq, (batch, q_heads, head_dim), jnp.bfloat16)
+        lengths = jax.random.randint(kl, (batch,), 1,
+                                     PAGE * pages_per_seq + 1, jnp.int32)
+        unpack = lambda p: jnp.moveaxis(p[0].reshape(  # noqa: E731
+            total, PAGE, kv_heads, head_dim), 2, 0)
+        one = jax.jit(paged_ops._reference_paged_attention)
+
+        def reference(q, k_pages, v_pages, lengths, tbl):
+            k, v = unpack(k_pages), unpack(v_pages)
+            return jnp.concatenate([
+                one(q[lo:lo + 16], k, v, lengths[lo:lo + 16],
+                    tbl[lo:lo + 16]) for lo in range(0, batch, 16)])
+
+        res = _compare(jax.jit(paged_ops.packed_decode_attention),
+                       reference, (q, k_pages, v_pages, lengths, tbl))
+        res.update(context_tokens=int(lengths.sum()))
+        return res
+    return case
+
+
+def _streamed(k: int, n: int, rows: int = 128) -> Callable[[Any], Dict]:
+    """A decode round's product through ops/pallas_streamed, the matrix
+    read from HBM inside the call: `lfm2-24b-a2b-l10`'s conv
+    projections at its 128 slots, held to the float32 product of the
+    same bf16 values. Its `run_us` is the host's enqueue of a call
+    (some 210 us), not the kernel's 13-44 us: a traced run has those."""
+    def case(key):
+        import jax
+        import jax.numpy as jnp
+        from skypilot_tpu.ops import pallas_streamed
+        kx, kw = jax.random.split(key)
+        x = jax.random.normal(kx, (rows, k), jnp.bfloat16)
+        w = (0.02 * jax.random.normal(kw, (k, n), jnp.float32)
+             ).astype(jnp.bfloat16)
+
+        def reference(x, w):
+            return jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32))
+
+        return _compare(jax.jit(pallas_streamed.streamed_matmul), reference,
+                        (x, w))
     return case
 
 
@@ -728,6 +808,25 @@ def cases() -> List[tuple]:
         ('experts/swiglu/chunk',
          'the same: a 512-token prefill chunk (4,096 rows)',
          _experts('swiglu', 'chunk')),
+        ('experts/swiglu/lfm2/decode',
+         'the held experts of lfm2-24b-a2b-l10, all 64 of 2,048 x '
+         '1,536: a decode round of 128 slots, a third live (172 rows)',
+         _experts('lfm2', 'decode')),
+        ('experts/swiglu/lfm2/chunk',
+         'the same: a 256-token prefill chunk (1,024 rows)',
+         _experts('lfm2', 'chunk')),
+        ('packed_paged_attention/bf16/D=64/S=1/128rows',
+         '`resolve_impl` a pool of 64-wide heads packed a token a row '
+         '(models/lfm2.py): packed_heads_xla, at lfm2-24b-a2b-l10\'s '
+         '128 slots of 2,560 tokens',
+         _packed_decode(batch=128)),
+        ('streamed_matmul/bf16/lfm2_in_proj/128rows',
+         "models/lfm2.py's conv in_proj in a decode round on a TPU: "
+         '[128, 2,048] x [2,048, 6,144]',
+         _streamed(2048, 6144)),
+        ('streamed_matmul/bf16/lfm2_out_proj/128rows',
+         'the same, out_proj: [128, 2,048] x [2,048, 2,048]',
+         _streamed(2048, 2048)),
         ('upstream_flash_attention/fwd+bwd/D=64/S=2048',
          'train_lm --seq >= 2048, GPT-2 heads',
          _flash(batch=2, heads=12, kv_heads=12, head_dim=64)),

@@ -22,6 +22,16 @@ chosen once, at trace time, by `pallas_paged.resolve_impl` from the
 backend and the static shapes and from nothing else; nothing switches
 routes at run time, and /stats names the one compiled.
 
+A pool of 64-wide heads that a model declares PACKED (`packed_kv_layout`,
+kind 'kv_packed': models/lfm2.py) keeps a token's K/V heads side by
+side in one row, [1, pages, page, Hkv * D]: a page is then whole lane
+tiles, so the gather of a row's pages takes the pool where it lies
+(a [Hkv, pages, page, 64] pool is given a page-major layout of its own
+by XLA:TPU, a copy of the whole pool in and out, each layer and
+round). `packed_decode_attention` and `packed_chunk_attention` gather
+by page and split the row into heads after the gather; the route,
+'packed_heads_xla', is the layout's own and never the decode kernel.
+
 Layouts (matching the pallas kernel):
   q            [B, num_q_heads, head_dim]      one decode token per row
   k/v_pages    [num_kv_heads, total_pages, page_size, head_dim]
@@ -456,7 +466,10 @@ class PageLayout:
     read the arrays from here instead of spelling K and V out.
 
     `kind` 'kv': keys and values, [Hkv, pages, page, D] each (Llama,
-    GPT-2, Mixtral). `kind` 'latent': MLA's compressed row, one
+    GPT-2, Mixtral); `kind` 'kv_packed': the same with a token's heads
+    side by side in one row, [1, pages, page, Hkv * D] each
+    (`packed_kv_layout`, LFM2's 64-wide heads). `kind` 'latent': MLA's
+    compressed row, one
     [1, pages, page, kv_lora_rank + rope_head_dim (in whole lane tiles:
     models/deepseek.py `latent_width`)] array a layer, and
     beside it, where the model has a lightning indexer, that token's
@@ -536,11 +549,88 @@ def kv_layout(num_kv_heads: int, head_dim: int, page_size: int,
                       page_size, total_pages)
 
 
+def packed_kv_layout(num_kv_heads: int, head_dim: int, page_size: int,
+                     total_pages: int) -> PageLayout:
+    """K and V with a token's `num_kv_heads` heads side by side in one
+    row of `num_kv_heads * head_dim` values (kind 'kv_packed',
+    models/lfm2.py): read by `packed_decode_attention` and
+    `packed_chunk_attention`, written by `write_kv_chunk` as a pool of
+    one head."""
+    width = num_kv_heads * head_dim
+    return PageLayout('kv_packed', (PoolArray('k_pages', 1, width),
+                                    PoolArray('v_pages', 1, width)),
+                      page_size, total_pages)
+
+
 #: Every leaf name a pool array may have: parallel/serving.py finds
 #: the pool's arrays in a cache tree by these.
 POOL_LEAF_NAMES = ('k_pages', 'v_pages', 'latent_pages', 'index_k_pages')
 #: Every leaf name an array of state by slot may have.
 SLOT_LEAF_NAMES = ('ssm_state', 'conv_state')
+
+
+def _gather_packed(pages: jax.Array, page_indices: jax.Array) -> jax.Array:
+    """A packed pool [1, pages, page, W] -> each row's history [B, T, W]
+    (T = pages_per_seq * page): a gather of whole pages, kept packed."""
+    _, _, page_size, width = pages.shape
+    batch, per_row = page_indices.shape
+    return pages[0][page_indices].reshape(batch, per_row * page_size,
+                                          width)
+
+
+def _packed_attention(q: jax.Array, k_pages: jax.Array,
+                      v_pages: jax.Array, bound: jax.Array,
+                      page_indices: jax.Array) -> jax.Array:
+    """q [B, S, Hq, D] over a packed pool: query s of row b attends every
+    cache index <= bound[b, s]. The rows stay packed, [B, T, Hkv * D]:
+    a query head is set into its K/V head's D lanes of a row of zeros
+    (block-diagonal), so QK^T contracts all Hkv * D lanes and PV gives
+    every lane, of which the head keeps its own. No pool-sized array is
+    relaid into heads of D lanes (a v5e spent 6.5 of a 29 ms round on
+    that for LFM2's 8 heads of 64); the products are Hkv times the
+    heads' own, which a decode round's read, bound by its bytes, does
+    not feel. Products of the pool's dtype accumulated in float32, the
+    softmax float32. Returns [B, S, Hq, D] (q.dtype)."""
+    batch, seq, q_heads, head_dim = q.shape
+    k = _gather_packed(k_pages, page_indices)
+    v = _gather_packed(v_pages, page_indices)
+    kv_heads = k.shape[-1] // head_dim
+    # own[h, w]: lane w of a packed row is query head h's K/V head's.
+    own = (jnp.arange(q_heads)[:, None] // (q_heads // kv_heads)
+           == jnp.arange(kv_heads * head_dim)[None, :] // head_dim)
+    spread = jnp.where(own, jnp.tile(q, (1, 1, 1, kv_heads)), 0)
+    s = jnp.einsum('bshw,btw->bhst', spread.astype(k.dtype), k,
+                   preferred_element_type=jnp.float32) * head_dim ** -0.5
+    mask = jnp.arange(k.shape[1])[None, None, :] <= bound[:, :, None]
+    s = jnp.where(mask[:, None], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum('bhst,btw->bhsw', p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    out = jnp.where(own[:, None], out, 0).reshape(
+        batch, q_heads, seq, kv_heads, head_dim).sum(axis=3)
+    return out.transpose(0, 2, 1, 3).astype(q.dtype)
+
+
+@jax.named_scope('paged_attention')
+def packed_decode_attention(q: jax.Array, k_pages: jax.Array,
+                            v_pages: jax.Array, lengths: jax.Array,
+                            page_indices: jax.Array) -> jax.Array:
+    """`paged_decode_attention` over a packed pool (`packed_kv_layout`):
+    q [B, Hq, D], k/v_pages [1, pages, page, Hkv * D], lengths i32[B]
+    (tokens in the cache, the current one included). Returns [B, Hq, D]
+    (q.dtype)."""
+    return _packed_attention(q[:, None], k_pages, v_pages,
+                             (lengths - 1)[:, None], page_indices)[:, 0]
+
+
+@jax.named_scope('chunk_attention')
+def packed_chunk_attention(q: jax.Array, k_pages: jax.Array,
+                           v_pages: jax.Array, positions: jax.Array,
+                           page_indices: jax.Array) -> jax.Array:
+    """`paged_chunk_attention` over a packed pool: q [B, S, Hq, D],
+    query s of row b attends every cache index <= positions[b, s] (the
+    chunk's own K/V already written). Returns [B, S, Hq, D] (q.dtype)."""
+    return _packed_attention(q, k_pages, v_pages, positions, page_indices)
 
 
 def init_pages(num_kv_heads: int, total_pages: int, page_size: int,

@@ -59,7 +59,8 @@ read (a refused shape such as 64-wide heads, every S>1 chunk) ->
 latent pool (`layout='latent'`, ops/sparse_latent.py) -> plain XLA,
 'sparse_latent_xla', but for a prefill chunk's attention on a TPU
 whose static shapes `pallas_latent.chunk_kernel_refusal` takes ->
-'sparse_latent_pallas', the kernel of ops/pallas_latent.py. No
+'sparse_latent_pallas', the kernel of ops/pallas_latent.py. A pool
+of packed heads (`layout='kv_packed'`) -> 'packed_heads_xla'. No
 argument, setter or environment variable selects a route; whoever
 wants one kernel by name calls that kernel. The one override is
 `impl_scope`, the tests' way to run the engine through the
@@ -161,8 +162,8 @@ def resolve_impl(*, quantized: bool = False,
                  decode_pool: Any = None, layout: str = 'kv',
                  latent_chunk: Any = None) -> str:
     """The route of one paged read: 'xla' | 'decode' | 'fused' |
-    'sparse_latent_xla' | 'sparse_latent_pallas' (or, inside an
-    `impl_scope`, the route the scope names).
+    'sparse_latent_xla' | 'sparse_latent_pallas' | 'packed_heads_xla'
+    (or, inside an `impl_scope`, the route the scope names).
 
     A pool whose model's page layout is 'latent' (MLA's compressed
     rows, ops/paged_attention.PageLayout) is read by
@@ -186,7 +187,18 @@ def resolve_impl(*, quantized: bool = False,
     XLA gather: a refused shape (64-wide heads) and every S>1 chunk.
     A route forced through `impl_scope` that cannot run here raises —
     it never degrades to another route, so what /stats reports is what
-    was compiled."""
+    was compiled.
+
+    A pool whose layout is 'kv_packed' (a page keeps a token's K/V
+    heads side by side in one row: 64-wide heads, models/lfm2.py) is
+    read by ops/paged_attention.packed_decode_attention and
+    packed_chunk_attention, a gather of whole pages split into heads
+    after it, on every backend: 'packed_heads_xla'. It never reaches
+    the decode kernel, which would take the packed row for one head."""
+    if layout == 'kv_packed':
+        if quantized:
+            raise ValueError('a packed K/V page pool has no int8 form')
+        return 'packed_heads_xla'
     if layout == 'latent':
         if quantized:
             raise ValueError('a latent page pool has no int8 form')

@@ -50,23 +50,25 @@ EXACT = jax.lax.Precision.HIGHEST
 
 
 def causal_conv(x: jax.Array, tail: jax.Array, weight: jax.Array,
-                bias: jax.Array, lengths: jax.Array
+                bias: Optional[jax.Array], lengths: jax.Array
                 ) -> Tuple[jax.Array, jax.Array]:
     """Depthwise causal convolution of a chunk that continues a
     sequence.
 
     x [B, S, C] the chunk's inputs, tail [B, K - 1, C] the K - 1 inputs
     before it (zeros at a sequence's start), weight [K, C] (tap K - 1
-    multiplies the current input), bias [C], lengths i32[B] the valid
+    multiplies the current input), bias [C] (None: none, LFM2's short
+    convolution), lengths i32[B] the valid
     inputs of each row. Returns (out [B, S, C] float32, the new tail
     [B, K - 1, C]: the last K - 1 inputs before position `lengths`, so
     a padded position never enters it)."""
     taps = weight.shape[0]
     seq = x.shape[1]
     full = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
-    out = bias.astype(F32)
+    out = None if bias is None else bias.astype(F32)
     for k in range(taps):
-        out = out + full[:, k:k + seq].astype(F32) * weight[k].astype(F32)
+        term = full[:, k:k + seq].astype(F32) * weight[k].astype(F32)
+        out = term if out is None else out + term
     new_tail = jax.vmap(
         lambda row, n: jax.lax.dynamic_slice_in_dim(row, n, taps - 1, 0)
     )(full, lengths)

@@ -34,9 +34,11 @@ def main() -> None:
     parser = argparse.ArgumentParser()
     parser.add_argument('--model', default='llama-tiny',
                         help='registry name (recipes/train_lm.py). A '
-                             'model with state-space layers '
+                             'model with state-space or short-'
+                             'convolution layers '
                              '(nemotron3-super-l11-ep4, '
-                             'nemotron-h-tiny) keeps recurrent state '
+                             'nemotron-h-tiny, lfm2-24b-a2b-l10, '
+                             'lfm2-tiny) keeps recurrent state '
                              'by SLOT beside its K/V pages (/stats '
                              'state_pool): a cached, spilled or '
                              'exported page holds no state to resume '

@@ -129,6 +129,22 @@ def _build_model(name: str, seq: int, remat: bool):
         if seq > cfg.max_seq_len:
             cfg = dataclasses.replace(cfg, max_seq_len=seq)
         return NemotronH(cfg), cfg.vocab_size, None
+    if name == 'lfm2-24b-a2b-l10':
+        # LFM2-24B-A2B at every published width as stage 1 of a
+        # 4-stage pipeline: the published layers 0-9 (two dense, then
+        # two periods of attention and three short convolutions, all
+        # 64 experts held), the whole vocabulary, the head tied
+        # (perfbench/configs/lfm2-24b-a2b-l10.json). Serving only: its
+        # state lives in the engine's slots and pages.
+        from skypilot_tpu.models.lfm2 import Lfm2, Lfm2Config
+        cfg = Lfm2Config.l10(max_seq_len=max(seq, 4096), remat=remat)
+        return Lfm2(cfg), cfg.vocab_size, None
+    if name == 'lfm2-tiny':
+        from skypilot_tpu.models.lfm2 import Lfm2, Lfm2Config
+        cfg = Lfm2Config.tiny(remat=remat)
+        if seq > cfg.max_seq_len:
+            cfg = dataclasses.replace(cfg, max_seq_len=seq)
+        return Lfm2(cfg), cfg.vocab_size, None
     if name == 'qwen2-7b':
         from skypilot_tpu.models.llama import Llama, LlamaConfig
         cfg = LlamaConfig(vocab_size=152064, num_layers=28,

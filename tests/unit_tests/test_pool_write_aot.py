@@ -437,3 +437,312 @@ def test_expert_layer_takes_the_kernel_and_no_switch(compile_for_chip,
     assert len(calls) == 1, calls
     assert '/experts/' in calls[0] and 'grouped_experts' in calls[0]
     assert ' conditional(' not in text
+
+
+# -- 64-wide heads packed a token a row, and a short convolution's tail by
+# slot (models/lfm2.py) -----------------------------------------------------
+#: `lfm2-24b-a2b-l10`'s pool: 8 K/V heads of 64 side by side in one
+#: 512-wide row, 1.5 GiB over its 2 attention layers (24,576 pages), 128
+#: slots of 2,560 tokens (160 pages a row).
+LFM2_SLOTS, LFM2_PAGES_PER_ROW, LFM2_CHUNK = 128, 160, 256
+
+
+def _lfm2_program(module, rows, chunk, **call):
+    """One module of `lfm2-24b-a2b-l10` through the engine's cache, as a
+    flat function of (cache leaves, params, x, positions, live, slot,
+    table) whose cache leaves are donated, and its avals."""
+    import flax.linen as nn
+    x = jax.ShapeDtypeStruct((rows, chunk, 2048), jnp.bfloat16)
+    # The cache as the engine makes it: one token a slot.
+    variables = nn.meta.unbox(jax.eval_shape(
+        lambda: module.init(
+            jax.random.PRNGKey(0),
+            jnp.zeros((LFM2_SLOTS, 1, 2048), x.dtype),
+            **_lfm2_init_args(module))))
+    params = jax.tree.map(
+        lambda s: (s.shape, jnp.bfloat16 if len(s.shape) > 1 else s.dtype),
+        variables['params'])
+    cache = jax.tree.map(lambda s: (s.shape, s.dtype), variables['cache'])
+    is_aval = lambda x: isinstance(x, tuple)  # noqa: E731
+    flat_cache, cache_def = jax.tree.flatten(cache, is_leaf=is_aval)
+    flat_params, params_def = jax.tree.flatten(params, is_leaf=is_aval)
+
+    def fn(*args):
+        n, m = len(flat_cache), len(flat_params)
+        x, positions, live, slot, table = args[n + m:]
+        out, mutated = module.apply(
+            {'params': jax.tree.unflatten(params_def, args[n:n + m]),
+             'cache': jax.tree.unflatten(cache_def, args[:n])},
+            x, **_lfm2_call_args(module, positions, live, slot, table,
+                                 chunk, call), mutable=['cache'])
+        return mutated['cache'], out
+
+    avals = (*flat_cache, *flat_params, (x.shape, x.dtype),
+             ((rows, chunk), jnp.int32), ((rows, chunk), jnp.bool_),
+             ((), jnp.int32), ((rows, LFM2_PAGES_PER_ROW), jnp.int32))
+    return fn, tuple(range(len(flat_cache))), avals, variables['cache']
+
+
+def _lfm2_init_args(module):
+    from skypilot_tpu.models import lfm2
+    if isinstance(module, lfm2.ShortConv):
+        return {'decode': True}
+    return {'positions': jnp.zeros((LFM2_SLOTS, 1), jnp.int32),
+            'decode': True,
+            'page_indices': jnp.zeros((LFM2_SLOTS, LFM2_PAGES_PER_ROW),
+                                      jnp.int32)}
+
+
+def _lfm2_call_args(module, positions, live, slot, table, chunk, call):
+    from skypilot_tpu.models import lfm2
+    if isinstance(module, lfm2.ShortConv):
+        return dict(decode=True, live=live,
+                    slots=None if chunk == 1 else slot[None], **call)
+    return dict(positions=positions, decode=True, page_indices=table,
+                **call)
+
+
+def _lfm2_cache(**leaves):
+    return {'layer_0': {'mixer': leaves}}
+
+
+@pytest.mark.parametrize('chunk', [1, LFM2_CHUNK],
+                         ids=['decode', 'later_chunk'])
+def test_packed_64_wide_heads_are_read_where_they_lie(compile_for_chip,
+                                                      monkeypatch, chunk):
+    """An attention layer of `lfm2-24b-a2b-l10` at the cell's shapes (a
+    [1, 24,576, 16, 512] pool a K and a V, 128 slots; a 256-token chunk
+    behind its history), the backend steered to the chip's routes: the
+    write and the read by page compile for the chip with
+    `pool_copy_lines` at zero, where GPT-2's [12, ..., 64] pool is
+    copied whole (`test_decode_layer_of_64_wide_heads_compiles_as_the_
+    gather`); the read is the route of the packed layout, plain XLA,
+    never the 128-lane decode kernel, which would take the packed row
+    for one head; its values reach the read as heads of 64."""
+    from skypilot_tpu.models import lfm2
+    from skypilot_tpu.ops import pallas_paged
+    monkeypatch.setattr(pallas_paged, 'available', lambda: True)
+    cfg = lfm2.Lfm2Config.l10(kv_total_pages=24576)
+    layout = cfg.page_layout()
+    assert layout.kind == 'kv_packed'
+    assert pallas_paged.resolve_impl(layout=layout.kind) == \
+        'packed_heads_xla'
+    # The shape alone would have taken the decode kernel.
+    pool = jax.ShapeDtypeStruct(layout.shape(layout.arrays[0]),
+                                jnp.bfloat16)
+    assert pool.shape == (1, 24576, 16, 512)
+    assert pallas_paged.decode_kernel_refusal(pool) is None
+    rows = LFM2_SLOTS if chunk == 1 else 1
+    fn, donate, avals, cache = _lfm2_program(
+        lfm2.Attention(cfg), rows, chunk, prefill=False,
+        page_aligned=chunk > 1)
+    assert {k: tuple(v.shape) for k, v in cache.items()} == {
+        'k_pages': pool.shape, 'v_pages': pool.shape}
+    compiled = compile_for_chip(fn, donate, *avals)
+    assert pool_copy_lines(compiled, _lfm2_cache(**cache)) == []
+    text = compiled.as_text()
+    assert 'tpu_custom_call' not in text
+    assert ' dynamic-update-slice(' in text and ' scatter(' not in text
+    # A row's 160 pages gathered whole, [160, 16, 512] a row (the rows'
+    # axis folded away for one), and split into heads of 64 after.
+    assert re.search(rf'= bf16\[({rows},)?160,16,512\]\S* gather\(',
+                     text)
+
+
+@pytest.mark.parametrize('chunk', [1, LFM2_CHUNK],
+                         ids=['decode', 'later_chunk'])
+def test_the_conv_tail_is_updated_where_it_lies(compile_for_chip, chunk):
+    """A conv mixer of `lfm2-24b-a2b-l10` (128 slots of a tail of two
+    inputs of 2,048 one after the other, bf16[4,096]: ops/paged_attention
+    .SlotArray `conv_state`): a decode round shifts the live lanes'
+    tails and a later chunk reads its slot's row and writes it back,
+    both with no copy of the tail array, no scatter, the array donated
+    and handed back as the same buffer, and the mixer's work under the
+    scope the trace is read by. (Without the barrier in `conv_step`
+    XLA:TPU may order the shift before the taps and copy the old array
+    for them.)"""
+    from skypilot_tpu.models import lfm2
+    cfg = lfm2.Lfm2Config.l10()
+    rows = LFM2_SLOTS if chunk == 1 else 1
+    fn, donate, avals, cache = _lfm2_program(lfm2.ShortConv(cfg), rows,
+                                             chunk, prefill=False)
+    assert {k: tuple(v.shape) for k, v in cache.items()} == {
+        'conv_state': (LFM2_SLOTS, 4096)}
+    compiled = compile_for_chip(fn, donate, *avals)
+    assert pool_copy_lines(compiled, _lfm2_cache(**cache)) == []
+    text = compiled.as_text()
+    assert 'input_output_alias={ {0}: (0, {}, may-alias)' in text
+    assert ' scatter(' not in text and 'tpu_custom_call' not in text
+    assert '/short_conv/' in text and '/conv_tail/' in text
+    if chunk > 1:
+        assert ' dynamic-update-slice(' in text
+
+
+def test_the_conv_projections_read_their_matrices_in_scope(
+        one_chip, compile_for_chip, monkeypatch):
+    """A decode round of two conv layers of `lfm2-24b-a2b-l10` (its two
+    dense layers, a small vocabulary), the backend steered to the
+    chip's route: each of the four projections is an ops/
+    pallas_streamed call in scope `short_conv` that takes its matrix
+    as the program's parameter, and memory-space assignment copies no
+    projection's matrix into VMEM ahead of it. Without the kernel's
+    HBM constraint it does: the second layer's matrices arrive in
+    slices during the first layer's MLP, and the waits for them
+    belong to no scope (rooflines/short_conv_decode.py)."""
+    import flax.linen as nn
+    from skypilot_tpu.models import lfm2
+    from skypilot_tpu.ops import pallas_streamed
+    monkeypatch.setattr(pallas_streamed, 'available', lambda: True)
+    cfg = lfm2.Lfm2Config.l10(layer_types=('conv', 'conv'), vocab_size=1024,
+                              kv_total_pages=64)
+    model = lfm2.Lfm2(cfg)
+    slots, pages = LFM2_SLOTS, 4
+    variables = nn.meta.unbox(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32),
+        positions=jnp.zeros((slots, 1), jnp.int32), decode=True,
+        page_indices=jnp.zeros((slots, pages), jnp.int32))))
+
+    def aval(s, dtype=None):
+        return jax.ShapeDtypeStruct(s.shape, dtype or s.dtype,
+                                    sharding=one_chip)
+
+    def step(params, cache, tokens, positions, table, live):
+        logits, mutated = model.apply(
+            {'params': params, 'cache': cache}, tokens,
+            positions=positions, decode=True, page_indices=table,
+            live=live, mutable=['cache'])
+        return mutated['cache'], logits
+
+    rows = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        jax.tree.map(lambda s: aval(s, jnp.bfloat16), variables['params']),
+        jax.tree.map(aval, variables['cache']), rows, rows,
+        jax.ShapeDtypeStruct((slots, pages), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((slots, 1), jnp.bool_, sharding=one_chip)
+    ).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 4
+    for line in calls:
+        assert re.search(r'/short_conv/(in|out)_proj/\S*/streamed_matmul/',
+                         line), line[:300]
+        # The matrix goes in as the entry parameter, in HBM.
+        operand = re.search(r'custom-call\(%\S+, %(\S+)\)', line).group(1)
+        assert re.search(rf'%{re.escape(operand)} = bf16\[2048,\d+\]'
+                         rf'[^ ]* parameter\(', text), operand
+    assert not re.search(r'(copy|slice)-start\(%\S*_proj', text)
+
+
+# -- the other cells' programs, as they lowered before models/lfm2.py ------
+#: sha256 of each cell's decode round and first prefill chunk lowered for
+#: the chip (`_normalized` text), the cell's published widths at 4 slots
+#: and a 64-page pool, recorded from the tree before models/lfm2.py came
+#: in. The LFM2 change touches code these programs run (MoEByShare's
+#: shared expert, ops/ssm.causal_conv's bias, the paged read's route);
+#: they must lower to the same text. A change that means to alter one
+#: records its new digest here and says so.
+LOWERED_BEFORE_LFM2 = {
+    ('mistral-7b-l16', 'decode'):
+        'f62be756b1104e667cb9163adf0ad78717f8703febc166b5999bac6bd9df8861',
+    ('mistral-7b-l16', 'first_chunk'):
+        '0f4e28d2659fef804aeed575ca2b22ece110ddd87233c55e78d33079cfb213fe',
+    ('deepseek-v32-l5-ep16', 'decode'):
+        'adcfeb966906939be6707de67da8d206c9f63bdb5acbf8520bab1cdc2a62f8f2',
+    ('deepseek-v32-l5-ep16', 'first_chunk'):
+        '559036a551a6f3565f498a29ffcde343844a745cda98a9f8a499413929f3e098',
+    ('nemotron3-super-l11-ep4', 'decode'):
+        '97d5094a915c9a578ab21f81017572b9f1f9ad7005027d5f07dbb81f6b3824cb',
+    ('nemotron3-super-l11-ep4', 'first_chunk'):
+        '553e81a393acc7a86554bb8cd0bdcee811cc9c3b930d28e332eacb004a5b9eda',
+}
+
+
+def _normalized(text):
+    """A lowered module's text with each Mosaic kernel's serialized body
+    replaced by the digest of its text WITHOUT source locations: a
+    kernel's bytecode carries the file and line of every operation, so
+    a line added above a kernel moves it, and that is no change to the
+    program."""
+    import base64
+    import hashlib
+    from jax._src.lib.mlir import ir
+
+    def kernel(match):
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            module = ir.Module.parse(base64.b64decode(match.group(1)))
+            asm = module.operation.get_asm(enable_debug_info=False)
+        return 'KERNEL ' + hashlib.sha256(asm.encode()).hexdigest()
+
+    return re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]*)\\22', kernel, text)
+
+
+def _cell_model(name):
+    import json
+    import os
+    from perfbench import shim
+    from skypilot_tpu.models import deepseek, llama, nemotron_h
+    if name == 'mistral-7b-l16':
+        root = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        with open(os.path.join(root, 'perfbench', 'configs',
+                               'mistral-7b-l16.json'), encoding='utf-8') as f:
+            cfg = json.load(f)
+        return llama.Llama(llama.LlamaConfig(
+            **shim.llama_kwargs(cfg), max_seq_len=4096, kv_total_pages=64))
+    if name == 'deepseek-v32-l5-ep16':
+        return deepseek.Deepseek(
+            deepseek.DeepseekConfig.v32_l5_ep16(kv_total_pages=64))
+    return nemotron_h.NemotronH(
+        nemotron_h.NemotronHConfig.super_l11_ep4(kv_total_pages=64))
+
+
+@pytest.mark.parametrize('name, program', sorted(LOWERED_BEFORE_LFM2))
+def test_the_other_cells_programs_lower_as_before(one_chip, monkeypatch,
+                                                  name, program):
+    """Mistral's, DeepSeek's and Nemotron's decode round and first
+    prefill chunk (the model's call as the engine makes it, the routes
+    steered to the chip's) lower to the text they lowered to before
+    LFM2 shared their code."""
+    import hashlib
+    import flax.linen as nn
+    from skypilot_tpu.ops import pallas_experts, pallas_ssm
+    from skypilot_tpu.ops import pallas_paged
+    for module in (pallas_paged, pallas_experts, pallas_ssm):
+        monkeypatch.setattr(module, 'available', lambda: True)
+    slots, pages, chunk = 4, 8, 64
+    model = _cell_model(name)
+    var = nn.meta.unbox(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32),
+        positions=jnp.zeros((slots, 1), jnp.int32), decode=True,
+        page_indices=jnp.zeros((slots, pages), jnp.int32))))
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda s: aval(s.shape, (
+        jnp.bfloat16 if jnp.issubdtype(s.dtype, jnp.floating)
+        else s.dtype)), var['params'])
+    cache = jax.tree.map(lambda s: aval(s.shape, s.dtype), var['cache'])
+    takes_live = getattr(model, 'takes_live_mask', False)
+    slot_state = bool(model.config.page_layout().slot_arrays)
+    first = program == 'first_chunk'
+    rows, seq = (1, chunk) if first else (slots, 1)
+
+    def fn(params, cache, tokens, positions, table, live, slot):
+        kw = {'live': live} if takes_live else {}
+        if slot_state and first:
+            kw['slots'] = slot[None]
+        logits, mutated = model.apply(
+            {'params': params, 'cache': cache}, tokens, positions=positions,
+            decode=True, page_indices=table, prefill=first,
+            page_aligned=first, mutable=['cache'], **kw)
+        return mutated['cache'], logits
+
+    lowered = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, aval((rows, seq), jnp.int32),
+        aval((rows, seq), jnp.int32), aval((rows, pages), jnp.int32),
+        aval((rows, seq), jnp.bool_), aval((), jnp.int32))
+    assert hashlib.sha256(_normalized(lowered.as_text()).encode()
+                          ).hexdigest() == LOWERED_BEFORE_LFM2[name, program]
