@@ -1720,17 +1720,20 @@ class ContinuousBatchingEngine:
     def attention_impl(self) -> str:
         """The route this engine's traced decode read takes:
         ops/pallas_paged.resolve_impl given what that read gives it
-        (whether the pool is int8, the K pool's static shape), so the
-        name reported is the program compiled. 'dense' when the
-        engine runs the dense per-slot cache — no paged read in play.
-        Surfaced via the attention_impl_info gauge and /stats."""
+        (whether the pool is int8, the K pool's static shape, the
+        layout, inside the engine's mesh), so the name reported is the
+        program compiled. 'dense' when the engine runs the dense
+        per-slot cache — no paged read in play. Surfaced via the
+        attention_impl_info gauge and /stats."""
         if not self.paged:
             return 'dense'
         from skypilot_tpu.ops import pallas_paged
-        return pallas_paged.resolve_impl(
-            quantized=self.kv_dtype == 'int8',
-            decode_pool=self._pool_aval,
-            layout=self.page_layout.kind)
+        with (self.mesh if self.mesh is not None
+              else contextlib.nullcontext()):
+            return pallas_paged.resolve_impl(
+                quantized=self.kv_dtype == 'int8',
+                decode_pool=self._pool_aval,
+                layout=self.page_layout.kind)
 
     def expert_impl(self) -> str:
         """The route of a routed model's grouped expert pass in this

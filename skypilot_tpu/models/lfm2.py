@@ -32,7 +32,10 @@ last a final RMSNorm and the head, tied to the embedding.
 Serving keeps two kinds of cache. K and V of the attention layers live
 in the page pool with a token's heads side by side in one row
 (ops/paged_attention.packed_kv_layout: 64-wide heads read by the
-page with no copy of the pool, route 'packed_heads_xla'). A conv
+page with no copy of the pool; a decode round's read is on a TPU the
+kernel of ops/pallas_packed.py, route 'packed_decode', which copies the
+live pages of the live lanes only, and a chunk's the gather,
+'packed_heads_xla'). A conv
 layer's only state is its convolution's tail, the last K - 1 inputs
 `u` of a sequence: one row a SLOT (`page_layout().slot_arrays`,
 `conv_state` [(K - 1) x hidden], the inputs one after the other). A
@@ -297,7 +300,8 @@ class ShortConv(nn.Module):
 
 class Attention(nn.Module):
     """Grouped-query attention with q and k RMS-normed a head before
-    RoPE, over the packed K/V page pool when serving."""
+    RoPE, over the packed K/V page pool when serving; a decode round's
+    dead lanes (`live` False) read none of it."""
     config: Lfm2Config
 
     @nn.compact
@@ -305,7 +309,8 @@ class Attention(nn.Module):
                  decode: bool = False,
                  page_indices: Optional[jax.Array] = None,
                  prefill: bool = False,
-                 page_aligned: bool = False) -> jax.Array:
+                 page_aligned: bool = False,
+                 live: Optional[jax.Array] = None) -> jax.Array:
         cfg = self.config
         batch, seq, _ = x.shape
         hd, kv_heads = cfg.head_dim, cfg.num_kv_heads
@@ -336,9 +341,11 @@ class Attention(nn.Module):
                 v.reshape(batch, seq, 1, kv_heads * hd), positions,
                 page_indices, page_aligned=page_aligned and seq > 1)
             if seq == 1:
+                # A dead lane reads nothing: length 0, and zeros out.
                 out = paged_ops.packed_decode_attention(
                     q[:, 0], k_pages.value, v_pages.value,
-                    lengths=positions[:, 0] + 1,
+                    lengths=jnp.where(_rows(live, batch, 1)[:, 0],
+                                      positions[:, 0] + 1, 0),
                     page_indices=page_indices)[:, None]
             elif prefill:
                 # The sequence starts with this chunk: chunk-local.
@@ -373,7 +380,8 @@ class Block(nn.Module):
                                                 slots)
         elif self.kind == 'full_attention':
             x = x + Attention(cfg, name='self_attn')(
-                h, positions, decode, page_indices, prefill, page_aligned)
+                h, positions, decode, page_indices, prefill, page_aligned,
+                live)
         else:
             raise ValueError(
                 f'layer_types entry {self.kind!r}: this model builds '

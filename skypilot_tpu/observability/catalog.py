@@ -125,10 +125,11 @@ SPECS: Dict[str, Tuple] = {
         ('kv_dtype', 'weight_dtype')),
     'skypilot_serving_attention_impl_info': (
         'gauge', 'Resolved paged-attention implementation in effect '
-                 '(always 1; read the labels — impl is xla | decode | '
-                 'fused | fused_interpret, or dense when the '
-                 'engine runs the dense KV cache; ops/pallas_paged.py '
-                 'dispatch rules)',
+                 '(always 1; read the labels — impl is the decode '
+                 'read\'s route, e.g. xla | decode | fused | '
+                 'packed_decode | packed_heads_xla | sparse_latent_xla, '
+                 'or dense when the engine runs the dense KV cache; '
+                 'ops/pallas_paged.resolve_impl)',
         ('engine', 'impl', 'kv_dtype')),
     'skypilot_serving_attention_bytes_per_token': (
         'gauge', 'Modeled HBM bytes one decode step moves per '

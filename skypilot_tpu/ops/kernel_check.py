@@ -42,10 +42,13 @@ of 7,168 x 2,048), a decode round and a 512-token chunk each, against
 the `lax.switch` loop on the operands in float32, with the bf16 loop's
 own run time (`loop_us`), and the 64 `swiglu` experts of 2,048 x
 1,536 of LFM2-24B-A2B at its 128 slots and 256-token chunks. The
-`packed_paged_attention/...` case is the decode read of a pool of
+`packed_paged_attention/...` cases are the decode read of a pool of
 64-wide heads packed a token a row (LFM2's 8 K/V heads in one 512-wide
-row), plain XLA, at that cell's 128 slots of 2,560 tokens, against the
-gather reference of the pool unpacked. The `streamed_matmul/...` cases
+row) at that cell's 128 slots of 2,560 tokens, against the gather
+reference of the pool unpacked: the XLA gather of every row's whole
+table by name, and the kernel of ops/pallas_packed.py (route
+`packed_decode`) at the cell's round (32 rows of about 510 tokens live,
+96 of length 0) and with every row live. The `streamed_matmul/...` cases
 are ops/pallas_streamed at LFM2's two conv projections for a decode
 round of 128 slots, the matrix read from HBM inside the call, against
 the float32 product; a call is shorter than the host's enqueue of it,
@@ -622,14 +625,20 @@ def _experts(cell: str, phase: str) -> Callable[[Any], Dict]:
     return case
 
 
-def _packed_decode(batch: int, heads: tuple = (32, 8, 64),
-                   pages_per_seq: int = 160) -> Callable[[Any], Dict]:
+def _packed_decode(route: str, batch: int = 128, live_every: int = 1,
+                   heads: tuple = (32, 8, 64), pages_per_seq: int = 160
+                   ) -> Callable[[Any], Dict]:
     """The decode read of a pool of 64-wide heads packed a token a row
-    (ops/paged_attention.packed_kv_layout, route 'packed_heads_xla'):
-    `lfm2-24b-a2b-l10`'s 32 query and 8 K/V heads of 64 at its 128
-    slots of 2,560 tokens, plain XLA compiled for the chip, held to the
-    gather reference of the same pool unpacked ([8, pages, page, 64]) in
-    float32, 16 rows at a time."""
+    (ops/paged_attention.packed_kv_layout): `lfm2-24b-a2b-l10`'s 32
+    query and 8 K/V heads of 64 at its 128 slots of 2,560 tokens, held
+    to the gather reference of the same pool unpacked ([8, pages, page,
+    64]) in float32, 16 rows at a time. Route 'packed_decode' is the
+    model's call, `packed_decode_attention`, which on a TPU is the
+    kernel of ops/pallas_packed.py; 'packed_heads_xla' the gather of
+    every row's whole table by name (`packed_decode_xla`), what the
+    read was before the kernel. Every `live_every`-th row is live, of
+    about 510 tokens when some are dead (the cell's rows), of 1 to
+    2,560 when all are; the rest have length 0 and give zeros."""
     q_heads, kv_heads, head_dim = heads
 
     def case(key):
@@ -637,32 +646,44 @@ def _packed_decode(batch: int, heads: tuple = (32, 8, 64),
         import jax.numpy as jnp
         from skypilot_tpu.ops import paged_attention as paged_ops
         from skypilot_tpu.ops import pallas_paged
-        got = pallas_paged.resolve_impl(layout='kv_packed')
-        if got != 'packed_heads_xla':
-            return _wrong_route('packed_heads_xla', got)
         kq, kk, kv, kp, kl = jax.random.split(key, 5)
         total = batch * pages_per_seq + 1
         shape = (1, total, PAGE, kv_heads * head_dim)
         k_pages = jax.random.normal(kk, shape, jnp.bfloat16)
         v_pages = jax.random.normal(kv, shape, jnp.bfloat16)
+        pool = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        got = pallas_paged.resolve_impl(
+            layout='kv_packed',
+            decode_pool=pool if route == 'packed_decode' else None)
+        if got != route:
+            return _wrong_route(route, got)
         tbl = (jax.random.permutation(kp, total - 1) + 1).reshape(
             batch, pages_per_seq).astype(jnp.int32)
         q = jax.random.normal(kq, (batch, q_heads, head_dim), jnp.bfloat16)
-        lengths = jax.random.randint(kl, (batch,), 1,
-                                     PAGE * pages_per_seq + 1, jnp.int32)
+        if live_every == 1:
+            lengths = jax.random.randint(kl, (batch,), 1,
+                                         PAGE * pages_per_seq + 1, jnp.int32)
+        else:
+            lengths = jnp.where(
+                jnp.arange(batch) % live_every == 0,
+                jax.random.randint(kl, (batch,), 384, 640, jnp.int32), 0)
         unpack = lambda p: jnp.moveaxis(p[0].reshape(  # noqa: E731
             total, PAGE, kv_heads, head_dim), 2, 0)
         one = jax.jit(paged_ops._reference_paged_attention)
 
         def reference(q, k_pages, v_pages, lengths, tbl):
             k, v = unpack(k_pages), unpack(v_pages)
-            return jnp.concatenate([
-                one(q[lo:lo + 16], k, v, lengths[lo:lo + 16],
+            out = jnp.concatenate([
+                one(q[lo:lo + 16], k, v, jnp.maximum(lengths[lo:lo + 16], 1),
                     tbl[lo:lo + 16]) for lo in range(0, batch, 16)])
+            return jnp.where((lengths > 0)[:, None, None], out, 0)
 
-        res = _compare(jax.jit(paged_ops.packed_decode_attention),
-                       reference, (q, k_pages, v_pages, lengths, tbl))
-        res.update(context_tokens=int(lengths.sum()))
+        read = (paged_ops.packed_decode_attention if route == 'packed_decode'
+                else paged_ops.packed_decode_xla)
+        res = _compare(jax.jit(read), reference,
+                       (q, k_pages, v_pages, lengths, tbl))
+        res.update(context_tokens=int(lengths.sum()),
+                   live_rows=int((lengths > 0).sum()))
         return res
     return case
 
@@ -816,10 +837,19 @@ def cases() -> List[tuple]:
          'the same: a 256-token prefill chunk (1,024 rows)',
          _experts('lfm2', 'chunk')),
         ('packed_paged_attention/bf16/D=64/S=1/128rows',
-         '`resolve_impl` a pool of 64-wide heads packed a token a row '
-         '(models/lfm2.py): packed_heads_xla, at lfm2-24b-a2b-l10\'s '
-         '128 slots of 2,560 tokens',
-         _packed_decode(batch=128)),
+         'a pool of 64-wide heads packed a token a row (models/lfm2.py) '
+         'read by the gather of every row\'s whole table by name, '
+         'packed_heads_xla, at lfm2-24b-a2b-l10\'s 128 slots of 2,560 '
+         'tokens',
+         _packed_decode('packed_heads_xla')),
+        ('packed_paged_attention/kernel/bf16/D=64/S=1/128rows/32live',
+         '`resolve_impl` the same pool\'s decode read on a TPU: '
+         'packed_decode, the kernel of ops/pallas_packed.py; the cell\'s '
+         'round, 32 rows of about 510 tokens live and 96 of length 0',
+         _packed_decode('packed_decode', live_every=4)),
+        ('packed_paged_attention/kernel/bf16/D=64/S=1/128rows/128live',
+         'the same with every row live, of 1 to 2,560 tokens',
+         _packed_decode('packed_decode')),
         ('streamed_matmul/bf16/lfm2_in_proj/128rows',
          "models/lfm2.py's conv in_proj in a decode round on a TPU: "
          '[128, 2,048] x [2,048, 6,144]',

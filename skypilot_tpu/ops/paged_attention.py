@@ -25,12 +25,16 @@ routes at run time, and /stats names the one compiled.
 A pool of 64-wide heads that a model declares PACKED (`packed_kv_layout`,
 kind 'kv_packed': models/lfm2.py) keeps a token's K/V heads side by
 side in one row, [1, pages, page, Hkv * D]: a page is then whole lane
-tiles, so the gather of a row's pages takes the pool where it lies
-(a [Hkv, pages, page, 64] pool is given a page-major layout of its own
-by XLA:TPU, a copy of the whole pool in and out, each layer and
-round). `packed_decode_attention` and `packed_chunk_attention` gather
-by page and split the row into heads after the gather; the route,
-'packed_heads_xla', is the layout's own and never the decode kernel.
+tiles, so a read by page takes the pool where it lies (a [Hkv, pages,
+page, 64] pool is given a page-major layout of its own by XLA:TPU, a
+copy of the whole pool in and out, each layer and round). On a TPU
+`packed_decode_attention` is the kernel of ops/pallas_packed.py (route
+'packed_decode'), one 16 KB copy a live page of a live row, the rows
+kept packed; `packed_chunk_attention`, and the decode read off a TPU
+or under a mesh of several devices, gather every row's pages whole and
+keep the rows packed through the products (`_packed_attention`, route
+'packed_heads_xla'). Neither is ever the 128-wide decode kernel,
+which would take the packed row for one head.
 
 Layouts (matching the pallas kernel):
   q            [B, num_q_heads, head_dim]      one decode token per row
@@ -617,10 +621,34 @@ def packed_decode_attention(q: jax.Array, k_pages: jax.Array,
                             page_indices: jax.Array) -> jax.Array:
     """`paged_decode_attention` over a packed pool (`packed_kv_layout`):
     q [B, Hq, D], k/v_pages [1, pages, page, Hkv * D], lengths i32[B]
-    (tokens in the cache, the current one included). Returns [B, Hq, D]
-    (q.dtype)."""
-    return _packed_attention(q[:, None], k_pages, v_pages,
-                             (lengths - 1)[:, None], page_indices)[:, 0]
+    (tokens in the cache, the current one included; 0: a dead row,
+    which returns zeros). Returns [B, Hq, D] (q.dtype).
+
+    The route is `pallas_paged.resolve_impl`'s for the layout: on a TPU
+    'packed_decode', ops/pallas_packed.packed_decode_kernel, which
+    copies the live pages of the live rows only; else
+    'packed_heads_xla', `_packed_attention` over every row's whole
+    table."""
+    from skypilot_tpu.ops import pallas_paged
+    impl = pallas_paged.resolve_impl(layout='kv_packed',
+                                     decode_pool=k_pages)
+    if impl in ('packed_decode', 'packed_decode_interpret'):
+        from skypilot_tpu.ops import pallas_packed
+        return pallas_packed.packed_decode_kernel(
+            q, k_pages, v_pages, lengths, page_indices,
+            interpret=impl == 'packed_decode_interpret')
+    return packed_decode_xla(q, k_pages, v_pages, lengths, page_indices)
+
+
+def packed_decode_xla(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
+                      lengths: jax.Array, page_indices: jax.Array
+                      ) -> jax.Array:
+    """`packed_decode_attention`'s route 'packed_heads_xla': every row's
+    whole table gathered, a row of length 0 zeros."""
+    out = _packed_attention(q[:, None], k_pages, v_pages,
+                            (jnp.maximum(lengths, 1) - 1)[:, None],
+                            page_indices)[:, 0]
+    return jnp.where((lengths > 0)[:, None, None], out, 0).astype(q.dtype)
 
 
 @jax.named_scope('chunk_attention')

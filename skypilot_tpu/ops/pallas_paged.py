@@ -60,7 +60,10 @@ latent pool (`layout='latent'`, ops/sparse_latent.py) -> plain XLA,
 'sparse_latent_xla', but for a prefill chunk's attention on a TPU
 whose static shapes `pallas_latent.chunk_kernel_refusal` takes ->
 'sparse_latent_pallas', the kernel of ops/pallas_latent.py. A pool
-of packed heads (`layout='kv_packed'`) -> 'packed_heads_xla'. No
+of packed heads (`layout='kv_packed'`) -> 'packed_decode', the
+kernel of ops/pallas_packed.py, for a one-token decode read on a TPU
+whose pool `pallas_packed.kernel_refusal` takes, outside a mesh of
+several devices; else 'packed_heads_xla', the gather kept packed. No
 argument, setter or environment variable selects a route; whoever
 wants one kernel by name calls that kernel. The one override is
 `impl_scope`, the tests' way to run the engine through the
@@ -110,11 +113,17 @@ from skypilot_tpu.ops import pallas_latent
 #: (compiles everywhere); 'decode' this module's decode read of an
 #: unquantized pool (`paged_decode_kernel`); 'fused' this module's
 #: int8-capable kernel; 'fused_interpret' the same kernel in pallas
-#: interpret mode (runs anywhere, CPU included; `impl_scope` only).
-IMPLS: Tuple[str, ...] = ('xla', 'decode', 'fused', 'fused_interpret')
+#: interpret mode (runs anywhere, CPU included; `impl_scope` only);
+#: 'packed_decode' the decode read of a packed pool (ops/pallas_packed
+#: .packed_decode_kernel) and 'packed_decode_interpret' the same in
+#: interpret mode (`impl_scope` only).
+IMPLS: Tuple[str, ...] = ('xla', 'decode', 'fused', 'fused_interpret',
+                          'packed_decode', 'packed_decode_interpret')
 
 #: The routes Mosaic compiles: a TPU backend only.
-_COMPILED = ('decode', 'fused')
+_COMPILED = ('decode', 'fused', 'packed_decode')
+#: The routes that read a packed pool, and nothing else.
+_PACKED = ('packed_decode', 'packed_decode_interpret')
 
 # -- availability -----------------------------------------------------------
 def available() -> bool:
@@ -162,8 +171,9 @@ def resolve_impl(*, quantized: bool = False,
                  decode_pool: Any = None, layout: str = 'kv',
                  latent_chunk: Any = None) -> str:
     """The route of one paged read: 'xla' | 'decode' | 'fused' |
-    'sparse_latent_xla' | 'sparse_latent_pallas' | 'packed_heads_xla'
-    (or, inside an `impl_scope`, the route the scope names).
+    'sparse_latent_xla' | 'sparse_latent_pallas' | 'packed_decode' |
+    'packed_heads_xla' (or, inside an `impl_scope`, the route the
+    scope names).
 
     A pool whose model's page layout is 'latent' (MLA's compressed
     rows, ops/paged_attention.PageLayout) is read by
@@ -190,15 +200,22 @@ def resolve_impl(*, quantized: bool = False,
     was compiled.
 
     A pool whose layout is 'kv_packed' (a page keeps a token's K/V
-    heads side by side in one row: 64-wide heads, models/lfm2.py) is
-    read by ops/paged_attention.packed_decode_attention and
-    packed_chunk_attention, a gather of whole pages split into heads
-    after it, on every backend: 'packed_heads_xla'. It never reaches
-    the decode kernel, which would take the packed row for one head."""
+    heads side by side in one row: 64-wide heads, models/lfm2.py) never
+    reaches this module's decode kernel, which would take the packed
+    row for one head. Its one-token decode read (`decode_pool` given)
+    is, on a TPU, outside a mesh of more than one device (the packed
+    row is one 'head' and cannot be split over `tensor`; a Pallas call
+    is opaque to GSPMD) and for a pool `pallas_packed.kernel_refusal`
+    takes, the kernel of ops/pallas_packed.py, 'packed_decode', which
+    walks the live pages of the live rows; else, and for every S>1
+    chunk, ops/paged_attention._packed_attention, a gather of whole
+    pages kept packed: 'packed_heads_xla'. A scope naming a packed
+    route applies to a packed pool's decode read only, and is an
+    error on any other read."""
     if layout == 'kv_packed':
         if quantized:
             raise ValueError('a packed K/V page pool has no int8 form')
-        return 'packed_heads_xla'
+        return _packed_route(decode_pool)
     if layout == 'latent':
         if quantized:
             raise ValueError('a latent page pool has no int8 form')
@@ -209,6 +226,9 @@ def resolve_impl(*, quantized: bool = False,
         return 'sparse_latent_xla'
     impl = _scoped_impl
     if impl is not None:
+        if impl in _PACKED:
+            raise ValueError(
+                f'paged-attention impl {impl!r} reads packed pools only')
         if impl == 'decode' and quantized:
             raise ValueError(
                 "paged-attention impl 'decode' reads unquantized pools "
@@ -226,6 +246,26 @@ def resolve_impl(*, quantized: bool = False,
             and decode_kernel_refusal(decode_pool) is None):
         return 'decode'
     return 'xla'
+
+
+def _packed_route(decode_pool: Any) -> str:
+    """`resolve_impl` for a packed pool (see there)."""
+    from skypilot_tpu.ops import pallas_packed
+    from skypilot_tpu.ops.attention import _active_mesh
+    if decode_pool is None:
+        return 'packed_heads_xla'
+    impl = _scoped_impl
+    if impl in _PACKED:
+        if impl in _COMPILED and not available():
+            raise ValueError(
+                f'paged-attention impl {impl!r} was selected but '
+                f'cannot run: {unavailable_reason()}')
+        return impl
+    mesh = _active_mesh()
+    if (available() and (mesh is None or mesh.size <= 1)
+            and pallas_packed.kernel_refusal(decode_pool) is None):
+        return 'packed_decode'
+    return 'packed_heads_xla'
 
 
 def lora_fusion_impl(quantized: bool = False) -> Optional[str]:

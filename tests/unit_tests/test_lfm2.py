@@ -386,3 +386,66 @@ def test_a_round_through_the_streamed_projections_equals_xlas(monkeypatch):
 def _interpreted(shapes, streamed_matmul, x, w):
     shapes.append((x.shape, w.shape))
     return streamed_matmul(x, w, interpret=True)
+
+
+def test_decode_rounds_on_the_packed_kernel_route(params):
+    """The rounds of `test_prefill_chunks_then_decode_through_the_cache`
+    (one lane live of four, the dead lanes at length 0) with the decode
+    read on the kernel of ops/pallas_packed.py, in interpret mode: the
+    reference's log-probabilities to `TOL`, as on the gather's route."""
+    from skypilot_tpu.ops import pallas_paged
+    toks = _tokens(61, 61)
+    with pallas_paged.impl_scope('packed_decode_interpret'):
+        got, _ = _through_the_cache(CFG, params, toks, 32, 45)
+    assert _err(got, REF.log_probs(params, FILE_CFG, toks)) < TOL
+
+
+def _served(params, impl):
+    from skypilot_tpu.ops import pallas_paged
+    with pallas_paged.impl_scope(impl):
+        engine = ContinuousBatchingEngine(
+            lfm2.Lfm2(CFG), params, num_slots=2, max_total_len=96,
+            prefill_chunk=32)
+        try:
+            assert engine.attention_impl() == (
+                'packed_heads_xla' if impl == 'xla' else impl)
+            assert engine.chunk_attention_impl() == 'packed_heads_xla'
+            prompts = [_tokens(30 + i, n) for i, n in enumerate((37, 20, 45))]
+            futs = [engine.submit(p, max_new_tokens=10) for p in prompts]
+            return [f.result(timeout=300) for f in futs]
+        finally:
+            engine.stop()
+
+
+def test_the_engine_serves_the_same_tokens_on_the_packed_kernel(params):
+    """Three requests through two slots, so that rounds run with a lane
+    dead and a slot is taken over: the kernel's route (interpret mode)
+    gives the gather's tokens."""
+    kernel = _served(params, 'packed_decode_interpret')
+    assert kernel == _served(params, 'xla')
+    assert len({tuple(row) for row in kernel}) == 3
+
+
+@pytest.mark.parametrize('heads, want', [(4, 'packed_heads_xla'),
+                                         (2, 'packed_decode')],
+                         ids=['rows_of_64', 'rows_of_128'])
+def test_the_engine_names_the_packed_route_its_decode_holds(
+        monkeypatch, heads, want):
+    """`attention_impl()` (/stats, the gauge) where the compiled routes
+    exist: the kernel for a packed row of whole lane tiles (two K/V
+    heads of 64), the gather for lfm2-tiny's row of 64 lanes; a chunk
+    reads by the gather either way."""
+    from skypilot_tpu.ops import pallas_paged
+    cfg = dataclasses.replace(CFG, num_heads=heads, num_kv_heads=2)
+    model = lfm2.Lfm2(cfg)
+    p = nn.meta.unbox(jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))['params'])
+    engine = ContinuousBatchingEngine(model, p, num_slots=2,
+                                      max_total_len=64)
+    try:
+        assert engine.attention_impl() == 'packed_heads_xla'   # this CPU
+        monkeypatch.setattr(pallas_paged, 'available', lambda: True)
+        assert engine.attention_impl() == want
+        assert engine.chunk_attention_impl() == 'packed_heads_xla'
+    finally:
+        engine.stop()

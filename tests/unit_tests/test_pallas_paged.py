@@ -23,6 +23,7 @@ Five contracts:
     XLA engine, and the mesh-sharded (tensor-2 host devices) kernel
     equals the unsharded kernel exactly.
 """
+import contextlib
 import os
 import tempfile
 
@@ -34,6 +35,7 @@ import pytest
 
 from skypilot_tpu.models import lora as lora_lib
 from skypilot_tpu.ops import paged_attention as pa
+from skypilot_tpu.ops import pallas_packed
 from skypilot_tpu.ops import pallas_paged as pp
 
 PAGE, PSEQ, TOTAL, D = 8, 4, 32, 16
@@ -539,6 +541,191 @@ def test_engine_names_the_route_its_decode_holds(monkeypatch, heads,
         assert eng.attention_impl() == want
     finally:
         eng.stop()
+
+
+# -- the decode read of a packed pool (ops/pallas_packed.py) ----------------
+PPAGE, PPSEQ = 16, 160        # `lfm2-24b-a2b-l10`'s rows: 2,560 tokens
+#: Lengths 0 (no copy), 1, a page's edge, the block's edge (1,024 tokens
+#: at 512 lanes, bf16 and float32 alike: 64 and 32 pages of 16 KB and 32
+#: KB against the budget, twice 32 for float32), a whole table, ragged.
+PACKED_LENGTHS = [0, 1, 16, 17, 1023, 1024, 1025, 2560, 0, 300]
+
+
+def _packed_inputs(hq, hkv, d, lengths, dtype, seed=7, by_head=False):
+    """A packed pool [1, pages, 16, hkv * d] whose page 0 (the trash
+    page) is NaN and a table that names real pages only below ceil(
+    length / page), the rest of it page 0, as the engine leaves them.
+    `by_head`: every value of K/V head h is h + 1. Returns (q, k, v,
+    lengths, table) and the pool with the trash page zeroed."""
+    rng = np.random.default_rng(seed)
+    lengths = np.asarray(lengths, np.int32)
+    live = -(-lengths // PPAGE)
+    total = int(live.sum()) + 1
+    width = hkv * d
+    k = rng.standard_normal((1, total, PPAGE, width)).astype(np.float32)
+    v = rng.standard_normal((1, total, PPAGE, width)).astype(np.float32)
+    if by_head:
+        v[...] = np.repeat(np.arange(1, hkv + 1, dtype=np.float32), d)
+    q = jnp.asarray(rng.standard_normal((len(lengths), hq, d)), dtype)
+    perm = iter(rng.permutation(total - 1) + 1)
+    tbl = np.zeros((len(lengths), PPSEQ), np.int32)
+    for row, n in enumerate(live):
+        tbl[row, :n] = [next(perm) for _ in range(n)]
+    k_nan, v_nan = k.copy(), v.copy()
+    k_nan[:, 0] = v_nan[:, 0] = np.nan
+    k[:, 0] = v[:, 0] = 0.0
+    as_pool = lambda x: jnp.asarray(x, dtype)  # noqa: E731
+    return ((q, as_pool(k_nan), as_pool(v_nan), jnp.asarray(lengths),
+             jnp.asarray(tbl)), (as_pool(k), as_pool(v)))
+
+
+def _unpacked_reference(q, k, v, lengths, tbl, hkv):
+    """The float32 gather reference over the same pool unpacked into
+    heads, [hkv, pages, page, d], a row at a time (a row's gathered
+    history alone is 21 MB in float32)."""
+    def unpack(p):
+        p = jnp.asarray(p, jnp.float32)[0]
+        return jnp.moveaxis(p.reshape(*p.shape[:2], hkv, -1), 2, 0)
+
+    one = jax.jit(pa._reference_paged_attention)
+    k, v = unpack(k), unpack(v)
+    return np.concatenate([np.asarray(one(
+        jnp.asarray(q[b:b + 1], jnp.float32), k, v,
+        jnp.maximum(lengths[b:b + 1], 1), tbl[b:b + 1]))
+        for b in range(q.shape[0])])
+
+
+@pytest.mark.parametrize(
+    'hq,hkv,d,dtype,atol',
+    [(32, 8, 64, jnp.float32, ATOL),
+     (32, 8, 64, jnp.bfloat16, 2e-2),
+     (8, 2, 64, jnp.float32, ATOL)],
+    ids=['lfm2_32x8x64', 'lfm2_32x8x64_bf16', 'two_heads_8x2x64'])
+def test_packed_decode_kernel_parity(hq, hkv, d, dtype, atol):
+    """The packed read against the XLA route's `_packed_attention` and
+    the float32 reference over the same pool unpacked: lengths 0, 1, a
+    page's and a block's edges, the whole 2,560-token table and a
+    ragged row. A row of length 0 returns zeros, and no row reads a
+    page past its length: the trash page behind every unused table
+    entry holds NaN."""
+    args, (k, v) = _packed_inputs(hq, hkv, d, PACKED_LENGTHS, dtype)
+    q, _, _, lengths, tbl = args
+    if d * hkv == 512:
+        assert pp.decode_block_pages(k, PPSEQ) * PPAGE == (
+            1024 if dtype == jnp.bfloat16 else 512)
+    out = np.asarray(pallas_packed.packed_decode_kernel(
+        *args, interpret=True), np.float32)
+    assert np.all(np.isfinite(out))
+    live = np.asarray(lengths) > 0
+    assert not np.any(out[~live])
+    xla = np.asarray(pa._packed_attention(
+        q[:, None], k, v, (jnp.maximum(lengths, 1) - 1)[:, None],
+        tbl)[:, 0], np.float32)
+    ref = _unpacked_reference(q, k, v, lengths, tbl, hkv)
+    rtol = atol if atol > ATOL else 0
+    np.testing.assert_allclose(out[live], xla[live], atol=atol, rtol=rtol)
+    np.testing.assert_allclose(out[live], ref[live], atol=atol, rtol=rtol)
+
+
+def test_packed_decode_kernel_keeps_each_head_to_its_lanes():
+    """The block-diagonal split: every value of K/V head h is h + 1, so
+    query head j reads exactly j // group + 1 whatever its weights,
+    and a head set into another's lanes, or a lane kept by the wrong
+    head, shows."""
+    hq, hkv, d = 32, 8, 64
+    args, _ = _packed_inputs(hq, hkv, d, [5, 0, 40], jnp.float32,
+                             by_head=True)
+    out = np.asarray(pallas_packed.packed_decode_kernel(
+        *args, interpret=True))
+    want = np.repeat(np.arange(hkv) + 1.0, hq // hkv)[:, None]
+    np.testing.assert_allclose(out[0], np.broadcast_to(want, (hq, d)),
+                               atol=1e-6)
+    np.testing.assert_allclose(out[2], np.broadcast_to(want, (hq, d)),
+                               atol=1e-6)
+    assert not np.any(out[1])
+
+
+def test_perturbed_packed_decode_kernel_fails_the_pin():
+    """Non-vacuity control for the packed read's pin."""
+    args, (k, v) = _packed_inputs(8, 2, 64, [1, 33, 300], jnp.float32)
+    ref = _unpacked_reference(args[0], k, v, args[3], args[4], 2)
+    good = pallas_packed.packed_decode_kernel(*args, interpret=True)
+    np.testing.assert_allclose(np.asarray(good), ref, atol=ATOL)
+    bad = pallas_packed.packed_decode_kernel(*args, interpret=True,
+                                             perturb=0.5)
+    with pytest.raises(AssertionError):
+        np.testing.assert_allclose(np.asarray(bad), ref, atol=ATOL)
+
+
+@pytest.mark.parametrize('impl', ['packed_heads_xla',
+                                  'packed_decode_interpret'])
+def test_packed_decode_attention_routes_agree(impl):
+    """`packed_decode_attention`, the model's call, on either route:
+    the same values, zeros for a row of length 0 on both."""
+    args, (k, v) = _packed_inputs(16, 4, 64, [0, 7, 33, 0], jnp.float32)
+    q, _, _, lengths, tbl = args
+    scope = (pp.impl_scope(impl) if impl != 'packed_heads_xla'
+             else contextlib.nullcontext())
+    with scope:
+        assert pp.resolve_impl(layout='kv_packed', decode_pool=k) == impl
+        out = np.asarray(pa.packed_decode_attention(q, k, v, lengths, tbl))
+    assert not np.any(out[[0, 3]])
+    ref = _unpacked_reference(q, k, v, lengths, tbl, 4)
+    np.testing.assert_allclose(out[[1, 2]], ref[[1, 2]], atol=ATOL)
+
+
+@pytest.mark.parametrize(
+    'shape,dtype,why',
+    [((1, 9, 16, 512), jnp.bfloat16, None),      # lfm2-24b-a2b-l10
+     ((1, 9, 8, 128), jnp.float32, None),
+     ((1, 9, 16, 64), jnp.float32, '128-lane'),  # lfm2-tiny: 2 x 32
+     ((1, 9, 8, 512), jnp.bfloat16, 'sublane'),
+     ((8, 9, 16, 128), jnp.bfloat16, 'one row'),
+     ((1, 9, 16, 512), jnp.int8, 'dtype')],
+    ids=['lfm2', 'f32', 'narrow_row', 'half_tile_page', 'unpacked',
+         'int8'])
+def test_packed_kernel_refusal_rules(shape, dtype, why):
+    refusal = pallas_packed.kernel_refusal(jax.ShapeDtypeStruct(shape,
+                                                                dtype))
+    assert (refusal is None) == (why is None)
+    if why is not None:
+        assert why in refusal
+
+
+def test_resolve_impl_packed_rules(monkeypatch):
+    """A packed pool's route: the kernel for its one-token decode read
+    on a TPU (faked here), for a shape the kernel takes and outside a
+    mesh of several devices; the gather kept packed for every chunk,
+    off a TPU, for a refused shape and under a tensor mesh."""
+    from skypilot_tpu.parallel import mesh as mesh_lib
+    pool = jax.ShapeDtypeStruct((1, 24576, 16, 512), jnp.bfloat16)
+    narrow = jax.ShapeDtypeStruct((1, 128, 16, 64), jnp.float32)
+    route = lambda p=None: pp.resolve_impl(  # noqa: E731
+        layout='kv_packed', decode_pool=p)
+    assert route(pool) == route() == 'packed_heads_xla'       # the CPU
+    with pytest.raises(ValueError, match='no int8 form'):
+        pp.resolve_impl(layout='kv_packed', quantized=True)
+    monkeypatch.setattr(pp, 'available', lambda: True)
+    assert route(pool) == 'packed_decode'
+    assert route() == 'packed_heads_xla'                      # a chunk
+    assert route(narrow) == 'packed_heads_xla'
+    # Never the 128-wide decode kernel, which the shape alone takes.
+    assert pp.resolve_impl(decode_pool=pool) == 'decode'
+    if len(jax.devices()) >= 2:
+        mesh = mesh_lib.make_mesh(mesh_lib.MeshConfig(tensor=2),
+                                  devices=jax.devices()[:2])
+        with mesh:
+            assert route(pool) == 'packed_heads_xla'
+    # The scope: a packed route for a packed pool's decode read only.
+    with pp.impl_scope('packed_decode_interpret'):
+        assert route(narrow) == 'packed_decode_interpret'
+        assert route() == 'packed_heads_xla'
+        with pytest.raises(ValueError, match='packed pools only'):
+            pp.resolve_impl(decode_pool=pool)
+    monkeypatch.setattr(pp, 'available', lambda: False)
+    with pp.impl_scope('packed_decode'):
+        with pytest.raises(ValueError, match='cannot run'):
+            route(pool)
 
 
 # -- end-to-end engine bit identity (int8 KV + active LoRA) -----------------

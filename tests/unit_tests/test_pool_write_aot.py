@@ -506,6 +506,13 @@ def _lfm2_cache(**leaves):
     return {'layer_0': {'mixer': leaves}}
 
 
+#: What the XLA route's gather of every slot's whole page table makes
+#: at the cell's 128 slots of 160 pages: [20480, 16, 512] (as XLA:TPU
+#: folds it), [128, 160, 16, 512] or [128, 2560, 512].
+FULL_TABLE_GATHER = re.compile(
+    r'= bf16\[(20480,16|128,160,16|128,2560),512\]\S* (gather|fusion)\(')
+
+
 @pytest.mark.parametrize('chunk', [1, LFM2_CHUNK],
                          ids=['decode', 'later_chunk'])
 def test_packed_64_wide_heads_are_read_where_they_lie(compile_for_chip,
@@ -516,9 +523,12 @@ def test_packed_64_wide_heads_are_read_where_they_lie(compile_for_chip,
     write and the read by page compile for the chip with
     `pool_copy_lines` at zero, where GPT-2's [12, ..., 64] pool is
     copied whole (`test_decode_layer_of_64_wide_heads_compiles_as_the_
-    gather`); the read is the route of the packed layout, plain XLA,
-    never the 128-lane decode kernel, which would take the packed row
-    for one head; its values reach the read as heads of 64."""
+    gather`); never the 128-lane decode kernel, which would take the
+    packed row for one head. A decode round's read is the kernel of
+    ops/pallas_packed.py, one call in scope `paged_attention` that takes
+    the pools where they lie, and no gather of the whole tables; a
+    chunk's is the gather of its row's 160 pages, split into heads of
+    64 after."""
     from skypilot_tpu.models import lfm2
     from skypilot_tpu.ops import pallas_paged
     monkeypatch.setattr(pallas_paged, 'available', lambda: True)
@@ -532,6 +542,8 @@ def test_packed_64_wide_heads_are_read_where_they_lie(compile_for_chip,
                                 jnp.bfloat16)
     assert pool.shape == (1, 24576, 16, 512)
     assert pallas_paged.decode_kernel_refusal(pool) is None
+    assert pallas_paged.resolve_impl(layout=layout.kind,
+                                     decode_pool=pool) == 'packed_decode'
     rows = LFM2_SLOTS if chunk == 1 else 1
     fn, donate, avals, cache = _lfm2_program(
         lfm2.Attention(cfg), rows, chunk, prefill=False,
@@ -541,12 +553,74 @@ def test_packed_64_wide_heads_are_read_where_they_lie(compile_for_chip,
     compiled = compile_for_chip(fn, donate, *avals)
     assert pool_copy_lines(compiled, _lfm2_cache(**cache)) == []
     text = compiled.as_text()
-    assert 'tpu_custom_call' not in text
     assert ' dynamic-update-slice(' in text and ' scatter(' not in text
-    # A row's 160 pages gathered whole, [160, 16, 512] a row (the rows'
-    # axis folded away for one), and split into heads of 64 after.
-    assert re.search(rf'= bf16\[({rows},)?160,16,512\]\S* gather\(',
-                     text)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    if chunk == 1:
+        assert len(calls) == 1
+        assert re.search(r'/paged_attention/\S*/packed_decode_attention/',
+                         calls[0]), calls[0][:300]
+        # No page is gathered (the write gathers its page ids only).
+        assert not re.search(r'= bf16\[[0-9,]+\]\S* gather\(', text)
+        assert not FULL_TABLE_GATHER.search(text)
+    else:
+        assert calls == []
+        # A row's 160 pages gathered whole, [160, 16, 512] a row (the
+        # rows' axis folded away for one), and split into heads of 64.
+        assert re.search(rf'= bf16\[({rows},)?160,16,512\]\S* gather\(',
+                         text)
+
+
+def test_the_decode_round_reads_no_whole_page_table(one_chip, monkeypatch):
+    """A decode round of `lfm2-24b-a2b-l10` cut to an attention and a
+    conv layer (dense, a small vocabulary) at the cell's 128 slots of
+    160 pages and its 24,576-page pool, the backend steered to the
+    chip's routes: the attention layer's read is one
+    `packed_decode_attention` call, the program holds no gather or
+    fusion of every slot's whole table (`bf16[20480,16,512]`, which the
+    traced round of the XLA route listed at 0.13 s over a span) and no
+    pool-shaped copy, and the live mask reaches the read as the
+    lengths."""
+    import flax.linen as nn
+    from skypilot_tpu.models import lfm2
+    from skypilot_tpu.ops import pallas_paged
+    monkeypatch.setattr(pallas_paged, 'available', lambda: True)
+    cfg = lfm2.Lfm2Config.l10(layer_types=('full_attention', 'conv'),
+                              vocab_size=1024, kv_total_pages=24576)
+    model = lfm2.Lfm2(cfg)
+    slots, pages = LFM2_SLOTS, LFM2_PAGES_PER_ROW
+    variables = nn.meta.unbox(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32),
+        positions=jnp.zeros((slots, 1), jnp.int32), decode=True,
+        page_indices=jnp.zeros((slots, pages), jnp.int32))))
+
+    def aval(s, dtype=None):
+        return jax.ShapeDtypeStruct(s.shape, dtype or s.dtype,
+                                    sharding=one_chip)
+
+    def step(params, cache, tokens, positions, table, live):
+        logits, mutated = model.apply(
+            {'params': params, 'cache': cache}, tokens,
+            positions=positions, decode=True, page_indices=table,
+            live=live, mutable=['cache'])
+        return mutated['cache'], logits
+
+    rows = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    lowered = jax.jit(step, donate_argnums=(1,)).lower(
+        jax.tree.map(lambda s: aval(s, jnp.bfloat16), variables['params']),
+        jax.tree.map(aval, variables['cache']), rows, rows,
+        jax.ShapeDtypeStruct((slots, pages), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((slots, 1), jnp.bool_, sharding=one_chip))
+    # The live mask is one of the kernel's inputs: the lengths a select.
+    assert 'select' in str(lowered.as_text())
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and '/packed_decode_attention/' in line]
+    assert len(calls) == 1
+    assert not FULL_TABLE_GATHER.search(text)
+    assert pool_copy_lines(compiled, variables['cache']) == []
 
 
 @pytest.mark.parametrize('chunk', [1, LFM2_CHUNK],
